@@ -5,7 +5,7 @@
 .PHONY: verify test bench lint serve-smoke prefix-smoke chaos-smoke \
 	kernel-smoke stats-smoke fleet-smoke observe-smoke elastic-smoke \
 	spec-smoke mem-smoke disagg-smoke cascade-smoke \
-	cascade-decode-smoke tiered-smoke install-hooks
+	cascade-decode-smoke tiered-smoke chip-smoke install-hooks
 
 verify: lint cascade-smoke cascade-decode-smoke tiered-smoke
 	python tools/check_tier1.py
@@ -26,6 +26,15 @@ test:
 
 bench:
 	python bench.py
+
+# Chip smoke (NOT part of verify: it needs a TPU and refuses to start
+# without one): mistral-7b at full width and depth through the sweep,
+# the server, the kernels-vs-dense check and the CLI, on one chip; the
+# last stdout line is {"ok": true, "device": {...}}. `python
+# chip_smoke.py --chips 4` runs the mesh and replica paths instead.
+# No platform is set here: JAX finds the TPU or the script exits non-zero.
+chip-smoke:
+	python chip_smoke.py
 
 # Online-serving smoke: boot the server on the fake backend, push 50
 # requests (incl. duplicate re-asks), assert zero sheds + nonzero dedup

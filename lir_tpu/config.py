@@ -109,12 +109,6 @@ class RuntimeConfig:
     # (capped at the shape count). OFF restores lazy per-shape jit.
     aot_precompile: bool = True       # host-only (plan policy, not shapes)
     precompile_workers: int = 0       # host-only
-    # Persistent XLA compilation cache (utils/compile_cache.py): compiled
-    # executables survive process restarts, so a restarted worker / model
-    # swap / autoscale event deserializes instead of recompiling. None
-    # resolves $LIR_TPU_COMPILE_CACHE then ~/.cache/lir_tpu/xla; the CLI
-    # and bench enable it by default (--no-compile-cache opts out).
-    compile_cache_dir: Optional[str] = None   # host-only
 
     # Cross-request radix prefix cache over the paged KV allocator
     # (models/paged.py + engine/prefix_tree.py). ON: the engine keeps a
@@ -265,12 +259,13 @@ class RuntimeConfig:
     cascade_prefill: bool = True      # cli: --no-cascade-prefill
     # Cascade DECODE (ops/flash_decode trunk variants; DEPLOY.md §1r):
     # on a shared-trunk dispatch, every decode step's trunk-key splits
-    # compute as ONE batched GEMM per kv head against cache row 0's
-    # trunk K/V — the trunk tiles stream from HBM once per step instead
-    # of once per row — and only the per-row suffix splits run the
-    # split-K path; the log-sum-exp merge makes the result BITWISE the
-    # flat kernel's (tests/test_cascade.py pins it, speculative verify
-    # windows ride flash_decode_mq_trunk the same way). Independent of
+    # read their K/V from the FIRST batch block for every block's
+    # queries — the trunk tiles stream from HBM once per step instead
+    # of once per batch block — and only the tail splits read each
+    # block's own rows; it is the flat kernel with another index map,
+    # so the result is the flat kernel's (tests/test_cascade_decode.py
+    # pins it, speculative verify windows ride flash_decode_mq_trunk
+    # the same way). Independent of
     # cascade_prefill: a dense-prefill or paged-warm dispatch dedups its
     # decode too. --no-cascade-decode restores the flat kernels exactly
     # (the flag mirrors into the static ModelConfig, re-keying every

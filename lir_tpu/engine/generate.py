@@ -226,7 +226,7 @@ def greedy_decode_fused(params, cfg: ModelConfig, tokens: jax.Array,
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "max_new", "topk", "prefill_fn",
                                     "return_cache"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_grouped(params, cfg: ModelConfig, prefix: jax.Array,
                                 prefix_mask: jax.Array, sfx: jax.Array,
                                 sfx_mask: jax.Array, group_idx: jax.Array,
@@ -371,7 +371,7 @@ def _paged_prefix(params, cfg: ModelConfig, pool, slot_src: jax.Array,
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
                                     "return_cache", "decode_trunk"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_shared_paged(params, cfg: ModelConfig, pool,
                                      slot_src: jax.Array,
                                      win_start: jax.Array,
@@ -486,7 +486,7 @@ def _cascade_branches(params, cfg: ModelConfig, tcache, trunk_len: int,
                    static_argnames=("cfg", "trunk_len", "max_new_a",
                                     "max_new_b", "topk", "int8_qk",
                                     "return_cache"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_shared_cascade(params, cfg: ModelConfig,
                                        prefix: jax.Array,
                                        prefix_mask: jax.Array,
@@ -534,7 +534,7 @@ def greedy_decode_fused_shared_cascade(params, cfg: ModelConfig,
                    static_argnames=("cfg", "trunk_len", "max_new_a",
                                     "max_new_b", "topk", "int8_qk",
                                     "return_cache"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_shared_cascade_paged(params, cfg: ModelConfig, pool,
                                              slot_src: jax.Array,
                                              win_start: jax.Array,
@@ -580,7 +580,7 @@ def greedy_decode_fused_shared_cascade_paged(params, cfg: ModelConfig, pool,
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "max_new", "topk", "return_cache"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_grouped_paged(params, cfg: ModelConfig, pool,
                                       slot_src: jax.Array,
                                       win_start: jax.Array,
@@ -1054,7 +1054,7 @@ def spec_total_len(bucket: int, sfx_a: int, sfx_b: int, max_new_a: int,
                                     "spec_k", "ngram", "draft_cfg",
                                     "prefill_fn", "return_cache",
                                     "decode_trunk"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_shared_spec(
         params, cfg: ModelConfig, prefix: jax.Array, prefix_mask: jax.Array,
         sfx_a: jax.Array, sfx_a_mask: jax.Array, sfx_b: jax.Array,
@@ -1104,7 +1104,7 @@ def greedy_decode_fused_shared_spec(
                    static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
                                     "spec_k", "ngram", "return_cache",
                                     "decode_trunk"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_shared_paged_spec(
         params, cfg: ModelConfig, pool, slot_src: jax.Array,
         win_start: jax.Array, prefix_mask: jax.Array, rem: jax.Array,
@@ -1288,7 +1288,7 @@ def shared_piggyback_drain(params, cfg: ModelConfig, carry: PiggybackCarry,
                    static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
                                     "prefill_fn", "return_cache",
                                     "decode_trunk"),
-                   donate_argnames=("scratch_cache",))
+                   donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_fused_shared(params, cfg: ModelConfig, prefix: jax.Array,
                                prefix_mask: jax.Array, sfx_a: jax.Array,
                                sfx_a_mask: jax.Array, sfx_b: jax.Array,

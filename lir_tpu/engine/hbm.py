@@ -114,18 +114,31 @@ class OomSignal(BaseException):
         self.err = err
 
 
+def device_bytes_limit() -> Optional[int]:
+    """The first device's reported HBM limit. None ONLY on the CPU
+    backend (host RAM governs there and no budget is derived); on an
+    accelerator a missing ``bytes_limit`` is an error — a governor that
+    silently never engages hides the device."""
+    import jax
+
+    dev = jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit)
+    if dev.platform == "cpu":
+        return None
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no "
+        f"memory_stats()['bytes_limit']; the HBM governor cannot derive "
+        f"a budget (set GovernorConfig.budget_bytes explicitly)")
+
+
 def device_budget_bytes(reserve_frac: float = 0.08) -> Optional[int]:
     """The device's reported HBM limit minus the reserve slack, or None
-    when the backend exposes no memory stats (CPU smoke — host RAM
-    governs and the ladder never engages)."""
-    try:
-        import jax
-
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-    except Exception:  # noqa: BLE001 — no stats, no derived budget
-        return None
-    if not limit:
+    on the CPU backend (CPU smoke — host RAM governs and the ladder
+    never engages)."""
+    limit = device_bytes_limit()
+    if limit is None:
         return None
     return int(limit * (1.0 - reserve_frac))
 
@@ -391,6 +404,13 @@ class HbmGovernor:
         caller's time. The engaged rungs release through the ordinary
         hysteresis walk once pressure clears."""
         self.stats.site("oom_events", site)
+        import jax
+
+        # What the DEVICE says it holds, beside what the ledger says
+        # (on a TPU, bytes_reservable_limit is what program temp regions
+        # may still take — the ledger does not model it).
+        log.warning("device OOM at %s: memory_stats %s; ledger %s", site,
+                    jax.devices()[0].memory_stats(), self.ledger())
         if not self.cfg.enabled:
             return False
         freed = False

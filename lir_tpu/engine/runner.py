@@ -25,6 +25,7 @@ import numpy as np
 from ..config import CascadeConfig, GovernorConfig, RuntimeConfig, SpecConfig
 from ..guard.watchdog import DispatchWatchdog
 from ..models import decoder, paged, quant
+from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CompileStats, FaultStats,
                                GuardStats, KernelStats, PrefixCacheStats,
                                SpecStats, cascade_decode_bytes_saved,
@@ -34,10 +35,24 @@ from . import (compile_plan, generate, hbm, prefix_tree,
                tokens as tok)
 
 
+log = get_logger(__name__)
+
+
 class PiggybackIneligible(RuntimeError):
     """A dispatch can't ride the piggyback chain (layout fallback, memory
     headroom, learned-position ceiling) — the caller dispatches it through
     the plain path instead. Deliberate control flow, never an error."""
+
+
+def _params_span(params: Any) -> int:
+    """Devices the widest-sharded param leaf lives on (1 for host/aval
+    trees and single-device engines)."""
+    n = 1
+    for leaf in jax.tree.leaves(params):
+        sh = getattr(leaf, "sharding", None)
+        if sh is not None:
+            n = max(n, len(sh.device_set))
+    return n
 
 
 def _tail_batch(n: int, cap: int) -> int:
@@ -145,6 +160,25 @@ class ScoringEngine:
         self._spec_pending: List[Any] = []
         self.spec_fault_plan = None
         self.encoder_decoder = encoder_decoder
+        # Static rule for SHARDED engines: a Mosaic (Pallas TPU) kernel
+        # cannot be partitioned by GSPMD — the TPU compiler refuses the
+        # program ("Mosaic kernels cannot be automatically partitioned.
+        # Please wrap the call in a shard_map") — so an engine whose
+        # params span more than one device runs every attention route
+        # dense until the kernels are wrapped in shard_map over the
+        # `model` axis (ROADMAP S3/W2). Decided here, from the params'
+        # own shardings, never by catching the compiler's error.
+        span = _params_span(params)
+        if span > 1:
+            log.info("params span %d devices: Pallas kernels off (fused "
+                     "decode, cascade prefill/decode, flash prefill) — "
+                     "GSPMD cannot partition a Mosaic call", span)
+            self.rt = dataclasses.replace(
+                self.rt, fused_decode=False, cascade_prefill=False,
+                cascade_decode=False)
+            if getattr(cfg, "use_flash_attention", False):
+                self.cfg = cfg = dataclasses.replace(
+                    cfg, use_flash_attention=False)
         # Fused decode kernels are a RUNTIME choice surfaced through the
         # static model config (the decode executables specialize on it):
         # --no-fused-decode restores the dense decode lowering exactly,
@@ -1439,12 +1473,8 @@ class ScoringEngine:
                 # "handoff"; the riding dispatch's own cache (plus
                 # fragmentation slack) must fit what is left.
                 return 1.2 * cache_bytes < headroom
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-            limit = stats.get("bytes_limit")
-        except Exception:  # noqa: BLE001 — no stats, no gate
-            limit = None
-        if not limit:
+        limit = hbm.device_bytes_limit()   # None on the CPU backend only
+        if limit is None:
             return True
         return (quant.param_bytes(self.params) + 2.2 * cache_bytes
                 < 0.92 * limit)

@@ -63,7 +63,10 @@ def _dispatch_with_recovery(engine, call, cost=None):
     the donation chain the failed dispatch may have consumed) and retry
     under DISPATCH_RETRY. KeyboardInterrupt/SystemExit and simulated
     preemptions (BaseException) always propagate — recovery outlives
-    faults, not kills.
+    faults, not kills. A program error (faults.is_program_error: the
+    tracer, lowering or compiler refused the dispatch) propagates too,
+    at once: lazy jit would only compile the same refusal again, four
+    times under back-off.
 
     The call runs under the engine's dispatch WATCHDOG (guard/watchdog):
     ``cost`` is the dispatch's scheduler.bucket_cost() price, and a call
@@ -72,6 +75,7 @@ def _dispatch_with_recovery(engine, call, cost=None):
     Exception, so a HANG flows through exactly this recovery path (one
     deadline lost, then degrade + retry) instead of parking the sweep
     forever."""
+    from ..faults.ladder import is_program_error
     from ..utils.profiling import is_oom_error
 
     wd = getattr(engine, "watchdog", None)
@@ -88,6 +92,8 @@ def _dispatch_with_recovery(engine, call, cost=None):
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception as err:  # noqa: BLE001 — retried below
+        if is_program_error(err):
+            raise
         if is_oom_error(err):
             # Capacity, not transience — the retry/backoff ladder would
             # only re-OOM. Route through the governor: force-engage the
@@ -117,7 +123,8 @@ def _dispatch_with_recovery(engine, call, cost=None):
         engine.degrade_to_lazy()
         out = retry_with_exponential_backoff(
             call, retry_on=(Exception,), config=DISPATCH_RETRY,
-            log=lambda m: log.warning("sweep dispatch retry: %s", m))
+            log=lambda m: log.warning("sweep dispatch retry: %s", m),
+            give_up=is_program_error)
         engine.fault_stats.count("recovered_dispatches")
         return out
 

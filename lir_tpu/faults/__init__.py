@@ -28,7 +28,7 @@ profiling.FaultStats and profiling.GuardStats.
 """
 
 from .breaker import CLOSED, HALF_OPEN, OPEN, CircuitBreaker
-from .ladder import degrade_dispatch
+from .ladder import degrade_dispatch, is_program_error
 from .plan import (KINDS, SITES, FaultPlan, InjectedFault,
                    InjectedPreemption, InjectedReplicaKill, SiteSchedule,
                    corrupt_export_chunks, corrupt_result_nan,
@@ -36,6 +36,7 @@ from .plan import (KINDS, SITES, FaultPlan, InjectedFault,
                    wrap_migrator, wrap_replica, wrap_server, wrap_tiers)
 
 __all__ = [
+    "is_program_error",
     "FaultPlan", "SiteSchedule", "InjectedFault", "InjectedPreemption",
     "InjectedReplicaKill",
     "SITES", "KINDS", "wrap_engine", "wrap_server", "wrap_replica",

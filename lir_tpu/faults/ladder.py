@@ -27,6 +27,37 @@ from typing import Callable, List, Optional, Sequence
 
 from .plan import InjectedPreemption  # noqa: F401  (re-export for callers)
 
+# Status words the XLA/TPU runtime puts on an error that belongs to the
+# PROGRAM it was handed, not to the moment it ran: the compiler refused
+# it. Device trouble reads UNAVAILABLE / ABORTED / DEADLINE_EXCEEDED /
+# DATA_LOSS / INTERNAL-without-these, and keeps every recovery it had.
+_REFUSED_BY_COMPILER = ("INVALID_ARGUMENT", "UNIMPLEMENTED",
+                        "Mosaic failed to compile", "failed to compile",
+                        "Compilation failure")
+
+
+def is_program_error(err: BaseException) -> bool:
+    """True when a dispatch failed because its program is wrong — the
+    tracer, the lowering or the compiler refused it — rather than
+    because the device hiccuped. Such a failure is deterministic:
+    retrying recompiles the same refusal, degrading to lazy jit compiles
+    it again, and bisecting makes every row a "poison row". Every
+    recovery site (sweep dispatch, the serve supervisors, the ladder
+    below, and their retry loops through ``give_up=``) asks this first
+    and lets the error surface.
+
+    ValueError / TypeError / NotImplementedError are what JAX tracing
+    and the Pallas TPU lowering raise; compiler-side refusals arrive as
+    the runtime's error class carrying one of ``_REFUSED_BY_COMPILER``.
+    Injected faults (RuntimeError), watchdog stalls and OOMs are none of
+    these and recover as before."""
+    if isinstance(err, (ValueError, TypeError, NotImplementedError)):
+        return True
+    if type(err).__name__ in ("JaxRuntimeError", "XlaRuntimeError"):
+        msg = str(err)
+        return any(tag in msg for tag in _REFUSED_BY_COMPILER)
+    return False
+
 
 def degrade_dispatch(score_fn: Callable[[list], List[dict]],
                      rows: Sequence,
@@ -38,7 +69,10 @@ def degrade_dispatch(score_fn: Callable[[list], List[dict]],
     for rows that fail even in a batch of one.
 
     KeyboardInterrupt/SystemExit/InjectedPreemption always propagate —
-    the ladder recovers work, it does not resist being killed.
+    the ladder recovers work, it does not resist being killed. So does a
+    program error (:func:`is_program_error`): a program the compiler
+    refuses fails for every subset, and bisecting it would only report
+    each innocent row as poison.
     """
     rows = list(rows)
     out: List[Optional[dict]] = [None] * len(rows)
@@ -49,6 +83,8 @@ def degrade_dispatch(score_fn: Callable[[list], List[dict]],
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as err:  # noqa: BLE001 — bisect decides
+            if is_program_error(err):
+                raise
             if hi - lo == 1:
                 if log is not None:
                     log(f"poison row isolated at index {lo}: {err!r}")

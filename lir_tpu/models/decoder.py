@@ -262,16 +262,28 @@ def _attention_cached_int8(q: jax.Array, kq, ks, vq, vs,
     return out.astype(q.dtype).reshape(B, S, H * hd)
 
 
-def _fused_decode_ok(cfg: ModelConfig, S: int, fused_ctx) -> bool:
+def _decode_kernels_lower(batch: int) -> bool:
+    """Where the fused decode kernels lower. On the TPU backend the
+    static rule is ``batch % 8 == 0``: ops/flash_decode's K/V blocks
+    carry whole sublane groups of cache rows (the cache's (B, hd) minor
+    pair is the TPU's register tile), so the power-of-two tail batches
+    below one group (1, 2, 4) decode dense. CPU runs them only under the
+    interpreter test hook, at any batch."""
+    if jax.default_backend() == "tpu":
+        return batch % 8 == 0
+    return FUSED_DECODE_INTERPRET_ON_CPU
+
+
+def _fused_decode_ok(cfg: ModelConfig, batch: int, S: int,
+                     fused_ctx) -> bool:
     """Static routing decision for the fused flash-decode kernel: a single-
-    query decode step, a non-int8 cache, the flag on, and a backend that
-    lowers Pallas (TPU; CPU only under the interpreter test hook)."""
+    query decode step, a non-int8 cache, the flag on, and a backend and
+    batch the kernel lowers for (:func:`_decode_kernels_lower`)."""
     return (cfg.fused_decode
             and not cfg.kv_cache_int8
             and fused_ctx is not None
             and S == 1
-            and (jax.default_backend() == "tpu"
-                 or FUSED_DECODE_INTERPRET_ON_CPU))
+            and _decode_kernels_lower(batch))
 
 
 def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -289,9 +301,10 @@ def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     ``trunk_len`` > 0 (a shared-trunk dispatch with cascade decode on)
     routes through the trunk-aware variant: the cache's leading
     ``trunk_len`` slots are identical across rows, so the trunk splits
-    read K/V from cache row 0 ONCE per kv head for all rows' queries —
-    bitwise the flat kernel (the split ladder, per-split arithmetic and
-    merge are unchanged; only the trunk tiles' HBM reads dedup)."""
+    read K/V from the first batch block ONCE per kv head for all rows'
+    queries — the flat kernel's arithmetic exactly (the split ladder,
+    per-split arithmetic and merge are unchanged; only the trunk tiles'
+    HBM reads dedup)."""
     from ..ops.flash_decode import flash_decode, flash_decode_trunk
 
     B, S, H, hd = q.shape
@@ -312,7 +325,8 @@ def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, S, H * hd)
 
 
-def _fused_decode_mq_ok(cfg: ModelConfig, S: int, fused_ctx) -> bool:
+def _fused_decode_mq_ok(cfg: ModelConfig, batch: int, S: int,
+                        fused_ctx) -> bool:
     """Static routing decision for the MULTI-QUERY fused decode kernel
     (the speculative verify window): same gates as :func:`_fused_decode_ok`
     but for a window of S > 1 teacher-forced queries carrying per-query
@@ -322,8 +336,7 @@ def _fused_decode_mq_ok(cfg: ModelConfig, S: int, fused_ctx) -> bool:
             and fused_ctx is not None
             and S > 1
             and getattr(fused_ctx[0], "ndim", 1) == 2
-            and (jax.default_backend() == "tpu"
-                 or FUSED_DECODE_INTERPRET_ON_CPU))
+            and _decode_kernels_lower(batch))
 
 
 def _attention_cached_flash_mq(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -470,10 +483,10 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
                                           (0, cache_index, 0, 0))
             cv = lax.dynamic_update_slice(cv, v_t.astype(cv.dtype),
                                           (0, cache_index, 0, 0))
-            if _fused_decode_ok(cfg, S, fused_ctx):
+            if _fused_decode_ok(cfg, q.shape[0], S, fused_ctx):
                 attn = _attention_cached_flash(q, ck, cv, cfg, fused_ctx,
                                                trunk_len=trunk_len)
-            elif _fused_decode_mq_ok(cfg, S, fused_ctx):
+            elif _fused_decode_mq_ok(cfg, q.shape[0], S, fused_ctx):
                 attn = _attention_cached_flash_mq(q, ck, cv, cfg, fused_ctx,
                                                   trunk_len=trunk_len)
             else:

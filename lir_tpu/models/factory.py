@@ -106,12 +106,17 @@ def load_engine(
     spec_config=None,
     governor_config=None,
     cascade_config=None,
+    devices=None,
 ) -> ScoringEngine:
     """Build a ready ScoringEngine from a local HF checkpoint directory.
 
     With `cache_root`, the converted pytree is cached via models.cache: the
     HF-layout conversion happens once per model ever, subsequent loads
-    restore orbax buffers directly (sharded, when a mesh is given)."""
+    restore orbax buffers directly (sharded, when a mesh is given).
+    ``devices`` pins the engine: the mesh is built over them, or (no
+    mesh) the params are committed to the first — how each in-process
+    serving replica gets a chip of its own
+    (parallel.sharding.replica_devices)."""
     import jax
     import transformers
 
@@ -182,7 +187,7 @@ def load_engine(
                 f"encoder-decoder checkpoints ({model_dir.name}); use a "
                 f"DATAxMODEL mesh (e.g. "
                 f"{mesh_cfg.data}x{mesh_cfg.model * mesh_cfg.seq})")
-        mesh = sharding.build_mesh(mesh_cfg)
+        mesh = sharding.build_mesh(mesh_cfg, devices)
         params = sharding.shard_params(params, cfg, mesh)
         if mesh_cfg.seq > 1:
             # Long-context: engine prefills seq-sharded (ring attention)
@@ -192,6 +197,11 @@ def load_engine(
             "sharded %s over mesh %s", model_dir.name,
             dict(zip(mesh.axis_names, mesh.devices.shape)),
         )
+
+    elif devices is not None:
+        # Committed placement: every dispatch of this engine follows its
+        # params to this device instead of the process default.
+        params = jax.device_put(params, devices[0])
 
     log.info("loaded %s (%s, %s)", model_dir.name,
              "enc-dec" if encdec else "decoder", np.dtype(dtype).name)
@@ -219,7 +229,7 @@ def engine_factory(
     ``checkpoint_root/<org>__<name>`` or ``checkpoint_root/<name>``."""
     checkpoint_root = Path(checkpoint_root)
 
-    def factory(model_name: str) -> ScoringEngine:
+    def factory(model_name: str, devices=None) -> ScoringEngine:
         candidates = [
             checkpoint_root / model_name.replace("/", "__"),
             checkpoint_root / model_name.split("/")[-1],
@@ -234,7 +244,8 @@ def engine_factory(
                                    kv_cache_int8=kv_cache_int8,
                                    spec_config=spec_config,
                                    governor_config=governor_config,
-                                   cascade_config=cascade_config)
+                                   cascade_config=cascade_config,
+                                   devices=devices)
         raise FileNotFoundError(
             f"no local checkpoint for {model_name} under {checkpoint_root} "
             f"(tried {[str(c) for c in candidates]})"

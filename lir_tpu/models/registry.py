@@ -80,11 +80,11 @@ class ModelConfig:
     fused_decode: bool = True
 
     # Cascade decode (ops/flash_decode.flash_decode_trunk): shared-trunk
-    # dispatches compute the trunk's split-K decode partials ONCE per kv
-    # head for ALL rows' queries (the trunk K/V tiles stream from HBM
-    # once per step instead of once per row), per-row suffix splits run
-    # the flat kernel's path over only the tail, merged by ops/lse —
-    # bitwise the flat kernel by construction. Static so the decode
+    # dispatches read the trunk splits' K/V from the first batch block
+    # for ALL blocks' queries (the trunk K/V tiles stream from HBM once
+    # per step instead of once per batch block); tail splits read each
+    # block's own rows, merged by ops/lse — the flat kernel with another
+    # index map, so the flat kernel's result by construction. Static so the decode
     # executables specialize on it; mirrored from RuntimeConfig.
     # cascade_decode / --no-cascade-decode, which restores the flat
     # kernel exactly (the trunk extent is then pinned to 0).

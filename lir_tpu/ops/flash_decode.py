@@ -1,7 +1,7 @@
 """Pallas fused flash-decode: single-query attention over the KV cache.
 
 The decode phase of the scoring step is where the 36% MFU plateau lives
-(BENCH_r02-r05): each greedy step attends ONE query per row over the whole
+(BENCH_r05): each greedy step attends ONE query per row over the whole
 cache, and XLA's dense lowering materializes the (B, H, 1, T) score row,
 the fp32 softmax, and the probability row as separate HBM round-trips
 between three kernels. This kernel is the Flash-Decoding treatment (Dao
@@ -27,7 +27,10 @@ split width is the largest divisor of T no wider than the requested
 block (preferring sublane-aligned multiples of 8), falling back to a
 single full-width split — every cache extent the bucket ladder plans
 (bucket + suffix + decode budget) therefore lowers without padding or
-out-of-bounds tail blocks. ``interpret=True`` runs the kernel in the
+out-of-bounds tail blocks. The batch axis rides in blocks of 8 rows (the
+TPU's sublane tile — see ``_decode_kernel``); one kernel and one
+pallas_call sit behind all four entry points, and all of them compile
+for a described v5e in tests/test_tpu_compile.py. ``interpret=True`` runs the kernel in the
 Pallas interpreter so tier-1 exercises it on CPU (tests/test_kernels.py);
 production CPU runs keep the dense path (models/decoder.FUSED_DECODE_
 INTERPRET_ON_CPU is the test hook, mirroring FLASH_INTERPRET_ON_CPU).
@@ -41,7 +44,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import DEFAULT_BLOCK_K
 from .lse import merge_partials
@@ -62,31 +64,178 @@ def pick_split(total: int, want: int = DEFAULT_BLOCK_K) -> int:
     return int(total)
 
 
-def _decode_kernel(qpos_ref, slope_ref, mask_ref, kpos_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_ref, l_ref, *, sm_scale: float,
-                   alibi: bool, n_groups: int):
-    b = pl.program_id(0)
-    kh = pl.program_id(1)
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bs, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (G, bs)
-    kmask = mask_ref[0, 0] > 0                            # (bs,)
-    kp = kpos_ref[0, 0]                                   # (bs,)
-    qp = qpos_ref[b, 0]
+# Sublane edge of the TPU's (8, 128) register tile: the cache's batch axis
+# is the second-minor dimension of a K/V block, so blocks carry whole
+# sublane groups of rows (Mosaic refuses a block whose second-minor
+# extent is neither a multiple of 8 nor the array's own).
+SUBLANE = 8
+# fp32 score-tile budget per grid program (elements): the (rows, keys)
+# tile is BATCH_BLOCK times wider than the useful scores (see
+# _decode_kernel), so wide query groups (falcon MQA, G = 71) take a
+# narrower key split to stay inside the scoped VMEM limit.
+_SCORE_TILE_ELEMS = 256 * 1024
+# Masked cache slots ride the key-position array as this sentinel (above
+# any real position), so one int32 operand carries validity AND position.
+_MASKED_POS = 1 << 30
+
+
+def batch_block(batch: int) -> int:
+    """Rows of the cache's batch axis one K/V block carries: one sublane
+    group when the batch divides into them, else a single row — a block
+    only the interpreter takes (the TPU gate requires ``batch % 8 == 0``,
+    see models/decoder._decode_kernels_lower)."""
+    return SUBLANE if batch % SUBLANE == 0 else 1
+
+
+def decode_split(total: int, batch: int, n_groups: int,
+                 block_k: int = DEFAULT_BLOCK_K) -> int:
+    """Key-split width every decode entry point uses for a (cache extent,
+    batch, query-group) shape — chosen from those alone (never from the
+    verify-window length), so the single- and multi-query kernels and
+    their trunk variants share one split ladder and their partials line
+    up split for split."""
+    bb = batch_block(batch)
+    cap = max(SUBLANE, _SCORE_TILE_ELEMS // (bb * bb * n_groups))
+    return pick_split(total, min(int(block_k), cap))
+
+
+def _decode_kernel(rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref,
+                   q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
+                   sm_scale: float, alibi: bool):
+    """One (kv head, key split, batch block, window query) program.
+
+    The cache block is (split, bb, hd): ``bb`` batch rows ride the
+    sublanes under every key slot, which is the cache's own (B, hd)
+    minor pair — no relayout in HBM or VMEM. Collapsing the two leading
+    axes is free (bb is a whole sublane group), giving a (split*bb, hd)
+    key matrix whose row ``t*bb + b`` is row b's key t. The block's
+    queries, rows ordered (b, g), contract against ALL of it in one MXU
+    matmul; a score is kept only where the key's batch row is the
+    query's own (the block diagonal), everything else masks to -inf
+    exactly like an invalid slot. The bb-fold padding costs MXU work the
+    decode step has to spare, and buys plain 2-D matmuls and lane
+    reductions — nothing the TPU compiler has to shuffle across
+    sublanes."""
+    # MXU operands stay in the cache dtype (bf16 on the chip: one MXU
+    # pass, as the dense path's einsums run); accumulation is fp32.
+    kb = k_ref[0]                                         # (bs, bb, hd)
+    bs, bb, hd = kb.shape
+    k = kb.reshape(bs * bb, hd)
+    v = v_ref[0].reshape(bs * bb, hd)
+    q = q_ref[0, 0, 0].astype(k.dtype)                    # (R, hd)
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+    kp = kpos_ref[0, 0]                                   # (1, bs*bb)
+    qp = qpos_ref[0, 0]                                   # (R, 1)
     if alibi:
-        # Per-head slopes for this kv head's query group (h = kh*G + g).
-        slope = slope_ref[pl.ds(kh * n_groups, n_groups), 0]  # (G,)
-        s = s + slope[:, None] * kp.astype(jnp.float32)[None, :]
-    valid = (kmask & (kp <= qp))[None, :]                 # (1, bs)
+        # Per-row slope of this kv head's query group (h = kh*G + g).
+        s = s + slope_ref[0] * kp.astype(jnp.float32)
+    valid = (laneb_ref[...] == rowb_ref[...]) & (kp <= qp)  # (R, bs*bb)
     s = jnp.where(valid, s, -jnp.inf)
 
-    m = s.max(axis=-1)                                    # (G,)
-    p = jnp.exp(s - m[:, None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)                # all-masked split
-    o_ref[0, 0, 0] = jnp.dot(p, v, preferred_element_type=jnp.float32)
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = p.sum(axis=-1)
+    m = s.max(axis=-1, keepdims=True)                     # (R, 1)
+    p = jnp.exp(s - m)
+    p = jnp.where(valid, p, 0.0)                          # all-masked split
+    o_ref[0, 0, 0, 0] = jnp.dot(p.astype(v.dtype), v,
+                                preferred_element_type=jnp.float32)
+    m_ref[0, 0, 0, 0] = m
+    l_ref[0, 0, 0, 0] = p.sum(axis=-1, keepdims=True)
+
+
+def _decode_call(q, k, v, q_positions, key_mask, key_positions,
+                 alibi_slopes, trunk_len: int, block_k: int,
+                 interpret: bool):
+    """The one pallas_call behind all four entry points. ``q``:
+    (B, S, H, hd), ``q_positions``: (B, S). Grid (K, T/split, B/bb, S):
+    the window-query axis is innermost and the batch-block axis next, so
+    consecutive programs that name the same K/V block skip its DMA —
+    every query of a verify window reuses the block its row already
+    loaded, and for the leading ``trunk_len`` slots (identical in every
+    row by the cascade contract) every batch block names block 0: the
+    trunk's K/V leaves HBM once per (kv head, split), not once per
+    row."""
+    B, S, H, hd = q.shape
+    K, T = k.shape[0], k.shape[1]
+    G = H // K
+    bb = batch_block(B)
+    nB = B // bb
+    R = bb * G
+    sm_scale = 1.0 / np.sqrt(hd)
+    alibi = alibi_slopes is not None
+    if key_positions is None:
+        key_positions = jnp.maximum(jnp.cumsum(key_mask, axis=-1) - 1, 0)
+    kpos = jnp.where(jnp.asarray(key_mask, jnp.int32) > 0,
+                     jnp.asarray(key_positions, jnp.int32), _MASKED_POS)
+    split = decode_split(T, B, G, block_k)
+    n_splits = T // split
+    nt = max(0, min(int(trunk_len), T - 1)) // split
+    L = split * bb
+
+    # Lane order of a split's keys is (slot, batch row); one full-extent
+    # (1, L) row per (batch block, split), so any split width lowers.
+    kpos = (kpos.reshape(nB, bb, n_splits, split).transpose(0, 2, 3, 1)
+            .reshape(nB, n_splits, 1, L))
+    # Row order of a block's queries is (batch row, group).
+    qg = (q.reshape(nB, bb, S, K, G, hd).transpose(0, 3, 2, 1, 4, 5)
+          .reshape(nB, K, S, R, hd))
+    qpos = jnp.broadcast_to(
+        q_positions.astype(jnp.int32).reshape(nB, bb, S).transpose(0, 2, 1)
+        [..., None], (nB, S, bb, G)).reshape(nB, S, R, 1)
+    rowb = jnp.repeat(jnp.arange(bb, dtype=jnp.int32), G).reshape(R, 1)
+    laneb = jnp.tile(jnp.arange(bb, dtype=jnp.int32), split).reshape(1, L)
+    if alibi:
+        slopes = jnp.broadcast_to(
+            jnp.asarray(alibi_slopes, jnp.float32).reshape(K, 1, G),
+            (K, bb, G)).reshape(K, R, 1)
+    else:
+        slopes = jnp.zeros((K, R, 1), jnp.float32)
+
+    if nt:
+        def kv_index(h, j, i, s):
+            return (h, j, jnp.where(j < nt, 0, i), 0)
+    else:
+        def kv_index(h, j, i, s):
+            return (h, j, i, 0)
+
+    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
+                               alibi=alibi)
+    f32 = jnp.float32
+    o_p, m_p, l_p = pl.pallas_call(
+        kernel,
+        grid=(K, n_splits, nB, S),
+        in_specs=[
+            pl.BlockSpec((R, 1), lambda h, j, i, s: (0, 0)),
+            pl.BlockSpec((1, L), lambda h, j, i, s: (0, 0)),
+            pl.BlockSpec((1, 1, R, 1), lambda h, j, i, s: (i, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, L), lambda h, j, i, s: (i, j, 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda h, j, i, s: (h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, R, hd),
+                         lambda h, j, i, s: (i, h, s, 0, 0)),
+            pl.BlockSpec((1, split, bb, hd), kv_index),
+            pl.BlockSpec((1, split, bb, hd), kv_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, 1, R, hd),
+                         lambda h, j, i, s: (i, h, s, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, R, 1),
+                         lambda h, j, i, s: (i, h, s, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, R, 1),
+                         lambda h, j, i, s: (i, h, s, j, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((nB, K, S, n_splits, R, hd), f32),
+            jax.ShapeDtypeStruct((nB, K, S, n_splits, R, 1), f32),
+            jax.ShapeDtypeStruct((nB, K, S, n_splits, R, 1), f32),
+        ],
+        interpret=interpret,
+    )(rowb, laneb, qpos, kpos, slopes, qg, k, v)
+
+    # Log-sum-exp combine across splits (ops/lse.merge_partials, shared
+    # with the cascade-prefill merge): renormalize each partial by the
+    # global row max, then sum the weighted accumulators and weights. A
+    # fully-masked split carries m = -inf and weight exactly 0.
+    out = merge_partials(o_p, m_p[..., 0], l_p[..., 0], axis=3)
+    out = out.reshape(nB, K, S, bb, G, hd).transpose(0, 3, 2, 1, 4, 5)
+    return out.reshape(B, S, H, hd).astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -109,110 +258,13 @@ def flash_decode(
     mask); defaults to the mask's own cumsum. ``alibi_slopes``: optional
     (H,) per-head slopes (bloom) added as ``slope * key_position``.
 
-    Grid is (B, K, T / split): each program owns one key split in VMEM and
+    Each grid program owns one key split of one batch block in VMEM and
     emits a partial (o, m, l); the final output is the log-sum-exp
     combination of the splits — exact attention, any split count.
     """
-    B, H, hd = q.shape
-    K, T = k.shape[0], k.shape[1]
-    G = H // K
-    sm_scale = 1.0 / np.sqrt(hd)
-    alibi = alibi_slopes is not None
-    if key_positions is None:
-        key_positions = jnp.maximum(jnp.cumsum(key_mask, axis=-1) - 1, 0)
-    key_mask = jnp.asarray(key_mask, jnp.int32)
-    key_positions = jnp.asarray(key_positions, jnp.int32)
-    if alibi_slopes is None:
-        slopes = jnp.zeros((H, 1), jnp.float32)
-    else:
-        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(H, 1)
-
-    split = pick_split(T, block_k)
-    n_splits = T // split
-    qg = q.reshape(B, K, G, hd)
-
-    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                               alibi=alibi, n_groups=G)
-    f32 = jnp.float32
-    o_p, m_p, l_p = pl.pallas_call(
-        kernel,
-        grid=(B, K, n_splits),
-        in_specs=[
-            # Per-row query position: whole (B, 1) array in SMEM (TPU
-            # lowering wants full-array blocks for tiny scalars — same
-            # pattern as flash_attention's first-valid index).
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            # Per-head ALiBi slopes, whole (H, 1) array in SMEM.
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            # Key mask / positions as (B, 1, T): one split per program.
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j)),
-            # Query group (1, 1, G, hd); cache splits (1, split, 1, hd).
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda b, h, j: (h, j, b, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda b, h, j: (h, j, b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, hd), lambda b, h, j: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, n_splits, G, hd), f32),
-            jax.ShapeDtypeStruct((B, K, n_splits, G), f32),
-            jax.ShapeDtypeStruct((B, K, n_splits, G), f32),
-        ],
-        interpret=interpret,
-    )(q_positions[:, None].astype(jnp.int32), slopes,
-      key_mask[:, None, :], key_positions[:, None, :], qg, k, v)
-
-    # Log-sum-exp combine across splits (ops/lse.merge_partials, shared
-    # with the cascade-prefill merge): renormalize each partial by the
-    # global row max, then sum the weighted accumulators and weights. A
-    # fully-masked split carries m = -inf and weight exactly 0.
-    out = merge_partials(o_p, m_p, l_p, axis=2)           # (B, K, G, hd)
-    return out.reshape(B, H, hd).astype(q.dtype)
-
-
-def _trunk_decode_kernel(qpos_ref, slope_ref, mask_ref, kpos_ref, q_ref,
-                         k_ref, v_ref, o_ref, m_ref, l_ref, *,
-                         sm_scale: float, alibi: bool, n_groups: int):
-    """Trunk-split sibling of :func:`_decode_kernel` for shared-prefix
-    cascade decode: every row of a shared dispatch attends the SAME
-    trunk KV (the cascade cache broadcasts the trunk into every batch
-    row), so a split that lies fully inside the trunk reads its K/V
-    block from cache row 0 ONLY — once per (kv head, split) instead of
-    once per row — and batches ALL rows' queries into one MXU GEMM.
-    Per-(row, group) arithmetic is exactly the single-row kernel's (the
-    batched dot never mixes rows, masks/positions stay per-row), which
-    is what keeps the merged output bitwise the flat kernel's."""
-    kh = pl.program_id(0)
-    q = q_ref[0].astype(jnp.float32) * sm_scale           # (B, G, hd)
-    B, G, hd = q.shape
-    k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bs, hd) row 0
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q.reshape(B * G, hd), k.T,
-                preferred_element_type=jnp.float32)       # (B*G, bs)
-    s = s.reshape(B, G, -1)
-    kmask = mask_ref[0] > 0                               # (B, bs)
-    kp = kpos_ref[0]                                      # (B, bs)
-    qp = qpos_ref[:, 0]                                   # (B,)
-    if alibi:
-        slope = slope_ref[pl.ds(kh * n_groups, n_groups), 0]  # (G,)
-        s = s + slope[None, :, None] * kp.astype(jnp.float32)[:, None, :]
-    valid = (kmask & (kp <= qp[:, None]))[:, None, :]     # (B, 1, bs)
-    s = jnp.where(valid, s, -jnp.inf)
-
-    m = s.max(axis=-1)                                    # (B, G)
-    p = jnp.exp(s - m[..., None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)                # all-masked split
-    o = jnp.dot(p.reshape(B * G, -1), v,
-                preferred_element_type=jnp.float32)
-    o_ref[0, 0] = o.reshape(B, G, hd)
-    m_ref[0, 0] = m
-    l_ref[0, 0] = p.sum(axis=-1)
+    return _decode_call(q[:, None], k, v, q_positions[:, None], key_mask,
+                        key_positions, alibi_slopes, 0, block_k,
+                        interpret)[:, 0]
 
 
 @functools.partial(jax.jit,
@@ -232,154 +284,17 @@ def flash_decode_trunk(
     Arguments as :func:`flash_decode` plus static ``trunk_len``: the
     leading cache extent whose KV is bitwise-identical across the batch
     (the shared trunk a cascade/shared dispatch broadcast or prefilled
-    into every row). The split ladder is the flat kernel's exactly —
-    ``pick_split(T)`` over the WHOLE cache extent — but the splits that
-    lie fully inside the trunk run as one batched GEMM per kv head
-    against row 0's K/V (HBM loads the trunk tiles once per step, not
-    once per row), while the tail splits run the unmodified per-row
-    kernel. The two partial sets concatenate in original split order
-    and merge through the same :func:`~lir_tpu.ops.lse.merge_partials`
-    reduction, so the result is BITWISE the flat kernel's (pinned by
-    tests/test_cascade_decode) — trunk dedup is a pure HBM-traffic
-    lever. Per step and layer it saves ``2 * K * nt*split * hd *
-    itemsize * (B - 1)`` trunk bytes, nt the trunk split count.
+    into every row). Same kernel, same split ladder, same per-split
+    arithmetic and merge as the flat entry point; the only difference is
+    the K/V index map, which sends every batch block to block 0 for the
+    splits that lie fully inside the trunk, so those tiles are fetched
+    once per (kv head, split) instead of once per batch block. Per step
+    and layer it saves ``2 * K * nt*split * hd * itemsize * (B - bb)``
+    trunk bytes, nt the trunk split count and bb the batch block.
     """
-    B, H, hd = q.shape
-    K, T = k.shape[0], k.shape[1]
-    G = H // K
-    split = pick_split(T, block_k)
-    nt = max(0, min(int(trunk_len), T - 1)) // split
-    if nt == 0:
-        # No full split fits inside the trunk: the flat kernel verbatim.
-        return flash_decode(q, k, v, q_positions, key_mask, key_positions,
-                            alibi_slopes, block_k, interpret)
-    sm_scale = 1.0 / np.sqrt(hd)
-    alibi = alibi_slopes is not None
-    if key_positions is None:
-        key_positions = jnp.maximum(jnp.cumsum(key_mask, axis=-1) - 1, 0)
-    key_mask = jnp.asarray(key_mask, jnp.int32)
-    key_positions = jnp.asarray(key_positions, jnp.int32)
-    if alibi_slopes is None:
-        slopes = jnp.zeros((H, 1), jnp.float32)
-    else:
-        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(H, 1)
-
-    n_splits = T // split
-    qg = q.reshape(B, K, G, hd)
-    f32 = jnp.float32
-    qpos2 = q_positions[:, None].astype(jnp.int32)
-
-    # Trunk leg: grid (K, nt); K/V blocks index row 0 only — the dedup.
-    kernel_t = functools.partial(_trunk_decode_kernel, sm_scale=sm_scale,
-                                 alibi=alibi, n_groups=G)
-    o_t, m_t, l_t = pl.pallas_call(
-        kernel_t,
-        grid=(K, nt),
-        in_specs=[
-            pl.BlockSpec(index_map=lambda h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(index_map=lambda h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, B, split), lambda h, j: (0, 0, j)),
-            pl.BlockSpec((1, B, split), lambda h, j: (0, 0, j)),
-            pl.BlockSpec((1, B, G, hd), lambda h, j: (h, 0, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda h, j: (h, j, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda h, j: (h, j, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, B, G, hd), lambda h, j: (h, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, B, G), lambda h, j: (h, j, 0, 0)),
-            pl.BlockSpec((1, 1, B, G), lambda h, j: (h, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, nt, B, G, hd), f32),
-            jax.ShapeDtypeStruct((K, nt, B, G), f32),
-            jax.ShapeDtypeStruct((K, nt, B, G), f32),
-        ],
-        interpret=interpret,
-    )(qpos2, slopes, key_mask[None], key_positions[None],
-      qg.transpose(1, 0, 2, 3), k, v)
-
-    # Suffix leg: the unmodified per-row kernel over only the tail
-    # splits (index maps offset by nt — no cache slicing or copies).
-    ns = n_splits - nt
-    kernel_s = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                                 alibi=alibi, n_groups=G)
-    o_s, m_s, l_s = pl.pallas_call(
-        kernel_s,
-        grid=(B, K, ns),
-        in_specs=[
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j + nt)),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j + nt)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd),
-                         lambda b, h, j: (h, j + nt, b, 0)),
-            pl.BlockSpec((1, split, 1, hd),
-                         lambda b, h, j: (h, j + nt, b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, hd), lambda b, h, j: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, ns, G, hd), f32),
-            jax.ShapeDtypeStruct((B, K, ns, G), f32),
-            jax.ShapeDtypeStruct((B, K, ns, G), f32),
-        ],
-        interpret=interpret,
-    )(qpos2, slopes, key_mask[:, None, :], key_positions[:, None, :],
-      qg, k, v)
-
-    # Concatenate in original split order, then the flat kernel's merge:
-    # every partial equals the flat kernel's for its split, so the
-    # reduction — and the output — are bitwise-identical.
-    o_p = jnp.concatenate([o_t.transpose(2, 0, 1, 3, 4), o_s], axis=2)
-    m_p = jnp.concatenate([m_t.transpose(2, 0, 1, 3), m_s], axis=2)
-    l_p = jnp.concatenate([l_t.transpose(2, 0, 1, 3), l_s], axis=2)
-    out = merge_partials(o_p, m_p, l_p, axis=2)           # (B, K, G, hd)
-    return out.reshape(B, H, hd).astype(q.dtype)
-
-
-def _decode_kernel_mq(qpos_ref, slope_ref, mask_ref, kpos_ref, q_ref, k_ref,
-                      v_ref, o_ref, m_ref, l_ref, *, sm_scale: float,
-                      alibi: bool, n_groups: int):
-    """Multi-query sibling of :func:`_decode_kernel` for the speculative
-    verify pass: S teacher-forced queries per row, each with its OWN
-    mask-aware position, reduced with exactly the single-query kernel's
-    per-row ops — every (query, group) row's score/softmax/weighted-sum
-    arithmetic is independent of S, which is what keeps a verified
-    position bitwise the sequential decode step's."""
-    b = pl.program_id(0)
-    kh = pl.program_id(1)
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # (S, G, hd)
-    S, G, hd = q.shape
-    k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bs, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q.reshape(S * G, hd), k.T,
-                preferred_element_type=jnp.float32)       # (S*G, bs)
-    s = s.reshape(S, G, -1)
-    kmask = mask_ref[0, 0] > 0                            # (bs,)
-    kp = kpos_ref[0, 0]                                   # (bs,)
-    qp = qpos_ref[b]                                      # (S,)
-    if alibi:
-        slope = slope_ref[pl.ds(kh * n_groups, n_groups), 0]  # (G,)
-        s = s + slope[None, :, None] * kp.astype(jnp.float32)[None, None, :]
-    valid = (kmask[None, :] & (kp[None, :] <= qp[:, None]))[:, None, :]
-    s = jnp.where(valid, s, -jnp.inf)                     # (S, G, bs)
-
-    m = s.max(axis=-1)                                    # (S, G)
-    p = jnp.exp(s - m[..., None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)                # all-masked split
-    o = jnp.dot(p.reshape(S * G, -1), v,
-                preferred_element_type=jnp.float32)
-    o_ref[0, 0, 0] = o.reshape(S, G, hd)
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = p.sum(axis=-1)
+    return _decode_call(q[:, None], k, v, q_positions[:, None], key_mask,
+                        key_positions, alibi_slopes, trunk_len, block_k,
+                        interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -401,101 +316,12 @@ def flash_decode_mq(
     (B, S) per-query mask-aware positions: causality (``kp <= qp`` per
     query) is what keeps a query from seeing later drafts, exactly as
     ``decoder._causal_bias`` orders the dense path. Other arguments as
-    :func:`flash_decode`. Per-query results are bitwise the single-query
-    kernel's for the same cache state (pinned by tests/test_spec_decode):
-    the per-(query, group) row reductions never mix queries, and the
-    split ladder is chosen from T alone.
+    :func:`flash_decode`. The window rides an extra innermost grid axis
+    of the single-query kernel, so per-query results are the single-query
+    kernel's for the same cache state (pinned by tests/test_spec_decode).
     """
-    B, S, H, hd = q.shape
-    K, T = k.shape[0], k.shape[1]
-    G = H // K
-    sm_scale = 1.0 / np.sqrt(hd)
-    alibi = alibi_slopes is not None
-    if key_positions is None:
-        key_positions = jnp.maximum(jnp.cumsum(key_mask, axis=-1) - 1, 0)
-    key_mask = jnp.asarray(key_mask, jnp.int32)
-    key_positions = jnp.asarray(key_positions, jnp.int32)
-    if alibi_slopes is None:
-        slopes = jnp.zeros((H, 1), jnp.float32)
-    else:
-        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(H, 1)
-
-    split = pick_split(T, block_k)
-    n_splits = T // split
-    qg = q.reshape(B, S, K, G, hd).transpose(0, 2, 1, 3, 4)  # (B, K, S, G, hd)
-
-    kernel = functools.partial(_decode_kernel_mq, sm_scale=sm_scale,
-                               alibi=alibi, n_groups=G)
-    f32 = jnp.float32
-    o_p, m_p, l_p = pl.pallas_call(
-        kernel,
-        grid=(B, K, n_splits),
-        in_specs=[
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, S, G, hd), lambda b, h, j: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda b, h, j: (h, j, b, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda b, h, j: (h, j, b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, S, G, hd),
-                         lambda b, h, j: (b, h, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, S, G), lambda b, h, j: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, S, G), lambda b, h, j: (b, h, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, n_splits, S, G, hd), f32),
-            jax.ShapeDtypeStruct((B, K, n_splits, S, G), f32),
-            jax.ShapeDtypeStruct((B, K, n_splits, S, G), f32),
-        ],
-        interpret=interpret,
-    )(q_positions.astype(jnp.int32), slopes,
-      key_mask[:, None, :], key_positions[:, None, :], qg, k, v)
-
-    # Same log-sum-exp combine as flash_decode, with the query axis along.
-    out = merge_partials(o_p, m_p, l_p, axis=2)           # (B, K, S, G, hd)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, S, H, hd).astype(q.dtype)
-
-
-def _trunk_decode_kernel_mq(qpos_ref, slope_ref, mask_ref, kpos_ref, q_ref,
-                            k_ref, v_ref, o_ref, m_ref, l_ref, *,
-                            sm_scale: float, alibi: bool, n_groups: int):
-    """Trunk-split sibling of :func:`_decode_kernel_mq`: all rows' verify
-    windows (B*S queries) batch into one GEMM per (kv head, trunk
-    split), K/V read from cache row 0 only — speculative verify rides
-    the same trunk dedup as the single-query step, with identical
-    per-(row, query, group) arithmetic."""
-    kh = pl.program_id(0)
-    q = q_ref[0].astype(jnp.float32) * sm_scale           # (B, S, G, hd)
-    B, S, G, hd = q.shape
-    k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bs, hd) row 0
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q.reshape(B * S * G, hd), k.T,
-                preferred_element_type=jnp.float32)
-    s = s.reshape(B, S, G, -1)
-    kmask = mask_ref[0] > 0                               # (B, bs)
-    kp = kpos_ref[0]                                      # (B, bs)
-    qp = qpos_ref[:]                                      # (B, S)
-    if alibi:
-        slope = slope_ref[pl.ds(kh * n_groups, n_groups), 0]  # (G,)
-        s = s + (slope[None, None, :, None]
-                 * kp.astype(jnp.float32)[:, None, None, :])
-    valid = (kmask[:, None, :]
-             & (kp[:, None, :] <= qp[:, :, None]))[:, :, None, :]
-    s = jnp.where(valid, s, -jnp.inf)                     # (B, S, G, bs)
-
-    m = s.max(axis=-1)                                    # (B, S, G)
-    p = jnp.exp(s - m[..., None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)                # all-masked split
-    o = jnp.dot(p.reshape(B * S * G, -1), v,
-                preferred_element_type=jnp.float32)
-    o_ref[0, 0] = o.reshape(B, S, G, hd)
-    m_ref[0, 0] = m
-    l_ref[0, 0] = p.sum(axis=-1)
+    return _decode_call(q, k, v, q_positions, key_mask, key_positions,
+                        alibi_slopes, 0, block_k, interpret)
 
 
 @functools.partial(jax.jit,
@@ -511,103 +337,8 @@ def flash_decode_mq_trunk(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Trunk-aware multi-query decode: :func:`flash_decode_mq` with the
-    :func:`flash_decode_trunk` split dedup, so speculative verify
-    windows in a shared-trunk dispatch load the trunk KV once per
-    (kv head, split) per verify pass instead of once per row. Bitwise
-    the flat mq kernel's output (same split ladder, same per-element
-    arithmetic, same merge)."""
-    B, S, H, hd = q.shape
-    K, T = k.shape[0], k.shape[1]
-    G = H // K
-    split = pick_split(T, block_k)
-    nt = max(0, min(int(trunk_len), T - 1)) // split
-    if nt == 0:
-        return flash_decode_mq(q, k, v, q_positions, key_mask,
-                               key_positions, alibi_slopes, block_k,
-                               interpret)
-    sm_scale = 1.0 / np.sqrt(hd)
-    alibi = alibi_slopes is not None
-    if key_positions is None:
-        key_positions = jnp.maximum(jnp.cumsum(key_mask, axis=-1) - 1, 0)
-    key_mask = jnp.asarray(key_mask, jnp.int32)
-    key_positions = jnp.asarray(key_positions, jnp.int32)
-    if alibi_slopes is None:
-        slopes = jnp.zeros((H, 1), jnp.float32)
-    else:
-        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(H, 1)
-
-    n_splits = T // split
-    qg = q.reshape(B, S, K, G, hd).transpose(0, 2, 1, 3, 4)  # (B, K, S, G, hd)
-    f32 = jnp.float32
-    qpos = q_positions.astype(jnp.int32)
-
-    kernel_t = functools.partial(_trunk_decode_kernel_mq, sm_scale=sm_scale,
-                                 alibi=alibi, n_groups=G)
-    o_t, m_t, l_t = pl.pallas_call(
-        kernel_t,
-        grid=(K, nt),
-        in_specs=[
-            pl.BlockSpec(index_map=lambda h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(index_map=lambda h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, B, split), lambda h, j: (0, 0, j)),
-            pl.BlockSpec((1, B, split), lambda h, j: (0, 0, j)),
-            pl.BlockSpec((1, B, S, G, hd), lambda h, j: (h, 0, 0, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda h, j: (h, j, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd), lambda h, j: (h, j, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, B, S, G, hd),
-                         lambda h, j: (h, j, 0, 0, 0, 0)),
-            pl.BlockSpec((1, 1, B, S, G), lambda h, j: (h, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, B, S, G), lambda h, j: (h, j, 0, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, nt, B, S, G, hd), f32),
-            jax.ShapeDtypeStruct((K, nt, B, S, G), f32),
-            jax.ShapeDtypeStruct((K, nt, B, S, G), f32),
-        ],
-        interpret=interpret,
-    )(qpos, slopes, key_mask[None], key_positions[None],
-      qg.transpose(1, 0, 2, 3, 4), k, v)
-
-    ns = n_splits - nt
-    kernel_s = functools.partial(_decode_kernel_mq, sm_scale=sm_scale,
-                                 alibi=alibi, n_groups=G)
-    o_s, m_s, l_s = pl.pallas_call(
-        kernel_s,
-        grid=(B, K, ns),
-        in_specs=[
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(index_map=lambda b, h, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j + nt)),
-            pl.BlockSpec((1, 1, split), lambda b, h, j: (b, 0, j + nt)),
-            pl.BlockSpec((1, 1, S, G, hd), lambda b, h, j: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, split, 1, hd),
-                         lambda b, h, j: (h, j + nt, b, 0)),
-            pl.BlockSpec((1, split, 1, hd),
-                         lambda b, h, j: (h, j + nt, b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, S, G, hd),
-                         lambda b, h, j: (b, h, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, S, G), lambda b, h, j: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, S, G), lambda b, h, j: (b, h, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, ns, S, G, hd), f32),
-            jax.ShapeDtypeStruct((B, K, ns, S, G), f32),
-            jax.ShapeDtypeStruct((B, K, ns, S, G), f32),
-        ],
-        interpret=interpret,
-    )(qpos, slopes, key_mask[:, None, :], key_positions[:, None, :],
-      qg, k, v)
-
-    o_p = jnp.concatenate([o_t.transpose(2, 0, 1, 3, 4, 5), o_s], axis=2)
-    m_p = jnp.concatenate([m_t.transpose(2, 0, 1, 3, 4), m_s], axis=2)
-    l_p = jnp.concatenate([l_t.transpose(2, 0, 1, 3, 4), l_s], axis=2)
-    out = merge_partials(o_p, m_p, l_p, axis=2)           # (B, K, S, G, hd)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, S, H, hd).astype(q.dtype)
+    :func:`flash_decode_trunk` index map, so speculative verify windows
+    in a shared-trunk dispatch fetch the trunk KV once per (kv head,
+    split) per verify pass."""
+    return _decode_call(q, k, v, q_positions, key_mask, key_positions,
+                        alibi_slopes, trunk_len, block_k, interpret)

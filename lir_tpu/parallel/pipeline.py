@@ -32,10 +32,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ._compat import shard_map
 
 from ..models import decoder
 from ..models.registry import ModelConfig

@@ -52,6 +52,22 @@ def build_mesh(cfg: MeshConfig, devices=None) -> Mesh:
     return Mesh(arr, cfg.axis_names)
 
 
+def replica_devices(index: int, per_replica: int = 1, devices=None) -> list:
+    """The devices replica ``index`` of an in-process replica set lives
+    on (``serve --replicas N``): ``per_replica`` consecutive devices
+    (the replica's mesh size, 1 without a mesh) starting at
+    ``index * per_replica``, so replica i of four one-chip replicas sits
+    on chip i. A host with fewer devices than the set needs wraps around
+    — a one-device CPU host serves every replica from it."""
+    devices = list(jax.devices() if devices is None else devices)
+    if per_replica > len(devices):
+        raise ValueError(f"a replica needs {per_replica} devices, have "
+                         f"{len(devices)}")
+    slots = len(devices) // per_replica
+    start = (index % slots) * per_replica
+    return devices[start:start + per_replica]
+
+
 def single_device_mesh() -> Mesh:
     return Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
                 ("data", "model", "seq"))

@@ -48,7 +48,8 @@ from ..engine import hbm
 from ..engine import scheduler as sched_mod
 from ..engine import stream_stats
 from ..engine import tokens as tok
-from ..faults import CLOSED, HALF_OPEN, CircuitBreaker, degrade_dispatch
+from ..faults import (CLOSED, HALF_OPEN, CircuitBreaker, degrade_dispatch,
+                      is_program_error)
 from ..guard import numerics
 from ..observe import registry as metrics_mod
 from ..observe import tracing
@@ -115,6 +116,8 @@ class ScoringServer:
                 and self.config.stream_window > 0):
             self.stream = stream_stats.ServeStreamSink(
                 window=self.config.stream_window)
+        # The first program error a dispatch raised (_dispatch_refused).
+        self.program_error: Optional[BaseException] = None
         self.breaker = CircuitBreaker(
             failure_threshold=self.config.max_consecutive_failures,
             cooldown_s=self.config.breaker_cooldown_s,
@@ -189,11 +192,13 @@ class ScoringServer:
 
     @property
     def healthy(self) -> bool:
-        """True while the circuit breaker is CLOSED. Half-open (probing
-        after a cooldown) reads unhealthy to external supervisors but
-        already admits traffic — a probe success flips this back True
-        without a restart."""
-        return self.breaker.state == CLOSED
+        """True while the circuit breaker is CLOSED and no dispatch was
+        refused as a program error. Half-open (probing after a cooldown)
+        reads unhealthy to external supervisors but already admits
+        traffic — a probe success flips this back True without a
+        restart; a refused program stays refused until the code
+        changes."""
+        return self.breaker.state == CLOSED and self.program_error is None
 
     @property
     def queue_depth(self) -> int:
@@ -509,7 +514,7 @@ class ScoringServer:
                     config=self.config.retry,
                     log=lambda m: log.warning("serve dispatch retry: %s",
                                               m),
-                    clock=self.clock)
+                    clock=self.clock, give_up=is_program_error)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except hbm.OomSignal as sig:
@@ -522,7 +527,12 @@ class ScoringServer:
                 if payloads is None:
                     return
             except Exception as err:  # noqa: BLE001 — degrade, never crash
-                self._dispatch_failed(bucket, rows, err, probing)
+                if is_program_error(err):
+                    # Refused by the tracer/compiler: no retry, lazy-jit
+                    # fallback or bisection can change that.
+                    self._dispatch_refused(rows, err)
+                else:
+                    self._dispatch_failed(bucket, rows, err, probing)
                 return
             if attempts["n"] > 1:
                 # Transient fault outlived by the retry policy alone.
@@ -569,6 +579,25 @@ class ScoringServer:
                 request_id=p.request.request_id, status=STATUS_ERROR,
                 note=note, latency_s=now - p.t_submit))
         return None
+
+    def _dispatch_refused(self, rows, err: BaseException) -> None:
+        """A program error (faults.is_program_error): the dispatch ends
+        here with that error on every row — attempted once, never
+        recompiled under back-off, never bisected into "poison" rows —
+        and the server reads unhealthy from now on (``program_error``),
+        so the serve CLI exits non-zero instead of answering every
+        later request the same way."""
+        self.program_error = err
+        now = self.clock()
+        self.stats.count("errors", len(rows))
+        log.error("serve: dispatch refused by the tracer/compiler (not "
+                  "retried, not bisected): %r", err)
+        for p in rows:
+            p.future.resolve(ServeResult(
+                request_id=p.request.request_id, status=STATUS_ERROR,
+                note=f"program error (not retried): {err!r}",
+                latency_s=now - p.t_submit))
+        self.breaker.record_failure()
 
     def _dispatch_failed(self, bucket: int, rows, err: BaseException,
                          probing: bool) -> None:
@@ -1059,17 +1088,22 @@ class FleetScoringServer:
                 retry_on=(Exception,), config=self.config.retry,
                 log=lambda m: log.warning(
                     "fleet dispatch retry (%s): %s", model_id, m),
-                clock=self.clock)
+                clock=self.clock, give_up=is_program_error)
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as err:  # noqa: BLE001 — resolve, never crash
+            refused = is_program_error(err)
+            if refused:
+                log.error("fleet: dispatch on %s refused by the tracer/"
+                          "compiler (not retried): %r", model_id, err)
+            what = ("program error (not retried)" if refused
+                    else "device error after retries")
             now = self.clock()
             self.stats.count("errors", len(rows))
             for p in rows:
                 p.future.resolve(ServeResult(
                     request_id=p.request.request_id, status=STATUS_ERROR,
-                    note=f"device error after retries on {model_id}: "
-                         f"{err!r}",
+                    note=f"{what} on {model_id}: {err!r}",
                     latency_s=now - p.t_submit))
             return
         now = self.clock()

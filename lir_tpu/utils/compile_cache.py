@@ -33,8 +33,15 @@ from .logging import get_logger
 
 log = get_logger(__name__)
 
-ENV_CACHE_DIR = "LIR_TPU_COMPILE_CACHE"
-DEFAULT_CACHE_DIR = "~/.cache/lir_tpu/xla"
+# JAX's own variable: when it is set JAX already has that directory, and
+# this module sets none (a machine that comes with it set keeps one cache
+# across every program on it).
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# Otherwise: ONE fixed path inside the checkout, derived from the
+# package's own location (git-ignored). The path is part of JAX's cache
+# key, so a directory that moves — a temp dir, a pid, a timestamp —
+# never hits.
+REPO_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 _state_lock = threading.Lock()
 _enabled_dir: Optional[Path] = None
@@ -47,10 +54,23 @@ _hits = 0
 
 
 def resolve_cache_dir(cache_dir: Optional[os.PathLike | str] = None
-                      ) -> Path:
-    """Explicit argument > $LIR_TPU_COMPILE_CACHE > the per-user default."""
-    raw = cache_dir or os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
-    return Path(raw).expanduser()
+                      ) -> Optional[Path]:
+    """The directory THIS code hands to JAX — the one place the rule
+    lives. ``None`` when ``$JAX_COMPILATION_CACHE_DIR`` is set: JAX
+    already has that directory and the code sets none (an explicit
+    ``cache_dir`` is then ignored with a warning). Unset: the explicit
+    ``cache_dir`` (tests that need a private one), else the fixed
+    ``<checkout>/.jax_cache``."""
+    env = os.environ.get(ENV_JAX_CACHE_DIR)
+    if env:
+        if cache_dir is not None:
+            log.warning("%s=%s is set; ignoring the explicit compile "
+                        "cache directory %s", ENV_JAX_CACHE_DIR, env,
+                        cache_dir)
+        return None
+    if cache_dir is not None:
+        return Path(cache_dir).expanduser()
+    return REPO_CACHE_DIR
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -91,12 +111,16 @@ def enable_persistent_cache(cache_dir: Optional[os.PathLike | str] = None,
     ~instead of recompiling~ every bucket executable it already built in
     any previous life. ``min_compile_time_secs=0`` caches everything —
     the sweep's per-bucket programs are exactly the many-small-programs
-    workload the default 1 s threshold would skip. Returns the cache dir,
-    or None when the runtime refused it (old jax, unwritable dir) — the
-    engine then just compiles lazily, nothing breaks.
+    workload the default 1 s threshold would skip. The directory follows
+    :func:`resolve_cache_dir`; with ``$JAX_COMPILATION_CACHE_DIR`` set
+    only the thresholds, the hit/miss listener and the manifest location
+    are set here. Returns the directory in effect, or None when it is
+    unwritable — the engine then just compiles lazily, nothing breaks.
     """
     global _enabled_dir
-    path = resolve_cache_dir(cache_dir)
+    ours = resolve_cache_dir(cache_dir)
+    path = (Path(os.environ[ENV_JAX_CACHE_DIR]).expanduser()
+            if ours is None else ours)
     with _state_lock:
         if _enabled_dir == path:
             return path
@@ -104,24 +128,28 @@ def enable_persistent_cache(cache_dir: Optional[os.PathLike | str] = None,
         path.mkdir(parents=True, exist_ok=True)
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", str(path))
+        if ours is not None:
+            jax.config.update("jax_compilation_cache_dir", str(ours))
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time_secs))
-        # jax initializes its cache object at most once per process and
-        # has no config hook on the dir — reset so a changed dir (tests,
-        # --compile-cache-dir after an earlier enable) actually takes.
-        from jax._src import compilation_cache as _cc
+        if ours is not None:
+            # jax initializes its cache object at most once per process
+            # and has no config hook on the dir — reset so a changed dir
+            # (tests enabling a private one) actually takes.
+            from jax.experimental.compilation_cache import (
+                compilation_cache as _cc)
 
-        _cc.reset_cache()
-    except Exception as err:  # noqa: BLE001 — cache is an optimization
+            _cc.reset_cache()
+    except OSError as err:  # the cache is an optimization
         log.warning("persistent compile cache unavailable (%s); "
                     "compiles will not survive restarts", err)
         return None
     install_cache_listener()
     with _state_lock:
         _enabled_dir = path
-    log.info("persistent compile cache: %s", path)
+    log.info("persistent compile cache: %s%s", path,
+             "" if ours is not None else f" (from ${ENV_JAX_CACHE_DIR})")
     return path
 
 
@@ -133,14 +161,11 @@ def disable_persistent_cache() -> None:
     """Turn the persistent cache back off (tests; --no-compile-cache is
     handled by simply never enabling)."""
     global _enabled_dir
-    try:
-        import jax
-        from jax._src import compilation_cache as _cc
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        jax.config.update("jax_compilation_cache_dir", None)
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001
-        pass
+    jax.config.update("jax_compilation_cache_dir", None)
+    _cc.reset_cache()
     with _state_lock:
         _enabled_dir = None
 

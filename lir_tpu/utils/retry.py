@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import random
 import time
-from typing import Callable, Tuple, Type, TypeVar
+from typing import Callable, Optional, Tuple, Type, TypeVar
 
 from lir_tpu.config import RetryConfig
 
@@ -40,7 +40,11 @@ def retry_with_exponential_backoff(
     sleep: Callable[[float], None] = time.sleep,
     log: Callable[[str], None] = print,
     clock: Callable[[], float] = time.monotonic,
+    give_up: Optional[Callable[[BaseException], bool]] = None,
 ) -> T:
+    """``give_up(exc)`` true re-raises at once, whatever ``retry_on``
+    says: a failure that retrying cannot change (faults.is_program_error
+    — a program the compiler refuses) is not slept on."""
     delay = config.initial_delay
     start = clock()
     for attempt in range(config.max_retries + 1):
@@ -49,7 +53,8 @@ def retry_with_exponential_backoff(
         except retry_on as exc:
             if isinstance(exc, (KeyboardInterrupt, SystemExit)):
                 raise  # shutdown signals are not transient failures
-            if attempt == config.max_retries:
+            if attempt == config.max_retries or (give_up is not None
+                                                 and give_up(exc)):
                 raise
             if config.full_jitter:
                 wait = random.uniform(0.0, min(delay, config.max_delay))

@@ -28,6 +28,13 @@ from lir_tpu.utils import compile_cache
 from lir_tpu.utils.profiling import CompileStats, OccupancyStats
 
 
+@pytest.fixture(autouse=True)
+def _cache_dir_not_placed_from_outside(monkeypatch):
+    """These tests enable private cache directories; a directory placed
+    from outside would (by the rule under test) override them."""
+    monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR, raising=False)
+
+
 # ---------------------------------------------------------------------------
 # Manifest key: every configuration input separates the key space
 # ---------------------------------------------------------------------------
@@ -240,6 +247,25 @@ def test_piggyback_chain_runs_precompiled(tmp_path):
     assert engine.kernel_stats.counters.get("piggybacked_steps") == 2
 
 
+@pytest.mark.parametrize("kind", ["shared", "grouped"])
+def test_donated_scratch_cache_really_aliases_the_output(kind):
+    """The KV handoff donates the previous dispatch's cache as a scratch
+    argument the program never READS — memory reuse only. jit prunes
+    unused arguments by default, and a pruned donation aliases nothing:
+    on the v5e the "donated" 7B executable held the old cache AND a new
+    one (PR 21, RESOURCE_EXHAUSTED at batch 40 / bucket 512 / bf16 KV).
+    The decode programs keep unused arguments, so the compiled donated
+    variant must carry an input/output alias for the cache pair."""
+    engine = _tiny_engine(RuntimeConfig(batch_size=4, max_seq_len=128))
+    spec = (compile_plan.shared_spec(64, 4, 8, 8, 4, 8, False, True)
+            if kind == "shared" else
+            compile_plan.grouped_spec(64, 2, 4, 8, 4, False, True))
+    text = compile_plan._lower_compile(engine, spec).as_text()
+    assert "input_output_alias" in text
+    assert text[text.index("input_output_alias"):][:400].count(
+        "alias)") >= 2                      # k and v
+
+
 def test_engines_with_different_configs_get_different_manifest_keys():
     e1 = _tiny_engine(RuntimeConfig(batch_size=4, max_seq_len=256))
     e2 = _tiny_engine(RuntimeConfig(batch_size=8, max_seq_len=256))
@@ -344,3 +370,82 @@ def test_manifest_written_next_to_cache(tmp_path):
         compile_cache.disable_persistent_cache()
     # No cache enabled -> no-op, not an error.
     assert compile_cache.write_manifest("zzz", {}) is None
+
+
+# ---------------------------------------------------------------------------
+# Compile cache placed from outside (resolve_cache_dir is the one rule)
+# ---------------------------------------------------------------------------
+
+def test_cache_dir_unset_is_one_fixed_path_in_the_checkout(monkeypatch):
+    import os
+    from pathlib import Path
+
+    import lir_tpu
+
+    monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR, raising=False)
+    first = compile_cache.resolve_cache_dir()
+    # Another process's worth of os.getpid() (and a later clock) resolves
+    # the very same path: nothing of the process or the moment is in it.
+    monkeypatch.setattr(os, "getpid", lambda: 424242)
+    second = compile_cache.resolve_cache_dir()
+    assert first == second == compile_cache.REPO_CACHE_DIR
+    repo = Path(lir_tpu.__file__).resolve().parent.parent
+    assert first == repo / ".jax_cache"
+    assert "tmp" not in first.parts and "424242" not in str(first)
+    # ... and git ignores it.
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+
+def test_cache_dir_explicit_argument_survives_only_when_unset(
+        monkeypatch, tmp_path):
+    monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR, raising=False)
+    assert compile_cache.resolve_cache_dir(tmp_path / "x") == tmp_path / "x"
+    monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, str(tmp_path / "j"))
+    assert compile_cache.resolve_cache_dir() is None
+    assert compile_cache.resolve_cache_dir(tmp_path / "x") is None
+
+
+def test_enable_sets_no_directory_when_jax_variable_is_set(
+        monkeypatch, tmp_path):
+    """With $JAX_COMPILATION_CACHE_DIR set JAX already has the directory:
+    the code sets none (thresholds, listener and manifest location only)."""
+    monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, str(tmp_path / "j"))
+    updates = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        updates.append(name)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    min_size = jax.config.jax_persistent_cache_min_entry_size_bytes
+    try:
+        got = compile_cache.enable_persistent_cache(tmp_path / "ignored")
+        assert got == tmp_path / "j"
+        assert compile_cache.enabled_cache_dir() == tmp_path / "j"
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_compile_time_secs" in updates
+        assert not (tmp_path / "ignored").exists()
+    finally:
+        monkeypatch.undo()
+        compile_cache.disable_persistent_cache()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          min_size)
+
+
+def test_no_moving_parts_in_any_cache_path():
+    """No mkdtemp, pid or clock anywhere a cache directory is chosen."""
+    from pathlib import Path
+
+    import lir_tpu
+
+    repo = Path(lir_tpu.__file__).resolve().parent.parent
+    src = (repo / "lir_tpu" / "utils" / "compile_cache.py").read_text()
+    for word in ("mkdtemp", "getpid", "time.time", "LIR_TPU_COMPILE_CACHE"):
+        assert word not in src, word
+    bench = (repo / "bench.py").read_text()
+    assert "mkdtemp" not in bench
+    assert "compile_cache.enable_persistent_cache()" in bench

@@ -616,8 +616,9 @@ def test_throughput_meter_mfu_fields():
 # ---------------------------------------------------------------------------
 
 class _FakeDev:
-    def __init__(self, kind):
+    def __init__(self, kind, platform="tpu"):
         self.device_kind = kind
+        self.platform = platform
 
 
 def test_chip_peak_table_covers_tpu_generations():
@@ -631,9 +632,13 @@ def test_chip_peak_table_covers_tpu_generations():
     assert prof.chip_peak_flops(_FakeDev("TPU v4"), int8=True) == 275e12
     assert prof.chip_peak_flops(_FakeDev("TPU v5p"), int8=True) == 2 * 459e12
     assert prof.chip_peak_flops(_FakeDev("TPU v6 lite"), int8=True) == 2 * 918e12
-    # unknown kind -> None (bench.py then ABORTS unless --allow-ungated)
-    assert prof.chip_peak_flops(_FakeDev("TPU v9 hyper")) is None
-    assert prof.chip_peak_flops(_FakeDev("")) is None
+    # the CPU backend has no peak (callers skip the MFU gate there) ...
+    assert prof.chip_peak_flops(_FakeDev("cpu", platform="cpu")) is None
+    # ... an accelerator kind missing from the table is an error, not a
+    # default (bench.py ABORTS on it unless --allow-ungated)
+    for kind in ("TPU v9 hyper", ""):
+        with pytest.raises(KeyError, match="no published peak"):
+            prof.chip_peak_flops(_FakeDev(kind))
 
 
 @pytest.mark.slow
@@ -750,3 +755,43 @@ def test_cli_bench_rejects_unknowns_before_subcommand(monkeypatch):
     assert called == []                  # bench.py never ran
     cli.main(["bench", "--no-varlen"])   # post-subcommand still forwards
     assert called
+
+
+# ---------------------------------------------------------------------------
+# One process per chip: the multi-process tools' parents stay off JAX
+# ---------------------------------------------------------------------------
+
+def test_multiprocess_tool_parents_never_import_jax():
+    """tools/stats_device_bench.py and tools/layout_probe.py start
+    children that need the chip; a parent that has touched JAX holds it,
+    and the child then fails or hangs. Importing the parents must not
+    import jax (tools.scale_validation, which layout_probe imports at
+    module level, keeps its jax imports inside functions)."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    code = ("import sys; sys.argv = ['x']; "
+            "import tools.layout_probe, tools.stats_device_bench; "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-500:]
+    assert out.stdout.strip() == "False"
+
+
+def test_chip_smoke_refuses_to_start_without_a_tpu():
+    """The driver's contract: no accelerator -> another exit code than 0
+    and no result line — before any model is built."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    out = subprocess.run([sys.executable, str(repo / "chip_smoke.py")],
+                         cwd=repo, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode != 0
+    assert out.stdout == ""
+    assert "no TPU" in out.stderr

@@ -78,6 +78,49 @@ def test_load_engine_forward_parity(tiny_checkpoint, monkeypatch):
     np.testing.assert_allclose(ours, ref_logits, atol=2e-3)
 
 
+def test_replica_devices_gives_each_replica_its_own():
+    """``serve --replicas N``: replica i sits on device slice i (one
+    factory used to land every replica on device 0)."""
+    import jax
+
+    from lir_tpu.parallel.sharding import replica_devices
+
+    devs = jax.devices()
+    assert len(devs) >= 8                      # conftest's virtual mesh
+    assert [replica_devices(i, 1) for i in range(4)] == [
+        [devs[0]], [devs[1]], [devs[2]], [devs[3]]]
+    assert replica_devices(1, 4) == devs[4:8]
+    assert replica_devices(2, 4) == devs[0:4]          # wraps
+    assert replica_devices(5, 1, devs[:1]) == [devs[0]]  # one-device host
+    with pytest.raises(ValueError, match="needs 4 devices"):
+        replica_devices(0, 4, devs[:2])
+
+
+def test_engine_factory_pins_replica_to_its_device(tiny_checkpoint,
+                                                   monkeypatch):
+    import jax
+    import transformers as tf
+
+    from lir_tpu.backends.fake import FakeTokenizer
+    from lir_tpu.parallel.sharding import replica_devices
+
+    monkeypatch.setattr(
+        tf.AutoTokenizer, "from_pretrained",
+        classmethod(lambda cls, *a, **k: FakeTokenizer()),
+    )
+    path, _ = tiny_checkpoint
+    factory = engine_factory(path.parent, RuntimeConfig(batch_size=2))
+    homes = []
+    for i in range(3):
+        engine = factory("org/tiny-llama", devices=replica_devices(i, 1))
+        homes.append({d.id for leaf in jax.tree.leaves(engine.params)
+                      for d in leaf.devices()})
+    assert homes == [{jax.devices()[i].id} for i in range(3)]
+    # ... and a dispatch follows its params there.
+    out = engine.score_prompts(["a b c", "d e"])
+    assert len(out) == 2
+
+
 @pytest.mark.slow
 def test_engine_factory_resolution(tiny_checkpoint, monkeypatch):
     import transformers as tf

@@ -572,6 +572,129 @@ def test_server_ladder_isolates_poison_request():
     assert server.healthy                           # no breaker trip
 
 
+# ---------------------------------------------------------------------------
+# A program the compiler refuses is not a transient fault
+# ---------------------------------------------------------------------------
+
+_REFUSAL = ("The Pallas TPU lowering currently requires that the last two "
+            "dimensions of your block shape are divisible by 8 and 128 "
+            "respectively")
+
+
+class JaxRuntimeError(RuntimeError):
+    """Stand-in carrying the runtime error class's NAME (the classifier
+    reads the name so it needs no private jax import)."""
+
+
+@pytest.mark.parametrize("err,expected", [
+    (ValueError(_REFUSAL), True),
+    (TypeError("unsupported operand"), True),
+    (NotImplementedError("Unsupported vector.shape_cast"), True),
+    (JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel"), True),
+    (JaxRuntimeError("INVALID_ARGUMENT: bad layout"), True),
+    (JaxRuntimeError("UNAVAILABLE: TPU halted, slice restarting"), False),
+    (JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory"), False),
+    (RuntimeError("device fell over"), False),
+    (faults.InjectedFault("injected fault at dispatch call 0"), False),
+])
+def test_is_program_error_classification(err, expected):
+    assert faults.is_program_error(err) is expected
+
+
+def test_degrade_dispatch_never_bisects_a_program_error():
+    calls = []
+
+    def score(rows):
+        calls.append(list(rows))
+        raise ValueError(_REFUSAL)
+
+    with pytest.raises(ValueError, match="Pallas TPU lowering"):
+        faults.degrade_dispatch(score, list(range(8)))
+    assert calls == [list(range(8))]        # one attempt, no halves
+
+
+def test_sweep_attempts_a_refused_dispatch_exactly_once(tmp_path):
+    """A lowering-style ValueError ends the sweep at once: no degrade to
+    lazy jit, no DISPATCH_RETRY recompiles, nothing counted recovered."""
+    from lir_tpu.engine.sweep import _dispatch_with_recovery
+
+    engine = _tiny_engine()
+    degraded = []
+    engine.degrade_to_lazy = lambda: degraded.append(1)
+    calls = []
+
+    def call():
+        calls.append(1)
+        raise ValueError(_REFUSAL)
+
+    with pytest.raises(ValueError, match="Pallas TPU lowering"):
+        _dispatch_with_recovery(engine, call)
+    assert len(calls) == 1 and not degraded
+    assert engine.fault_stats.recovered_dispatches == 0
+
+    # ... and through the whole sweep entry point too.
+    lp, perts = _tiny_grid(4)
+    engine = _tiny_engine()
+    n = {"calls": 0}
+
+    def refused(*a, **kw):
+        n["calls"] += 1
+        raise ValueError(_REFUSAL)
+
+    engine.decode_fused_shared = refused
+    with pytest.raises(ValueError, match="Pallas TPU lowering"):
+        run_perturbation_sweep(engine, "f", lp, perts,
+                               tmp_path / "refused.csv",
+                               checkpoint_every=100)
+    assert n["calls"] == 1
+    assert engine.fault_stats.recovered_dispatches == 0
+
+
+def test_server_does_not_bisect_a_refused_program():
+    """The serve supervisor ends the dispatch with the error on every
+    row — attempted once, no ladder, no "poison row" verdicts — and
+    reads unhealthy; a transient fault right after still recovers."""
+    server = ScoringServer(_tiny_engine(batch=4), "f",
+                           _serve_cfg(max_consecutive_failures=3))
+    real_score = server.batcher.score
+    calls = []
+
+    def refused(bucket, rows):
+        calls.append(len(rows))
+        raise ValueError(_REFUSAL)
+
+    server.batcher.score = refused
+    futs = [server.submit(_req(i)) for i in range(4)]
+    server.start()
+    try:
+        results = [f.result(timeout=60) for f in futs]
+        assert calls == [4]                     # exactly one attempt
+        assert all(r.status == "error" for r in results)
+        assert all("program error (not retried)" in r.note
+                   and "Pallas TPU lowering" in r.note for r in results)
+        assert not any("poison" in r.note for r in results)
+        assert server.faults.degraded_dispatches == 0
+        assert server.faults.degraded_rows == 0
+        assert server.faults.recovered_dispatches == 0
+        assert not server.healthy
+        assert isinstance(server.program_error, ValueError)
+        # Transient faults keep their recovery on the same server.
+        flaky = {"n": 0}
+
+        def transient(bucket, rows):
+            flaky["n"] += 1
+            if flaky["n"] == 1:
+                raise RuntimeError("device hiccup")
+            return real_score(bucket, rows)
+
+        server.batcher.score = transient
+        ok = server.submit(_req(9)).result(timeout=60)
+        assert ok.status == "ok"
+        assert server.faults.recovered_dispatches >= 1
+    finally:
+        server.stop()
+
+
 def test_server_shutdown_checkpoint_resume_zero_lost(tmp_path):
     ckpt = tmp_path / "state.json"
     server = ScoringServer(_tiny_engine(), "f", _serve_cfg())

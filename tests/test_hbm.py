@@ -550,3 +550,43 @@ def test_router_placement_penalizes_pressure():
         assert h.replica_id == "calm"
     summary = router.stats_summary()
     assert summary["replicas"]["squeezed"]["hbm_pressure"] == 2.0
+
+
+# ---------------------------------------------------------------------------
+# A missing bytes_limit is the CPU's privilege, not an accelerator's
+# ---------------------------------------------------------------------------
+
+class _StatsDev:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "fake", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,expected", [
+    ("cpu", None, None),
+    ("cpu", {}, None),
+    ("tpu", {"bytes_limit": 16 * 2**30}, 16 * 2**30),
+])
+def test_device_bytes_limit(monkeypatch, platform, stats, expected):
+    import jax
+
+    from lir_tpu.engine import hbm
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_StatsDev(platform, stats)])
+    assert hbm.device_bytes_limit() == expected
+    budget = hbm.device_budget_bytes(0.25)
+    assert budget == (None if expected is None else int(expected * 0.75))
+
+
+@pytest.mark.parametrize("stats", [None, {}, {"bytes_in_use": 5}])
+def test_accelerator_without_bytes_limit_is_an_error(monkeypatch, stats):
+    import jax
+
+    from lir_tpu.engine import hbm
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_StatsDev("tpu", stats)])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        hbm.device_budget_bytes()

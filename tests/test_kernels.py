@@ -121,6 +121,83 @@ class TestFlashDecodeKernel:
                                    atol=2e-5)
 
 
+class TestFlashDecodeSublaneBlocks:
+    """Batches that fill whole sublane groups take the TPU block shape:
+    8 cache rows per K/V block, queries contracted against the whole
+    block and kept on the block diagonal (ops/flash_decode._decode_kernel).
+    The small-batch cases above run one row per block, so the shape the
+    chip compiles is pinned against the dense path here."""
+
+    def _case(self, B, H, K, T=128, hd=16, S=None, seed=0, shared=0):
+        rng = np.random.default_rng(seed)
+        qshape = (B, H, hd) if S is None else (B, S, H, hd)
+        q = jnp.asarray(rng.normal(size=qshape), jnp.float32)
+        k = rng.normal(size=(K, T, B, hd)).astype(np.float32)
+        v = rng.normal(size=(K, T, B, hd)).astype(np.float32)
+        k[:, :shared] = k[:, :shared, :1]
+        v[:, :shared] = v[:, :shared, :1]
+        mask = np.zeros((B, T), np.int32)
+        for r in range(B):
+            lo = 0 if shared else (r % 3) * 5
+            mask[r, lo: T - 3 * (r % 5)] = 1
+        key_pos = np.maximum(np.cumsum(mask, -1) - 1, 0)
+        last = mask.sum(-1).astype(np.int32) - 1
+        q_pos = (last if S is None else
+                 last[:, None] - np.arange(S - 1, -1, -1, np.int32)[None])
+        return (q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(q_pos),
+                jnp.asarray(mask), jnp.asarray(key_pos))
+
+    @pytest.mark.parametrize("B,H,K", [(8, 4, 2), (16, 4, 1), (8, 4, 4),
+                                       (24, 6, 2)])
+    @pytest.mark.parametrize("alibi", [False, True])
+    def test_single_query_matches_dense(self, B, H, K, alibi):
+        from lir_tpu.ops.flash_decode import batch_block
+
+        assert batch_block(B) == 8
+        q, k, v, q_pos, mask, key_pos = self._case(B, H, K, seed=B + H)
+        slopes = jnp.asarray(decoder.alibi_slopes(H)) if alibi else None
+        exp = _dense_decode_reference(q, k, v, q_pos, mask, key_pos,
+                                      slopes=slopes)
+        got = flash_decode(q, k, v, q_pos, mask, key_pos,
+                           alibi_slopes=slopes, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("B,H,K", [(8, 4, 2), (16, 4, 1)])
+    @pytest.mark.parametrize("trunk", [0, 64, 100])
+    def test_trunk_and_window_match_dense(self, B, H, K, trunk):
+        """The trunk index map (every batch block reads block 0 inside
+        the trunk) and the verify-window grid axis, against the dense
+        path query by query."""
+        from lir_tpu.ops.flash_decode import (flash_decode_mq_trunk,
+                                              flash_decode_trunk)
+
+        q, k, v, q_pos, mask, key_pos = self._case(
+            B, H, K, S=3, seed=trunk + B, shared=trunk)
+        got = flash_decode_mq_trunk(q, k, v, q_pos, mask, key_pos,
+                                    trunk_len=trunk, interpret=True)
+        for s in range(3):
+            exp = _dense_decode_reference(q[:, s], k, v, q_pos[:, s], mask,
+                                          key_pos)
+            np.testing.assert_allclose(np.asarray(got[:, s]),
+                                       np.asarray(exp), atol=2e-5)
+            one = flash_decode_trunk(q[:, s], k, v, q_pos[:, s], mask,
+                                     key_pos, trunk_len=trunk,
+                                     interpret=True)
+            np.testing.assert_allclose(np.asarray(one), np.asarray(exp),
+                                       atol=2e-5)
+
+    def test_wide_group_narrows_the_split(self):
+        """falcon's 71-wide MQA group takes a narrower key split so the
+        score tile stays inside VMEM; the ladder ignores the window."""
+        from lir_tpu.ops.flash_decode import decode_split
+
+        assert decode_split(512, 40, 4) == 128
+        assert decode_split(512, 40, 1) == 128
+        assert decode_split(512, 40, 71) == 32
+        assert decode_split(76, 3, 2) == pick_split(76)
+
+
 @pytest.fixture()
 def fused_decode_interpret():
     """Arm the tier-1 interpret hook; jit caches key on cfg, so tests
@@ -186,7 +263,7 @@ class TestFusedDecodeRouting:
         assert eng2.cfg.fused_decode is True
         # CPU without the interpret hook: routing stays dense either way.
         assert not decoder._fused_decode_ok(
-            eng2.cfg, 1, (jnp.zeros((1,)), None, None))
+            eng2.cfg, 1, 1, (jnp.zeros((1,)), None, None))
 
 
 class TestInt8MatmulFusion:

@@ -186,6 +186,38 @@ def test_retry_max_elapsed_cap_is_deterministic():
     assert t[0] <= cfg.max_elapsed
 
 
+def test_retry_gives_up_at_once_on_what_retrying_cannot_change():
+    """``give_up``: a failure the predicate names re-raises on the first
+    attempt, with no sleep, whatever ``retry_on`` says; other failures
+    keep the whole policy."""
+    cfg = RetryConfig(max_retries=5, initial_delay=1.0, max_delay=1.0,
+                      jitter=(1.0, 1.0), max_elapsed=None)
+    calls, waits = [], []
+
+    def refused():
+        calls.append(1)
+        raise ValueError("refused by the compiler")
+
+    with pytest.raises(ValueError):
+        retry_with_exponential_backoff(
+            refused, (Exception,), cfg, sleep=waits.append,
+            log=lambda s: None,
+            give_up=lambda e: isinstance(e, ValueError))
+    assert len(calls) == 1 and waits == []
+
+    def flaky():
+        calls.append(1)
+        raise RuntimeError("transient")
+
+    calls.clear()
+    with pytest.raises(RuntimeError):
+        retry_with_exponential_backoff(
+            flaky, (Exception,), cfg, sleep=waits.append,
+            log=lambda s: None,
+            give_up=lambda e: isinstance(e, ValueError))
+    assert len(calls) == 6 and len(waits) == 5
+
+
 def test_retry_full_jitter_stays_inside_the_envelope():
     random.seed(0)
     waits, t = [], [0.0]

@@ -1,0 +1,178 @@
+"""Compile every Pallas entry point of the scoring path for a DESCRIBED
+TPU v5e, from this CPU sandbox, at the published 7B head layouts.
+
+Interpret mode (tests/test_kernels.py, test_cascade*.py) proves the
+arithmetic; it cannot see what the chip's compiler refuses — block specs
+whose minor pair is not (8, 128)-tileable, relayouts Mosaic does not
+implement, blocks past the scoped VMEM limit. These tests hand the real
+shapes to the real TPU compiler (no chip attached, nothing runs), so a
+kernel that stops lowering fails here, at no chip time.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: describing it loads the TPU library, which one process at a time
+may hold, so it must happen only in the worker that runs this file —
+never at import, in a ``skipif``, or in ``parametrize`` arguments. Keep
+every such compile in THIS file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from lir_tpu.ops.cascade_prefill import cascade_attention
+from lir_tpu.ops.flash_attention import flash_attention
+from lir_tpu.ops.flash_decode import (flash_decode, flash_decode_mq,
+                                      flash_decode_mq_trunk,
+                                      flash_decode_trunk)
+
+# Published widths of the three head layouts the zoo has:
+# (query heads, kv heads, head dim).
+LAYOUTS = {
+    "mha": (32, 32, 128),     # llama-2-7b / qwen / baichuan (bloom: +ALiBi)
+    "gqa": (32, 8, 128),      # mistral-7b
+    "mqa": (71, 1, 64),       # falcon-7b — 71 is not a multiple of 8
+}
+BATCH = 40                    # the smoke's and the sweep's dispatch batch
+WINDOW = 5                    # speculative verify window (spec_k + 1)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, args, one_chip, **static):
+    shaped = [None if a is None else
+              jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip)
+              for a in args]
+    return jax.jit(functools.partial(fn, **static)).lower(*shaped).compile()
+
+
+def _decode_args(layout, T, window=None, alibi=False):
+    H, K, hd = LAYOUTS[layout]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    q = ((BATCH, H, hd) if window is None else (BATCH, window, H, hd), bf16)
+    qpos = ((BATCH,) if window is None else (BATCH, window), i32)
+    kv = ((K, T, BATCH, hd), bf16)
+    mask = ((BATCH, T), i32)
+    slopes = ((H,), jnp.float32) if alibi else None
+    return [q, kv, kv, qpos, mask, mask, slopes]
+
+
+def _cascade_args(layout, R, Tt, alibi=False):
+    H, K, hd = LAYOUTS[layout]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    q = ((BATCH, R, H, hd), bf16)
+    sfx = ((BATCH, R, K, hd), bf16)
+    trunk = ((K, Tt, hd), bf16)
+    rows = ((BATCH, R), i32)
+    slopes = ((H,), jnp.float32) if alibi else None
+    return [q, sfx, sfx, trunk, trunk, rows, rows, slopes]
+
+
+# 552 = the 512 bucket + a 32-token suffix + the 8-token decode budget:
+# the extent the sweep really decodes over (not a multiple of 128).
+DECODE_CASES = [(lay, T, False) for lay in LAYOUTS for T in (256, 512, 552)]
+DECODE_CASES.append(("mha", 512, True))            # bloom-7b1: ALiBi
+
+
+@pytest.mark.parametrize("layout,T,alibi", DECODE_CASES)
+def test_flash_decode_compiles(one_chip, layout, T, alibi):
+    _compile(flash_decode, _decode_args(layout, T, alibi=alibi), one_chip)
+
+
+# Trunk extents on the CascadeConfig.trunk_quantum = 16 grid: whole
+# splits, a partial trailing split, and a trunk past the cache edge.
+@pytest.mark.parametrize("trunk", [128, 208, 10_000])
+@pytest.mark.parametrize("layout,T,alibi", DECODE_CASES)
+def test_flash_decode_trunk_compiles(one_chip, layout, T, alibi, trunk):
+    _compile(flash_decode_trunk, _decode_args(layout, T, alibi=alibi),
+             one_chip, trunk_len=trunk)
+
+
+@pytest.mark.parametrize("layout,T,alibi", DECODE_CASES)
+def test_flash_decode_mq_compiles(one_chip, layout, T, alibi):
+    _compile(flash_decode_mq,
+             _decode_args(layout, T, window=WINDOW, alibi=alibi), one_chip)
+
+
+@pytest.mark.parametrize("layout,T,alibi", DECODE_CASES)
+def test_flash_decode_mq_trunk_compiles(one_chip, layout, T, alibi):
+    _compile(flash_decode_mq_trunk,
+             _decode_args(layout, T, window=WINDOW, alibi=alibi), one_chip,
+             trunk_len=T - 128)
+
+
+# (remainder window, trunk) pairs a 256/512 bucket plans on the quantum
+# grid, at both the fused single-launch lowering and the two-leg one
+# (float and in-kernel int8 QK^T prefix legs).
+CASCADE_SHAPES = [(64, 192), (64, 384), (128, 384), (48, 208), (448, 64)]
+CASCADE_CASES = [(lay, R, Tt, False) for lay in LAYOUTS
+                 for R, Tt in CASCADE_SHAPES]
+CASCADE_CASES.append(("mha", 64, 384, True))       # bloom-7b1: ALiBi
+
+
+@pytest.mark.parametrize("layout,R,Tt,alibi", CASCADE_CASES)
+def test_cascade_fused_compiles(one_chip, layout, R, Tt, alibi):
+    _compile(cascade_attention, _cascade_args(layout, R, Tt, alibi),
+             one_chip, fused_suffix=True)
+
+
+@pytest.mark.parametrize("int8_qk", [False, True])
+@pytest.mark.parametrize("layout,R,Tt,alibi", CASCADE_CASES)
+def test_cascade_two_leg_compiles(one_chip, layout, R, Tt, alibi, int8_qk):
+    _compile(cascade_attention, _cascade_args(layout, R, Tt, alibi),
+             one_chip, fused_suffix=False, int8_qk=int8_qk)
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+@pytest.mark.parametrize("masked", [False, True])
+def test_flash_attention_compiles(one_chip, layout, masked):
+    """The prefill flash kernel (off for every 7B preset, on by flag):
+    it takes per-query-head k/v, as models/decoder._attention repeats
+    them."""
+    H, _, hd = LAYOUTS[layout]
+    S = 512
+    qkv = ((BATCH, S, H, hd), jnp.bfloat16)
+    mask = ((BATCH, S), jnp.int32) if masked else None
+    shaped = [jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip)
+              for a in (qkv, qkv, qkv)]
+    kw = {}
+    if masked:
+        kw["key_mask"] = jax.ShapeDtypeStruct(mask[0], mask[1],
+                                              sharding=one_chip)
+    jax.jit(functools.partial(flash_attention, causal=True)).lower(
+        *shaped, **kw).compile()
+
+
+def test_compiled_text_carries_the_kernel(one_chip):
+    """The compile really went through Mosaic: the executable holds a
+    ``tpu_custom_call`` (not an XLA fallback)."""
+    for fn, args, static in (
+            (flash_decode, _decode_args("gqa", 512), {}),
+            (flash_decode_trunk, _decode_args("gqa", 512),
+             {"trunk_len": 384}),
+            (cascade_attention, _cascade_args("gqa", 64, 384), {})):
+        text = _compile(fn, args, one_chip, **static).as_text()
+        assert "tpu_custom_call" in text, fn

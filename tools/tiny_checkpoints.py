@@ -98,7 +98,8 @@ def build_bpe_tokenizer():
     tok.decoder = decoders.ByteLevel()
     trainer = trainers.BpeTrainer(
         vocab_size=1024, special_tokens=["<|endoftext|>"],
-        initial_alphabet=pre_tokenizers.ByteLevel.alphabet())
+        initial_alphabet=pre_tokenizers.ByteLevel.alphabet(),
+        show_progress=False)    # its bar writes blank lines to stdout
     tok.train_from_iterator(corpus, trainer)
     return tf.PreTrainedTokenizerFast(
         tokenizer_object=tok, eos_token="<|endoftext|>")
