@@ -20,8 +20,19 @@ Three layers cooperate:
    (utils/compile_cache.manifest_key), so an executable compiled for one
    configuration can never be looked up by another.
 3. **Observability** (utils/profiling.CompileStats): per-shape compile
-   seconds, registry hit / lazy-miss counts, persistent-cache hit/miss
-   deltas — logged per sweep and surfaced in bench.py's headline.
+   seconds, registry hit / lazy-miss counts, how many planned programs
+   were ever dispatched, the wall seconds spent loading, persistent-cache
+   hit/miss deltas — logged per sweep and surfaced in bench.py's
+   headline. Trace spans (observe/tracing): ``engine/compile_load`` per
+   executable on the pool threads, ``engine/compile_wait`` where a
+   dispatch blocks on one (``engine/compile_lazy``, a compile outside
+   the plan, comes from utils/compile_cache's listener).
+4. **Scope tables** (:func:`scope_table`, ``ExecutableRegistry.
+   scope_tables``): for each executable handed out, which
+   ``lir.<phase>`` scope each optimized HLO instruction was built
+   under — the route from a device operation in a profiler trace
+   (keyed by instruction name) back to prefill / extend / decode /
+   readout. Built only when asked.
 
 The registry is an OPTIMIZATION: every lookup miss (unplanned shape, the
 runner's shared-prefix fallback path, a failed compile) falls through to
@@ -30,13 +41,16 @@ the ordinary jitted call, which is always correct.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from ..observe import tracing
 from ..utils.logging import get_logger
 from ..utils.profiling import CompileStats
 
@@ -658,7 +672,12 @@ def _avals_piggy(engine, spec: ShapeSpec):
 
 
 def _lower_compile(engine, spec: ShapeSpec):
-    """Lower + compile one spec; returns the jax Compiled executable.
+    """Lower + compile one spec; returns the jax Compiled executable."""
+    return _lower(engine, spec).compile()
+
+
+def _lower(engine, spec: ShapeSpec):
+    """Lower one spec; returns the jax Lowered program.
 
     The donated variant needs the KV-cache aval, which is exactly the
     scratchless variant's returned cache — recovered via eval_shape
@@ -670,14 +689,14 @@ def _lower_compile(engine, spec: ShapeSpec):
 
         return stream_stats.lower_fold(
             spec.bucket, spec.groups, spec.batch, TOPK,
-            spec.stops_armed).compile()
+            spec.stops_armed)
     if spec.kind.startswith("piggy"):
         fn = {"piggy_prefill": generate.shared_piggyback_prefill,
               "piggy_step": generate.shared_piggyback_step,
               "piggy_drain": generate.shared_piggyback_drain}[spec.kind]
         args, kwargs, statics = _avals_piggy(engine, spec)
         return fn.lower(engine.params, engine.cfg, *args, **kwargs,
-                        **statics).compile()
+                        **statics)
     if spec.kind == "shared":
         fn = (generate.greedy_decode_fused_shared_spec if spec.spec_k
               else generate.greedy_decode_fused_shared)
@@ -703,9 +722,8 @@ def _lower_compile(engine, spec: ShapeSpec):
         out_shape = fn.eval_shape(args[0], engine.cfg, *args[1:],
                                   scratch_cache=None, **kwargs, **statics)
         scratch = out_shape[-1]  # the returned final cache's aval tree
-    lowered = fn.lower(args[0], engine.cfg, *args[1:],
-                       scratch_cache=scratch, **kwargs, **statics)
-    return lowered.compile()
+    return fn.lower(args[0], engine.cfg, *args[1:],
+                    scratch_cache=scratch, **kwargs, **statics)
 
 
 # Process-wide executable cache: the AOT analogue of jit's in-memory
@@ -752,6 +770,7 @@ class ExecutableRegistry:
         self.compile_timeout_s = compile_timeout_s
         self.guard_stats = guard_stats
         self._futures: Dict[ShapeSpec, "Future"] = {}
+        self._handed: List[ShapeSpec] = []   # executables get() returned
         self._lock = threading.Lock()
         self._warned = False
 
@@ -772,10 +791,12 @@ class ExecutableRegistry:
                 return
 
             def task():
-                t0 = time.perf_counter()
-                compiled = _lower_compile(engine, spec)
-                self.stats.record_shape(spec.label,
-                                        time.perf_counter() - t0)
+                with self.stats.loading(), tracing.span(
+                        "engine/compile_load", label=spec.label):
+                    t0 = time.perf_counter()
+                    compiled = _lower_compile(engine, spec)
+                    self.stats.record_shape(spec.label,
+                                            time.perf_counter() - t0)
                 with _EXEC_CACHE_LOCK:
                     _EXEC_CACHE[cache_key] = compiled
                 return compiled
@@ -788,8 +809,11 @@ class ExecutableRegistry:
         if fut is None:
             self.stats.lazy_misses += 1
             return None
+        waiting = (contextlib.nullcontext() if fut.done() else
+                   tracing.span("engine/compile_wait", label=spec.label))
         try:
-            compiled = fut.result(timeout=self.compile_timeout_s)
+            with waiting:
+                compiled = fut.result(timeout=self.compile_timeout_s)
         except FuturesTimeout:
             # Stalled compile: abandon the wait (the pool thread keeps
             # the future; a late success still lands in _EXEC_CACHE for
@@ -809,8 +833,52 @@ class ExecutableRegistry:
                             err)
             self.stats.lazy_misses += 1
             return None
-        self.stats.aot_hits += 1
+        self.stats.hit(spec.label)
+        with self._lock:
+            if spec not in self._handed:
+                self._handed.append(spec)
         return compiled
+
+    def scope_tables(self, engine) -> List[Dict[str, Any]]:
+        """For each dispatch program this registry handed out:
+        ``{"label", "module", "scopes", "instructions", "recompiled"}``
+        with ``scopes`` = :func:`scope_table` of its optimized HLO.
+        Built only when asked (after a traced window), never on the
+        dispatch path.
+
+        The persistent cache's key leaves metadata out, so an entry
+        written by a build of this code with other scopes (or none) is
+        handed back with ITS op_names: an executable whose text names no
+        ``lir.`` scope is compiled once more from this process's own
+        lowering with metadata in the key (a separate cache entry; the
+        optimized instruction names do not depend on metadata), and
+        ``recompiled`` says so. The compiler option passed there changes
+        nothing in the program: an explicit option is what makes jit
+        compile again instead of handing back the executable it
+        remembers for this lowering."""
+        with self._lock:
+            handed = [s for s in self._handed if s.kind != "stream_fold"]
+        out = []
+        for spec in handed:
+            compiled = self._futures[spec].result()
+            module, scopes, n = scope_table(compiled.as_text())
+            stale = not scopes
+            if stale:
+                import jax
+
+                flag = "jax_compilation_cache_include_metadata_in_key"
+                before = getattr(jax.config, flag)
+                jax.config.update(flag, True)
+                try:
+                    module, scopes, n = scope_table(
+                        _lower(engine, spec).compile(compiler_options={
+                            "xla_hlo_graph_addresses": False}).as_text())
+                finally:
+                    jax.config.update(flag, before)
+            out.append({"label": spec.label, "module": module,
+                        "scopes": scopes, "instructions": n,
+                        "recompiled": stale})
+        return out
 
     def wait(self) -> int:
         """Block until every submitted compile finishes; returns the count
@@ -825,6 +893,39 @@ class ExecutableRegistry:
             except Exception as err:  # noqa: BLE001
                 log.warning("precompile failed for %s: %r", spec.label, err)
         return ok
+
+
+_HLO_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)", re.M)
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE = re.compile(r"(?:^|/)(lir\.\w+)")
+
+
+def scope_table(hlo_text: str) -> Tuple[str, Dict[str, str], int]:
+    """(module name, {instruction name: scope}, instructions seen) of
+    one optimized HLO module's text (``compiled.as_text()``).
+
+    An instruction's scope is the OUTERMOST ``lir.<phase>`` component
+    of its ``metadata={op_name=...}`` (``verify_extend`` inside the
+    speculative decode loop is decode); instructions under no scope are
+    left out. A profiler trace names a device operation by the same
+    instruction name (``%fusion.1081``) under the ``XLA Modules`` event
+    of this module. A fusion carries its root's op_name, so the phases
+    need not cover a program's whole device time: a reader reports the
+    cover beside them."""
+    found = _HLO_MODULE.search(hlo_text)
+    scopes: Dict[str, str] = {}
+    n = 0
+    for line in hlo_text.splitlines():
+        head = _HLO_INSTRUCTION.match(line)
+        if head is None:
+            continue
+        n += 1
+        op_name = _OP_NAME.search(line)
+        scope = _SCOPE.search(op_name.group(1)) if op_name else None
+        if scope is not None:
+            scopes[head.group(1)] = scope.group(1)
+    return (found.group(1) if found else ""), scopes, n
 
 
 def precompile_async(engine, specs: Sequence[ShapeSpec],
@@ -858,8 +959,9 @@ def precompile_async(engine, specs: Sequence[ShapeSpec],
     import os
 
     workers = max_workers or min(len(specs), max(2, (os.cpu_count() or 4)))
-    executor = ThreadPoolExecutor(max_workers=workers,
-                                  thread_name_prefix="compile-plan")
+    executor = ThreadPoolExecutor(
+        max_workers=workers,
+        thread_name_prefix=compile_cache.COMPILE_PLAN_THREADS)
     for spec in specs:
         registry.submit(spec, engine, executor)
     executor.shutdown(wait=False)

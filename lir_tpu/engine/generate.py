@@ -116,12 +116,13 @@ def _fused_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
     early_stop = stop_mask is not None and eos_id is not None
     # Position-0 extras (first generated position): top-k logprob map +
     # weighted confidence.
-    logp0 = logits0 - jax.scipy.special.logsumexp(
-        logits0, axis=-1, keepdims=True)
-    tk_vals, tk_ids = lax.top_k(logp0, topk)
-    p_digits = jnp.exp(logp0[:, digit_ids])                    # (B, K)
-    mass = jnp.maximum(p_digits.sum(axis=-1), 1e-10)
-    wconf = (p_digits * digit_vals[None, :]).sum(axis=-1) / mass
+    with jax.named_scope("lir.readout"):
+        logp0 = logits0 - jax.scipy.special.logsumexp(
+            logits0, axis=-1, keepdims=True)
+        tk_vals, tk_ids = lax.top_k(logp0, topk)
+        p_digits = jnp.exp(logp0[:, digit_ids])                # (B, K)
+        mass = jnp.maximum(p_digits.sum(axis=-1), 1e-10)
+        wconf = (p_digits * digit_vals[None, :]).sum(axis=-1) / mass
 
     B = logits0.shape[0]
 
@@ -178,19 +179,21 @@ def _fused_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
                 (emit, p_yes, p_no, top2))
 
     zeros_b = jnp.zeros((B,), bool)
-    (_, cache_f, _, _, _, _), (gen, p_yes, p_no, top2) = lax.scan(
-        step, (logits0, cache, cache_mask0, zeros_b, zeros_b, zeros_b),
-        jnp.arange(max_new_tokens))
+    with jax.named_scope("lir.decode"):
+        (_, cache_f, _, _, _, _), (gen, p_yes, p_no, top2) = lax.scan(
+            step, (logits0, cache, cache_mask0, zeros_b, zeros_b, zeros_b),
+            jnp.arange(max_new_tokens))
 
-    return FusedDecodeOut(
-        generated=jnp.swapaxes(gen, 0, 1),
-        p_yes=jnp.swapaxes(p_yes, 0, 1),
-        p_no=jnp.swapaxes(p_no, 0, 1),
-        top2_ids=jnp.swapaxes(top2, 0, 1),
-        topk_logprobs=tk_vals,
-        topk_ids=tk_ids,
-        weighted_confidence=wconf,
-    ), cache_f
+    with jax.named_scope("lir.readout"):
+        return FusedDecodeOut(
+            generated=jnp.swapaxes(gen, 0, 1),
+            p_yes=jnp.swapaxes(p_yes, 0, 1),
+            p_no=jnp.swapaxes(p_no, 0, 1),
+            top2_ids=jnp.swapaxes(top2, 0, 1),
+            topk_logprobs=tk_vals,
+            topk_ids=tk_ids,
+            weighted_confidence=wconf,
+        ), cache_f
 
 
 @functools.partial(jax.jit,
@@ -315,6 +318,7 @@ def prefill_cache(params, cfg: ModelConfig, tokens: jax.Array,
     return cache
 
 
+@jax.named_scope("lir.prefill")
 def _paged_prefix(params, cfg: ModelConfig, pool, slot_src: jax.Array,
                   win_start: jax.Array, prefix_mask: jax.Array,
                   rem: jax.Array, rem_mask: jax.Array, total_len: int):
@@ -728,12 +732,13 @@ def _spec_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
     W = ctx0.shape[1]
 
     # Position-0 extras — identical to _fused_tail.
-    logp0 = logits0 - jax.scipy.special.logsumexp(
-        logits0, axis=-1, keepdims=True)
-    tk_vals, tk_ids = lax.top_k(logp0, topk)
-    p_digits = jnp.exp(logp0[:, digit_ids])
-    mass = jnp.maximum(p_digits.sum(axis=-1), 1e-10)
-    wconf = (p_digits * digit_vals[None, :]).sum(axis=-1) / mass
+    with jax.named_scope("lir.readout"):
+        logp0 = logits0 - jax.scipy.special.logsumexp(
+            logits0, axis=-1, keepdims=True)
+        tk_vals, tk_ids = lax.top_k(logp0, topk)
+        p_digits = jnp.exp(logp0[:, digit_ids])
+        mass = jnp.maximum(p_digits.sum(axis=-1), 1e-10)
+        wconf = (p_digits * digit_vals[None, :]).sum(axis=-1) / mass
 
     rows = jnp.arange(B)
     i32 = jnp.int32
@@ -933,32 +938,34 @@ def _spec_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
 
         return lax.cond(go, run, lambda car: car, carry), None
 
-    carry, _ = lax.scan(_window, carry0, jnp.arange(T))
+    with jax.named_scope("lir.decode"):
+        carry, _ = lax.scan(_window, carry0, jnp.arange(T))
 
-    gen_b, py_b = carry["gen"], carry["p_yes"]
-    pn_b, t2_b = carry["p_no"], carry["top2"]
-    if early:
-        # The sequential scan's all-done freeze: once EVERY row is done
-        # (global stop step t*), it skips the model forward and repeats
-        # the t*-step values to the end of the budget. Recover exactly
-        # that tail from the evolved buffers.
-        all_done = jnp.all(carry["done"])
-        tstar = jnp.max(carry["done_step"])
-        fr = jnp.clip(tstar, 0, T - 1)
-        pos = jnp.arange(T)[None, :]
-        tail = all_done & (pos > tstar)
-        gen_b = jnp.where(tail, eos_id, gen_b)
-        py_b = jnp.where(tail, py_b[:, fr][:, None], py_b)
-        pn_b = jnp.where(tail, pn_b[:, fr][:, None], pn_b)
-        t2_b = jnp.where(tail[..., None], t2_b[:, fr][:, None, :], t2_b)
-        seq_steps = jnp.where(all_done, jnp.minimum(tstar, T),
-                              jnp.full((), T, i32)).astype(i32)
-    else:
-        seq_steps = jnp.full((), T, i32)
+    with jax.named_scope("lir.readout"):
+        gen_b, py_b = carry["gen"], carry["p_yes"]
+        pn_b, t2_b = carry["p_no"], carry["top2"]
+        if early:
+            # The sequential scan's all-done freeze: once EVERY row is done
+            # (global stop step t*), it skips the model forward and repeats
+            # the t*-step values to the end of the budget. Recover exactly
+            # that tail from the evolved buffers.
+            all_done = jnp.all(carry["done"])
+            tstar = jnp.max(carry["done_step"])
+            fr = jnp.clip(tstar, 0, T - 1)
+            pos = jnp.arange(T)[None, :]
+            tail = all_done & (pos > tstar)
+            gen_b = jnp.where(tail, eos_id, gen_b)
+            py_b = jnp.where(tail, py_b[:, fr][:, None], py_b)
+            pn_b = jnp.where(tail, pn_b[:, fr][:, None], pn_b)
+            t2_b = jnp.where(tail[..., None], t2_b[:, fr][:, None, :], t2_b)
+            seq_steps = jnp.where(all_done, jnp.minimum(tstar, T),
+                                  jnp.full((), T, i32)).astype(i32)
+        else:
+            seq_steps = jnp.full((), T, i32)
 
-    out = FusedDecodeOut(
-        generated=gen_b, p_yes=py_b, p_no=pn_b, top2_ids=t2_b,
-        topk_logprobs=tk_vals, topk_ids=tk_ids, weighted_confidence=wconf)
+        out = FusedDecodeOut(
+            generated=gen_b, p_yes=py_b, p_no=pn_b, top2_ids=t2_b,
+            topk_logprobs=tk_vals, topk_ids=tk_ids, weighted_confidence=wconf)
     spec = SpecOut(drafted=carry["drafted"], accepted=carry["accepted"],
                    chunks=carry["chunks"], seq_steps=seq_steps)
     return out, carry["cache"], carry.get("dcache"), spec

@@ -149,11 +149,6 @@ class LeaseManager:
             if not steal:
                 self.stats.count("refused")
                 return False
-            from ..observe import tracing
-
-            tracing.add_span("lease/steal", now, self.clock(),
-                             shard=int(shard_id),
-                             frm=str(rec.get("holder")))
             self.stats.count("steals")
             log.warning("lease: stealing shard %d from %s (lease "
                         "expired %.1fs ago)", shard_id, rec.get("holder"),

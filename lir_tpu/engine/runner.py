@@ -214,6 +214,7 @@ class ScoringEngine:
         if seq_mesh is not None and not encoder_decoder:
             from ..parallel.seq_forward import prefill_seq_parallel
 
+            @jax.named_scope("lir.prefill")
             def _seq_prefill(p, c, t, m, T, *, _mesh=seq_mesh,
                              _impl=seq_impl):
                 return prefill_seq_parallel(p, c, t, m, T, mesh=_mesh,

@@ -11,10 +11,12 @@ These replace the reference's two L2 orchestration bodies:
 
 from __future__ import annotations
 
+import itertools
 import json
 import queue
 import re
 import threading
+import time
 from pathlib import Path
 from typing import Callable, List, Optional, Sequence
 
@@ -185,6 +187,7 @@ def _decode_complete(generated_row: np.ndarray, eos_id) -> bool:
     return bool(np.any(np.asarray(generated_row) == eos_id))
 
 
+@tracing.span("sweep/call")
 def run_perturbation_sweep(
     engine: ScoringEngine, model_name: str,
     prompts: Sequence[LegalPrompt], perturbations: Sequence[Sequence[str]],
@@ -212,124 +215,132 @@ def run_perturbation_sweep(
     fraction (runner.score_prompts_sampled); Weighted Confidence equals the
     parsed confidence integer (:459-464) and no logprob map is stored.
     """
-    results_path = schemas.resolve_results_path(results_path)
-    # Multi-host pods: each host owns a deterministic shard of the grid and
-    # its OWN results/manifest files (suffix .hostN) — disjoint writes, and
-    # a preempted host resumes exactly its shard. Single-process runs leave
-    # paths untouched.
-    from ..parallel import multihost
+    with tracing.span("sweep/plan", stage="grid"):
+        results_path = schemas.resolve_results_path(results_path)
+        # Multi-host pods: each host owns a deterministic shard of the grid
+        # and its OWN results/manifest files (suffix .hostN) — disjoint
+        # writes, and a preempted host resumes exactly its shard.
+        # Single-process runs leave paths untouched.
+        from ..parallel import multihost
 
-    if manifest is not None and multihost.is_multiprocess():
-        # An explicit manifest + multi-process execution would make every
-        # host sweep the FULL grid and race on one results file. Refuse
-        # loudly instead of silently duplicating work (ADVICE r2 #1).
-        raise ValueError(
-            "explicit manifest is incompatible with multi-process execution: "
-            "each host must own its .hostN results/manifest shard — pass "
-            "manifest=None and let the sweep derive per-host paths")
-    shard_grid = manifest is None and multihost.is_multiprocess()
-    base_results_path = results_path
-    if shard_grid:
-        i = __import__("jax").process_index()
-        results_path = results_path.with_name(
-            f"{results_path.stem}.host{i}{results_path.suffix}")
-        log.info("multihost: process %d writes %s", i, results_path)
-    # Leased shards (engine/lease.py): work distribution by lease
-    # records in a SHARED <results>.leases.jsonl log instead of the
-    # static host_shard split — every host sees the full grid, claims
-    # shards, and steals expired ones, so a slow or dead host
-    # rebalances instead of strangling the shard fence. Re-scored rows
-    # fold into the streaming lattice as bitwise no-ops (slot
-    # idempotence); pair with --no-row-artifact on pods, where a
-    # stolen shard's rows would otherwise appear in two hosts' row
-    # files (DEPLOY.md §1m).
-    lease_mode = (engine.rt.lease_shards and not reasoning
-                  and not engine.encoder_decoder)
-    # Crash-consistent resume: the done-set is the UNION of the manifest
-    # and the rows already in the results artifact. The flush order is
-    # results-append THEN manifest-mark, so a kill between the two leaves
-    # rows only the results file knows about — a manifest-only resume
-    # would re-score and duplicate them (pinned by tools/chaos_smoke.py).
-    # (`manifest or ...` would silently replace an EMPTY explicit
-    # manifest — len() == 0 is falsy — discarding any wrapping/faking a
-    # caller attached to it; test None explicitly.)
-    if manifest is None:
-        manifest = SweepManifest.from_existing_results(
-            results_path.with_suffix(".manifest.jsonl"), results_path,
-            grid_mod.RESUME_KEY_FIELDS,
-            column_map=grid_mod.RESUME_COLUMN_MAP)
-    engine.occupancy = None  # set by _run_pipelined's ragged planner
-    cells = grid_mod.build_grid(model_name, prompts, perturbations)
-    cells = grid_mod.random_subset(cells, subset_size, seed)
-    if shard_grid and not lease_mode:
-        cells = multihost.host_shard(cells)
-    todo = grid_mod.pending_cells(cells, manifest)
-    log.info("%s: %d/%d grid cells pending", model_name, len(todo), len(cells))
+        if manifest is not None and multihost.is_multiprocess():
+            # An explicit manifest + multi-process execution would make every
+            # host sweep the FULL grid and race on one results file. Refuse
+            # loudly instead of silently duplicating work (ADVICE r2 #1).
+            raise ValueError(
+                "explicit manifest is incompatible with multi-process "
+                "execution: each host must own its .hostN results/manifest "
+                "shard — pass manifest=None and let the sweep derive "
+                "per-host paths")
+        shard_grid = manifest is None and multihost.is_multiprocess()
+        base_results_path = results_path
+        if shard_grid:
+            i = __import__("jax").process_index()
+            results_path = results_path.with_name(
+                f"{results_path.stem}.host{i}{results_path.suffix}")
+            log.info("multihost: process %d writes %s", i, results_path)
+        # Leased shards (engine/lease.py): work distribution by lease
+        # records in a SHARED <results>.leases.jsonl log instead of the
+        # static host_shard split — every host sees the full grid, claims
+        # shards, and steals expired ones, so a slow or dead host
+        # rebalances instead of strangling the shard fence. Re-scored rows
+        # fold into the streaming lattice as bitwise no-ops (slot
+        # idempotence); pair with --no-row-artifact on pods, where a
+        # stolen shard's rows would otherwise appear in two hosts' row
+        # files (DEPLOY.md §1m).
+        lease_mode = (engine.rt.lease_shards and not reasoning
+                      and not engine.encoder_decoder)
+        # Crash-consistent resume: the done-set is the UNION of the manifest
+        # and the rows already in the results artifact. The flush order is
+        # results-append THEN manifest-mark, so a kill between the two leaves
+        # rows only the results file knows about — a manifest-only resume
+        # would re-score and duplicate them (pinned by tools/chaos_smoke.py).
+        # (`manifest or ...` would silently replace an EMPTY explicit
+        # manifest — len() == 0 is falsy — discarding any wrapping/faking a
+        # caller attached to it; test None explicitly.)
+        if manifest is None:
+            manifest = SweepManifest.from_existing_results(
+                results_path.with_suffix(".manifest.jsonl"), results_path,
+                grid_mod.RESUME_KEY_FIELDS,
+                column_map=grid_mod.RESUME_COLUMN_MAP)
+        engine.occupancy = None  # set by _run_pipelined's ragged planner
+        cells = grid_mod.build_grid(model_name, prompts, perturbations)
+        cells = grid_mod.random_subset(cells, subset_size, seed)
+        if shard_grid and not lease_mode:
+            cells = multihost.host_shard(cells)
+        todo = grid_mod.pending_cells(cells, manifest)
+        log.info("%s: %d/%d grid cells pending", model_name, len(todo),
+                 len(cells))
 
-    # Streaming statistics (engine/stream_stats.py): a device-resident
-    # accumulator lattice every scoring dispatch updates with ONE fused
-    # XLA call — grid -> percentile/kappa/bootstrap-CI estimates without
-    # round-tripping rows through the host. The bootstrap key is
-    # RECORDED in the manifest on first run and read back on resume, so
-    # streaming CIs are reproducible across resume and across
-    # --no-streaming-stats re-runs over the row artifact; the
-    # accumulator itself checkpoints at every flush boundary (atomic
-    # write) and re-seeds from that checkpoint, with re-folds of
-    # already-dispatched rows idempotent by slot layout.
-    sink = None
-    accum_path = None
-    write_rows = True
-    if (engine.rt.streaming_stats and not reasoning
-            and not engine.encoder_decoder and cells):
-        n_reph = 1 + max(c.rephrase_idx for c in cells)
-        stream_seed = manifest.meta.get("stream_seed")
-        if stream_seed is None:
-            stream_seed = int(seed)
-            manifest.set_meta("stream_seed", stream_seed)
-        sink = stream_mod.StreamSink(
-            len(prompts), n_reph, int(stream_seed),
-            guard=engine.rt.numerics_guard, stats=StreamStats())
-        accum_path = results_path.with_suffix(stream_mod.ACCUM_SUFFIX)
-        if len(manifest) and accum_path.exists():
-            if sink.load(accum_path):
-                log.info("streaming stats: resumed accumulator from %s "
-                         "(%d rows already folded)", accum_path,
-                         sink.snapshot().rows_folded)
-        write_rows = bool(engine.rt.row_artifact)
-    engine.stream_sink = sink
-    if sink is not None and getattr(engine, "governor", None) is not None:
-        # Accumulator lattice: a small but real device-resident
-        # consumer — the ledger carries it so pressure math is honest.
-        engine.governor.register("stream_accum", sink.accum_bytes)
+        # Streaming statistics (engine/stream_stats.py): a device-resident
+        # accumulator lattice every scoring dispatch updates with ONE fused
+        # XLA call — grid -> percentile/kappa/bootstrap-CI estimates without
+        # round-tripping rows through the host. The bootstrap key is
+        # RECORDED in the manifest on first run and read back on resume, so
+        # streaming CIs are reproducible across resume and across
+        # --no-streaming-stats re-runs over the row artifact; the
+        # accumulator itself checkpoints at every flush boundary (atomic
+        # write) and re-seeds from that checkpoint, with re-folds of
+        # already-dispatched rows idempotent by slot layout.
+        sink = None
+        accum_path = None
+        write_rows = True
+        if (engine.rt.streaming_stats and not reasoning
+                and not engine.encoder_decoder and cells):
+            n_reph = 1 + max(c.rephrase_idx for c in cells)
+            stream_seed = manifest.meta.get("stream_seed")
+            if stream_seed is None:
+                stream_seed = int(seed)
+                manifest.set_meta("stream_seed", stream_seed)
+            sink = stream_mod.StreamSink(
+                len(prompts), n_reph, int(stream_seed),
+                guard=engine.rt.numerics_guard, stats=StreamStats())
+            accum_path = results_path.with_suffix(stream_mod.ACCUM_SUFFIX)
+            if len(manifest) and accum_path.exists():
+                if sink.load(accum_path):
+                    log.info("streaming stats: resumed accumulator from %s "
+                             "(%d rows already folded)", accum_path,
+                             sink.snapshot().rows_folded)
+            write_rows = bool(engine.rt.row_artifact)
+        engine.stream_sink = sink
+        if sink is not None and getattr(engine, "governor", None) is not None:
+            # Accumulator lattice: a small but real device-resident
+            # consumer — the ledger carries it so pressure math is honest.
+            engine.governor.register("stream_accum", sink.accum_bytes)
 
-    # Pre-resolve per-prompt target token ids once (SURVEY §7 hard part 1).
-    target_ids = {
-        pi: tok.target_token_ids(engine.tokenizer, p.target_tokens,
-                                 encoder_decoder=engine.encoder_decoder)
-        for pi, p in enumerate(prompts)
-    }
+        # Pre-resolve per-prompt target token ids once (SURVEY §7 hard
+        # part 1).
+        target_ids = {
+            pi: tok.target_token_ids(engine.tokenizer, p.target_tokens,
+                                     encoder_decoder=engine.encoder_decoder)
+            for pi, p in enumerate(prompts)
+        }
 
-    rows: List[schemas.PerturbationRow] = []
-    pending_rows: List[schemas.PerturbationRow] = []
-    B = engine.rt.batch_size
-    checkpoint_every = max(1, checkpoint_every)
-    # Only position 0 feeds the D6 readouts; decode just enough tokens for
-    # the confidence integer / leading response text unless full-completion
-    # parity is requested (config.RuntimeConfig.sweep_decode_tokens).
-    # Reasoning mode ignores these budgets on purpose: its models emit
-    # chain-of-thought BEFORE the answer, so every sampled run gets the full
-    # max_new_tokens (the reference gives them max_completion_tokens=2000,
-    # perturb_prompts.py:249-252).
-    new_tokens = (engine.rt.max_new_tokens if engine.rt.sweep_full_completions
-                  else min(engine.rt.sweep_decode_tokens,
-                           engine.rt.max_new_tokens))
-    conf_tokens = (engine.rt.max_new_tokens
-                   if engine.rt.sweep_full_completions
-                   else min(engine.rt.sweep_confidence_tokens,
-                            engine.rt.max_new_tokens))
+        rows: List[schemas.PerturbationRow] = []
+        pending_rows: List[schemas.PerturbationRow] = []
+        B = engine.rt.batch_size
+        checkpoint_every = max(1, checkpoint_every)
+        # Only position 0 feeds the D6 readouts; decode just enough tokens for
+        # the confidence integer / leading response text unless full-completion
+        # parity is requested (config.RuntimeConfig.sweep_decode_tokens).
+        # Reasoning mode ignores these budgets on purpose: its models emit
+        # chain-of-thought BEFORE the answer, so every sampled run gets the
+        # full max_new_tokens (the reference gives them
+        # max_completion_tokens=2000, perturb_prompts.py:249-252).
+        new_tokens = (engine.rt.max_new_tokens
+                      if engine.rt.sweep_full_completions
+                      else min(engine.rt.sweep_decode_tokens,
+                               engine.rt.max_new_tokens))
+        conf_tokens = (engine.rt.max_new_tokens
+                       if engine.rt.sweep_full_completions
+                       else min(engine.rt.sweep_confidence_tokens,
+                                engine.rt.max_new_tokens))
     lease_mgr = None
     lease_shards_list = None
     score_shard = None
+    # time.monotonic() at which the writer last read a dispatch back:
+    # from there to the return is the call's tail (sweep/tail).
+    last_readback: List[float] = []
     if reasoning:
         for start in range(0, len(todo), B):
             batch = todo[start:start + B]
@@ -377,7 +388,7 @@ def run_perturbation_sweep(
                         results_path, manifest, checkpoint_every,
                         new_tokens, conf_tokens, rows, pending_rows,
                         sink=sink, accum_path=accum_path,
-                        write_rows=write_rows)
+                        write_rows=write_rows, last_readback=last_readback)
                 if pending_rows:
                     # Flush BEFORE the done-record: a shard is only
                     # "done" once its rows/marks are durable.
@@ -391,7 +402,8 @@ def run_perturbation_sweep(
                                new_tokens, conf_tokens, rows,
                                pending_rows, sink=sink,
                                accum_path=accum_path,
-                               write_rows=write_rows)
+                               write_rows=write_rows,
+                               last_readback=last_readback)
             else:
                 for sid, shard_cells in lease_mgr.claim_loop(
                         lease_shards_list):
@@ -408,41 +420,9 @@ def run_perturbation_sweep(
             # marked re-fold to bitwise-identical values, never double-
             # count (pinned by make chaos-smoke scenario 7).
             if sink is not None and accum_path is not None:
-                sink.checkpoint(accum_path)
-        engine.compile_stats.finish_persistent()
-        log.info("compile plan: %s",
-                 json.dumps(engine.compile_stats.summary()))
-        if engine.prefix_cache is not None:
-            log.info("prefix cache: %s",
-                     json.dumps(engine.prefix_stats.summary()))
-        if engine.fault_stats.recovered_dispatches:
-            log.info("fault recovery: %s",
-                     json.dumps(engine.fault_stats.summary()))
-        if lease_mgr is not None:
-            log.info("shard leases: %s",
-                     json.dumps(lease_mgr.stats.summary()))
-        if getattr(engine, "kernel_stats", None) is not None \
-                and engine.kernel_stats.counters:
-            log.info("piggyback chains: %s",
-                     json.dumps(engine.kernel_stats.counters))
-        if getattr(engine, "spec_stats", None) is not None:
-            engine.spec_flush()
-            if engine.spec_stats.spec_dispatches:
-                log.info("speculative decode: %s",
-                         json.dumps(engine.spec_stats.summary()))
-        if sink is not None:
-            # Cheap finalize (counts + kappa; CIs on demand via
-            # sink.finalize(n_boot=...)) — the live-estimate readout.
-            final = sink.finalize(n_boot=0)
-            log.info("streaming stats: %d rows folded on device, "
-                     "kappa=%.4f; counters: %s",
-                     final["rows_folded"], final["kappa"]["kappa"],
-                     json.dumps(sink.stats.summary()))
-        # Per-sweep unified metrics dump (observe/registry): the SAME
-        # canonical snapshot schema the serve {"op": "metrics"}
-        # endpoint answers live, with the per-device HBM gauges.
-        log.info("metrics: %s", json.dumps(
-            metrics_mod.engine_registry(engine, sink=sink).snapshot()))
+                with tracing.span("sweep/flush", stage="accum"):
+                    sink.checkpoint(accum_path)
+        _report(engine, sink, lease_mgr)
 
     if pending_rows:
         _flush(pending_rows, results_path, manifest, sink=sink,
@@ -539,7 +519,50 @@ def run_perturbation_sweep(
             "perturbation-merge-done",
             timeout_s=engine.rt.barrier_timeout_s,
             payload=len(rows), stats=engine.guard_stats)
+    if last_readback:
+        tracing.add_span("sweep/tail", last_readback[0], time.monotonic())
     return rows
+
+
+@tracing.span("sweep/finish")
+def _report(engine, sink, lease_mgr) -> None:
+    """What a finished sweep logs: the compile plan's bill, the caches,
+    recovery, leases, speculation, the streaming estimate and the
+    unified metrics snapshot."""
+    engine.compile_stats.finish_persistent()
+    log.info("compile plan: %s",
+             json.dumps(engine.compile_stats.summary()))
+    if engine.prefix_cache is not None:
+        log.info("prefix cache: %s",
+                 json.dumps(engine.prefix_stats.summary()))
+    if engine.fault_stats.recovered_dispatches:
+        log.info("fault recovery: %s",
+                 json.dumps(engine.fault_stats.summary()))
+    if lease_mgr is not None:
+        log.info("shard leases: %s",
+                 json.dumps(lease_mgr.stats.summary()))
+    if getattr(engine, "kernel_stats", None) is not None \
+            and engine.kernel_stats.counters:
+        log.info("piggyback chains: %s",
+                 json.dumps(engine.kernel_stats.counters))
+    if getattr(engine, "spec_stats", None) is not None:
+        engine.spec_flush()
+        if engine.spec_stats.spec_dispatches:
+            log.info("speculative decode: %s",
+                     json.dumps(engine.spec_stats.summary()))
+    if sink is not None:
+        # Cheap finalize (counts + kappa; CIs on demand via
+        # sink.finalize(n_boot=...)) — the live-estimate readout.
+        final = sink.finalize(n_boot=0)
+        log.info("streaming stats: %d rows folded on device, "
+                 "kappa=%.4f; counters: %s",
+                 final["rows_folded"], final["kappa"]["kappa"],
+                 json.dumps(sink.stats.summary()))
+    # Per-sweep unified metrics dump (observe/registry): the SAME
+    # canonical snapshot schema the serve {"op": "metrics"}
+    # endpoint answers live, with the per-device HBM gauges.
+    log.info("metrics: %s", json.dumps(
+        metrics_mod.engine_registry(engine, sink=sink).snapshot()))
 
 
 def _steps_used(gen_row: np.ndarray, eos_id) -> int:
@@ -598,7 +621,7 @@ def _plan_ragged(engine, todo, new_tokens, conf_tokens):
 def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                    manifest, checkpoint_every, new_tokens, conf_tokens,
                    rows, pending_rows, sink=None, accum_path=None,
-                   write_rows=True) -> None:
+                   write_rows=True, last_readback=None) -> None:
     """Greedy (non-reasoning) sweep loop, pipelined over a writer thread.
 
     The device is the scarce resource; everything host-side rides shotgun:
@@ -632,6 +655,16 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     not yet flushed when an earlier flush failed are NOT marked done, so a
     resumed sweep re-scores at most ``checkpoint_every`` cells (the same
     write-ahead guarantee as the synchronous loop).
+
+    Trace spans (observe/tracing): ``sweep/plan`` up to the first
+    enqueue; per dispatch, numbered by ``dispatch=`` in the queue item,
+    ``sweep/dispatch`` on the main thread and, caused by it,
+    ``sweep/drain`` on the writer with ``sweep/drain_wait`` around the
+    read-back (the drain's self time is the writer's host work);
+    ``sweep/writer_wait`` where the main thread waits for the writer (a
+    full queue, the final join); ``sweep/flush`` around checkpoints.
+    ``last_readback`` (a list) holds the ``time.monotonic`` of the
+    latest read-back's end.
     """
     B = engine.rt.batch_size
     work_q: "queue.Queue" = queue.Queue(maxsize=2)
@@ -643,65 +676,68 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                   and not engine.encoder_decoder)
     occupancy = None
     stop_armed = False
-    if ragged:
-        dispatches, occupancy = _plan_ragged(engine, todo, new_tokens,
-                                             conf_tokens)
-        stop_armed = early_stop and engine.digit_stop_mask is not None
-        engine.fresh_handoff()  # fresh donation chain per sweep
-        # Compile plan: the schedule fixes every dispatch shape, so lower
-        # + compile ALL bucket executables in background threads while
-        # the first bucket streams — the dispatch loop then consumes
-        # precompiled executables (runner.exec_registry) instead of
-        # paying trace-on-first-call serially inside the timed loop.
-        engine.exec_registry = None
-        if engine.rt.aot_precompile:
-            specs = compile_plan.plan_specs(
-                dispatches, B, new_tokens, conf_tokens, stop_armed,
-                prefix_page_size=(engine.prefix_cache.page_size
-                                  if engine.prefix_cache is not None
-                                  else 0),
-                piggyback=engine.piggyback_supported(),
-                stream_shape=(None if sink is None else
-                              (sink.n_prompts, sink.n_rephrase,
-                               sink.guard)),
-                spec_k=(engine.rt.spec_k
-                        if engine.spec_supported() else 0),
-                spec_draft=getattr(engine, "_spec_draft", None)
-                is not None,
-                cascade_trunk=(
-                    (lambda d: engine.cascade_trunk_for(
-                        [it.bin_ids[:it.lcp] for it in d.items],
-                        len(d.items), d.bucket))
-                    if getattr(engine, "cascade_supported",
-                               lambda: False)() else None),
-                cascade_int8=bool(
-                    getattr(engine, "cascade_cfg", None) is not None
-                    and engine.cascade_cfg.int8_qk),
-                decode_trunk=(
-                    (lambda d: engine.decode_trunk_for(
-                        [it.bin_ids[:it.lcp] for it in d.items],
-                        len(d.items), d.bucket))
-                    if getattr(engine, "cascade_decode_supported",
-                               lambda: False)() else None))
-            engine.exec_registry = compile_plan.precompile_async(
-                engine, specs, max_workers=engine.rt.precompile_workers)
-            log.info("compile plan: precompiling %d executable shapes "
-                     "in the background (manifest %s)", len(specs),
-                     engine.exec_registry.manifest_key)
-        if sink is not None and engine.exec_registry is not None:
-            # The sink consumes its planned accumulator-update
-            # executables through the same registry (lazy-jit fallback
-            # on any miss, as everywhere else).
-            registry = engine.exec_registry
+    with tracing.span("sweep/plan", stage="schedule"):
+        if ragged:
+            dispatches, occupancy = _plan_ragged(engine, todo, new_tokens,
+                                                 conf_tokens)
+            stop_armed = early_stop and engine.digit_stop_mask is not None
+            engine.fresh_handoff()  # fresh donation chain per sweep
+            # Compile plan: the schedule fixes every dispatch shape, so lower
+            # + compile ALL bucket executables in background threads while
+            # the first bucket streams — the dispatch loop then consumes
+            # precompiled executables (runner.exec_registry) instead of
+            # paying trace-on-first-call serially inside the timed loop.
+            engine.exec_registry = None
+            if engine.rt.aot_precompile:
+                specs = compile_plan.plan_specs(
+                    dispatches, B, new_tokens, conf_tokens, stop_armed,
+                    prefix_page_size=(engine.prefix_cache.page_size
+                                      if engine.prefix_cache is not None
+                                      else 0),
+                    piggyback=engine.piggyback_supported(),
+                    stream_shape=(None if sink is None else
+                                  (sink.n_prompts, sink.n_rephrase,
+                                   sink.guard)),
+                    spec_k=(engine.rt.spec_k
+                            if engine.spec_supported() else 0),
+                    spec_draft=getattr(engine, "_spec_draft", None)
+                    is not None,
+                    cascade_trunk=(
+                        (lambda d: engine.cascade_trunk_for(
+                            [it.bin_ids[:it.lcp] for it in d.items],
+                            len(d.items), d.bucket))
+                        if getattr(engine, "cascade_supported",
+                                   lambda: False)() else None),
+                    cascade_int8=bool(
+                        getattr(engine, "cascade_cfg", None) is not None
+                        and engine.cascade_cfg.int8_qk),
+                    decode_trunk=(
+                        (lambda d: engine.decode_trunk_for(
+                            [it.bin_ids[:it.lcp] for it in d.items],
+                            len(d.items), d.bucket))
+                        if getattr(engine, "cascade_decode_supported",
+                                   lambda: False)() else None))
+                engine.exec_registry = compile_plan.precompile_async(
+                    engine, specs, max_workers=engine.rt.precompile_workers)
+                log.info("compile plan: precompiling %d executable shapes "
+                         "in the background (manifest %s)", len(specs),
+                         engine.exec_registry.manifest_key)
+            if sink is not None and engine.exec_registry is not None:
+                # The sink consumes its planned accumulator-update
+                # executables through the same registry (lazy-jit fallback
+                # on any miss, as everywhere else).
+                registry = engine.exec_registry
 
-            def _stream_exec(width, _topk, _registry=registry):
-                return _registry.get(compile_plan.stream_fold_spec(
-                    sink.n_prompts, sink.n_rephrase, width, sink.guard))
+                def _stream_exec(width, _topk, _registry=registry):
+                    return _registry.get(compile_plan.stream_fold_spec(
+                        sink.n_prompts, sink.n_rephrase, width, sink.guard))
 
-            sink.registry_get = _stream_exec
+                sink.registry_get = _stream_exec
 
-    def _drain(batch, fused, res, cfused, spec_rec=None):
-        with tracing.span("sweep/drain", rows=len(batch)):
+    def _drain(origin, batch, fused, res, cfused, spec_rec=None):
+        seq, cause = origin   # the dispatch's number and its span
+        with tracing.span("sweep/drain", cause=cause, rows=len(batch),
+                          dispatch=seq):
             _drain_inner(batch, fused, res, cfused, spec_rec)
 
     def _drain_inner(batch, fused, res, cfused, spec_rec=None):
@@ -725,10 +761,13 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
             if len(pending_marks) >= checkpoint_every:
                 _flush_marks()
             return
-        res_h, lp_vals, lp_ids, gen_host = jax.device_get(
-            (res, fused.topk_logprobs, fused.topk_ids, fused.generated))
-        wconf, cgen_host = jax.device_get(
-            (cfused.weighted_confidence, cfused.generated))
+        with tracing.span("sweep/drain_wait"):
+            res_h, lp_vals, lp_ids, gen_host = jax.device_get(
+                (res, fused.topk_logprobs, fused.topk_ids, fused.generated))
+            wconf, cgen_host = jax.device_get(
+                (cfused.weighted_confidence, cfused.generated))
+        if last_readback is not None:
+            last_readback[:] = [time.monotonic()]
         if spec_rec is not None:
             # Prompt-lookup self-drafting warms itself: record each real
             # row's observed continuation into the radix tree's token
@@ -828,6 +867,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     # mark a row done that the accumulator lost.
     pending_marks: List[dict] = []
 
+    @tracing.span("sweep/flush")
     def _flush_marks():
         if sink is not None and accum_path is not None:
             sink.checkpoint(accum_path)
@@ -835,6 +875,14 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
         log.info("checkpoint: +%d rows (streaming-only) -> %s",
                  len(pending_marks), accum_path)
         del pending_marks[:]
+
+    seq_of = itertools.count().__next__   # dispatch numbers
+
+    def _enqueue(*item):
+        """Hand one dispatch's result handles to the writer; blocks
+        while the queue is full (the writer is behind)."""
+        with tracing.span("sweep/writer_wait", stage="put"):
+            work_q.put(item)
 
     def _writer():
         while True:
@@ -871,21 +919,26 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                 [target_ids[c.prompt_idx][0] for c in full], np.int32)
             t2 = np.asarray(
                 [target_ids[c.prompt_idx][1] for c in full], np.int32)
-            fused, cfused = _dispatch_with_recovery(
-                engine, lambda: engine.decode_fused_shared(
-                    [c.binary_prompt for c in full],
-                    [c.confidence_prompt for c in full],
-                    t1, t2, new_tokens=new_tokens, conf_tokens=conf_tokens,
-                    early_stop=early_stop),
-                # Legacy batches pick their bucket inside the engine;
-                # price at the ladder's widest edge (a generous deadline
-                # beats a hair-trigger one).
-                cost=sched_mod.bucket_cost(bsz, max(engine.buckets), B,
-                                           new_tokens + conf_tokens,
-                                           fused_decode=engine.rt.fused_decode))
-            res = score_mod.readout_from_fused(
-                fused, jnp.asarray(t1), jnp.asarray(t2), scan_positions=1)
-            work_q.put((batch, fused, res, cfused))
+            seq = seq_of()
+            with tracing.span("sweep/dispatch", kind="legacy", rows=n,
+                              dispatch=seq) as sid:
+                fused, cfused = _dispatch_with_recovery(
+                    engine, lambda: engine.decode_fused_shared(
+                        [c.binary_prompt for c in full],
+                        [c.confidence_prompt for c in full],
+                        t1, t2, new_tokens=new_tokens,
+                        conf_tokens=conf_tokens, early_stop=early_stop),
+                    # Legacy batches pick their bucket inside the engine;
+                    # price at the ladder's widest edge (a generous
+                    # deadline beats a hair-trigger one).
+                    cost=sched_mod.bucket_cost(
+                        bsz, max(engine.buckets), B,
+                        new_tokens + conf_tokens,
+                        fused_decode=engine.rt.fused_decode))
+                res = score_mod.readout_from_fused(
+                    fused, jnp.asarray(t1), jnp.asarray(t2),
+                    scan_positions=1)
+            _enqueue((seq, sid), batch, fused, res, cfused)
 
     # Chunked prefill/decode piggybacking: runs of CONSECUTIVE shared
     # dispatches with one compiled shape (the common case — bucket queues
@@ -914,8 +967,8 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     cascade_on = getattr(engine, "cascade_supported", lambda: False)()
     cascade_trunks = []
     piggy_keys = []
-    if ragged:
-        for d in dispatches:
+    with tracing.span("sweep/plan", stage="trunks"):
+        for d in (dispatches if ragged else ()):
             if d.kind == "shared":
                 n = len(d.items)
                 trunk = (engine.cascade_trunk_for(
@@ -950,12 +1003,14 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
             spec_rec = ([it.bin_ids for it in meta["full_items"]],
                         [it.conf_ids for it in meta["full_items"]],
                         meta["bucket"], meta["n"])
-        work_q.put((meta["batch"], fused, res, cfused, spec_rec))
+        _enqueue((meta["seq"], meta["span"]), meta["batch"], fused, res,
+                 cfused, spec_rec)
 
     def _plain_shared(meta):
         full_items, t1, t2 = meta["full_items"], meta["t1"], meta["t2"]
         with tracing.span("sweep/dispatch", bucket=int(meta["bucket"]),
-                          rows=int(meta["n"])):
+                          rows=int(meta["n"]),
+                          dispatch=meta["seq"]) as meta["span"]:
             fused, cfused = _dispatch_with_recovery(
                 engine, lambda: engine.decode_fused_shared(
                     [it.cell.binary_prompt for it in full_items],
@@ -972,7 +1027,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                     spec_decode=spec_on,
                     cascade=meta.get("trunk", 0) > 0,
                     trunk_tokens=meta.get("trunk", 0)))
-        _emit(meta, fused, cfused)
+            _emit(meta, fused, cfused)
 
     def _redispatch_pending():
         """Broken chain: the parked dispatch's carry is gone (possibly
@@ -987,11 +1042,14 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
             return
         meta = pending[0]
         try:
-            fused, cfused = _watched(
-                lambda: engine.piggy_drain(meta["t1"], meta["t2"]),
-                cost=sched_mod.decode_floor(
-                    meta["n"], B, new_tokens + conf_tokens,
-                    fused_decode=fused_dec))
+            with tracing.span("sweep/dispatch", kind="piggy_drain",
+                              rows=int(meta["n"]),
+                              dispatch=meta["seq"]):
+                fused, cfused = _watched(
+                    lambda: engine.piggy_drain(meta["t1"], meta["t2"]),
+                    cost=sched_mod.decode_floor(
+                        meta["n"], B, new_tokens + conf_tokens,
+                        fused_decode=fused_dec))
         except (KeyboardInterrupt, SystemExit):
             raise
         except BaseException as err:  # noqa: BLE001 — plain-path fallback
@@ -1019,6 +1077,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                      for it in full_items], np.int32)
                 meta = dict(batch=batch, full_items=full_items, t1=t1,
                             t2=t2, bucket=d.bucket, n=n, key=piggy_keys[i],
+                            seq=seq_of(),
                             sfx_ab=(d.sfx_bucket_a, d.sfx_bucket_b),
                             trunk=cascade_trunks[i])
                 # Chain iff the parked dispatch shares this shape, or this
@@ -1040,16 +1099,20 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                             prev["n"], B, new_tokens + conf_tokens,
                             fused_decode=fused_dec)
                     try:
-                        out = _watched(
-                            lambda: engine.decode_fused_shared_piggy(
-                                [it.bin_ids for it in full_items],
-                                [it.conf_ids for it in full_items],
-                                new_tokens, conf_tokens, early_stop,
-                                d.bucket,
-                                (d.sfx_bucket_a, d.sfx_bucket_b),
-                                prev_yes=(prev["t1"] if prev else None),
-                                prev_no=(prev["t2"] if prev else None)),
-                            cost)
+                        with tracing.span("sweep/dispatch", kind="piggy",
+                                          bucket=int(d.bucket), rows=n,
+                                          dispatch=meta["seq"]
+                                          ) as meta["span"]:
+                            out = _watched(
+                                lambda: engine.decode_fused_shared_piggy(
+                                    [it.bin_ids for it in full_items],
+                                    [it.conf_ids for it in full_items],
+                                    new_tokens, conf_tokens, early_stop,
+                                    d.bucket,
+                                    (d.sfx_bucket_a, d.sfx_bucket_b),
+                                    prev_yes=(prev["t1"] if prev else None),
+                                    prev_no=(prev["t2"] if prev else None)),
+                                cost)
                     except PiggybackIneligible as err:
                         log.info("piggyback ineligible (%s); dispatching "
                                  "plainly", err)
@@ -1083,8 +1146,10 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                 t2 = np.asarray(
                     [target_ids[it.cell.prompt_idx][1]
                      for it in d.items], np.int32)
+                seq = seq_of()
                 with tracing.span("sweep/dispatch", kind="grouped",
-                                  bucket=int(d.bucket), rows=n):
+                                  bucket=int(d.bucket), rows=n,
+                                  dispatch=seq) as sid:
                     out, m = _dispatch_with_recovery(
                         engine, lambda: engine.decode_fused_grouped(
                             d.groups, t1, t2, new_tokens, conf_tokens,
@@ -1096,31 +1161,31 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                         cost=sched_mod.bucket_cost(
                             2 * n, d.bucket, B, new_tokens + conf_tokens,
                             fused_decode=fused_dec))
-                # Member rows are [bin, conf] per cell: even rows carry
-                # the binary readout, odd rows the confidence one. Both
-                # ran the shared max(new, conf) budget, so each branch
-                # view trims its per-step fields back to ITS budget —
-                # greedy decoding is prefix-stable, so the trimmed tokens
-                # equal what a budget-exact decode would have produced
-                # (and the extra steps retire via the EOS stop when
-                # armed).
-                def _branch(start, budget):
-                    idx = slice(start, m, 2)
-                    return generate.FusedDecodeOut(
-                        generated=out.generated[idx, :budget],
-                        p_yes=out.p_yes[idx, :budget],
-                        p_no=out.p_no[idx, :budget],
-                        top2_ids=out.top2_ids[idx, :budget],
-                        topk_logprobs=out.topk_logprobs[idx],
-                        topk_ids=out.topk_ids[idx],
-                        weighted_confidence=out.weighted_confidence[idx])
+                    # Member rows are [bin, conf] per cell: even rows carry
+                    # the binary readout, odd rows the confidence one. Both
+                    # ran the shared max(new, conf) budget, so each branch
+                    # view trims its per-step fields back to ITS budget —
+                    # greedy decoding is prefix-stable, so the trimmed tokens
+                    # equal what a budget-exact decode would have produced
+                    # (and the extra steps retire via the EOS stop when
+                    # armed).
+                    def _branch(start, budget):
+                        idx = slice(start, m, 2)
+                        return generate.FusedDecodeOut(
+                            generated=out.generated[idx, :budget],
+                            p_yes=out.p_yes[idx, :budget],
+                            p_no=out.p_no[idx, :budget],
+                            top2_ids=out.top2_ids[idx, :budget],
+                            topk_logprobs=out.topk_logprobs[idx],
+                            topk_ids=out.topk_ids[idx],
+                            weighted_confidence=out.weighted_confidence[idx])
 
-                fused = _branch(0, new_tokens)
-                cfused = _branch(1, conf_tokens)
-                res = score_mod.readout_from_fused(
-                    fused, jnp.asarray(t1), jnp.asarray(t2),
-                    scan_positions=1)
-            work_q.put((batch, fused, res, cfused))
+                    fused = _branch(0, new_tokens)
+                    cfused = _branch(1, conf_tokens)
+                    res = score_mod.readout_from_fused(
+                        fused, jnp.asarray(t1), jnp.asarray(t2),
+                        scan_positions=1)
+            _enqueue((seq, sid), batch, fused, res, cfused)
         _drain_pending()   # close the piggyback chain's last dispatch
 
     wt = threading.Thread(target=_writer, name="sweep-writer", daemon=True)
@@ -1131,8 +1196,9 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
         else:
             _dispatch_legacy()
     finally:
-        work_q.put(None)
-        wt.join()
+        with tracing.span("sweep/writer_wait", stage="join"):
+            work_q.put(None)
+            wt.join()
     if writer_err:
         raise writer_err[0]
     if pending_marks:
@@ -1191,6 +1257,7 @@ def _reasoning_batch(engine, model_name, prompts, batch, full, seed,
     return pending_rows, rows
 
 
+@tracing.span("sweep/flush")
 def _flush(rows: List[schemas.PerturbationRow], results_path: Path,
            manifest: SweepManifest, sink=None, accum_path=None) -> None:
     """Atomic-append rows then mark them done (write-ahead order: a crash

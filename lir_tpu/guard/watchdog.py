@@ -37,6 +37,7 @@ import time
 import traceback
 from typing import Callable, Optional
 
+from ..observe import tracing
 from ..utils.logging import get_logger
 from ..utils.profiling import GuardStats
 
@@ -84,13 +85,17 @@ def watch_call(fn: Callable, deadline_s: Optional[float],
     - ``deadline_s=None`` waits forever (ticks still fire);
     - on expiry: dump all thread stacks to the log, abandon the worker
       (its eventual result or error is dropped and logged at INFO),
-      raise :class:`DispatchStalled`.
+      raise :class:`DispatchStalled`;
+    - the worker adopts the caller's open trace span as the parent of
+      the spans ``fn`` opens (observe/tracing.adopt).
     """
     done = threading.Event()
     box: dict = {}
     state = {"abandoned": False}
+    parent_span = tracing.current_span()
 
     def _run():
+        tracing.adopt(parent_span)
         try:
             box["result"] = fn()
         except BaseException as err:  # noqa: BLE001 — re-raised by caller

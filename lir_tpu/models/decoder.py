@@ -654,6 +654,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
+# Phase scopes (observe/tracing's naming: ``lir.<phase>``): every dispatch
+# program is built from these entry points, so each device operation's
+# op_name says which phase it belongs to (engine/compile_plan.scope_table
+# reads it back). The outermost scope decides: a caller that wraps an
+# ``extend`` in its own scope (the paged prefix window is prefill, the
+# speculative verify window is decode) re-labels it.
+@jax.named_scope("lir.prefill")
 def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
             attn_mask: jax.Array, max_len: int, attn_impl=None):
     """Run the prompt, fill the KV cache, return last-position logits.
@@ -708,6 +715,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     return logits, (ck, cv), next_positions
 
 
+@jax.named_scope("lir.extend")
 def extend(params: Params, cfg: ModelConfig, cache, suffix_tokens: jax.Array,
            suffix_mask: jax.Array, cache_mask: jax.Array, start_index: int):
     """Teacher-forced multi-token cache extension (chunked prefill).
@@ -745,6 +753,7 @@ def extend(params: Params, cfg: ModelConfig, cache, suffix_tokens: jax.Array,
     return logits, new_cache, next_positions
 
 
+@jax.named_scope("lir.prefill")
 def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
                    rem_tokens: jax.Array, rem_mask: jax.Array,
                    trunk_len: int, total_len: int, int8_qk: bool = False):
@@ -807,6 +816,7 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
     return side(tck, rk), side(tcv, rv)
 
 
+@jax.named_scope("lir.extend")
 def verify_extend(params: Params, cfg: ModelConfig, cache,
                   chunk_tokens: jax.Array, cache_mask: jax.Array,
                   start_index: jax.Array, trunk_len: int = 0):
@@ -862,6 +872,7 @@ def verify_extend(params: Params, cfg: ModelConfig, cache,
     return logits, new_cache
 
 
+@jax.named_scope("lir.decode")
 def decode_step(params: Params, cfg: ModelConfig, cache, token: jax.Array,
                 position: jax.Array, step_index: jax.Array,
                 prompt_mask: jax.Array, trunk_len: int = 0):

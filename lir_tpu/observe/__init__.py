@@ -8,12 +8,13 @@ Three pieces, each usable alone, designed to compose:
   ``{"op": "metrics"}`` JSONL endpoint and dumped per sweep; the
   ``metrics-drift`` lint pass (lir_tpu/lint/metricsdrift.py) proves
   statically that no public counter field can silently drop out of it.
-- :mod:`~lir_tpu.observe.tracing` — per-request structured trace spans
-  over the full serving lifecycle (admit → queue → batch-form →
-  dispatch → readout → resolve, plus fleet weight-swap and stream-fold
-  spans), correlated with device traces via
-  ``jax.profiler.TraceAnnotation`` and exportable as Chrome/Perfetto
-  trace JSON (``--trace-out``).
+- :mod:`~lir_tpu.observe.tracing` — structured trace spans with id,
+  parent and cause over the serving lifecycle (admit → queue →
+  batch-form → dispatch → readout → resolve), the whole offline sweep
+  call and the compile plan, per-name totals published as the metrics
+  source ``spans``, correlated with device traces via
+  ``jax.profiler.TraceAnnotation`` and ``clock_anchor`` and exportable
+  as Chrome/Perfetto trace JSON (``--trace-out``).
 - :mod:`~lir_tpu.observe.drift` + :mod:`~lir_tpu.observe.sentinel` —
   the reliability observatory itself: a :class:`SentinelScheduler` on
   the fleet server re-scores a sentinel grid on interval and on weight-

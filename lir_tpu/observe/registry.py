@@ -44,8 +44,9 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "decode_steps_live", "decode_steps_paid",
     ),
     "CompileStats": (
-        "shapes", "aot_hits", "lazy_misses", "persistent_requests",
-        "persistent_hits", "cold_start_s", "warm_start_s",
+        "shapes", "aot_hits", "aot_shapes_hit", "lazy_misses",
+        "load_wall_s", "persistent_requests", "persistent_hits",
+        "cold_start_s", "warm_start_s",
     ),
     "KernelStats": ("phases", "counters"),
     "ServeStats": (
@@ -259,8 +260,13 @@ def engine_registry(engine, sink=None,
     """Register one ScoringEngine's stats objects (the per-sweep dump
     and the single-model server both use this): guard, compile, fault,
     kernel, prefix, occupancy when set, and the streaming sink's
-    counters when a sink is attached."""
+    counters when a sink is attached; plus the process's span totals
+    (observe/tracing.TOTALS: per span name, count / total / self
+    seconds) as source ``spans``."""
+    from . import tracing
+
     reg = registry if registry is not None else MetricsRegistry()
+    reg.register("spans", tracing.TOTALS)
     reg.register("guard", engine.guard_stats)
     reg.register("compile", engine.compile_stats)
     reg.register("faults", engine.fault_stats)

@@ -169,6 +169,7 @@ def _prefix_partials(q, trunk_k, trunk_v, slopes, int8_qk: bool,
             jax.ShapeDtypeStruct((K, npad, 1), f32),
         ],
         interpret=interpret,
+        name="cascade_attention_prefix",
     )(sl, kscale, qf, trunk_k, trunk_v)
 
     def unflat(x):
@@ -331,6 +332,7 @@ def _cascade_fused(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
                                lambda h, b, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, RG, hd), jnp.float32),
         interpret=interpret,
+        name="cascade_attention",
     )(sl, qpos, kpos, smask, qf, skt, svt, trunk_k, trunk_v)
     out = out.reshape(B, K, R, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, R, H, hd).astype(q.dtype)

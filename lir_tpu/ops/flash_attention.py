@@ -180,6 +180,7 @@ def flash_attention(
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(first_valid[:, None], slopes, key_mask[:, None, :],
       key_positions[:, None, :], qt, kt, vt)
     return jnp.swapaxes(out, 1, 2)

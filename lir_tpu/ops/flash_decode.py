@@ -141,10 +141,14 @@ def _decode_kernel(rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref,
     l_ref[0, 0, 0, 0] = p.sum(axis=-1, keepdims=True)
 
 
-def _decode_call(q, k, v, q_positions, key_mask, key_positions,
+def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
                  alibi_slopes, trunk_len: int, block_k: int,
                  interpret: bool):
-    """The one pallas_call behind all four entry points. ``q``:
+    """The one pallas_call behind all four entry points, under the entry
+    point's ``name``: the name a profiler trace shows for the kernel
+    (``flash_decode_trunk``, ``flash_decode_mq`` ...), pinned here so
+    that renaming a jitted wrapper cannot silence the benchmark's
+    ``decode_kernel_roofline``. ``q``:
     (B, S, H, hd), ``q_positions``: (B, S). Grid (K, T/split, B/bb, S):
     the window-query axis is innermost and the batch-block axis next, so
     consecutive programs that name the same K/V block skip its DMA —
@@ -227,6 +231,7 @@ def _decode_call(q, k, v, q_positions, key_mask, key_positions,
             jax.ShapeDtypeStruct((nB, K, S, n_splits, R, 1), f32),
         ],
         interpret=interpret,
+        name=name,
     )(rowb, laneb, qpos, kpos, slopes, qg, k, v)
 
     # Log-sum-exp combine across splits (ops/lse.merge_partials, shared
@@ -262,9 +267,9 @@ def flash_decode(
     emits a partial (o, m, l); the final output is the log-sum-exp
     combination of the splits — exact attention, any split count.
     """
-    return _decode_call(q[:, None], k, v, q_positions[:, None], key_mask,
-                        key_positions, alibi_slopes, 0, block_k,
-                        interpret)[:, 0]
+    return _decode_call("flash_decode", q[:, None], k, v,
+                        q_positions[:, None], key_mask, key_positions,
+                        alibi_slopes, 0, block_k, interpret)[:, 0]
 
 
 @functools.partial(jax.jit,
@@ -292,9 +297,9 @@ def flash_decode_trunk(
     and layer it saves ``2 * K * nt*split * hd * itemsize * (B - bb)``
     trunk bytes, nt the trunk split count and bb the batch block.
     """
-    return _decode_call(q[:, None], k, v, q_positions[:, None], key_mask,
-                        key_positions, alibi_slopes, trunk_len, block_k,
-                        interpret)[:, 0]
+    return _decode_call("flash_decode_trunk", q[:, None], k, v,
+                        q_positions[:, None], key_mask, key_positions,
+                        alibi_slopes, trunk_len, block_k, interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -320,8 +325,8 @@ def flash_decode_mq(
     of the single-query kernel, so per-query results are the single-query
     kernel's for the same cache state (pinned by tests/test_spec_decode).
     """
-    return _decode_call(q, k, v, q_positions, key_mask, key_positions,
-                        alibi_slopes, 0, block_k, interpret)
+    return _decode_call("flash_decode_mq", q, k, v, q_positions, key_mask,
+                        key_positions, alibi_slopes, 0, block_k, interpret)
 
 
 @functools.partial(jax.jit,
@@ -340,5 +345,6 @@ def flash_decode_mq_trunk(
     :func:`flash_decode_trunk` index map, so speculative verify windows
     in a shared-trunk dispatch fetch the trunk KV once per (kv head,
     split) per verify pass."""
-    return _decode_call(q, k, v, q_positions, key_mask, key_positions,
-                        alibi_slopes, trunk_len, block_k, interpret)
+    return _decode_call("flash_decode_mq_trunk", q, k, v, q_positions,
+                        key_mask, key_positions, alibi_slopes, trunk_len,
+                        block_k, interpret)

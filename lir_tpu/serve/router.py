@@ -782,11 +782,6 @@ class ReplicaRouter:
                                  remaining_s=remaining)
                 if nxt is not None:
                     self.stats.count("failovers")
-                    tracing.add_span("router/failover", now,
-                                     self.clock(),
-                                     request_id=pending.request.request_id,
-                                     frm=handle.replica_id,
-                                     to=nxt.replica_id)
                     self._attempt(pending, nxt, "failover")
                     return
             if not pending.claim_resolution():
@@ -818,11 +813,6 @@ class ReplicaRouter:
                          self.clock() + self.migrate_config.timeout_s)
         with self._lock:
             self._migrations[id(mig)] = mig
-        tracing.add_span("router/migrate_start", self.clock(),
-                         self.clock(),
-                         request_id=pending.request.request_id,
-                         src=src.replica_id, dst=dst.replica_id,
-                         prefill=need_prefill)
         if need_prefill:
             self.migrate_stats.count("prefill_ops")
             fut = src.server.submit_prefill(bucket, prefix)
@@ -897,10 +887,6 @@ class ReplicaRouter:
             self.migrator.account(export, imp)
         else:
             self.migrate_stats.count("cluster_tree_hits")
-        tracing.add_span("router/migrate_done", self.clock(),
-                         self.clock(),
-                         request_id=mig.pending.request.request_id,
-                         pages=int(imp.pages))
         self._attempt(mig.pending, mig.dst, "migrated")
 
     def _mig_fallback(self, mig: _Migration, reason: str) -> None:
@@ -979,8 +965,6 @@ class ReplicaRouter:
             n += 1
             self.stats.count("re_admitted")
             self._attempt(p, nxt, "re_admit")
-        tracing.add_span("router/replica_kill", t0, self.clock(),
-                         replica=replica_id, re_admitted=n)
         log.warning("router: replica %s killed; %d in-flight request(s) "
                     "re-admitted to survivors", replica_id, n)
         return n
@@ -1036,7 +1020,4 @@ class ReplicaRouter:
                     continue
                 p.hedged = True
             self.stats.count("hedged")
-            tracing.add_span("router/hedge", now, self.clock(),
-                             request_id=p.request.request_id,
-                             to=nxt.replica_id)
             self._attempt(p, nxt, "hedge")

@@ -26,6 +26,7 @@ import hashlib
 import json
 import os
 import threading
+import time
 from pathlib import Path
 from typing import Any, Dict, Optional, Sequence
 
@@ -42,6 +43,9 @@ ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 # key, so a directory that moves — a temp dir, a pid, a timestamp —
 # never hits.
 REPO_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+# Thread-name prefix of engine/compile_plan's precompile pool.
+COMPILE_PLAN_THREADS = "compile-plan"
 
 _state_lock = threading.Lock()
 _enabled_dir: Optional[Path] = None
@@ -79,6 +83,25 @@ def _on_event(event: str, **kwargs) -> None:
         _requests += 1
     elif event == "/jax/compilation_cache/cache_hits":
         _hits += 1
+        # JAX reports on the compiling thread: the span open there (the
+        # plan's engine/compile_load) learns that the disk served it.
+        from ..observe import tracing
+
+        tracing.annotate(persistent_cache_hit=True)
+
+
+def _on_duration(event: str, seconds: float, **kwargs) -> None:
+    """A backend compile (or load from the persistent cache) on a thread
+    that is not the compile plan's: trace-on-first-call. Recorded as the
+    span ``engine/compile_lazy`` under whatever span is open there, so a
+    compile inside a dispatch has a name and a culprit."""
+    if (event.endswith("backend_compile_duration")
+            and not threading.current_thread().name.startswith(
+                COMPILE_PLAN_THREADS)):
+        from ..observe import tracing
+
+        now = time.monotonic()
+        tracing.add_span("engine/compile_lazy", now - seconds, now)
 
 
 def install_cache_listener() -> None:
@@ -93,6 +116,7 @@ def install_cache_listener() -> None:
 
     jax.monitoring.register_event_listener(
         lambda event, **kw: _on_event(event))
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def persistent_cache_counters() -> Dict[str, int]:

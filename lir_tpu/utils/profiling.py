@@ -224,8 +224,15 @@ class CompileStats:
     - ``shapes``: per-shape AOT compile seconds, keyed by the spec label
       (kind/bucket/batch/suffixes/variant) — the itemized cold-start bill.
     - ``aot_hits``: dispatches served by a registry executable;
+      ``aot_shapes_hit``: how many DISTINCT planned executables were
+      ever dispatched (counted on a label's first hit) — against
+      ``aot_shapes`` it says what share of the loaded plan ran at all;
       ``lazy_misses``: dispatches that fell back to trace-on-first-call
       (registry miss, failed compile, or precompile disabled).
+    - ``load_wall_s``: wall seconds during which at least one planned
+      executable was being compiled or loaded (the union of the
+      ``engine/compile_load`` spans; the pool works in parallel, so the
+      per-shape seconds sum to more).
     - ``persistent_requests/hits``: XLA persistent-cache counters for the
       window between ``snapshot_persistent()`` and ``finish_persistent()``
       (the jax.monitoring events are process-global; the snapshot diff
@@ -237,16 +244,51 @@ class CompileStats:
 
     shapes: Dict[str, float] = dataclasses.field(default_factory=dict)
     aot_hits: int = 0
+    aot_shapes_hit: int = 0
     lazy_misses: int = 0
+    load_wall_s: float = 0.0
     persistent_requests: int = 0
     persistent_hits: int = 0
     cold_start_s: Optional[float] = None
     warm_start_s: Optional[float] = None
     _persistent_base: Optional[Dict[str, int]] = None
 
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+        self._hit_labels: set = set()
+        self._loading = 0           # loads in flight
+        self._loading_since = 0.0   # when the first of them began
+
     def record_shape(self, label: str, seconds: float) -> None:
         self.shapes[label] = round(
             self.shapes.get(label, 0.0) + seconds, 4)
+
+    def hit(self, label: str) -> None:
+        """One dispatch served by the registry executable ``label``."""
+        with self._lock:
+            self.aot_hits += 1
+            if label not in self._hit_labels:
+                self._hit_labels.add(label)
+                self.aot_shapes_hit += 1
+
+    @contextlib.contextmanager
+    def loading(self) -> Iterator[None]:
+        """Around one planned executable's compile or load, on any
+        thread: ``load_wall_s`` grows by the time any was in flight."""
+        with self._lock:
+            if not self._loading:
+                self._loading_since = time.monotonic()
+            self._loading += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._loading -= 1
+                if not self._loading:
+                    self.load_wall_s += (time.monotonic()
+                                         - self._loading_since)
 
     @property
     def compile_s(self) -> float:
@@ -273,7 +315,9 @@ class CompileStats:
         out: Dict[str, object] = {
             "aot_shapes": len(self.shapes),
             "aot_compile_s": self.compile_s,
+            "aot_load_wall_s": round(self.load_wall_s, 4),
             "aot_hits": self.aot_hits,
+            "aot_shapes_hit": self.aot_shapes_hit,
             "lazy_misses": self.lazy_misses,
             "persistent_cache_requests": self.persistent_requests,
             "persistent_cache_hits": self.persistent_hits,

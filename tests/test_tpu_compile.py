@@ -176,3 +176,53 @@ def test_compiled_text_carries_the_kernel(one_chip):
             (cascade_attention, _cascade_args("gqa", 64, 384), {})):
         text = _compile(fn, args, one_chip, **static).as_text()
         assert "tpu_custom_call" in text, fn
+
+
+def _flash_attention_args():
+    H, _, hd = LAYOUTS["gqa"]
+    qkv = ((BATCH, 512, H, hd), jnp.bfloat16)
+    return [qkv, qkv, qkv]
+
+
+# Entry point, arguments, statics, and the name the compiled program (and
+# so a profiler trace on the chip) must show for its Mosaic call. The
+# benchmark's ``decode_kernel_roofline`` and ``cascade_prefill_roofline``
+# find the kernels by these names (``^flash_decode\w*trunk``,
+# ``^cascade_attention``): the name is pinned on the ``pallas_call``
+# itself, not borrowed from whichever jitted wrapper encloses it.
+KERNEL_NAMES = [
+    ("flash_decode", flash_decode, lambda: _decode_args("gqa", 512), {}),
+    ("flash_decode_trunk", flash_decode_trunk,
+     lambda: _decode_args("gqa", 512), {"trunk_len": 384}),
+    ("flash_decode_mq", flash_decode_mq,
+     lambda: _decode_args("gqa", 512, window=WINDOW), {}),
+    ("flash_decode_mq_trunk", flash_decode_mq_trunk,
+     lambda: _decode_args("gqa", 512, window=WINDOW), {"trunk_len": 384}),
+    ("cascade_attention", cascade_attention,
+     lambda: _cascade_args("gqa", 64, 384), {}),
+    ("cascade_attention_prefix", cascade_attention,
+     lambda: _cascade_args("gqa", 64, 384), {"fused_suffix": False}),
+    ("flash_attention", flash_attention, _flash_attention_args,
+     {"causal": True}),
+]
+
+
+@pytest.mark.parametrize("name,fn,args,static", KERNEL_NAMES,
+                         ids=[k[0] for k in KERNEL_NAMES])
+def test_kernel_keeps_its_name_under_a_renamed_wrapper(one_chip, name, fn,
+                                                       args, static):
+    import re
+
+    def some_other_wrapper(*a):
+        return fn(*a, **static)
+
+    shaped = [None if a is None else
+              jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip)
+              for a in args()]
+    text = jax.jit(some_other_wrapper).lower(*shaped).compile().as_text()
+    calls = [re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = ", line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line
+             and " custom-call(" in line]
+    assert calls, "no Mosaic call in the compiled program"
+    assert any(re.fullmatch(re.escape(name) + r"(\.\d+)*", c)
+               for c in calls), (name, calls)
