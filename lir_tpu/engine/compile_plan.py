@@ -266,7 +266,9 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
     bucket's executable compiles first and the dispatch loop rarely
     waits). Mirrors the runner's padding/handoff behavior exactly:
     the first dispatch of each handoff key runs the scratchless variant,
-    every consecutive same-key dispatch the donated one.
+    every consecutive same-key dispatch the donated one. A spec's
+    ``bucket`` is the prefix extent the dispatch RUNS at — the plan's
+    tight ``Dispatch.edge`` — since that is what the runner is handed.
 
     ``prefix_page_size`` > 0 (an engine whose cross-request prefix cache
     is enabled) additionally plans the block-table executables: for each
@@ -326,7 +328,7 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
     for d in dispatches:
         g_pad, m_pad = d.padded_rows(batch_size)
         if d.kind == "shared":
-            key = ("shared", d.bucket, m_pad, d.sfx_bucket_a,
+            key = ("shared", d.edge, m_pad, d.sfx_bucket_a,
                    d.sfx_bucket_b, new_tokens, conf_tokens)
             scratch = key == prev_key
             trunk = int(cascade_trunk(d)) if cascade_trunk else 0
@@ -336,7 +338,7 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
             # dead compiles.
             dt = (int(decode_trunk(d))
                   if (decode_trunk is not None and not trunk) else 0)
-            add(shared_spec(d.bucket, m_pad, d.sfx_bucket_a,
+            add(shared_spec(d.edge, m_pad, d.sfx_bucket_a,
                             d.sfx_bucket_b, new_tokens, conf_tokens,
                             stops_armed, scratch=scratch,
                             decode_trunk=dt))
@@ -345,13 +347,13 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
                 # (bucket, batch, k) alongside the sequential shape
                 # (the runner falls back to it on a spec-ineligible
                 # dispatch).
-                add(shared_spec(d.bucket, m_pad, d.sfx_bucket_a,
+                add(shared_spec(d.edge, m_pad, d.sfx_bucket_a,
                                 d.sfx_bucket_b, new_tokens, conf_tokens,
                                 stops_armed, scratch=scratch,
                                 spec_k=spec_k, spec_draft=spec_draft,
                                 decode_trunk=dt))
             if trunk:
-                add(shared_cascade_spec(d.bucket, m_pad, trunk,
+                add(shared_cascade_spec(d.edge, m_pad, trunk,
                                         d.sfx_bucket_a, d.sfx_bucket_b,
                                         new_tokens, conf_tokens,
                                         stops_armed, scratch=scratch,
@@ -360,26 +362,26 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
                     for w in paged_mod.window_edges(trunk,
                                                     prefix_page_size):
                         add(shared_cascade_paged_spec(
-                            d.bucket, m_pad, trunk, w, d.sfx_bucket_a,
+                            d.edge, m_pad, trunk, w, d.sfx_bucket_a,
                             d.sfx_bucket_b, new_tokens, conf_tokens,
                             stops_armed, scratch=scratch,
                             int8_qk=cascade_int8))
             if piggyback and scratch and not trunk:
                 # A repeat of the previous shared shape — the sweep will
                 # chain these dispatches: plan all three chain stages.
-                add(piggy_prefill_spec(d.bucket, m_pad, d.sfx_bucket_a,
+                add(piggy_prefill_spec(d.edge, m_pad, d.sfx_bucket_a,
                                        d.sfx_bucket_b, new_tokens,
                                        conf_tokens))
-                add(piggy_step_spec(d.bucket, m_pad, d.sfx_bucket_a,
+                add(piggy_step_spec(d.edge, m_pad, d.sfx_bucket_a,
                                     d.sfx_bucket_b, new_tokens,
                                     conf_tokens, stops_armed))
-                add(piggy_drain_spec(d.bucket, m_pad, d.sfx_bucket_a,
+                add(piggy_drain_spec(d.edge, m_pad, d.sfx_bucket_a,
                                      d.sfx_bucket_b, new_tokens,
                                      conf_tokens, stops_armed))
             if prefix_page_size:
-                for w in paged_mod.window_edges(d.bucket, prefix_page_size):
+                for w in paged_mod.window_edges(d.edge, prefix_page_size):
                     add(shared_paged_spec(
-                        d.bucket, m_pad, w, d.sfx_bucket_a, d.sfx_bucket_b,
+                        d.edge, m_pad, w, d.sfx_bucket_a, d.sfx_bucket_b,
                         new_tokens, conf_tokens, stops_armed,
                         scratch=scratch, decode_trunk=dt))
                     if spec_k and not spec_draft:
@@ -388,21 +390,21 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
                         # prefix tokens — nothing for a draft model to
                         # prefill from).
                         add(shared_paged_spec(
-                            d.bucket, m_pad, w, d.sfx_bucket_a,
+                            d.edge, m_pad, w, d.sfx_bucket_a,
                             d.sfx_bucket_b, new_tokens, conf_tokens,
                             stops_armed, scratch=scratch, spec_k=spec_k,
                             decode_trunk=dt))
         else:
             sfx = max(d.sfx_bucket_a, d.sfx_bucket_b)
             max_new = max(new_tokens, conf_tokens)
-            key = ("grouped", d.bucket, g_pad, m_pad, sfx, max_new)
+            key = ("grouped", d.edge, g_pad, m_pad, sfx, max_new)
             scratch = key == prev_key
-            add(grouped_spec(d.bucket, g_pad, m_pad, sfx, max_new,
+            add(grouped_spec(d.edge, g_pad, m_pad, sfx, max_new,
                              stops_armed, scratch=scratch))
             if prefix_page_size:
-                for w in paged_mod.window_edges(d.bucket, prefix_page_size):
+                for w in paged_mod.window_edges(d.edge, prefix_page_size):
                     add(grouped_paged_spec(
-                        d.bucket, g_pad, m_pad, w, sfx, max_new,
+                        d.edge, g_pad, m_pad, w, sfx, max_new,
                         stops_armed, scratch=scratch))
         prev_key = key
     return specs

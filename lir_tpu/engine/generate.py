@@ -22,7 +22,20 @@ from jax import lax
 from ..models import decoder
 from ..models.registry import ModelConfig, T5Config
 from ..models import encdec
+from ..ops.flash_decode import decode_extent
 from . import tokens as _tok
+
+
+def cache_extent(cfg: ModelConfig, need: int, batch: int) -> int:
+    """Slots a dispatch program allocates for a KV cache that must hold
+    ``need`` of them (prefix edge + suffix edge + decode budget) at
+    ``batch`` rows: the decode kernel's own rule
+    (ops/flash_decode.decode_extent — an extent its key splits tile
+    well, at most 32 masked slots above ``need``). EVERY program below
+    sizes its cache through here and nowhere else, so the compile plan
+    (which lowers these same functions), the donated handoff buffer and
+    the dispatch can never disagree on the extent."""
+    return decode_extent(need, batch, cfg.n_heads // cfg.n_kv_heads)
 
 
 @jax.tree_util.register_dataclass
@@ -215,10 +228,10 @@ def greedy_decode_fused(params, cfg: ModelConfig, tokens: jax.Array,
     enable the confidence early stop (see _fused_tail).
     """
     B, S = tokens.shape
-    T = S + max_new_tokens
+    T = cache_extent(cfg, S + max_new_tokens, B)
     pf = prefill_fn or decoder.prefill
     logits0, cache, pos0 = pf(params, cfg, tokens, attn_mask, T)
-    cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, max_new_tokens)))
+    cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, T - S)))
     out, _ = _fused_tail(params, cfg, logits0, cache, cache_mask0, pos0, S,
                          yes_ids, no_ids, digit_ids, digit_vals,
                          max_new_tokens, topk, stop_mask=stop_mask,
@@ -276,7 +289,7 @@ def greedy_decode_fused_grouped(params, cfg: ModelConfig, prefix: jax.Array,
     del scratch_cache  # donated scratch: memory reuse only, never read
     G, S = prefix.shape
     M, S2 = sfx.shape
-    T0 = S + S2 + max_new
+    T0 = cache_extent(cfg, S + S2 + max_new, M)
     pf = prefill_fn or decoder.prefill
     _, gcache, _ = pf(params, cfg, prefix, prefix_mask, T0)
 
@@ -285,7 +298,7 @@ def greedy_decode_fused_grouped(params, cfg: ModelConfig, prefix: jax.Array,
     cache = cache_mod.gather_rows(gcache, group_idx)
     pm = jnp.take(prefix_mask, group_idx, axis=0)              # (M, S)
     cm = jnp.concatenate(
-        [pm, sfx_mask, jnp.zeros((M, max_new), pm.dtype)], axis=1)
+        [pm, sfx_mask, jnp.zeros((M, T0 - S - S2), pm.dtype)], axis=1)
     logits_l, cache2, pos = decoder.extend(
         params, cfg, cache, sfx, sfx_mask, cm, S)
     out, cache_f = _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
@@ -408,7 +421,7 @@ def greedy_decode_fused_shared_paged(params, cfg: ModelConfig, pool,
     del scratch_cache  # donated scratch: memory reuse only, never read
     B, S = prefix_mask.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = S + max(S2a + max_new_a, S2b + max_new_b)
+    T0 = cache_extent(cfg, S + max(S2a + max_new_a, S2b + max_new_b), B)
     cache = _paged_prefix(params, cfg, pool, slot_src, win_start,
                           prefix_mask, rem, rem_mask, T0)
 
@@ -452,7 +465,7 @@ def _cascade_branches(params, cfg: ModelConfig, tcache, trunk_len: int,
     the dense path's donated cache buffer (same cache aval)."""
     B, S = prefix.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = S + max(S2a + max_new_a, S2b + max_new_b)
+    T0 = cache_extent(cfg, S + max(S2a + max_new_a, S2b + max_new_b), B)
     # Static trunk split: slots [0, trunk_len) are the shared trunk
     # (right-padded canonical layout — slot == position), the remainder
     # is everything after, per row.
@@ -615,7 +628,7 @@ def greedy_decode_fused_grouped_paged(params, cfg: ModelConfig, pool,
     del scratch_cache  # donated scratch: memory reuse only, never read
     G, S = prefix_mask.shape
     M, S2 = sfx.shape
-    T0 = S + S2 + max_new
+    T0 = cache_extent(cfg, S + S2 + max_new, M)
     gcache = _paged_prefix(params, cfg, pool, slot_src, win_start,
                            prefix_mask, rem, rem_mask, T0)
 
@@ -624,7 +637,7 @@ def greedy_decode_fused_grouped_paged(params, cfg: ModelConfig, pool,
     cache = cache_mod.gather_rows(gcache, group_idx)
     pm = jnp.take(prefix_mask, group_idx, axis=0)              # (M, S)
     cm = jnp.concatenate(
-        [pm, sfx_mask, jnp.zeros((M, max_new), pm.dtype)], axis=1)
+        [pm, sfx_mask, jnp.zeros((M, T0 - S - S2), pm.dtype)], axis=1)
     logits_l, cache2, pos = decoder.extend(
         params, cfg, cache, sfx, sfx_mask, cm, S)
     out, cache_f = _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
@@ -997,8 +1010,8 @@ def _shared_spec_branches(params, cfg: ModelConfig, cache, dcache,
     B, S = prefix_mask.shape
     empty_ids = jnp.zeros((0,), jnp.int32)
     empty_vals = jnp.zeros((0,), jnp.float32)
-    T0_seq = S + max(sfx_a.shape[1] + max_new_a,
-                     sfx_b.shape[1] + max_new_b)
+    T0_seq = cache_extent(cfg, S + max(sfx_a.shape[1] + max_new_a,
+                                       sfx_b.shape[1] + max_new_b), B)
 
     def _extend_seq_extent(ext_params, ext_cfg, cache_in, sfx, sfx_mask):
         S2 = sfx.shape[1]
@@ -1047,13 +1060,18 @@ def _shared_spec_branches(params, cfg: ModelConfig, cache, dcache,
     return out_a, out_b, spec_a, spec_b
 
 
-def spec_total_len(bucket: int, sfx_a: int, sfx_b: int, max_new_a: int,
-                   max_new_b: int, spec_k: int) -> int:
+def spec_total_len(cfg: ModelConfig, batch: int, bucket: int, sfx_a: int,
+                   sfx_b: int, max_new_a: int, max_new_b: int,
+                   spec_k: int) -> int:
     """Cache length a speculative shared dispatch allocates: each of the
     T decode windows owns spec_k slots (rejected tails stay masked), so
-    the decode region is budget * spec_k instead of budget."""
-    return bucket + max(sfx_a + max_new_a * spec_k,
-                        sfx_b + max_new_b * spec_k)
+    the decode region is budget * spec_k instead of budget — on the
+    decode kernel's grid like every dispatch cache (:func:`cache_extent`,
+    monotone, so never below the sequential extent the suffix extension
+    views)."""
+    return cache_extent(cfg, bucket + max(sfx_a + max_new_a * spec_k,
+                                          sfx_b + max_new_b * spec_k),
+                        batch)
 
 
 @functools.partial(jax.jit,
@@ -1091,7 +1109,7 @@ def greedy_decode_fused_shared_spec(
     del scratch_cache  # donated scratch: memory reuse only, never read
     B, S = prefix.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = spec_total_len(S, S2a, S2b, max_new_a, max_new_b, spec_k)
+    T0 = spec_total_len(cfg, B, S, S2a, S2b, max_new_a, max_new_b, spec_k)
     pf = prefill_fn or decoder.prefill
     _, cache, _ = pf(params, cfg, prefix, prefix_mask, T0)
     dcache = None
@@ -1136,7 +1154,7 @@ def greedy_decode_fused_shared_paged_spec(
     del scratch_cache  # donated scratch: memory reuse only, never read
     B, S = prefix_mask.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = spec_total_len(S, S2a, S2b, max_new_a, max_new_b, spec_k)
+    T0 = spec_total_len(cfg, B, S, S2a, S2b, max_new_a, max_new_b, spec_k)
     cache = _paged_prefix(params, cfg, pool, slot_src, win_start,
                           prefix_mask, rem, rem_mask, T0)
     return _shared_spec_branches(
@@ -1190,7 +1208,7 @@ def _piggyback_extend(params, cfg: ModelConfig, prefix, prefix_mask,
     disjoint-region piggyback cache layout (see PiggybackCarry)."""
     B, S = prefix.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T = S + S2a + max_new_a + S2b + max_new_b
+    T = cache_extent(cfg, S + S2a + max_new_a + S2b + max_new_b, B)
     pf = prefill_fn or decoder.prefill
     _, cache, _ = pf(params, cfg, prefix, prefix_mask, T)
     zeros = functools.partial(jnp.zeros, dtype=prefix_mask.dtype)
@@ -1201,7 +1219,7 @@ def _piggyback_extend(params, cfg: ModelConfig, prefix, prefix_mask,
     off_b = S + S2a + max_new_a
     cm_b = jnp.concatenate(
         [prefix_mask, zeros((B, S2a + max_new_a)), sfx_b_mask,
-         zeros((B, max_new_b))], axis=1)
+         zeros((B, T - off_b - S2b))], axis=1)
     logits_b, cache, pos_b = decoder.extend(
         params, cfg, cache, sfx_b, sfx_b_mask, cm_b, off_b)
     return PiggybackCarry(logits_a=logits_a, logits_b=logits_b, cache=cache,
@@ -1341,7 +1359,7 @@ def greedy_decode_fused_shared(params, cfg: ModelConfig, prefix: jax.Array,
     del scratch_cache  # donated scratch: memory reuse only, never read
     B, S = prefix.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = S + max(S2a + max_new_a, S2b + max_new_b)
+    T0 = cache_extent(cfg, S + max(S2a + max_new_a, S2b + max_new_b), B)
     pf = prefill_fn or decoder.prefill
     _, cache, _ = pf(params, cfg, prefix, prefix_mask, T0)
 
@@ -1387,11 +1405,11 @@ def greedy_decode(params, cfg: ModelConfig, tokens: jax.Array,
     Returns (generated (B, max_new_tokens) int32,
              step_logits (B, max_new_tokens, V) fp32)."""
     B, S = tokens.shape
-    T = S + max_new_tokens
+    T = cache_extent(cfg, S + max_new_tokens, B)
     pf = prefill_fn or decoder.prefill
     logits0, cache, pos0 = pf(params, cfg, tokens, attn_mask, T)
 
-    cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, max_new_tokens)))
+    cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, T - S)))
 
     def step(carry, t):
         logits, cache, cache_mask = carry
@@ -1436,12 +1454,12 @@ def sample_decode(params, cfg: ModelConfig, tokens: jax.Array,
     captured — rephrasings need text only, and dropping the (B, T, V) stack
     keeps HBM free for long sample runs."""
     B, S = tokens.shape
-    T = S + max_new_tokens
+    T = cache_extent(cfg, S + max_new_tokens, B)
     per_row = is_per_row_keys(key)
     early = eos_id is not None
     pf = prefill_fn or decoder.prefill
     logits0, cache, pos0 = pf(params, cfg, tokens, attn_mask, T)
-    cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, max_new_tokens)))
+    cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, T - S)))
 
     def step(carry, xs):
         logits, cache, cache_mask, done = carry

@@ -600,17 +600,17 @@ class ScoringEngine:
             return 0
         return self._lcp_trunk(prefix_ids, n_real, bucket)
 
-    def _note_cascade_decode(self, dtrunk: int, rows: int, bucket: int,
-                             ba: int, bb: int, new_tokens: int,
-                             conf_tokens: int) -> None:
+    def _note_cascade_decode(self, dtrunk: int, rows: int, cache,
+                             new_tokens: int, conf_tokens: int) -> None:
         """Fold one trunk-aware decode dispatch into the cascade
         counters: the analytic HBM bytes the trunk dedup did NOT stream
         (trunk K/V tiles load once per decode step instead of once per
         row — profiling.cascade_decode_bytes_saved), over both format
-        branches' full decode budgets."""
+        branches' full decode budgets, at the extent of the dispatch's
+        own ``cache`` (slots are its leaves' axis 2)."""
         if not dtrunk or rows <= 1:
             return
-        t0 = bucket + max(ba + new_tokens, bb + conf_tokens)
+        t0 = jax.tree.leaves(cache)[0].shape[2]
         self.cascade_stats.count("cascade_decode_dispatches")
         self.cascade_stats.count(
             "trunk_bytes_deduped",
@@ -1160,7 +1160,7 @@ class ScoringEngine:
                     self._finish_prefix_resume(plan, cache)
                 self._note_cascade_decode(
                     dtrunk, len(bin_ids) if n_real is None else n_real,
-                    bucket, ba, bb, new_tokens, conf_tokens)
+                    cache, new_tokens, conf_tokens)
                 return fused, cfused
             try:
                 if plan is not None and plan.window is not None:
@@ -1230,7 +1230,7 @@ class ScoringEngine:
                 self._finish_prefix_resume(plan, cache)
             self._note_cascade_decode(
                 dtrunk, len(bin_ids) if n_real is None else n_real,
-                bucket, ba, bb, new_tokens, conf_tokens)
+                cache, new_tokens, conf_tokens)
             return fused, cfused
         return generate.greedy_decode_fused_shared(
             self.params, self.cfg, jnp.asarray(prefix),
@@ -1433,8 +1433,8 @@ class ScoringEngine:
         # through) — count that side's dedup where the kernels actually
         # run (the decode gate, not the prefill one).
         if self.cascade_decode_supported():
-            self._note_cascade_decode(trunk, rows, bucket, ba, bb,
-                                      new_tokens, conf_tokens)
+            self._note_cascade_decode(trunk, rows, cache, new_tokens,
+                                      conf_tokens)
         return fused, cfused
 
     # -- chunked prefill/decode piggybacking --------------------------------
@@ -1516,6 +1516,9 @@ class ScoringEngine:
                                      sfx_buckets))
         bb = max(bb, tok.pick_bucket([len(s) for s in sfx_b_ids],
                                      sfx_buckets))
+        # Learned positions count real tokens, so the table check reads
+        # the slots the branches can fill; the HBM gate reads the slots
+        # the program allocates (generate.cache_extent).
         total_len = bucket + ba + new_tokens + bb + conf_tokens
         if (max_sfx > max(sfx_buckets)
                 or max_total > max(self.buckets)
@@ -1535,7 +1538,9 @@ class ScoringEngine:
             # the moment the rung re-arms.
             raise PiggybackIneligible(
                 "memory governor: piggyback disabled under pressure")
-        if not self._piggyback_fits(len(bin_ids), total_len):
+        if not self._piggyback_fits(
+                len(bin_ids), generate.cache_extent(self.cfg, total_len,
+                                                    len(bin_ids))):
             raise PiggybackIneligible("no HBM headroom for two caches")
 
         prefix, prefix_mask = tok.right_pad_ids(

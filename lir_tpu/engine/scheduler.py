@@ -48,6 +48,33 @@ from ..utils.profiling import OccupancyStats
 from . import tokens as tok
 
 SUFFIX_BUCKETS = (8, 16, 32, 64, 128, 256)
+# Grid a plan tightens each prefix edge to (RaggedScheduler.schedule): the
+# ladder decides which rows ride together, the longest prefix a key's
+# dispatches carry decides the shape, rounded up to this. 64 bounds an
+# engine to a handful of prefix shapes (eight up to 512), keeps a cascade
+# remainder window (edge - trunk, the trunk on its 16 grid) a multiple of
+# 16, and keeps calls whose longest row wanders by a few tokens on one
+# executable. An engine whose prefill runs the Pallas flash kernel plans
+# on that kernel's block instead (tokens.FLASH_BLOCK, which its ladder
+# already sits on): an edge off it would drop the prefill to dense
+# attention.
+PREFIX_EDGE_GRID = 64
+
+
+def _grid_edge(length: int, bucket: int, grid: int) -> int:
+    """``length`` rounded up to ``grid``, never above ``bucket``."""
+    return min(bucket, -(-max(length, 1) // grid) * grid)
+
+
+def prefix_edges(length: int, bucket: int,
+                 grid: int = PREFIX_EDGE_GRID) -> Tuple[int, ...]:
+    """Every prefix extent a row of ``length`` prefix tokens queued under
+    ladder ``bucket`` may be dispatched at — which one is known only once
+    the plan is final (the key's longest row decides). The radix cache
+    keeps one namespace per extent, so a plan-time probe for a row's warm
+    pages looks under each of these (engine/sweep._plan_ragged)."""
+    return tuple(range(_grid_edge(length, bucket, grid), bucket,
+                       grid)) + (bucket,)
 
 # Decode-floor price constants: how many prefill row-tokens one
 # decode-scan token is worth. Recalibrated against the PR-7 fused kernel
@@ -237,9 +264,15 @@ class Dispatch:
     """One engine call. ``kind`` is "shared" (pairwise prefix sharing,
     decode_fused_shared) or "grouped" (cross-cell prefix reuse,
     decode_fused_grouped). ``refilled`` counts cells promoted here from a
-    smaller bucket's ragged tail. Suffix-bucket edges are planned per
-    PREFIX bucket (not per dispatch) so every dispatch in a bucket shares
-    one compiled shape and one handoff cache buffer."""
+    smaller bucket's ragged tail. ``bucket`` is the LADDER edge the rows
+    were queued under; ``edge`` is the prefix extent the dispatch runs at
+    — the one field every consumer of the shape reads (dispatch, compile
+    plan, cascade trunk clamp, radix namespace): the bucket tightened to
+    the longest prefix its ``(kind, bucket)`` key carries
+    (PREFIX_EDGE_GRID), the bucket itself where a row reaches it. Edges,
+    prefix and suffix, are planned per key (not per dispatch) so every
+    dispatch of a bucket shares one compiled shape and one handoff cache
+    buffer."""
 
     kind: str
     bucket: int
@@ -248,6 +281,7 @@ class Dispatch:
     groups: Optional[List[PrefixGroup]] = None
     sfx_bucket_a: int = 0
     sfx_bucket_b: int = 0
+    edge: int = 0
 
     @property
     def cells(self) -> List[Any]:
@@ -305,6 +339,8 @@ class RaggedScheduler:
         its prefill — decode steps are the fixed price of dispatching
         at all, which is what promotion avoids.
     suffix_buckets: right-pad edges for format suffixes.
+    edge_grid: grid the plan tightens prefix edges to (PREFIX_EDGE_GRID;
+        the sweep passes tokens.FLASH_BLOCK for a flash-prefill engine).
     max_extent: position ceiling (learned-position tables); None = no cap.
     min_group_prefix / min_group_cells: cross-cell grouping engages only
         for >= min_group_cells cells agreeing on >= min_group_prefix
@@ -315,13 +351,16 @@ class RaggedScheduler:
         into the cross-request radix prefix cache (engine/prefix_tree.
         match_len). The slot-refill rule then prices cached-prefix
         tokens as FREE prefill — and since the radix namespaces are
-        per-bucket, promoting a tail into the next bucket honestly
-        loses this bucket's cached pages, which the probe reflects.
+        per prefix extent, promoting a tail into the next bucket
+        honestly loses this bucket's cached pages, which the probe
+        reflects (it looks under every extent the bucket may be
+        tightened to, :func:`prefix_edges`).
     """
 
     def __init__(self, buckets: Sequence[int], batch_size: int, *,
                  new_budget: int = 8, decode_cost: Optional[int] = None,
                  suffix_buckets: Sequence[int] = SUFFIX_BUCKETS,
+                 edge_grid: int = PREFIX_EDGE_GRID,
                  max_extent: Optional[int] = None,
                  min_group_prefix: int = 16, min_group_cells: int = 4,
                  group_cells: bool = True,
@@ -335,6 +374,7 @@ class RaggedScheduler:
                                else decode_cost)
         self.fused_decode = bool(fused_decode)
         self.suffix_buckets = tuple(sorted(suffix_buckets))
+        self.edge_grid = int(edge_grid)
         self.max_extent = max_extent
         self.min_group_prefix = int(min_group_prefix)
         self.min_group_cells = int(min_group_cells)
@@ -507,42 +547,57 @@ class RaggedScheduler:
             groups, rest = [], items
         dispatches = self._plan_shared(rest) + self._plan_grouped(groups)
 
-        # Plan suffix buckets PER PREFIX BUCKET (shape/handoff stability).
+        # Plan the edges PER PREFIX BUCKET (shape/handoff stability), from
+        # the lengths the final queues hold: the suffix edges from the
+        # longest suffix, the prefix edge from the longest prefix on the
+        # edge grid — never above the ladder bucket, which is also what an
+        # over-long row (truncated into the largest bucket) keeps.
         sfx_a: Dict[Tuple[str, int], int] = {}
         sfx_b: Dict[Tuple[str, int], int] = {}
+        longest: Dict[Tuple[str, int], int] = {}
         for d in dispatches:
             key = (d.kind, d.bucket)
             if d.kind == "shared":
                 la = max(len(it.bin_ids) - it.lcp for it in d.items)
                 lb = max(len(it.conf_ids) - it.lcp for it in d.items)
+                lp = max(it.prefix_len for it in d.items)
             else:
                 la = lb = max(
                     max(len(it.bin_ids), len(it.conf_ids)) - g.plen
                     for g in d.groups for it in g.items)
+                lp = max(g.plen for g in d.groups)
             sfx_a[key] = max(sfx_a.get(key, 1), la)
             sfx_b[key] = max(sfx_b.get(key, 1), lb)
+            longest[key] = max(longest.get(key, 1), lp)
         for d in dispatches:
             key = (d.kind, d.bucket)
             d.sfx_bucket_a = tok.pick_bucket([sfx_a[key]], self.suffix_buckets)
             d.sfx_bucket_b = tok.pick_bucket([sfx_b[key]], self.suffix_buckets)
+            d.edge = _grid_edge(longest[key], d.bucket, self.edge_grid)
 
         self._account(dispatches)
         return dispatches
 
     def _account(self, dispatches: List[Dispatch]) -> None:
+        """Charge the occupancy counters for the shapes DISPATCHED: slot
+        tokens at the planned ``edge`` (so padding waste is what the
+        device pads), under the ladder bucket's name, with what the
+        tightening took off counted beside it (``trimmed_slots``)."""
         for d in dispatches:
             n = len(d.items)
             if d.kind == "shared":
                 slots = _tail_batch(n, self.batch)
                 real = sum(it.prefix_len for it in d.items)
-                self.stats.add_dispatch(d.bucket, n, slots, real,
-                                        refilled=d.refilled)
+                self.stats.add_dispatch(d.edge, n, slots, real,
+                                        refilled=d.refilled,
+                                        bucket=d.bucket)
             else:
                 g_pad = _tail_batch(len(d.groups), self.batch)
                 m_pad = _tail_batch(2 * n, 2 * self.batch)
                 real = sum(grp.plen for grp in d.groups)
-                self.stats.add_dispatch(d.bucket, n, m_pad, real,
+                self.stats.add_dispatch(d.edge, n, m_pad, real,
                                         used_slots=2 * n,
-                                        prefill_slots=g_pad)
+                                        prefill_slots=g_pad,
+                                        bucket=d.bucket)
                 self.stats.grouped_cells += n
                 self.stats.grouped_prefill_rows += g_pad

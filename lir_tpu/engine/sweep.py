@@ -589,16 +589,25 @@ def _plan_ragged(engine, todo, new_tokens, conf_tokens):
                   else None)
     # Prefix-aware slot-refill pricing: with the cross-request radix
     # cache enabled, cached-prefix tokens are free prefill and the
-    # promotion rule accounts for the per-bucket namespaces (a promoted
-    # tail abandons this bucket's cached pages).
+    # promotion rule accounts for the per-extent namespaces (a promoted
+    # tail abandons this bucket's cached pages). Which extent a bucket's
+    # rows will run at is the plan's own result, so the probe takes the
+    # best of those the bucket can be tightened to.
+    edge_grid = (tok.FLASH_BLOCK
+                 if getattr(engine.cfg, "use_flash_attention", False)
+                 else sched_mod.PREFIX_EDGE_GRID)
     cached_probe = None
     if engine.prefix_cache is not None:
-        cached_probe = (lambda it, b: engine.prefix_cache.match_len(
-            b, it.bin_ids[:it.lcp]))
+        def cached_probe(it, b):
+            ids = it.bin_ids[:it.lcp]
+            return max(engine.prefix_cache.match_len(e, ids)
+                       for e in sched_mod.prefix_edges(len(ids), b,
+                                                       edge_grid))
     planner = sched_mod.RaggedScheduler(
         engine.buckets, engine.rt.batch_size,
         new_budget=max(new_tokens, conf_tokens),
         decode_cost=new_tokens + conf_tokens, max_extent=max_extent,
+        edge_grid=edge_grid,
         min_group_prefix=engine.rt.sweep_group_min_prefix,
         min_group_cells=engine.rt.sweep_group_min_cells,
         group_cells=engine.rt.sweep_group_min_cells > 0,
@@ -608,11 +617,12 @@ def _plan_ragged(engine, todo, new_tokens, conf_tokens):
     dispatches = planner.schedule(items)
     engine.occupancy = stats
     log.info(
-        "ragged schedule: %d cells -> %d dispatches over buckets %s "
-        "(occupancy %.1f%%, padding waste %.1f%%, refilled %d, "
-        "grouped %d)", len(todo), len(dispatches),
-        sorted({d.bucket for d in dispatches}), stats.occupancy_pct,
-        stats.padding_waste_pct,
+        "ragged schedule: %d cells -> %d dispatches over buckets %s at "
+        "edges %s (occupancy %.1f%%, padding waste %.1f%%, edge trim "
+        "%.1f%%, refilled %d, grouped %d)", len(todo), len(dispatches),
+        sorted({d.bucket for d in dispatches}),
+        sorted({d.edge for d in dispatches}), stats.occupancy_pct,
+        stats.padding_waste_pct, stats.edge_trim_pct,
         sum(b.refilled for b in stats.buckets.values()),
         stats.grouped_cells)
     return dispatches, stats
@@ -705,7 +715,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                     cascade_trunk=(
                         (lambda d: engine.cascade_trunk_for(
                             [it.bin_ids[:it.lcp] for it in d.items],
-                            len(d.items), d.bucket))
+                            len(d.items), d.edge))
                         if getattr(engine, "cascade_supported",
                                    lambda: False)() else None),
                     cascade_int8=bool(
@@ -714,7 +724,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                     decode_trunk=(
                         (lambda d: engine.decode_trunk_for(
                             [it.bin_ids[:it.lcp] for it in d.items],
-                            len(d.items), d.bucket))
+                            len(d.items), d.edge))
                         if getattr(engine, "cascade_decode_supported",
                                    lambda: False)() else None))
                 engine.exec_registry = compile_plan.precompile_async(
@@ -972,12 +982,12 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
             if d.kind == "shared":
                 n = len(d.items)
                 trunk = (engine.cascade_trunk_for(
-                    [it.bin_ids[:it.lcp] for it in d.items], n, d.bucket)
+                    [it.bin_ids[:it.lcp] for it in d.items], n, d.edge)
                     if cascade_on else 0)
                 cascade_trunks.append(trunk)
                 piggy_keys.append(
                     None if trunk else
-                    (d.bucket, B if n == B else _tail_batch(n, B),
+                    (d.edge, B if n == B else _tail_batch(n, B),
                      d.sfx_bucket_a, d.sfx_bucket_b))
             else:
                 cascade_trunks.append(0)
@@ -1002,14 +1012,14 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
         if engine.spec_supported() and engine.prefix_cache is not None:
             spec_rec = ([it.bin_ids for it in meta["full_items"]],
                         [it.conf_ids for it in meta["full_items"]],
-                        meta["bucket"], meta["n"])
+                        meta["edge"], meta["n"])
         _enqueue((meta["seq"], meta["span"]), meta["batch"], fused, res,
                  cfused, spec_rec)
 
     def _plain_shared(meta):
         full_items, t1, t2 = meta["full_items"], meta["t1"], meta["t2"]
         with tracing.span("sweep/dispatch", bucket=int(meta["bucket"]),
-                          rows=int(meta["n"]),
+                          edge=int(meta["edge"]), rows=int(meta["n"]),
                           dispatch=meta["seq"]) as meta["span"]:
             fused, cfused = _dispatch_with_recovery(
                 engine, lambda: engine.decode_fused_shared(
@@ -1019,10 +1029,10 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                     conf_tokens=conf_tokens, early_stop=early_stop,
                     pretokenized_a=[it.bin_ids for it in full_items],
                     pretokenized_b=[it.conf_ids for it in full_items],
-                    bucket=meta["bucket"], sfx_buckets_ab=meta["sfx_ab"],
+                    bucket=meta["edge"], sfx_buckets_ab=meta["sfx_ab"],
                     reuse_cache=True, n_real=meta["n"]),
                 cost=sched_mod.bucket_cost(
-                    meta["n"], meta["bucket"], B,
+                    meta["n"], meta["edge"], B,
                     new_tokens + conf_tokens, fused_decode=fused_dec,
                     spec_decode=spec_on,
                     cascade=meta.get("trunk", 0) > 0,
@@ -1076,7 +1086,8 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                     [target_ids[it.cell.prompt_idx][1]
                      for it in full_items], np.int32)
                 meta = dict(batch=batch, full_items=full_items, t1=t1,
-                            t2=t2, bucket=d.bucket, n=n, key=piggy_keys[i],
+                            t2=t2, bucket=d.bucket, edge=d.edge, n=n,
+                            key=piggy_keys[i],
                             seq=seq_of(),
                             sfx_ab=(d.sfx_bucket_a, d.sfx_bucket_b),
                             trunk=cascade_trunks[i])
@@ -1092,7 +1103,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                 if chainable:
                     prev = pending[0]
                     cost = sched_mod.bucket_cost(
-                        n, d.bucket, B, new_tokens + conf_tokens,
+                        n, d.edge, B, new_tokens + conf_tokens,
                         fused_decode=fused_dec)
                     if prev is not None:
                         cost += sched_mod.decode_floor(
@@ -1100,7 +1111,8 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                             fused_decode=fused_dec)
                     try:
                         with tracing.span("sweep/dispatch", kind="piggy",
-                                          bucket=int(d.bucket), rows=n,
+                                          bucket=int(d.bucket),
+                                          edge=int(d.edge), rows=n,
                                           dispatch=meta["seq"]
                                           ) as meta["span"]:
                             out = _watched(
@@ -1108,7 +1120,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                                     [it.bin_ids for it in full_items],
                                     [it.conf_ids for it in full_items],
                                     new_tokens, conf_tokens, early_stop,
-                                    d.bucket,
+                                    d.edge,
                                     (d.sfx_bucket_a, d.sfx_bucket_b),
                                     prev_yes=(prev["t1"] if prev else None),
                                     prev_no=(prev["t2"] if prev else None)),
@@ -1148,18 +1160,18 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                      for it in d.items], np.int32)
                 seq = seq_of()
                 with tracing.span("sweep/dispatch", kind="grouped",
-                                  bucket=int(d.bucket), rows=n,
-                                  dispatch=seq) as sid:
+                                  bucket=int(d.bucket), edge=int(d.edge),
+                                  rows=n, dispatch=seq) as sid:
                     out, m = _dispatch_with_recovery(
                         engine, lambda: engine.decode_fused_grouped(
                             d.groups, t1, t2, new_tokens, conf_tokens,
-                            early_stop, d.bucket,
+                            early_stop, d.edge,
                             max(d.sfx_bucket_a, d.sfx_bucket_b),
                             reuse_cache=True),
                         # Grouped dispatches run [bin, conf] member rows
                         # per cell — price the doubled row count.
                         cost=sched_mod.bucket_cost(
-                            2 * n, d.bucket, B, new_tokens + conf_tokens,
+                            2 * n, d.edge, B, new_tokens + conf_tokens,
                             fused_decode=fused_dec))
                     # Member rows are [bin, conf] per cell: even rows carry
                     # the binary readout, odd rows the confidence one. Both

@@ -41,7 +41,7 @@ SNAPSHOT_VERSION = 1
 STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "OccupancyStats": (
         "buckets", "grouped_cells", "grouped_prefill_rows",
-        "decode_steps_live", "decode_steps_paid",
+        "trimmed_slots", "decode_steps_live", "decode_steps_paid",
     ),
     "CompileStats": (
         "shapes", "aot_hits", "aot_shapes_hit", "lazy_misses",

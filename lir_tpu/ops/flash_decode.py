@@ -25,9 +25,12 @@ position does not exceed the query's; ALiBi families add
 Block sizes align to the flash_attention edges (DEFAULT_BLOCK_K): the
 split width is the largest divisor of T no wider than the requested
 block (preferring sublane-aligned multiples of 8), falling back to a
-single full-width split — every cache extent the bucket ladder plans
-(bucket + suffix + decode budget) therefore lowers without padding or
-out-of-bounds tail blocks. The batch axis rides in blocks of 8 rows (the
+single full-width split — any cache extent therefore lowers without
+padding or out-of-bounds tail blocks, and the extents the dispatch
+programs allocate are chosen so that the divisor is a wide one
+(:func:`decode_extent`: prefix edge + suffix + decode budget, grown by
+a few masked slots onto an extent that splits well). The batch axis
+rides in blocks of 8 rows (the
 TPU's sublane tile — see ``_decode_kernel``); one kernel and one
 pallas_call sit behind all four entry points, and all of them compile
 for a described v5e in tests/test_tpu_compile.py. ``interpret=True`` runs the kernel in the
@@ -87,6 +90,14 @@ def batch_block(batch: int) -> int:
     return SUBLANE if batch % SUBLANE == 0 else 1
 
 
+def _split_cap(batch: int, n_groups: int, block_k: int) -> int:
+    """Widest key split a (batch, query-group) shape may take: the
+    requested block, or what keeps the score tile inside its budget."""
+    bb = batch_block(batch)
+    cap = max(SUBLANE, _SCORE_TILE_ELEMS // (bb * bb * n_groups))
+    return min(int(block_k), cap)
+
+
 def decode_split(total: int, batch: int, n_groups: int,
                  block_k: int = DEFAULT_BLOCK_K) -> int:
     """Key-split width every decode entry point uses for a (cache extent,
@@ -94,9 +105,35 @@ def decode_split(total: int, batch: int, n_groups: int,
     verify-window length), so the single- and multi-query kernels and
     their trunk variants share one split ladder and their partials line
     up split for split."""
-    bb = batch_block(batch)
-    cap = max(SUBLANE, _SCORE_TILE_ELEMS // (bb * bb * n_groups))
-    return pick_split(total, min(int(block_k), cap))
+    return pick_split(total, _split_cap(batch, n_groups, block_k))
+
+
+# Most slots :func:`decode_extent` adds to what a dispatch needs.
+EXTENT_GROWTH = 32
+
+
+def decode_extent(need: int, batch: int, n_groups: int,
+                  block_k: int = DEFAULT_BLOCK_K) -> int:
+    """Cache extent for a dispatch that needs ``need`` slots (prefix edge
+    + suffix edge + decode budget): the smallest multiple of 8 >= ``need``,
+    at most EXTENT_GROWTH slots above it, that :func:`decode_split` cuts
+    into sublane-aligned splits at least half as wide as the widest it may
+    take. The split must divide the extent exactly, so an extent with few
+    divisors (552 = 8 * 3 * 23) falls to narrow splits (23 of 24) and the
+    decode step pays per grid program, not per byte; a few slots more
+    (560 = 5 * 112) keep the grid short. The added slots are masked like
+    any slot a row does not fill. Every dispatch program sizes its cache
+    through this one rule (engine/generate.cache_extent), so the AOT plan,
+    the donated handoff buffer and the dispatch agree by construction.
+    Where no candidate splits well the extent is ``need`` on the 8 grid.
+    Monotone in ``need``."""
+    first = -(-int(need) // SUBLANE) * SUBLANE
+    cap = _split_cap(batch, n_groups, block_k)
+    for total in range(first, int(need) + EXTENT_GROWTH + 1, SUBLANE):
+        split = pick_split(total, cap)
+        if split % SUBLANE == 0 and 2 * split >= min(cap, total):
+            return total
+    return first
 
 
 def _decode_kernel(rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref,

@@ -135,9 +135,15 @@ class OccupancyStats:
       a bucket's ragged tail into the next bucket's queue) exists to keep
       this high when the grid spreads over many buckets.
     - padding waste %  = padded prefix-token slots / total prefix-token
-      slots — the FLOPs fraction the prefill burns on left-padding. The
-      bucket ladder exists to keep this low on variable-length grids
-      (one global bucket pads every short prompt to the max).
+      slots — the FLOPs fraction the prefill burns on padding, at the
+      prefix edge each dispatch RUNS at. The bucket ladder exists to keep
+      this low on variable-length grids (one global bucket pads every
+      short prompt to the max), and the plan-time edge
+      (scheduler.PREFIX_EDGE_GRID) tightens each bucket to the rows it
+      carries.
+    - edge trim % = prefix-token slots the plan-time edge took off the
+      ladder's own shapes / the slots those would have paid
+      (``trimmed_slots`` over ``trimmed_slots`` + slot tokens).
     - decode occupancy % = decode steps that produced a live (pre-retire)
       token / decode steps paid for. Rows retired mid-scan by the early
       stop (EOS / complete-integer) idle until the batch's slowest row.
@@ -147,6 +153,7 @@ class OccupancyStats:
         default_factory=dict)
     grouped_cells: int = 0          # cells scored via a cross-cell prefix group
     grouped_prefill_rows: int = 0   # prefix rows actually prefilled for them
+    trimmed_slots: int = 0          # sum of prefill rows x (bucket - edge)
     decode_steps_live: int = 0
     decode_steps_paid: int = 0
 
@@ -156,20 +163,26 @@ class OccupancyStats:
     def add_dispatch(self, edge: int, cells: int, slots: int,
                      prompt_tokens: int, refilled: int = 0,
                      used_slots: Optional[int] = None,
-                     prefill_slots: Optional[int] = None) -> None:
+                     prefill_slots: Optional[int] = None,
+                     bucket: Optional[int] = None) -> None:
         """``slots``/``used_slots`` count batch rows (occupancy);
-        ``prefill_slots`` counts rows actually prefilled at this bucket's
-        width (padding waste) — they differ in grouped dispatches, where
-        member rows outnumber the shared prefix rows."""
-        b = self.bucket(edge)
+        ``prefill_slots`` counts rows actually prefilled at ``edge``
+        slots each (padding waste) — they differ in grouped dispatches,
+        where member rows outnumber the shared prefix rows. ``edge`` is
+        the prefix extent the dispatch runs at; ``bucket`` the ladder
+        edge it was queued under (default: the same), which names the
+        counters and prices what the tighter edge took off."""
+        bucket = int(edge) if bucket is None else int(bucket)
+        rows = slots if prefill_slots is None else prefill_slots
+        b = self.bucket(bucket)
         b.dispatches += 1
         b.cells += cells
         b.slots += slots
         b.used_slots += cells if used_slots is None else used_slots
         b.prompt_tokens += prompt_tokens
-        b.slot_tokens += (slots if prefill_slots is None
-                          else prefill_slots) * int(edge)
+        b.slot_tokens += rows * int(edge)
         b.refilled += refilled
+        self.trimmed_slots += rows * (bucket - int(edge))
 
     def add_decode(self, steps_live: int, steps_paid: int) -> None:
         self.decode_steps_live += steps_live
@@ -188,6 +201,12 @@ class OccupancyStats:
         return 100.0 * (slot_tok - tok) / slot_tok if slot_tok else 0.0
 
     @property
+    def edge_trim_pct(self) -> float:
+        slot_tok = sum(b.slot_tokens for b in self.buckets.values())
+        ladder = slot_tok + self.trimmed_slots
+        return 100.0 * self.trimmed_slots / ladder if ladder else 0.0
+
+    @property
     def decode_occupancy_pct(self) -> float:
         if not self.decode_steps_paid:
             return 0.0
@@ -197,6 +216,8 @@ class OccupancyStats:
         out: Dict[str, object] = {
             "occupancy_pct": round(self.occupancy_pct, 2),
             "padding_waste_pct": round(self.padding_waste_pct, 2),
+            "trimmed_slots": self.trimmed_slots,
+            "edge_trim_pct": round(self.edge_trim_pct, 2),
             "per_bucket": {
                 str(edge): {
                     "dispatches": b.dispatches, "cells": b.cells,

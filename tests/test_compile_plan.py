@@ -247,6 +247,47 @@ def test_piggyback_chain_runs_precompiled(tmp_path):
     assert engine.kernel_stats.counters.get("piggybacked_steps") == 2
 
 
+@pytest.mark.parametrize("piggyback", [False, True])
+def test_tight_edge_plan_compiles_the_shapes_it_dispatches(tmp_path,
+                                                           piggyback):
+    """Rows of ~140 tokens queue under the 256 bucket and run at the 192
+    edge. The plan's executables are lowered from that edge and the
+    dispatch hands the runner the same one, so every dispatch (and every
+    stage of a piggyback chain) is served by a planned executable: a
+    plan at the ladder's shapes would miss (lazy) or be called with
+    other avals (raise)."""
+    from lir_tpu.engine.sweep import run_perturbation_sweep
+    from lir_tpu.engine import generate
+
+    compile_plan.exec_cache_clear()
+    engine = _tiny_engine(RuntimeConfig(batch_size=4, max_seq_len=256,
+                                        piggyback_prefill=piggyback))
+    lp, perts = _grid(12, words_each=140)
+    rows = run_perturbation_sweep(engine, "cp-tight", lp, perts,
+                                  tmp_path / "r.xlsx",
+                                  checkpoint_every=100)
+    assert len(rows) == 12
+    occ = engine.occupancy
+    assert set(occ.buckets) == {256} and occ.trimmed_slots == 12 * 64
+    reg = engine.exec_registry
+    programs = [s for s in reg._futures if s.kind != "stream_fold"]
+    assert programs and {s.bucket for s in programs} == {192}
+    assert engine.compile_stats.lazy_misses == 0
+    # 3 dispatches (plain: 3 calls; chained: opener + 2 steps + drain)
+    # + 3 accumulator folds, all from the registry.
+    assert engine.compile_stats.aot_hits == (7 if piggyback else 6)
+    # The handoff buffer the plan lowered the donated variant against is
+    # the dispatched one: prefix edge + suffix edges + budget, on the
+    # decode kernel's grid.
+    spec = next(s for s in programs if s.kind == "shared" and s.scratch
+                and not s.spec_k)
+    cache = compile_plan._lower(engine, spec).out_info[-1]
+    need = spec.bucket + max(spec.sfx_a + spec.new_tokens,
+                             spec.sfx_b + spec.conf_tokens)
+    assert {leaf.shape[2] for leaf in jax.tree.leaves(cache)} == {
+        generate.cache_extent(engine.cfg, need, spec.batch)}
+
+
 @pytest.mark.parametrize("kind", ["shared", "grouped"])
 def test_donated_scratch_cache_really_aliases_the_output(kind):
     """The KV handoff donates the previous dispatch's cache as a scratch

@@ -198,6 +198,65 @@ class TestFlashDecodeSublaneBlocks:
         assert decode_split(76, 3, 2) == pick_split(76)
 
 
+# need -> (extent, split) by query-group width G: what a dispatch that
+# needs that many slots (prefix edge + suffix edge + decode budget)
+# allocates, and how the decode kernel cuts it. 552 is the ladder's 512
+# edge + 40 (23 splits of 24 if left alone), 488 / 424 / 296 / 168 the
+# 448 / 384 / 256 / 128 edges + 40.
+EXTENT_TABLE = {
+    488: {1: (504, 72), 4: (504, 72), 71: (504, 56)},
+    552: {1: (560, 112), 4: (560, 112), 71: (560, 56)},
+    424: {1: (432, 72), 4: (432, 72), 71: (432, 48)},
+    296: {1: (312, 104), 4: (312, 104), 71: (320, 40)},
+    168: {1: (176, 88), 4: (176, 88), 71: (168, 56)},
+    512: {1: (512, 128), 4: (512, 128), 71: (512, 32)},
+    40: {1: (40, 40), 4: (40, 40), 71: (40, 40)},    # one split: as is
+    13: {1: (16, 16), 4: (16, 16), 71: (16, 16)},    # onto the 8 grid
+}
+
+
+@pytest.mark.parametrize("batch", [8, 40])
+@pytest.mark.parametrize("groups", [1, 4, 71])
+@pytest.mark.parametrize("need", sorted(EXTENT_TABLE))
+def test_decode_extent_table(need, groups, batch):
+    """ops/flash_decode.decode_extent: the smallest multiple of 8 >= need,
+    at most 32 above it, whose split is sublane-aligned and at least half
+    the widest the (batch, group) shape may take."""
+    from lir_tpu.ops.flash_decode import (EXTENT_GROWTH, decode_extent,
+                                          decode_split)
+
+    extent = decode_extent(need, batch, groups)
+    split = decode_split(extent, batch, groups)
+    assert (extent, split) == EXTENT_TABLE[need][groups]
+    assert extent % 8 == 0 and need <= extent <= need + EXTENT_GROWTH == \
+        need + 32
+    cap = 128 if groups < 71 else 57        # 262144 // (8 * 8 * 71) = 57
+    assert split % 8 == 0 and extent % split == 0
+    assert 2 * split >= min(cap, extent)
+    # no smaller multiple of 8 would have done
+    for smaller in range(-(-need // 8) * 8, extent, 8):
+        s = decode_split(smaller, batch, groups)
+        assert s % 8 or 2 * s < min(cap, smaller)
+
+
+def test_decode_extent_is_monotone_and_bounded():
+    """A longer need never gets a shorter cache (the speculative program
+    views its sequential extent inside the speculative one), and where no
+    candidate splits well the extent is ``need`` on the 8 grid."""
+    from lir_tpu.ops.flash_decode import decode_extent, decode_split
+
+    for groups in (1, 4, 71):
+        prev = 0
+        for need in range(1, 2100):
+            extent = decode_extent(need, 40, groups)
+            assert prev <= extent and need <= extent <= need + 32
+            assert extent % 8 == 0
+            prev = extent
+    # 729..735: 736, 744, 752, 760 all split narrow (32, 24, 16, 40)
+    assert decode_extent(730, 40, 4) == 736
+    assert decode_split(736, 40, 4) == 32
+
+
 @pytest.fixture()
 def fused_decode_interpret():
     """Arm the tier-1 interpret hook; jit caches key on cfg, so tests

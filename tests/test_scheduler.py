@@ -88,13 +88,149 @@ def test_schedule_is_total_and_deterministic():
     for d in dispatches:
         assert d.kind in ("shared", "grouped")
         assert d.bucket in buckets
-        # every member's planned prefix fits its dispatch bucket
+        # the shape dispatched: on the edge grid (or the ladder edge
+        # itself), inside the ladder bucket the rows were queued under
+        assert d.edge in sched_mod.prefix_edges(1, d.bucket)
+        # every member's planned prefix fits its dispatch edge
         for it in d.items:
-            assert min(it.prefix_len, max(buckets)) <= d.bucket
+            assert min(it.prefix_len, max(buckets)) <= d.edge <= d.bucket
 
     again = plan()
-    assert [(d.kind, d.bucket, d.cells) for d in dispatches] == \
-           [(d.kind, d.bucket, d.cells) for d in again]
+    assert [(d.kind, d.bucket, d.edge, d.cells) for d in dispatches] == \
+           [(d.kind, d.bucket, d.edge, d.cells) for d in again]
+
+
+def _round_up(n, grid=sched_mod.PREFIX_EDGE_GRID):
+    return -(-n // grid) * grid
+
+
+# Prefix lengths -> what the plan must make of them (batch 4, ladder
+# 64..512, no cross-cell groups). ``edges`` maps a ladder bucket to the
+# prefix edge its dispatches run at.
+EDGE_PLANS = {
+    # the benchmark's own shape: 420-token rows into the 512 bucket
+    "long-rows-tighten-512-to-448": (
+        [420] * 8 + [421, 426, 430, 418], {512: 448}),
+    # the longest row reaches the ladder edge: nothing to trim
+    "row-on-the-ladder-edge": ([500] * 3 + [512], {512: 512}),
+    "just-past-a-grid-line": ([449] * 4, {512: 512}),
+    "on-a-grid-line": ([448] * 4, {512: 448}),
+    # a 96 bucket has no grid line below it; 256 and 384 have one each
+    "small-buckets": ([70] * 4 + [100] * 4 + [130] * 4 + [300] * 4,
+                      {96: 96, 128: 128, 256: 192, 384: 320}),
+    # one key, one edge: the longest row of ANY of its dispatches decides
+    "one-edge-per-key": ([130] * 4 + [250] * 4 + [140] * 4, {256: 256}),
+    # a promoted tail (decode cost 200 makes a lone row climb the whole
+    # ladder) counts where it lands: the 30-token row rides the 256
+    # bucket's first dispatch, the row it displaced ends alone in 512 —
+    # and runs there at the edge ITS length asks for
+    "promotion-moves-the-row": ([200] * 4 + [30], {256: 256, 512: 256}, 200),
+    "promotion-keeps-the-edge": ([150] * 4 + [30], {256: 192, 512: 192},
+                                 200),
+    # over-long rows are truncated into the largest bucket, as before
+    "over-long-rows-keep-the-bucket": ([600, 700, 520, 513], {512: 512}),
+    "over-long-beside-short": ([390] * 4 + [900], {512: 512}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EDGE_PLANS))
+def test_plan_tightens_prefix_edge_to_the_rows_it_carries(name):
+    lengths, want, *decode_cost = EDGE_PLANS[name]
+    buckets = tok.bucket_ladder(512)
+    batch = 4
+
+    def plan():
+        stats = OccupancyStats()
+        planner = sched_mod.RaggedScheduler(
+            buckets, batch, group_cells=False, stats=stats,
+            decode_cost=decode_cost[0] if decode_cost else None)
+        return planner.schedule(_items(lengths)), stats
+
+    dispatches, stats = plan()
+    by_key = {}
+    for d in dispatches:
+        by_key.setdefault((d.kind, d.bucket), []).append(d)
+    assert {b: ds[0].edge for (_, b), ds in by_key.items()} == want
+    slot_tokens = trimmed = 0
+    for (_, bucket), ds in by_key.items():
+        longest = max(it.prefix_len for d in ds for it in d.items)
+        for d in ds:
+            # one edge per (kind, bucket) key, after promotion
+            assert d.edge == ds[0].edge
+            # round-up-to-64 of the key's longest prefix, never above the
+            # ladder bucket ...
+            assert d.edge == min(bucket, _round_up(longest))
+            # ... never below any member that fits the ladder at all
+            for it in d.items:
+                assert d.edge >= min(it.prefix_len, max(buckets))
+            slots = sched_mod._tail_batch(len(d.items), batch)
+            slot_tokens += slots * d.edge
+            trimmed += slots * (bucket - d.edge)
+    # identical inputs plan identical edges
+    again, _ = plan()
+    assert [(d.bucket, d.edge, d.cells) for d in again] == \
+           [(d.bucket, d.edge, d.cells) for d in dispatches]
+    # the counters charge the shape DISPATCHED, not the ladder's
+    real = sum(lengths)
+    assert stats.trimmed_slots == trimmed
+    assert sum(b.slot_tokens for b in stats.buckets.values()) == slot_tokens
+    assert stats.padding_waste_pct == pytest.approx(
+        100.0 * (slot_tokens - real) / slot_tokens)
+    assert stats.edge_trim_pct == pytest.approx(
+        100.0 * trimmed / (slot_tokens + trimmed))
+    summ = stats.summary()
+    assert summ["trimmed_slots"] == trimmed
+    assert summ["edge_trim_pct"] == round(stats.edge_trim_pct, 2)
+    assert set(stats.buckets) == {b for _, b in by_key}   # ladder names
+
+
+def test_grouped_dispatch_edge_follows_the_group_prefix():
+    # 4 cells sharing 150 leading tokens: one grouped dispatch whose
+    # prefill rows are the GROUP prefixes (plen), so the edge rounds plen
+    # up, not the members' full lengths.
+    shared = [50 + (i % 40) for i in range(150)]
+    items = []
+    for i in range(4):
+        ids = shared + [300 + i] * (4 + i)
+        items.append(sched_mod.SweepItem(
+            cell=("g", i), bin_ids=tuple(ids + [7] * 5),
+            conf_ids=tuple(ids + [9] * 5), lcp=len(ids)))
+    stats = OccupancyStats()
+    planner = sched_mod.RaggedScheduler(tok.bucket_ladder(512), 8,
+                                        stats=stats)
+    (d,) = planner.schedule(items)
+    assert d.kind == "grouped" and d.bucket == 256
+    plen = d.groups[0].plen
+    assert 150 <= plen <= 154
+    assert d.edge == _round_up(plen) == 192
+    assert stats.trimmed_slots == 1 * (256 - 192)   # one prefill row
+
+
+@pytest.mark.parametrize("length,bucket,want", [
+    (420, 512, (448, 512)), (449, 512, (512,)), (100, 128, (128,)),
+    (70, 96, (96,)), (130, 256, (192, 256)), (10, 256, (64, 128, 192, 256)),
+    (600, 512, (512,)),
+])
+def test_prefix_edges_lists_every_extent_a_row_may_run_at(length, bucket,
+                                                         want):
+    assert sched_mod.prefix_edges(length, bucket) == want
+
+
+@pytest.mark.parametrize("grid,want", [
+    (sched_mod.PREFIX_EDGE_GRID, {512: 448, 768: 576}),
+    (tok.FLASH_BLOCK, {512: 512, 768: 640}),
+])
+def test_flash_prefill_engine_plans_on_the_kernel_block(grid, want):
+    # A flash-prefill engine's sweep passes the kernel's 128 block as
+    # the grid: its edges stay whole blocks (the ladder's own up to 512,
+    # 640 inside the 768 bucket), so no dispatch drops to dense attention.
+    planner = sched_mod.RaggedScheduler(
+        tok.bucket_ladder(1024), 4, group_cells=False, edge_grid=grid,
+        stats=OccupancyStats())
+    dispatches = planner.schedule(_items([420] * 4 + [570] * 4))
+    assert {d.bucket: d.edge for d in dispatches} == want
+    assert sched_mod.prefix_edges(570, 768, grid) == tuple(
+        range(want[768], 768, grid)) + (768,)
 
 
 def test_slot_refill_promotes_ragged_tail_once():
@@ -191,6 +327,66 @@ def _varlen_grid(rng):
     return prompts, perturbations
 
 
+def _assert_rows_match(rows_a, rows_b):
+    """Per-cell equality of two sweeps of one grid: token/text readouts
+    bit for bit, float readouts to shape-fusion tolerance (a cell padded
+    to another length fuses slightly differently; the last ulp of a
+    logprob can move)."""
+    def key(r):
+        return (r.original_main, r.rephrased_main)
+
+    by_key = {key(r): r for r in rows_b}
+    assert set(map(key, rows_a)) == set(by_key)
+    for r in rows_a:
+        l = by_key[key(r)]
+        assert r.model_response == l.model_response
+        assert r.model_confidence_response == l.model_confidence_response
+        assert r.confidence_value == l.confidence_value
+        np.testing.assert_allclose(r.token_1_prob, l.token_1_prob,
+                                   rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(r.token_2_prob, l.token_2_prob,
+                                   rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(r.weighted_confidence,
+                                   l.weighted_confidence,
+                                   rtol=1e-5, atol=1e-7)
+        lp_r, lp_l = (json.loads(r.log_probabilities),
+                      json.loads(l.log_probabilities))
+        assert list(lp_r) == list(lp_l)  # same top-20 ids, same order
+        np.testing.assert_allclose(list(lp_r.values()),
+                                   list(lp_l.values()), atol=2e-6)
+
+
+def test_tight_edge_sweep_matches_ladder_edge_per_cell(tmp_path,
+                                                       monkeypatch):
+    """Tightening a bucket's prefix edge removes masked slots and nothing
+    else: every cell's readout equals the sweep that pads to the ladder
+    edges (the grid set so coarse that every edge IS its bucket), to the
+    tolerance ragged-vs-legacy already uses."""
+    from lir_tpu.engine.sweep import run_perturbation_sweep
+
+    rng = np.random.default_rng(11)
+    prompts, perturbations = _varlen_grid(rng)
+
+    def run(sub):
+        rt = RuntimeConfig(batch_size=4, max_seq_len=256)
+        engine, _, _ = _tiny_engine(rt)
+        rows = run_perturbation_sweep(
+            engine, "sched-tiny", prompts, perturbations,
+            tmp_path / sub / "results.xlsx", checkpoint_every=100)
+        return rows, engine.occupancy
+
+    rows_t, occ_t = run("tight")
+    monkeypatch.setattr(sched_mod, "PREFIX_EDGE_GRID", 1 << 20)
+    rows_l, occ_l = run("ladder")
+    assert len(rows_t) == len(rows_l) == 13
+    _assert_rows_match(rows_t, rows_l)
+    # The two runs really dispatched different shapes.
+    assert occ_l.trimmed_slots == 0 and occ_l.edge_trim_pct == 0.0
+    assert occ_t.trimmed_slots > 0
+    assert occ_t.padding_waste_pct < occ_l.padding_waste_pct
+    assert set(occ_t.buckets) == set(occ_l.buckets)   # same queues
+
+
 @pytest.mark.slow
 def test_ragged_sweep_matches_legacy_per_cell(tmp_path):
     """The tentpole's safety property: bucket ladder + slot refill +
@@ -216,29 +412,7 @@ def test_ragged_sweep_matches_legacy_per_cell(tmp_path):
     rows_r, eng_r = run(True, "ragged")
     rows_l, _ = run(False, "legacy")
     assert len(rows_r) == len(rows_l) == 13
-
-    def key(r):
-        return (r.original_main, r.rephrased_main)
-
-    by_key = {key(r): r for r in rows_l}
-    assert set(map(key, rows_r)) == set(by_key)
-    for r in rows_r:
-        l = by_key[key(r)]
-        assert r.model_response == l.model_response
-        assert r.model_confidence_response == l.model_confidence_response
-        assert r.confidence_value == l.confidence_value
-        np.testing.assert_allclose(r.token_1_prob, l.token_1_prob,
-                                   rtol=1e-5, atol=1e-7)
-        np.testing.assert_allclose(r.token_2_prob, l.token_2_prob,
-                                   rtol=1e-5, atol=1e-7)
-        np.testing.assert_allclose(r.weighted_confidence,
-                                   l.weighted_confidence,
-                                   rtol=1e-5, atol=1e-7)
-        lp_r, lp_l = (json.loads(r.log_probabilities),
-                      json.loads(l.log_probabilities))
-        assert list(lp_r) == list(lp_l)  # same top-20 ids, same order
-        np.testing.assert_allclose(list(lp_r.values()),
-                                   list(lp_l.values()), atol=2e-6)
+    _assert_rows_match(rows_r, rows_l)
 
     # The ragged run actually scheduled (counters populated and sane).
     stats = eng_r.occupancy

@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from lir_tpu.ops.cascade_prefill import cascade_attention
 from lir_tpu.ops.flash_attention import flash_attention
-from lir_tpu.ops.flash_decode import (flash_decode, flash_decode_mq,
+from lir_tpu.ops.flash_decode import (decode_extent, decode_split,
+                                      flash_decode, flash_decode_mq,
                                       flash_decode_mq_trunk,
                                       flash_decode_trunk)
 
@@ -124,10 +125,38 @@ def test_flash_decode_mq_trunk_compiles(one_chip, layout, T, alibi):
              trunk_len=T - 128)
 
 
+# The extents a sweep dispatch really allocates since the plan tightens
+# its prefix edge and the cache extent sits on the decode kernel's grid
+# (decode_extent): need 488 = the 448 edge + 40, 552 = the 512 edge + 40.
+# The trunk is the sweep's 64-token shared head — narrower than mistral's
+# split at these extents, one whole split under falcon's cap.
+@pytest.mark.parametrize("need", [488, 552])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_flash_decode_compiles_at_the_planned_extent(one_chip, layout,
+                                                     need):
+    H, K, _ = LAYOUTS[layout]
+    T = decode_extent(need, BATCH, H // K)
+    assert decode_split(T, BATCH, H // K) >= 56
+    _compile(flash_decode, _decode_args(layout, T), one_chip)
+
+
+@pytest.mark.parametrize("need", [488, 552])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_flash_decode_trunk_compiles_at_the_planned_extent(one_chip, layout,
+                                                           need):
+    H, K, _ = LAYOUTS[layout]
+    T = decode_extent(need, BATCH, H // K)
+    _compile(flash_decode_trunk, _decode_args(layout, T), one_chip,
+             trunk_len=64)
+
+
 # (remainder window, trunk) pairs a 256/512 bucket plans on the quantum
 # grid, at both the fused single-launch lowering and the two-leg one
-# (float and in-kernel int8 QK^T prefix legs).
-CASCADE_SHAPES = [(64, 192), (64, 384), (128, 384), (48, 208), (448, 64)]
+# (float and in-kernel int8 QK^T prefix legs). (384, 64) is the 448 edge
+# the plan tightens 420-token rows to, over the sweep's 64-token head;
+# (448, 64) the ladder's own 512 edge.
+CASCADE_SHAPES = [(64, 192), (64, 384), (128, 384), (48, 208), (448, 64),
+                  (384, 64)]
 CASCADE_CASES = [(lay, R, Tt, False) for lay in LAYOUTS
                  for R, Tt in CASCADE_SHAPES]
 CASCADE_CASES.append(("mha", 64, 384, True))       # bloom-7b1: ALiBi
