@@ -47,14 +47,18 @@ class FakeTokenizer:
             raise ValueError(f"FakeTokenizer vocab {vocab} leaves no room "
                              f"past the {self._RESERVED} reserved ids")
         self.VOCAB = int(vocab)   # instance override; class default kept
+        # word -> id, filled as words are met: a sweep tokenizes the same
+        # few thousand words half a million times, and the md5 below was
+        # two thirds of its plan stage (PERF.md §6, PR 26).
+        self._ids = {"Yes": self.YES, "No": self.NO}
 
     def _word_id(self, w: str) -> int:
-        if w == "Yes":
-            return self.YES
-        if w == "No":
-            return self.NO
-        h = int(hashlib.md5(w.encode()).hexdigest(), 16)
-        return self._RESERVED + h % (self.VOCAB - self._RESERVED)
+        wid = self._ids.get(w)
+        if wid is None:
+            h = int(hashlib.md5(w.encode()).hexdigest(), 16)
+            wid = self._ids[w] = (self._RESERVED
+                                  + h % (self.VOCAB - self._RESERVED))
+        return wid
 
     def __call__(self, text: str, add_special_tokens: bool = True) -> _Encoding:
         return _Encoding([self._word_id(w) for w in text.split()])

@@ -372,6 +372,7 @@ def _paged_prefix(params, cfg: ModelConfig, pool, slot_src: jax.Array,
     """
     from ..models import paged as paged_mod
 
+    decoder.refuse_recurrent(cfg, "the paged prefix path")
     S = prefix_mask.shape[1]
     cache = paged_mod.gather_slots(pool, slot_src)          # S-slot view
     _, cache, _ = decoder.extend(params, cfg, cache, rem, rem_mask,
@@ -443,7 +444,10 @@ def greedy_decode_fused_shared_paged(params, cfg: ModelConfig, pool,
 
     out_a, cache_a = branch(cache, sfx_a, sfx_a_mask, max_new_a,
                             empty_ids, empty_vals, stop_mask=stop_mask_a)
-    out_b, cache_b = branch(cache_a, sfx_b, sfx_b_mask, max_new_b,
+    # B runs on A's K/V buffers (rewound by its mask) but starts from the
+    # recurrent state of the prefix's end, which `cache` still holds.
+    out_b, cache_b = branch(decoder.rewind(cache_a, cache), sfx_b,
+                            sfx_b_mask, max_new_b,
                             digit_ids, digit_vals, stop_mask=stop_mask_b)
     if return_cache:
         return out_a, out_b, cache_b
@@ -492,7 +496,10 @@ def _cascade_branches(params, cfg: ModelConfig, tcache, trunk_len: int,
 
     out_a, cache_a = branch(cache, sfx_a, sfx_a_mask, max_new_a,
                             empty_ids, empty_vals, stop_mask=stop_mask_a)
-    out_b, cache_b = branch(cache_a, sfx_b, sfx_b_mask, max_new_b,
+    # B runs on A's K/V buffers (rewound by its mask) but starts from the
+    # recurrent state of the prefix's end, which `cache` still holds.
+    out_b, cache_b = branch(decoder.rewind(cache_a, cache), sfx_b,
+                            sfx_b_mask, max_new_b,
                             digit_ids, digit_vals, stop_mask=stop_mask_b)
     if return_cache:
         return out_a, out_b, cache_b
@@ -1206,6 +1213,7 @@ def _piggyback_extend(params, cfg: ModelConfig, prefix, prefix_mask,
                       prefill_fn=None) -> PiggybackCarry:
     """Prefill + both suffix extensions WITHOUT the decode scans, into the
     disjoint-region piggyback cache layout (see PiggybackCarry)."""
+    decoder.refuse_recurrent(cfg, "the piggyback chain")
     B, S = prefix.shape
     S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
     T = cache_extent(cfg, S + S2a + max_new_a + S2b + max_new_b, B)
@@ -1344,7 +1352,11 @@ def greedy_decode_fused_shared(params, cfg: ModelConfig, prefix: jax.Array,
     Branch B consumes branch A's final cache buffer on purpose: A's suffix
     and generated slots are overwritten/masked (branch B's cache_mask shows
     only prefix + its own suffix), so XLA can alias the cache update
-    in place instead of holding two full KV caches live.
+    in place instead of holding two full KV caches live. That is a rewind
+    by mask, which K/V allows and a recurrent state does not: a model
+    with a state-space mixer starts B from the SSM state and conv tail
+    as they stood at the prefix's end (decoder.rewind: one held
+    snapshot, one working copy).
 
     Returns (binary FusedDecodeOut, confidence FusedDecodeOut); the
     confidence branch gets the digit table, the binary branch skips it.
@@ -1388,7 +1400,8 @@ def greedy_decode_fused_shared(params, cfg: ModelConfig, prefix: jax.Array,
                             empty_ids, empty_vals, stop_mask=stop_mask_a)
     # The confidence branch (B) takes the digit table and, when provided,
     # the digit early stop — only its first complete integer is read.
-    out_b, cache_b = branch(cache_a, sfx_b, sfx_b_mask, max_new_b,
+    out_b, cache_b = branch(decoder.rewind(cache_a, cache), sfx_b,
+                            sfx_b_mask, max_new_b,
                             digit_ids, digit_vals, stop_mask=stop_mask_b)
     if return_cache:
         return out_a, out_b, cache_b

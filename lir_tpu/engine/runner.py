@@ -28,7 +28,8 @@ from ..models import decoder, paged, quant
 from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CompileStats, FaultStats,
                                GuardStats, KernelStats, PrefixCacheStats,
-                               SpecStats, cascade_decode_bytes_saved,
+                               RecurrentStats, SpecStats,
+                               cascade_decode_bytes_saved,
                                cascade_prefill_flops_saved)
 from . import (compile_plan, generate, hbm, prefix_tree,
                scheduler as scheduler_mod, score, spec as spec_mod,
@@ -68,6 +69,18 @@ def _tail_batch(n: int, cap: int) -> int:
 # pool, the radix index, and the dispatch-scratch donation chain — live
 # under the one allocator module; this alias keeps the historical name.
 _CacheHandoff = paged.CacheHandoff
+
+
+def _tree_bytes(tree: Any) -> int:
+    """Bytes of a pytree's array leaves, from shape METADATA alone
+    (.size / .itemsize are host ints on an async jax array): no device
+    round-trip."""
+    nbytes = 0
+    for leaf in jax.tree.leaves(tree):
+        size, dtype = getattr(leaf, "size", None), getattr(leaf, "dtype", None)
+        if size is not None and dtype is not None:
+            nbytes += int(size) * int(jnp.dtype(dtype).itemsize)  # lint: allow(host-sync)
+    return nbytes
 
 
 @dataclasses.dataclass
@@ -156,6 +169,10 @@ class ScoringEngine:
         # policy + the dedup counters bench.py's "cascade" key reads.
         self.cascade_cfg = cascade_config or CascadeConfig()
         self.cascade_stats = CascadeStats()
+        # Recurrent state beside K/V (models with a state-space mixer):
+        # bytes per dispatch cache, forks, kernel calls, trunk states
+        # shared (profiling.RecurrentStats; metrics source "recurrent").
+        self.recurrent_stats = RecurrentStats()
         self._spec_draft = None
         self._spec_pending: List[Any] = []
         self.spec_fault_plan = None
@@ -406,6 +423,7 @@ class ScoringEngine:
         bitwise) instead of deleting them. The rung's engage callback
         is unchanged — demotion frees the same HBM pages eviction
         would, so the governor's reclaim accounting holds."""
+        decoder.refuse_recurrent(self.cfg, "the tiered page store")
         self._tier_store = store
 
     def _note_handoff(self, cache: Any) -> None:
@@ -414,17 +432,31 @@ class ScoringEngine:
         sync). One entry: the chain holds at most one parked cache."""
         if self.governor is None or cache is None:
             return
-        nbytes = 0
-        for leaf in jax.tree.leaves(cache):
-            size = getattr(leaf, "size", None)
-            dtype = getattr(leaf, "dtype", None)
-            if size is None or dtype is None:
-                continue
-            # .size/.itemsize are static shape METADATA (host ints on
-            # an async jax array) — no device round-trip happens here.
-            nbytes += int(size) * int(jnp.dtype(dtype).itemsize)  # lint: allow(host-sync)
+        nbytes = _tree_bytes(cache)
         self.governor.register(
             f"handoff:{getattr(self.cfg, 'name', 'model')}", nbytes)
+
+    def _note_recurrent(self, cache: Any, rows: int, steps: int,
+                        windows: int, trunk_rows: int = 0,
+                        forks: Optional[int] = None) -> None:
+        """Count one dispatch's recurrent state (host ints from shape
+        metadata; nothing for a model without a mixer). In a shared
+        dispatch both format branches start from the state of the
+        prefix's end: two forks a row, unless ``forks`` says otherwise.
+        ``windows`` is the number of chunked-scan windows the program
+        runs per layer (prefix [+ trunk], the suffix extends), ``steps``
+        the decode budget, each step a single-token update per layer."""
+        if not getattr(self.cfg, "has_mixer", False):
+            return
+        L = self.cfg.n_layers
+        stats = self.recurrent_stats
+        stats.count("dispatches")
+        stats.count("kv_bytes", _tree_bytes(cache[:2]))
+        stats.count("state_bytes", _tree_bytes(cache[2:]))
+        stats.count("forks", 2 * rows if forks is None else forks)
+        stats.count("scan_calls", windows * L)
+        stats.count("step_calls", steps * L)
+        stats.count("trunk_states_shared", trunk_rows)
 
     def enable_prefix_cache(self) -> None:
         """Build the paged KV pool + radix index (idempotent). The pool
@@ -440,6 +472,7 @@ class ScoringEngine:
             return
         if self.rt.prefix_cache_pages < 2:
             return
+        decoder.refuse_recurrent(self.cfg, "the radix prefix cache")
         pool = paged.KVPagePool(self.rt.prefix_cache_pages,
                                 self.rt.prefix_page_size)
         pool.ensure(self._cache_aval())
@@ -462,7 +495,10 @@ class ScoringEngine:
         is decided where the dispatch forms."""
         return (self.rt.spec_decode and self.rt.spec_k >= 2
                 and not self.encoder_decoder
-                and self._prefill_fn is None)
+                and self._prefill_fn is None
+                # no roll-back of recurrent state to the last accepted
+                # token yet (decoder.refuse_recurrent)
+                and not getattr(self.cfg, "has_mixer", False))
 
     def set_spec_draft(self, params: Any, cfg: Any, name: str = "") -> None:
         """Arm fleet-model drafting: the small model's (params, cfg)
@@ -1231,6 +1267,9 @@ class ScoringEngine:
             self._note_cascade_decode(
                 dtrunk, len(bin_ids) if n_real is None else n_real,
                 cache, new_tokens, conf_tokens)
+            self._note_recurrent(
+                cache, len(bin_ids) if n_real is None else n_real,
+                new_tokens + conf_tokens, windows=3)
             return fused, cfused
         return generate.greedy_decode_fused_shared(
             self.params, self.cfg, jnp.asarray(prefix),
@@ -1424,7 +1463,13 @@ class ScoringEngine:
             self._finish_prefix_resume(plan, cache)
         rows = B if n_real is None else n_real
         self.cascade_stats.count("cascade_dispatches")
+        # A row counts as deduped when ALL of its trunk was shared: its
+        # K/V and, for a model with a mixer, its recurrent state at the
+        # trunk's end (generate.greedy_decode_fused_shared_cascade
+        # computes both once at batch 1 and seeds every row with them).
         self.cascade_stats.count("trunk_rows_deduped", max(rows - 1, 0))
+        self._note_recurrent(cache, rows, new_tokens + conf_tokens,
+                             windows=4, trunk_rows=max(rows - 1, 0))
         self.cascade_stats.count(
             "prefix_flops_saved",
             int(cascade_prefill_flops_saved(self.cfg, rows, trunk)))
@@ -1450,6 +1495,7 @@ class ScoringEngine:
                 and not self.encoder_decoder
                 and self._prefill_fn is None
                 and self.prefix_cache is None
+                and not getattr(self.cfg, "has_mixer", False)
                 and "decode_fused_shared" not in self.__dict__)
 
     def _piggyback_fits(self, bsz: int, total_len: int) -> bool:
@@ -1779,6 +1825,11 @@ class ScoringEngine:
                 raise
             self._handoff.put(key, cache)
             self._note_handoff(cache)
+            # A member row is one format branch of one cell: the row
+            # gather hands each REAL member its own copy of its group's
+            # state (the padded rows repeat the last member's).
+            self._note_recurrent(cache, rows=0, steps=kwargs["max_new"],
+                                 windows=2, forks=m)
             if plan is not None:
                 self._finish_prefix_resume(plan, cache,
                                            row_map=first_member)

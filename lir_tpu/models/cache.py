@@ -31,6 +31,10 @@ log = get_logger(__name__)
 # axis 3 in both flavors, which is what makes the row gather below one
 # uniform tree_map.
 KV_BATCH_AXIS = 3
+# A model with a state-space mixer carries, after the K/V pair, the SSM
+# state (L, B, Hs, P, N) and the conv tail (L, B, taps - 1, C): batch is
+# axis 1 there.
+REC_BATCH_AXIS = 1
 
 
 def gather_rows(cache: Any, row_idx: jax.Array) -> Any:
@@ -47,8 +51,10 @@ def gather_rows(cache: Any, row_idx: jax.Array) -> Any:
     """
     import jax.numpy as jnp
 
-    return jax.tree.map(
-        lambda a: jnp.take(a, row_idx, axis=KV_BATCH_AXIS), cache)
+    kv = jax.tree.map(
+        lambda a: jnp.take(a, row_idx, axis=KV_BATCH_AXIS), tuple(cache[:2]))
+    return kv + tuple(jnp.take(a, row_idx, axis=REC_BATCH_AXIS)
+                      for a in cache[2:])
 
 
 def kv_cache_bytes(cfg, batch: int, max_len: int, dtype_bytes: int = 2) -> int:

@@ -54,6 +54,11 @@ FUSED_DECODE_INTERPRET_ON_CPU = False
 # hook, runner.ScoringEngine.cascade_supported).
 CASCADE_INTERPRET_ON_CPU = False
 
+# Same hook for the selective-scan kernels (ops/ssd_scan): tier-1 runs the
+# mixer's chunked scan and single-token update under the interpreter on
+# CPU; production CPU runs the recurrence token by token in XLA.
+SSM_INTERPRET_ON_CPU = False
+
 
 # ---------------------------------------------------------------------------
 # Param init (random weights for tests; real weights come from models/loader.py)
@@ -93,6 +98,17 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.mlp_bias:
         layers["b_up"] = jnp.zeros((L, F), dtype)
         layers["b_down"] = jnp.zeros((L, D), dtype)
+    if cfg.has_mixer:
+        Hs, C = cfg.ssm_heads, cfg.ssm_conv_dim
+        layers["w_in"] = w(L, D, cfg.ssm_in_width)
+        layers["w_out"] = w(L, cfg.ssm_inner, D)
+        layers["conv_w"] = w(L, cfg.ssm_conv, C, scale=0.5)
+        layers["conv_b"] = w(L, C, scale=0.1)
+        layers["a_log"] = jnp.log(jax.random.uniform(
+            next(k), (L, Hs), minval=1.0, maxval=16.0)).astype(dtype)
+        layers["ssm_d"] = jnp.ones((L, Hs), dtype)
+        layers["dt_bias"] = w(L, Hs, scale=0.5)
+        layers["ssm_norm"] = jnp.ones((L, cfg.ssm_inner), dtype)
 
     params: Params = {"tok_embed": w(cfg.vocab_size, D, scale=0.02), "layers": layers}
     if cfg.pos_embedding == "learned":
@@ -423,12 +439,117 @@ def _attention_cached(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, S, H * hd)
 
 
+def _ssm_kernels_lower(cfg: ModelConfig) -> bool:
+    """Where the selective-scan kernels run: the TPU backend of an engine
+    whose Pallas routes are on (``cfg.fused_decode`` is what a sharded
+    engine turns off: GSPMD cannot partition a Mosaic call), or CPU
+    under the interpreter test hook."""
+    if jax.default_backend() == "tpu":
+        return cfg.fused_decode
+    return SSM_INTERPRET_ON_CPU
+
+
+def _ssm_columns(cfg: ModelConfig) -> Optional[jax.Array]:
+    """The per-column muP vector over the input projection's output
+    (z | x | B | C | dt), or None when every multiplier is 1."""
+    if all(m == 1.0 for m in cfg.ssm_multipliers):
+        return None
+    gn = cfg.ssm_groups * cfg.ssm_state
+    widths = (cfg.ssm_inner, cfg.ssm_inner, gn, gn, cfg.ssm_heads)
+    return jnp.concatenate([jnp.full((n,), m, jnp.float32) for n, m in
+                            zip(widths, cfg.ssm_multipliers)])
+
+
+def _mixer(h: jax.Array, lp: Params, cfg: ModelConfig, state: jax.Array,
+           tail: jax.Array, mask: Optional[jax.Array]):
+    """Mamba-2 mixer over h (B, S, D), the block's normed input.
+
+    ``state`` (B, Hs, P, N) float32 and ``tail`` (B, conv - 1, C), the
+    conv's last inputs, are the row's recurrent state on entry; returned
+    as they stand after each row's LAST REAL token. ``mask`` (B, S) marks
+    real slots (None: all). A masked slot is a no-op on both: its conv
+    input is zeroed (left padding then reads like the empty history) and
+    its step size is zeroed (decay 1, nothing added), so the state a
+    right-padded row hands on is the state at its own end, and the tail
+    is gathered there. Returns (out (B, S, D), state, tail)."""
+    from ..ops import ssd_scan as scan_ops
+
+    B, S, _ = h.shape
+    Hs, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    inner, gn, taps = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state, cfg.ssm_conv
+    f32 = jnp.float32
+
+    if cfg.ssm_in_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.ssm_in_multiplier, h.dtype)
+    proj = _mm(h, lp["w_in"])
+    mu = _ssm_columns(cfg)
+    if mu is not None:
+        proj = (proj * mu).astype(h.dtype)
+    z, xbc, dt = (proj[..., :inner], proj[..., inner:inner + cfg.ssm_conv_dim],
+                  proj[..., inner + cfg.ssm_conv_dim:])
+    if mask is not None:
+        xbc = xbc * mask[:, :, None].astype(xbc.dtype)
+
+    # Depthwise causal conv over [tail | window], then SiLU.
+    seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    conv = lp["conv_b"].astype(f32)
+    for k in range(taps):
+        conv = conv + seq[:, k:k + S].astype(f32) * lp["conv_w"][k].astype(f32)
+    xbc = jax.nn.silu(conv).astype(h.dtype)
+    if mask is None:
+        tail = seq[:, S:]
+    else:
+        # The taps - 1 inputs ending at each row's last real slot (slot
+        # -1, the carried tail itself, for a row with none).
+        slots = jnp.arange(S, dtype=jnp.int32)
+        last = jnp.max(jnp.where(mask > 0, slots, -1), axis=1)       # (B,)
+        idx = last[:, None] + 1 + jnp.arange(taps - 1, dtype=jnp.int32)
+        tail = jnp.take_along_axis(seq, idx[:, :, None], axis=1)
+
+    x = xbc[..., :inner].reshape(B, S, Hs, P)
+    bm = xbc[..., inner:inner + gn].reshape(B, S, G, N)
+    cm = xbc[..., inner + gn:].reshape(B, S, G, N)
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    if mask is not None:
+        dt = dt * mask[:, :, None].astype(f32)
+    a = -jnp.exp(lp["a_log"].astype(f32))
+
+    kernels = _ssm_kernels_lower(cfg)
+    interpret = jax.default_backend() != "tpu"
+    if S == 1 and kernels:
+        y, state = scan_ops.ssm_step(x[:, 0], dt[:, 0], a, bm[:, 0],
+                                     cm[:, 0], state, interpret=interpret)
+        y = y[:, None]
+    elif kernels:
+        y, state = scan_ops.ssd_scan(x, dt, a, bm, cm, state,
+                                     chunk=cfg.ssm_chunk,
+                                     interpret=interpret)
+    else:
+        y, state = scan_ops.ssd_scan_tokens(x, dt, a, bm, cm, state)
+    y = y.astype(f32) + lp["ssm_d"].astype(f32)[:, None] * x.astype(f32)
+
+    # Gate, then RMSNorm over each group of heads on its own (the
+    # published ``mamba_norm_before_gate`` false, ``mamba_rms_norm`` true).
+    y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(f32))
+    yg = y.reshape(B, S, G, inner // G)
+    yg = yg * lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True)
+                        + cfg.norm_eps)
+    y = (yg.reshape(B, S, inner) * lp["ssm_norm"].astype(f32)).astype(h.dtype)
+    return _mm(y, lp["w_out"]), state, tail
+
+
 def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
            bias: jax.Array, cache_kv: Optional[Tuple[jax.Array, jax.Array]],
            cache_index: Optional[jax.Array],
            key_mask: Optional[jax.Array] = None,
-           attn_impl=None, fused_ctx=None, trunk_len: int = 0):
+           attn_impl=None, fused_ctx=None, trunk_len: int = 0,
+           rec=None, rec_mask: Optional[jax.Array] = None):
     """One transformer block. Returns (new_x, (k_full, v_full)).
+
+    A model with a state-space mixer (``cfg.has_mixer``) also takes
+    ``rec``, this layer's (SSM state, conv tail) on entry (None: the
+    empty history), and ``rec_mask`` (B, S), the window's real slots
+    (None: all); it returns (new_x, (k_full, v_full, state, tail)).
 
     ``attn_impl(q, k, v, key_mask) -> (B, S, H*hd)`` replaces dense
     attention when given (the sequence-parallel path, parallel/seq_forward);
@@ -445,13 +566,18 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     h_attn_in = _norm(x, lp["ln1"], cfg)
+    h_qkv = h_attn_in
+    if cfg.attention_in_multiplier != 1.0:
+        h_qkv = h_qkv * jnp.asarray(cfg.attention_in_multiplier, x.dtype)
     # Dynamic-int8 trees quantize the attention input ONCE for the whole
     # q/k/v triple (quant.shared_quant) — bit-identical to per-matrix
     # quantization, two fewer VPU amax/round passes per block.
-    h_qkv = _shared_quant(h_attn_in, lp["wq"], lp["wk"], lp["wv"])
+    h_qkv = _shared_quant(h_qkv, lp["wq"], lp["wk"], lp["wv"])
     q = _mm(h_qkv, lp["wq"])
     k = _mm(h_qkv, lp["wk"])
     v = _mm(h_qkv, lp["wv"])
+    if cfg.key_multiplier != 1.0:
+        k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
     if cfg.qkv_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
     q = q.reshape(B, S, H, hd)
@@ -502,6 +628,18 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
     attn = _mm(attn, lp["wo"])
     if cfg.attn_out_bias:
         attn = attn + lp["bo"]
+    if cfg.attention_out_multiplier != 1.0:
+        attn = attn * jnp.asarray(cfg.attention_out_multiplier, attn.dtype)
+    if cfg.has_mixer:
+        # The mixer reads the same normed input as attention; the two
+        # branches are summed into one residual update.
+        if rec is None:
+            rec = _empty_rec(cfg, B, x.dtype)
+        mix, state, tail = _mixer(h_attn_in, lp, cfg, rec[0], rec[1],
+                                  rec_mask)
+        if cfg.ssm_out_multiplier != 1.0:
+            mix = mix * jnp.asarray(cfg.ssm_out_multiplier, mix.dtype)
+        attn = attn + mix
 
     if cfg.parallel_block:
         mlp_in = h_attn_in if cfg.shared_block_ln else _norm(x, lp["ln2"], cfg)
@@ -517,20 +655,60 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
         up = up + lp["b_up"]
     if cfg.gated_mlp:
         gate = _mm(mlp_q, lp["w_gate"])
+        if cfg.mlp_multipliers[0] != 1.0:
+            gate = gate * jnp.asarray(cfg.mlp_multipliers[0], gate.dtype)
         hidden = _act(gate, cfg.activation) * up
     else:
         hidden = _act(up, cfg.activation)
     mlp = _mm(hidden, lp["w_down"])
     if cfg.mlp_bias:
         mlp = mlp + lp["b_down"]
+    if cfg.mlp_multipliers[1] != 1.0:
+        mlp = mlp * jnp.asarray(cfg.mlp_multipliers[1], mlp.dtype)
 
     out = x + attn + mlp if cfg.parallel_block else x + mlp
+    if cfg.has_mixer:
+        return out, (ck, cv, state, tail)
     return out, (ck, cv)
+
+
+def _empty_rec(cfg: ModelConfig, batch: int, dtype) -> Tuple:
+    """One layer's recurrent state before any token: (SSM state, conv
+    tail), both zero."""
+    return (jnp.zeros((batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                       cfg.ssm_state), jnp.float32),
+            jnp.zeros((batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype))
+
+
+def refuse_recurrent(cfg, what: str) -> None:
+    """The one plain error of everything that cannot hold recurrent state
+    yet (ROADMAP M4): paged / radix prefix reuse (pages hold K/V, not the
+    state at a page's edge), speculative verify (no roll-back to the last
+    accepted token), the piggyback chain (one parked cache, two branches),
+    tiering and migration (pages again). They refuse; none answers
+    wrongly."""
+    if getattr(cfg, "has_mixer", False):
+        raise NotImplementedError(
+            f"{cfg.name}: {what} cannot carry a state-space mixer's "
+            "recurrent state (SSM state + conv tail) yet; it holds K/V "
+            "only (ROADMAP M4)")
+
+
+def rewind(cache, snapshot):
+    """The cache a second branch starts from after a first branch ran on
+    ``snapshot``'s successor ``cache``: K/V is rewound by the branch's own
+    mask (the first branch's slots are masked away and overwritten), so
+    the buffers carry over; recurrent state cannot be rewound, so it is
+    taken from ``snapshot``, the state as it stood at the shared prefix's
+    end. A cache without recurrent state passes through."""
+    return cache if len(cache) == 2 else tuple(cache[:2]) + tuple(snapshot[2:])
 
 
 def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array,
            positions: jax.Array) -> jax.Array:
     x = jnp.take(params["tok_embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.pos_embedding == "learned":
         # mode="clip": an out-of-table position reuses the last row instead
         # of jnp.take's default NaN fill silently poisoning every logit.
@@ -554,6 +732,8 @@ def _unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     else:
         logits = jnp.einsum("bsd,dv->bsv", x.astype(jnp.float32),
                             head.astype(jnp.float32))
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
     if cfg.logit_softcap:
         logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
     return logits
@@ -592,20 +772,24 @@ def mask_positions(attn_mask: jax.Array) -> jax.Array:
 
 def _scan_blocks(params: Params, cfg: ModelConfig, x, sin, cos, bias,
                  cache=None, cache_index=None, key_mask=None, attn_impl=None,
-                 fused_ctx=None, trunk_len: int = 0):
-    """lax.scan over the stacked layer params."""
+                 fused_ctx=None, trunk_len: int = 0, rec_mask=None):
+    """lax.scan over the stacked layer params. ``cache`` is the (ck, cv)
+    pair, with (SSM state, conv tail) after it for a model with a mixer;
+    ``rec_mask`` marks the window's real slots for that state."""
     def body(carry, xs):
         h = carry
         if cache is None:
             lp = xs
             h, _ = _block(h, lp, cfg, sin, cos, bias, None, None,
-                          key_mask=key_mask, attn_impl=attn_impl)
+                          key_mask=key_mask, attn_impl=attn_impl,
+                          rec_mask=key_mask)
             return h, None
-        lp, (ck, cv) = xs
-        h, (nk, nv) = _block(h, lp, cfg, sin, cos, bias, (ck, cv),
-                             cache_index, fused_ctx=fused_ctx,
-                             trunk_len=trunk_len)
-        return h, (nk, nv)
+        lp, layer = xs
+        h, new = _block(h, lp, cfg, sin, cos, bias, tuple(layer[:2]),
+                        cache_index, fused_ctx=fused_ctx,
+                        trunk_len=trunk_len, rec=tuple(layer[2:]) or None,
+                        rec_mask=rec_mask)
+        return h, new
 
     xs = params["layers"] if cache is None else (params["layers"], cache)
     x, new_cache = lax.scan(body, x, xs)
@@ -651,7 +835,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
             return (jnp.zeros(shape, jnp.int8),
                     jnp.zeros(shape[:-1], jnp.float32))
         return (side(), side())
-    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    kv = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    if cfg.has_mixer:
+        # Beside K/V, per layer and row: the SSM state (float32) and the
+        # conv's last inputs. Batch is axis 1 here (models/cache.
+        # gather_rows knows), slots there are none: the state is the
+        # whole history folded, whatever the cache's extent.
+        state, tail = _empty_rec(cfg, batch, dtype)
+        L = cfg.n_layers
+        return kv + (jnp.zeros((L,) + state.shape, state.dtype),
+                     jnp.zeros((L,) + tail.shape, tail.dtype))
+    return kv
 
 
 # Phase scopes (observe/tracing's naming: ``lir.<phase>``): every dispatch
@@ -698,21 +892,24 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     pad_spec = ((0, 0), (0, pad), (0, 0), (0, 0))
 
     def body(h, lp):
-        h_out, (k, v) = _block(h, lp, cfg, sin, cos, bias, None, None,
-                               key_mask=attn_mask, attn_impl=attn_impl)
-        k = k.transpose(2, 1, 0, 3)  # (B, S, K, hd) -> (K, S, B, hd)
-        v = v.transpose(2, 1, 0, 3)
+        h_out, new = _block(h, lp, cfg, sin, cos, bias, None, None,
+                            key_mask=attn_mask, attn_impl=attn_impl,
+                            rec_mask=attn_mask)
+        k = new[0].transpose(2, 1, 0, 3)  # (B, S, K, hd) -> (K, S, B, hd)
+        v = new[1].transpose(2, 1, 0, 3)
         if cfg.kv_cache_int8:
             def side(x):
                 xq, xs = _quant_kv(x)
                 return (jnp.pad(xq, pad_spec), jnp.pad(xs, pad_spec[:-1]))
             return h_out, (side(k), side(v))
-        return h_out, (jnp.pad(k, pad_spec), jnp.pad(v, pad_spec))
+        # A mixer's state and conv tail (new[2:]) stack beside K/V as
+        # they stand after each row's last real token.
+        return h_out, (jnp.pad(k, pad_spec), jnp.pad(v, pad_spec)) + new[2:]
 
-    x, (ck, cv) = lax.scan(body, x, params["layers"])
+    x, cache = lax.scan(body, x, params["layers"])
     logits = _unembed(params, cfg, x[:, -1:, :])[:, 0, :]
     next_positions = positions[:, -1] + 1
-    return logits, (ck, cv), next_positions
+    return logits, cache, next_positions
 
 
 @jax.named_scope("lir.extend")
@@ -744,7 +941,8 @@ def extend(params: Params, cfg: ModelConfig, cache, suffix_tokens: jax.Array,
     bias = _causal_bias(suffix_mask, qpos, cfg,
                         key_positions=key_positions, key_mask=cache_mask)
     x, new_cache = _scan_blocks(params, cfg, x, sin, cos, bias,
-                                cache=cache, cache_index=start_index)
+                                cache=cache, cache_index=start_index,
+                                rec_mask=suffix_mask)
     # Per-row last REAL suffix position (right padding varies by row).
     last = jnp.maximum(jnp.sum(suffix_mask, axis=-1) - 1, 0)      # (B,)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # (B, 1, D)
@@ -785,21 +983,28 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
     sin = cos = None
     if cfg.pos_embedding == "rotary":
         sin, cos = _rope_sincos(qpos, cfg.rotary_dim, cfg.rope_theta)
-    tck, tcv = trunk_cache                                # (L, K, Tt, 1, hd)
+    tck, tcv = trunk_cache[:2]                            # (L, K, Tt, 1, hd)
 
     def body(h, xs):
-        lp, (tk, tv) = xs
+        lp, layer = xs
+        tk, tv = layer[:2]
 
         def impl(q, k, v, key_mask):
             return _attention_cascade(q, k, v,
                                       (tk[:, :, 0, :], tv[:, :, 0, :]),
                                       rem_mask, qpos, cfg, int8_qk)
 
-        h, (k, v) = _block(h, lp, cfg, sin, cos, None, None, None,
-                           key_mask=rem_mask, attn_impl=impl)
-        return h, (k, v)
+        # The trunk's recurrent state, computed once at batch 1, is every
+        # row's state on entry (the remainders continue from it).
+        rec = tuple(jnp.broadcast_to(a, (B,) + a.shape[1:])
+                    for a in layer[2:]) or None
+        h, new = _block(h, lp, cfg, sin, cos, None, None, None,
+                        key_mask=rem_mask, attn_impl=impl, rec=rec,
+                        rec_mask=rem_mask)
+        return h, new
 
-    _, (rk, rv) = lax.scan(body, x, (params["layers"], (tck, tcv)))
+    _, new = lax.scan(body, x, (params["layers"], tuple(trunk_cache)))
+    rk, rv = new[:2]
 
     # Assemble the B-row cache in the (L, K, T, B, hd) layout: the trunk
     # side broadcasts across rows (identical KV by construction — the
@@ -813,7 +1018,7 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
         z = jnp.zeros((L, K, pad, B, hd), trunk.dtype)
         return jnp.concatenate([t, w, z], axis=2)
 
-    return side(tck, rk), side(tcv, rv)
+    return (side(tck, rk), side(tcv, rv)) + tuple(new[2:])
 
 
 @jax.named_scope("lir.extend")
@@ -852,6 +1057,7 @@ def verify_extend(params: Params, cfg: ModelConfig, cache,
     once per kv head for every row's queries, bitwise the flat kernel.
 
     Returns (logits (B, S, V) fp32, new_cache)."""
+    refuse_recurrent(cfg, "a speculative verify window")
     B, S2 = chunk_tokens.shape
     key_positions = mask_positions(cache_mask)
     qpos = lax.dynamic_slice_in_dim(key_positions, start_index, S2, axis=1)

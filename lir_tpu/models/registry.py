@@ -106,6 +106,36 @@ class ModelConfig:
     # the pre-quantization k/v). Opt-in; measured accuracy in tests.
     kv_cache_int8: bool = False
 
+    # Mamba-2 mixer beside attention in every block (Falcon-H1):
+    # ``ssm_heads`` > 0 adds, on the same normed input as attention, a
+    # state-space mixer of ``ssm_heads`` heads of ``ssm_head_dim`` with a
+    # per-head (head_dim, ssm_state) float32 state, ``ssm_groups`` B/C
+    # groups, a depthwise causal conv of ``ssm_conv`` taps (with bias)
+    # over the x|B|C channels and a gated group-RMSNorm before the output
+    # projection (models/decoder._mixer; the scan is ops/ssd_scan at
+    # ``ssm_chunk`` tokens a chunk). Such a model carries a SECOND kind
+    # of per-sequence state beside K/V — the SSM state and the conv's
+    # last ``ssm_conv - 1`` inputs — which no mask can rewind
+    # (decoder.init_cache, decoder.rewind). 0 = no mixer: every model
+    # before this one, bit for bit.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # muP multipliers (Falcon-H1 publishes them in config.json); a
+    # multiplier of exactly 1.0 is not applied at all.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)          # gate pre-activation, output
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)   # z, x, B, C, dt
+
     def __post_init__(self) -> None:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.n_heads)
@@ -120,10 +150,37 @@ class ModelConfig:
         assert not (self.shared_block_ln and not self.parallel_block), (
             "shared_block_ln=True requires parallel_block=True "
             f"({self.name})")
+        object.__setattr__(self, "mlp_multipliers",
+                           tuple(self.mlp_multipliers))
+        object.__setattr__(self, "ssm_multipliers",
+                           tuple(self.ssm_multipliers))
+        if self.has_mixer and self.kv_cache_int8:
+            raise ValueError(
+                f"{self.name}: kv_cache_int8 has no recurrent-state side; "
+                "a model with a state-space mixer keeps a float cache")
 
     @property
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.rotary_pct)
+
+    @property
+    def has_mixer(self) -> bool:
+        return self.ssm_heads > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of the mixer's x (and z, and output) stream."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the depthwise conv runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_in_width(self) -> int:
+        """Columns of the mixer's input projection: z | x | B | C | dt."""
+        return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,6 +292,38 @@ def falcon_7b() -> ModelConfig:
     )
 
 
+def falcon_h1_34b(n_layers: int = 72) -> ModelConfig:
+    """tiiuae/Falcon-H1-34B-Instruct (config.json): in every block a
+    Mamba-2 mixer (32 heads of 128, state 256, 2 groups, conv 4) beside
+    GQA attention (20 query / 4 key-value heads of 128) on one RMSNorm,
+    then a gated SiLU MLP of 21504; muP multipliers on every branch;
+    untied 261120-row head. ``n_layers`` is the only size a cut may
+    change (benchmarks/configs/falcon-h1-34b.json runs 8 of the 72)."""
+    return ModelConfig(
+        name="falcon-h1-34b", vocab_size=261120, hidden_size=5120,
+        n_layers=n_layers, n_heads=20, n_kv_heads=4, head_dim=128,
+        intermediate_size=21504, max_seq_len=262144, rope_theta=1e11,
+        norm_eps=1e-5, use_flash_attention=False,
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+        ssm_conv=4, ssm_chunk=128,
+        embedding_multiplier=5.656854249492381,
+        lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0,
+        attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804,
+        ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369,
+                         0.5, 0.3535533905932738))
+
+
+def falcon_h1_34b_l8() -> ModelConfig:
+    """The published model cut in depth alone, to what one 16 GB chip
+    holds with the whole vocabulary at batch 40 (PERF.md §4)."""
+    return falcon_h1_34b(n_layers=8)
+
+
 def bloom_7b1() -> ModelConfig:
     return ModelConfig(
         name="bloom-7b1", vocab_size=250880, hidden_size=4096, n_layers=30,
@@ -293,6 +382,15 @@ def tiny(family: str) -> ModelConfig:
         return ModelConfig(name="tiny-falcon", pos_embedding="rotary", norm="layernorm",
                            activation="gelu", gated_mlp=False, parallel_block=True,
                            shared_block_ln=True, n_kv_heads=1, tie_embeddings=True, **base)
+    if family == "falcon-h1":
+        return ModelConfig(
+            name="tiny-falcon-h1", n_kv_heads=2, ssm_heads=4,
+            ssm_head_dim=16, ssm_state=16, ssm_groups=2, ssm_conv=4,
+            ssm_chunk=16, embedding_multiplier=2.0,
+            lm_head_multiplier=0.5, attention_out_multiplier=0.75,
+            key_multiplier=0.5, ssm_in_multiplier=0.5,
+            ssm_out_multiplier=0.75, mlp_multipliers=(0.5, 0.75),
+            ssm_multipliers=(0.5, 0.75, 1.25, 1.5, 0.5), **base)
     if family == "bloom":
         return ModelConfig(name="tiny-bloom", pos_embedding="alibi", norm="layernorm",
                            activation="gelu_new", gated_mlp=False, embedding_norm=True,
@@ -309,6 +407,7 @@ def tiny(family: str) -> ModelConfig:
 REGISTRY = {
     "gpt2": gpt2, "gptneox": gptneox, "llama2-7b": llama2_7b,
     "mistral-7b": mistral_7b, "qwen-7b": qwen_7b, "baichuan2-7b": baichuan2_7b,
-    "falcon-7b": falcon_7b, "bloom-7b1": bloom_7b1, "opt": opt,
+    "falcon-7b": falcon_7b, "falcon-h1-34b": falcon_h1_34b,
+    "falcon-h1-34b-l8": falcon_h1_34b_l8, "bloom-7b1": bloom_7b1, "opt": opt,
     "t5-v1_1": t5_v1_1, "flan-t5": flan_t5, "t0-3b": t0_3b,
 }

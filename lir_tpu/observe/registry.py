@@ -115,6 +115,10 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "prefix_flops_saved", "cascade_decode_dispatches",
         "trunk_bytes_deduped",
     ),
+    "RecurrentStats": (
+        "dispatches", "state_bytes", "kv_bytes", "forks", "scan_calls",
+        "step_calls", "trunk_states_shared",
+    ),
     "MemStats": (
         "ledger_bytes", "budget_bytes", "pressure", "rung",
         "rung_downs", "rung_ups", "admits", "denials", "oom_events",
@@ -280,6 +284,8 @@ def engine_registry(engine, sink=None,
         reg.register("spec", engine.spec_stats)
     if getattr(engine, "cascade_stats", None) is not None:
         reg.register("cascade", engine.cascade_stats)
+    if getattr(engine, "recurrent_stats", None) is not None:
+        reg.register("recurrent", engine.recurrent_stats)
     if getattr(engine, "governor", None) is not None:
         # HBM-governor gauges (engine/hbm.py): ledger/pressure/rung
         # land in the snapshot next to device_memory_stats(), so budget

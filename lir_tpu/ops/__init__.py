@@ -10,6 +10,10 @@ the framework.
   the trunk's attention once per dispatch as dense MXU matmuls (optional
   in-kernel s8×s8 QK^T) plus per-row suffix attention, merged by
   ``merge_partials`` (ROADMAP item 1's prefill plateau).
+- ``ssd_scan`` / ``ssm_step`` (Pallas): the chunked selective scan of a
+  Mamba-2 mixer, initial state in and final state out, and its
+  single-token update for the decode step (``ops/ssd_scan.py``; import
+  them from the module: a name re-exported here would shadow it).
 - ``merge_partials`` (``ops/lse.py``): the one log-sum-exp partial-merge
   both the decode split-K reduction and the cascade trunk/suffix merge
   reduce through.

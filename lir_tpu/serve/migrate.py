@@ -65,6 +65,7 @@ import jax
 import numpy as np
 
 from ..config import MigrationConfig
+from ..models import decoder
 from ..utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -212,6 +213,7 @@ def export_prefix(engine, bucket: int, ids, from_token: int = 0,
     duration, so eviction cannot free a page mid-copy. Returns None
     when nothing beyond ``from_token`` is cached — the chain falls back
     to a local prefill."""
+    decoder.refuse_recurrent(engine.cfg, "page migration")
     cfg = config or MigrationConfig()
     tree = getattr(engine, "prefix_cache", None)
     if tree is None:
@@ -291,6 +293,7 @@ def import_prefix(engine, export: PageExport,
     (refcounts restored, nodes removed, pages freed). Raises
     :class:`MigrationError` on checksum mismatch / layout disagreement;
     the router's fallback then re-prefills locally."""
+    decoder.refuse_recurrent(engine.cfg, "page migration")
     cfg = config or MigrationConfig()
     tree = getattr(engine, "prefix_cache", None)
     if tree is None:

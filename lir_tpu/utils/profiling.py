@@ -830,6 +830,67 @@ class CascadeStats:
 
 
 @dataclasses.dataclass
+class RecurrentStats:
+    """Counters of the second kind of per-sequence state, the recurrent
+    state a state-space mixer keeps beside K/V (models/decoder._mixer;
+    metrics source ``recurrent``). All stay 0 for a model without one.
+    Thread-safe like its siblings.
+
+    - ``dispatches``: shared dispatches that carried recurrent state.
+    - ``state_bytes`` / ``kv_bytes``: summed over those dispatches, the
+      bytes of the dispatch cache's SSM state + conv tail and of its
+      K/V (shape metadata of the cache the program returned).
+    - ``forks``: branches started from the state held at the shared
+      prefix's end (two a row: binary and confidence). K/V rewinds by
+      mask; this state is copied on write.
+    - ``scan_calls`` / ``step_calls``: chunked-scan windows
+      (ops/ssd_scan.ssd_scan) and single-token updates (``ssm_step``)
+      the dispatched programs hold, per layer.
+    - ``trunk_states_shared``: rows seeded from the ONE trunk state a
+      cascade dispatch computes at batch 1 (rows - 1 per dispatch).
+
+    ``forks``, ``scan_calls`` and ``step_calls`` are what the host says
+    of the program it dispatched (runner._note_recurrent), not readings
+    of the device. They are held to the program in
+    tests/test_hybrid_model.py (the dispatched program traced again with
+    every scan, update and rewind counted) and to the device's trace by
+    benchmarks/tests/ssm_trace.py (exits 1 where the counts differ).
+    """
+
+    dispatches: int = 0
+    state_bytes: int = 0
+    kv_bytes: int = 0
+    forks: int = 0
+    scan_calls: int = 0
+    step_calls: int = 0
+    trunk_states_shared: int = 0
+
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+
+    def count(self, field: str, n: int = 1) -> None:
+        with self._lock:
+            setattr(self, field, getattr(self, field) + n)
+
+    def summary(self) -> Dict[str, object]:
+        with self._lock:
+            held = self.state_bytes + self.kv_bytes
+            return {
+                "dispatches": self.dispatches,
+                "state_bytes": self.state_bytes,
+                "kv_bytes": self.kv_bytes,
+                "state_share": (self.state_bytes / held
+                                if held else 0.0),
+                "forks": self.forks,
+                "scan_calls": self.scan_calls,
+                "step_calls": self.step_calls,
+                "trunk_states_shared": self.trunk_states_shared,
+            }
+
+
+@dataclasses.dataclass
 class FleetStats:
     """Multi-model fleet counters (engine/fleet.py over
     models/weights.py): how much model-swap latency the async weight

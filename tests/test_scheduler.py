@@ -423,6 +423,55 @@ def test_ragged_sweep_matches_legacy_per_cell(tmp_path):
     assert stats.grouped_cells >= 4  # the shared-head rephrasings grouped
 
 
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_grouped_handoff_keeps_the_answers(kv_int8):
+    """decode_fused_grouped(reuse_cache=True), the way the sweep calls it:
+    the handoff notes (ledger, recurrent counters) read the cache the
+    program returned, whose K/V sides are (payload, scale) pairs under
+    ``kv_cache_int8``; the second call decodes into the first's cache.
+    Both answer as the call without the handoff does."""
+    import dataclasses
+
+    engine, _, cfg = _tiny_engine(
+        RuntimeConfig(batch_size=4, max_seq_len=256))
+    if kv_int8:
+        from lir_tpu.engine.runner import ScoringEngine
+
+        engine = ScoringEngine(
+            engine.params, dataclasses.replace(cfg, kv_cache_int8=True),
+            FakeTokenizer(), RuntimeConfig(batch_size=4, max_seq_len=256))
+    head = "the quick brown fox jumps over the lazy dog with filler text"
+    mains = [f"{head} word {i * 7} tail {i}" for i in range(3)]
+    ftok = engine.tokenizer
+    bin_ids = [ftok(m + " Respond with either Yes or No only").input_ids
+               for m in mains]
+    conf_ids = [ftok(m + " Give a confidence number from 0 to 100").input_ids
+                for m in mains]
+    items = sched_mod.build_items(bin_ids, conf_ids, list(range(3)))
+    plen = len(ftok(head).input_ids)
+    groups = [sched_mod.PrefixGroup(items=tuple(items), plen=plen)]
+    sfx = tok.pick_bucket(
+        [max(len(it.bin_ids), len(it.conf_ids)) - plen for it in items],
+        sched_mod.SUFFIX_BUCKETS)
+    t1 = np.full((3,), FakeTokenizer.YES, np.int32)
+    t2 = np.full((3,), FakeTokenizer.NO, np.int32)
+
+    def call(reuse):
+        out, m = engine.decode_fused_grouped(
+            groups, t1, t2, 3, 3, early_stop=False, bucket=16,
+            sfx_bucket=sfx, reuse_cache=reuse, use_prefix_cache=False)
+        assert m == 6
+        return out
+
+    plain, first, second = call(False), call(True), call(True)
+    assert engine.recurrent_stats.dispatches == 0        # no mixer here
+    for got in (first, second):
+        np.testing.assert_array_equal(np.asarray(got.generated[:6]),
+                                      np.asarray(plain.generated[:6]))
+        np.testing.assert_array_equal(np.asarray(got.p_yes[:6]),
+                                      np.asarray(plain.p_yes[:6]))
+
+
 @pytest.mark.slow
 def test_grouped_decode_matches_shared_pairwise():
     """decode_fused_grouped on one-cell groups ([bin, conf] members,

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from lir_tpu.ops.cascade_prefill import cascade_attention
 from lir_tpu.ops.flash_attention import flash_attention
+from lir_tpu.ops.ssd_scan import ssd_scan, ssm_step
 from lir_tpu.ops.flash_decode import (decode_extent, decode_split,
                                       flash_decode, flash_decode_mq,
                                       flash_decode_mq_trunk,
@@ -193,6 +194,36 @@ def test_flash_attention_compiles(one_chip, layout, masked):
                                               sharding=one_chip)
     jax.jit(functools.partial(flash_attention, causal=True)).lower(
         *shaped, **kw).compile()
+
+
+# The mixer of falcon-h1-34b at its published sizes: 32 heads of 128, state
+# 256, 2 groups; the sweep cell's scan windows (the 64-token trunk at one
+# row, the 384-token remainder and the 32-token suffixes at batch 40, and
+# the 8-row dispatch of the originals at its 128 edge) and its decode
+# update.
+MIXER = (32, 128, 2, 256)      # heads, head dim, groups, state
+
+
+def _scan_args(batch, length=None):
+    H, P, G, N = MIXER
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    t = () if length is None else (length,)
+    return [((batch, *t, H, P), bf16), ((batch, *t, H), f32), ((H,), f32),
+            ((batch, *t, G, N), bf16), ((batch, *t, G, N), bf16),
+            ((batch, H, P, N), f32)]
+
+
+@pytest.mark.parametrize("batch,length", [(1, 64), (BATCH, 384),
+                                          (BATCH, 32), (8, 128), (8, 40)])
+def test_ssd_scan_compiles(one_chip, batch, length):
+    text = _compile(ssd_scan, _scan_args(batch, length), one_chip).as_text()
+    assert "ssd_scan" in text
+
+
+@pytest.mark.parametrize("batch", [BATCH, 8, 1])
+def test_ssm_step_compiles(one_chip, batch):
+    text = _compile(ssm_step, _scan_args(batch), one_chip).as_text()
+    assert "ssm_step" in text
 
 
 def test_compiled_text_carries_the_kernel(one_chip):
