@@ -84,6 +84,55 @@ def _small_readout(logits: jax.Array, yes_ids: jax.Array, no_ids: jax.Array):
     return p_yes, p_no, top2.astype(jnp.int32)
 
 
+def _stepped(n: int, state0, emit, advance):
+    """The loop of every sequential tail: read a step's emission off the
+    pending logits, run the model on it, until ``n`` steps ran or every
+    row is done.
+
+    ``emit(t, state) -> (token (B,), record, state, stop)`` reads step
+    t's emission and whatever is recorded per step (a pytree of (B, ...)
+    rows), moves the rows' stop state, and says with the scalar ``stop``
+    whether every row is done after it; ``advance(t, token, state) ->
+    state`` is the model forward on that token (state holds the logits,
+    the cache and its mask). The order is emit 0, advance 0, emit 1, ...;
+    the loop ends BEFORE the first advance that ``stop`` makes needless,
+    so a generous budget costs the steps the longest row needs.
+
+    A ``lax.while_loop``, and not a scan whose steps skip the forward
+    under a ``lax.cond``: the cache in ``state`` is updated where it lies
+    by the layer loop (models/decoder._scan_blocks) only while nothing
+    but loops carries it. Through a conditional XLA's copy insertion
+    gives up on a program of a dispatch's size and copies each stacked
+    cache side in and out of the branch — seen compiled for a v5e: two
+    copies a side per LAYER at the compiler's default analysis budget
+    (tests/test_tpu_compile.py holds the loops to none).
+
+    Returns (records stacked (n, B, ...), rows past the end zero; the
+    step the loop ended at: ``n``, or the first whose emission made every
+    row done; the final state). One more ``emit`` than a scan would make
+    runs after the n-th advance; its record is dropped."""
+    i32 = jnp.int32
+
+    def put(bufs, t, rec):
+        return jax.tree.map(lambda b, r: b.at[t].set(r, mode="drop"),
+                            bufs, rec)
+
+    tok, rec, state, stop = emit(jnp.zeros((), i32), state0)
+    bufs = put(jax.tree.map(
+        lambda r: jnp.zeros((n,) + r.shape, r.dtype), rec), 0, rec)
+
+    def body(c):
+        t, tok, state, _, bufs = c
+        state = advance(t, tok, state)
+        tok, rec, state, stop = emit(t + 1, state)
+        return t + 1, tok, state, stop, put(bufs, t + 1, rec)
+
+    t_end, _, state, _, bufs = lax.while_loop(
+        lambda c: (c[0] < n) & ~c[3], body,
+        (jnp.zeros((), i32), tok, state, stop, bufs))
+    return bufs, t_end, state
+
+
 def _fused_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
                 cache_mask0: jax.Array, pos0: jax.Array, slot0: int,
                 yes_ids: jax.Array, no_ids: jax.Array, digit_ids: jax.Array,
@@ -113,10 +162,12 @@ def _fused_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
     run, and transparent specials (empty decode) change nothing, so the
     stop NEVER nulls an answer the full budget would have parsed. Done
     rows emit EOS from the next step (so host-side EOS trimming ends their
-    text at the stop point), and once EVERY row is done the remaining scan
-    steps skip the model forward via a scalar ``lax.cond`` — a generous
-    token budget then costs actual-response-length decode steps, not the
-    worst case. Per-step p_yes/p_no/top2 after a row's stop point reflect
+    text at the stop point), and once EVERY row is done the loop ends
+    (:func:`_stepped`) — a generous token budget then costs
+    actual-response-length decode steps, not the worst case; the steps
+    never run read as a scan that skipped their forward would have left
+    them (EOS emitted, the last step's probabilities repeated). Per-step
+    p_yes/p_no/top2 after a row's stop point reflect
     the EOS-fed model and must not be consumed (the sweep's confidence
     readout uses position 0 only).
 
@@ -138,66 +189,66 @@ def _fused_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
         wconf = (p_digits * digit_vals[None, :]).sum(axis=-1) / mass
 
     B = logits0.shape[0]
+    N = max_new_tokens
 
-    def step(carry, t):
-        logits, cache, cache_mask, done, digit_run, prev_ew = carry
+    def emit(t, state):
+        logits, cache, cache_mask, done, digit_run, prev_ew = state
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        p_yes, p_no, top2 = _small_readout(logits, yes_ids, no_ids)
-        if early_stop:
-            emit = jnp.where(done, eos_id, nxt)
-            cls = stop_mask[emit]
-            if stop_mask2 is not None:
-                cls = jnp.where(stop_sel, stop_mask2[emit], cls)
-            pure = (cls & _tok.STOP_PURE) != 0
-            prefix = (cls & _tok.STOP_PREFIX) != 0
-            glue = (cls & _tok.STOP_STARTS_WORD) != 0
-            ends_w = (cls & _tok.STOP_ENDS_WORD) != 0
-            transp = (cls & _tok.STOP_TRANSPARENT) != 0
-            done = done | (emit == eos_id) | (digit_run & ~glue & ~transp)
-            # A standalone digit run opens on a pure-digit token at a word
-            # boundary (space prefix, or previous token ended non-word —
-            # position 0 starts at a boundary: prev_ew init False), extends
-            # through unprefixed pure-digit tokens, and is spoiled by
-            # anything else. Transparent tokens freeze all text state.
-            digit_run = jnp.where(
-                transp, digit_run,
-                (pure & (prefix | ~prev_ew)) | (digit_run & pure & ~prefix))
-            prev_ew = jnp.where(transp, prev_ew, ends_w)
+        rec = _small_readout(logits, yes_ids, no_ids)
+        if not early_stop:
+            return nxt, (nxt,) + rec, state, jnp.zeros((), bool)
+        tok = jnp.where(done, eos_id, nxt)
+        cls = stop_mask[tok]
+        if stop_mask2 is not None:
+            cls = jnp.where(stop_sel, stop_mask2[tok], cls)
+        pure = (cls & _tok.STOP_PURE) != 0
+        prefix = (cls & _tok.STOP_PREFIX) != 0
+        glue = (cls & _tok.STOP_STARTS_WORD) != 0
+        ends_w = (cls & _tok.STOP_ENDS_WORD) != 0
+        transp = (cls & _tok.STOP_TRANSPARENT) != 0
+        done = done | (tok == eos_id) | (digit_run & ~glue & ~transp)
+        # A standalone digit run opens on a pure-digit token at a word
+        # boundary (space prefix, or previous token ended non-word —
+        # position 0 starts at a boundary: prev_ew init False), extends
+        # through unprefixed pure-digit tokens, and is spoiled by
+        # anything else. Transparent tokens freeze all text state.
+        digit_run = jnp.where(
+            transp, digit_run,
+            (pure & (prefix | ~prev_ew)) | (digit_run & pure & ~prefix))
+        prev_ew = jnp.where(transp, prev_ew, ends_w)
+        return (tok, (tok,) + rec,
+                (logits, cache, cache_mask, done, digit_run, prev_ew),
+                jnp.all(done))
 
-            # Defensive (ADVICE r4): the slot write happens only when the
-            # step actually runs, so an early-stopped tail's final cache
-            # never marks unwritten KV slots as valid. No current caller
-            # reads that mask (both fused callers discard it) — this
-            # removes the latent hazard for future cache reuse, nothing
-            # more.
-            all_done = jnp.all(done)
-            step_mask = cache_mask.at[:, slot0 + t].set(1)
-
-            def run(args):
-                lg, c = args
-                return decoder.decode_step(
-                    params, cfg, c, emit, pos0 + t, slot0 + t, step_mask,
-                    trunk_len=decode_trunk)
-
-            new_logits, cache = lax.cond(
-                all_done, lambda args: args, run, (logits, cache))
-            cache_mask = jnp.where(all_done, cache_mask, step_mask)
-        else:
-            emit = nxt
-            cache_mask = cache_mask.at[:, slot0 + t].set(1)
-            new_logits, cache = decoder.decode_step(
-                params, cfg, cache, emit, pos0 + t, slot0 + t, cache_mask,
-                trunk_len=decode_trunk)
-        return ((new_logits, cache, cache_mask, done, digit_run, prev_ew),
-                (emit, p_yes, p_no, top2))
+    def advance(t, tok, state):
+        _, cache, cache_mask, done, digit_run, prev_ew = state
+        # The slot is marked only when the step runs, so an early-stopped
+        # tail's final mask never calls an unwritten KV slot valid
+        # (ADVICE r4; no caller reads that mask today).
+        cache_mask = cache_mask.at[:, slot0 + t].set(1)
+        logits, cache = decoder.decode_step(
+            params, cfg, cache, tok, pos0 + t, slot0 + t, cache_mask,
+            trunk_len=decode_trunk)
+        return logits, cache, cache_mask, done, digit_run, prev_ew
 
     zeros_b = jnp.zeros((B,), bool)
     with jax.named_scope("lir.decode"):
-        (_, cache_f, _, _, _, _), (gen, p_yes, p_no, top2) = lax.scan(
-            step, (logits0, cache, cache_mask0, zeros_b, zeros_b, zeros_b),
-            jnp.arange(max_new_tokens))
+        (gen, p_yes, p_no, top2), t_end, state = _stepped(
+            N, (logits0, cache, cache_mask0, zeros_b, zeros_b, zeros_b),
+            emit, advance)
+        cache_f = state[1]
 
     with jax.named_scope("lir.readout"):
+        if early_stop:
+            # The steps after the one that made every row done never ran:
+            # they read EOS and that step's probabilities, as their
+            # pending logits never moved.
+            tail = jnp.arange(N) > t_end
+            last = jnp.clip(t_end, 0, N - 1)
+            gen = jnp.where(tail[:, None], eos_id, gen)
+            p_yes = jnp.where(tail[:, None], p_yes[last], p_yes)
+            p_no = jnp.where(tail[:, None], p_no[last], p_no)
+            top2 = jnp.where(tail[:, None, None], top2[last], top2)
         return FusedDecodeOut(
             generated=jnp.swapaxes(gen, 0, 1),
             p_yes=jnp.swapaxes(p_yes, 0, 1),
@@ -789,11 +840,14 @@ def _spec_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
         return jnp.take_along_axis(
             ctx, jnp.clip(idx, 0, W - 1)[:, None], axis=1)[:, 0]
 
-    def _window(carry, c):
+    def go(carry):
         all_done = jnp.all(carry["done"])
         tstar = jnp.max(carry["done_step"])
         needed = jnp.where(all_done, jnp.minimum(T, tstar + 1), T)
-        go = jnp.min(carry["filled"]) < needed
+        return jnp.min(carry["filled"]) < needed
+
+    def _window(state):
+        c, carry = state
 
         def run(carry):
             logits = carry["logits"]
@@ -956,10 +1010,15 @@ def _spec_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
                 out["dcache"] = new_dcache
             return out
 
-        return lax.cond(go, run, lambda car: car, carry), None
+        return c + 1, run(carry)
 
+    # Windows run while some row still owes tokens, at most T of them: a
+    # while loop, so that nothing but loops carries the caches (see
+    # :func:`_stepped` on what a lax.cond around the forward costs).
     with jax.named_scope("lir.decode"):
-        carry, _ = lax.scan(_window, carry0, jnp.arange(T))
+        _, carry = lax.while_loop(
+            lambda state: (state[0] < T) & go(state[1]), _window,
+            (jnp.zeros((), i32), carry0))
 
     with jax.named_scope("lir.readout"):
         gen_b, py_b = carry["gen"], carry["p_yes"]
@@ -1458,10 +1517,9 @@ def sample_decode(params, cfg: ModelConfig, tokens: jax.Array,
     ``eos_id`` arms the HF-generate-parity stop: a row emits EOS fill
     after its first EOS (no post-EOS samples leak into text, matching the
     API/HF semantics the reference relies on), and once EVERY row is done
-    the remaining scan steps skip the model forward via a scalar
-    lax.cond — a generous session budget then costs actual response
-    length. Non-done rows' draws are bit-identical to the unstopped
-    sampler (the per-step keys never depend on doneness).
+    the loop ends (:func:`_stepped`) — a generous session budget then
+    costs actual response length. Non-done rows' draws are bit-identical
+    to the unstopped sampler (the per-step keys never depend on doneness).
 
     Returns generated (B, max_new_tokens) int32. Per-step logits are not
     captured — rephrasings need text only, and dropping the (B, T, V) stack
@@ -1474,36 +1532,6 @@ def sample_decode(params, cfg: ModelConfig, tokens: jax.Array,
     logits0, cache, pos0 = pf(params, cfg, tokens, attn_mask, T)
     cache_mask0 = jnp.pad(attn_mask, ((0, 0), (0, T - S)))
 
-    def step(carry, xs):
-        logits, cache, cache_mask, done = carry
-        t, step_key = xs
-        scaled = logits / jnp.maximum(temperature, 1e-6)
-        if per_row:
-            nxt = jax.vmap(jax.random.categorical)(step_key, scaled)
-        else:
-            nxt = jax.random.categorical(step_key, scaled, axis=-1)
-        nxt = nxt.astype(jnp.int32)
-        if early:
-            emit = jnp.where(done, eos_id, nxt)
-            done = done | (emit == eos_id)
-            all_done = jnp.all(done)
-            step_mask = cache_mask.at[:, S + t].set(1)
-
-            def run(args):
-                lg, c = args
-                return decoder.decode_step(
-                    params, cfg, c, emit, pos0 + t, S + t, step_mask)
-
-            new_logits, cache = lax.cond(
-                all_done, lambda args: args, run, (logits, cache))
-            cache_mask = jnp.where(all_done, cache_mask, step_mask)
-        else:
-            emit = nxt
-            cache_mask = cache_mask.at[:, S + t].set(1)
-            new_logits, cache = decoder.decode_step(
-                params, cfg, cache, emit, pos0 + t, S + t, cache_mask)
-        return (new_logits, cache, cache_mask, done), emit
-
     if per_row:
         # (T, B, 2): row b's stream at step t = fold_in(keys[b], t).
         keys = jax.vmap(
@@ -1511,9 +1539,38 @@ def sample_decode(params, cfg: ModelConfig, tokens: jax.Array,
         )(jnp.arange(max_new_tokens))
     else:
         keys = jax.random.split(key, max_new_tokens)
-    (_, _, _, _), gen = lax.scan(
-        step, (logits0, cache, cache_mask0, jnp.zeros((B,), bool)),
-        (jnp.arange(max_new_tokens), keys))
+
+    def emit(t, state):
+        logits, cache, cache_mask, done = state
+        # The emit after the last step reads past the keys; the index
+        # clamps and its draw is dropped.
+        step_key = lax.dynamic_index_in_dim(keys, t, keepdims=False)
+        scaled = logits / jnp.maximum(temperature, 1e-6)
+        if per_row:
+            nxt = jax.vmap(jax.random.categorical)(step_key, scaled)
+        else:
+            nxt = jax.random.categorical(step_key, scaled, axis=-1)
+        nxt = nxt.astype(jnp.int32)
+        if not early:
+            return nxt, nxt, state, jnp.zeros((), bool)
+        tok = jnp.where(done, eos_id, nxt)
+        done = done | (tok == eos_id)
+        return tok, tok, (logits, cache, cache_mask, done), jnp.all(done)
+
+    def advance(t, tok, state):
+        _, cache, cache_mask, done = state
+        cache_mask = cache_mask.at[:, S + t].set(1)
+        logits, cache = decoder.decode_step(
+            params, cfg, cache, tok, pos0 + t, S + t, cache_mask)
+        return logits, cache, cache_mask, done
+
+    gen, t_end, _ = _stepped(
+        max_new_tokens, (logits0, cache, cache_mask0, jnp.zeros((B,), bool)),
+        emit, advance)
+    if early:
+        # Steps that never ran: every row was done, so they read EOS.
+        gen = jnp.where((jnp.arange(max_new_tokens) > t_end)[:, None],
+                        eos_id, gen)
     return jnp.swapaxes(gen, 0, 1)
 
 

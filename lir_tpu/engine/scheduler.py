@@ -22,8 +22,9 @@ process):
    whenever the cost model says the promoted rows are cheaper than a
    padded tail dispatch — the sweep then pays for at most one ragged tail
    instead of one per bucket. (In-scan retirement is already handled by
-   the early stop's all-done ``lax.cond`` skip; the retire positions feed
-   the decode-occupancy counter, profiling.OccupancyStats.)
+   the early stop: the decode loop ends once every row is done,
+   generate._stepped; the retire positions feed the decode-occupancy
+   counter, profiling.OccupancyStats.)
 3. **Cross-cell prefix reuse**: cells whose tokenized prompts agree on a
    long prefix (the sweep formats x rephrasings of one base prompt, when
    rephrasings preserve the opening tokens) are grouped; each group's

@@ -304,7 +304,7 @@ def _fused_decode_ok(cfg: ModelConfig, batch: int, S: int,
 
 def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
                             cfg: ModelConfig, fused_ctx,
-                            trunk_len: int = 0) -> jax.Array:
+                            trunk_len: int = 0, layer=None) -> jax.Array:
     """Decode-step attention through the fused Pallas flash-decode kernel
     (ops/flash_decode): the (B, H, 1, T) score row, fp32 softmax, and
     probability row stay in VMEM instead of round-tripping HBM between
@@ -312,7 +312,10 @@ def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     grouped contraction against the un-repeated cache, same masking
     semantics as :func:`_attention_cached` (pinned by tests/
     test_kernels.py); ALiBi rides per-head slopes + mask-aware key
-    positions exactly like the prefill flash kernel.
+    positions exactly like the prefill flash kernel. ``k`` / ``v`` are
+    the STACKED cache sides (L, K, T, B, hd) and ``layer`` the layer to
+    attend over: the kernel picks that layer's blocks out of the stacked
+    operand, so the layer loop never slices it out.
 
     ``trunk_len`` > 0 (a shared-trunk dispatch with cascade decode on)
     routes through the trunk-aware variant: the cache's leading
@@ -333,11 +336,11 @@ def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
         out = flash_decode_trunk(q[:, 0], k, v, q_pos, key_mask,
                                  key_positions=key_positions,
                                  alibi_slopes=slopes, trunk_len=trunk_len,
-                                 interpret=interpret)
+                                 interpret=interpret, layer=layer)
     else:
         out = flash_decode(q[:, 0], k, v, q_pos, key_mask,
                            key_positions=key_positions, alibi_slopes=slopes,
-                           interpret=interpret)
+                           interpret=interpret, layer=layer)
     return out.reshape(B, S, H * hd)
 
 
@@ -357,7 +360,7 @@ def _fused_decode_mq_ok(cfg: ModelConfig, batch: int, S: int,
 
 def _attention_cached_flash_mq(q: jax.Array, k: jax.Array, v: jax.Array,
                                cfg: ModelConfig, fused_ctx,
-                               trunk_len: int = 0) -> jax.Array:
+                               trunk_len: int = 0, layer=None) -> jax.Array:
     """Verify-window attention through the multi-query fused kernel
     (ops/flash_decode.flash_decode_mq): S teacher-forced queries per row
     attend over the cache (the window's own k/v already written) in one
@@ -365,7 +368,8 @@ def _attention_cached_flash_mq(q: jax.Array, k: jax.Array, v: jax.Array,
     the speculative verify path's decode-step parity contract.
     ``trunk_len`` > 0 routes the trunk-aware sibling so PR-13
     speculative verify windows ride the trunk-split dedup too (see
-    :func:`_attention_cached_flash`)."""
+    :func:`_attention_cached_flash`, also for the stacked ``k`` / ``v``
+    and ``layer``)."""
     from ..ops.flash_decode import flash_decode_mq, flash_decode_mq_trunk
 
     B, S, H, hd = q.shape
@@ -379,11 +383,12 @@ def _attention_cached_flash_mq(q: jax.Array, k: jax.Array, v: jax.Array,
                                     key_positions=key_positions,
                                     alibi_slopes=slopes,
                                     trunk_len=trunk_len,
-                                    interpret=interpret)
+                                    interpret=interpret, layer=layer)
     else:
         out = flash_decode_mq(q, k, v, q_pos, key_mask,
                               key_positions=key_positions,
-                              alibi_slopes=slopes, interpret=interpret)
+                              alibi_slopes=slopes, interpret=interpret,
+                              layer=layer)
     return out.reshape(B, S, H * hd)
 
 
@@ -423,7 +428,9 @@ def _attention_cached(q: jax.Array, k: jax.Array, v: jax.Array,
     layout XLA's decode while-loop prefers for these dots, so the loop
     carry aliases the prefill output instead of inserting two full-cache
     layout copies (measured 2x 2.08 GiB at 7B batch 32 — the difference
-    between fitting a chip and OOM; see SCALE.md). q: (B, S=1, H, hd).
+    between fitting a chip and OOM; see SCALE.md). q: (B, S=1, H, hd);
+    k, v: one layer of the stacked cache, read by the caller with
+    ``lax.dynamic_index_in_dim`` (a read XLA may fuse into these dots).
     GQA/MQA contracts grouped query heads against the un-repeated cache
     (see _attention_cached_int8).
     """
@@ -461,7 +468,7 @@ def _ssm_columns(cfg: ModelConfig) -> Optional[jax.Array]:
 
 
 def _mixer(h: jax.Array, lp: Params, cfg: ModelConfig, state: jax.Array,
-           tail: jax.Array, mask: Optional[jax.Array]):
+           tail: jax.Array, mask: Optional[jax.Array], layer=None):
     """Mamba-2 mixer over h (B, S, D), the block's normed input.
 
     ``state`` (B, Hs, P, N) float32 and ``tail`` (B, conv - 1, C), the
@@ -471,7 +478,15 @@ def _mixer(h: jax.Array, lp: Params, cfg: ModelConfig, state: jax.Array,
     input is zeroed (left padding then reads like the empty history) and
     its step size is zeroed (decay 1, nothing added), so the state a
     right-padded row hands on is the state at its own end, and the tail
-    is gathered there. Returns (out (B, S, D), state, tail)."""
+    is gathered there. Returns (out (B, S, D), state, tail).
+
+    With ``layer`` (the layer loop over a cache), ``state`` and ``tail``
+    are the cache's STACKED leaves (L, ...) and come back whole, layer
+    ``layer`` of each updated where it lies: the scan kernels read and
+    write that layer's blocks of the stacked state in place
+    (ops/ssd_scan), the tail (0.3 MB a layer) is read and written with a
+    dynamic slice. Only the token-by-token fallback (CPU, a sharded
+    engine) takes the layer's state out and puts it back."""
     from ..ops import ssd_scan as scan_ops
 
     B, S, _ = h.shape
@@ -491,20 +506,25 @@ def _mixer(h: jax.Array, lp: Params, cfg: ModelConfig, state: jax.Array,
         xbc = xbc * mask[:, :, None].astype(xbc.dtype)
 
     # Depthwise causal conv over [tail | window], then SiLU.
-    seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    stacked = layer is not None
+    own = (lax.dynamic_index_in_dim(tail, layer, keepdims=False) if stacked
+           else tail)
+    seq = jnp.concatenate([own.astype(xbc.dtype), xbc], axis=1)
     conv = lp["conv_b"].astype(f32)
     for k in range(taps):
         conv = conv + seq[:, k:k + S].astype(f32) * lp["conv_w"][k].astype(f32)
     xbc = jax.nn.silu(conv).astype(h.dtype)
     if mask is None:
-        tail = seq[:, S:]
+        own = seq[:, S:]
     else:
         # The taps - 1 inputs ending at each row's last real slot (slot
         # -1, the carried tail itself, for a row with none).
         slots = jnp.arange(S, dtype=jnp.int32)
         last = jnp.max(jnp.where(mask > 0, slots, -1), axis=1)       # (B,)
         idx = last[:, None] + 1 + jnp.arange(taps - 1, dtype=jnp.int32)
-        tail = jnp.take_along_axis(seq, idx[:, :, None], axis=1)
+        own = jnp.take_along_axis(seq, idx[:, :, None], axis=1)
+    tail = (lax.dynamic_update_index_in_dim(tail, own.astype(tail.dtype),
+                                            layer, 0) if stacked else own)
 
     x = xbc[..., :inner].reshape(B, S, Hs, P)
     bm = xbc[..., inner:inner + gn].reshape(B, S, G, N)
@@ -518,12 +538,18 @@ def _mixer(h: jax.Array, lp: Params, cfg: ModelConfig, state: jax.Array,
     interpret = jax.default_backend() != "tpu"
     if S == 1 and kernels:
         y, state = scan_ops.ssm_step(x[:, 0], dt[:, 0], a, bm[:, 0],
-                                     cm[:, 0], state, interpret=interpret)
+                                     cm[:, 0], state, interpret=interpret,
+                                     layer=layer)
         y = y[:, None]
     elif kernels:
         y, state = scan_ops.ssd_scan(x, dt, a, bm, cm, state,
                                      chunk=cfg.ssm_chunk,
-                                     interpret=interpret)
+                                     interpret=interpret, layer=layer)
+    elif stacked:
+        y, new = scan_ops.ssd_scan_tokens(
+            x, dt, a, bm, cm,
+            lax.dynamic_index_in_dim(state, layer, keepdims=False))
+        state = lax.dynamic_update_index_in_dim(state, new, layer, 0)
     else:
         y, state = scan_ops.ssd_scan_tokens(x, dt, a, bm, cm, state)
     y = y.astype(f32) + lp["ssm_d"].astype(f32)[:, None] * x.astype(f32)
@@ -539,17 +565,31 @@ def _mixer(h: jax.Array, lp: Params, cfg: ModelConfig, state: jax.Array,
 
 
 def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
-           bias: jax.Array, cache_kv: Optional[Tuple[jax.Array, jax.Array]],
-           cache_index: Optional[jax.Array],
+           bias: jax.Array, cache=None, layer=None,
+           cache_index: Optional[jax.Array] = None,
            key_mask: Optional[jax.Array] = None,
            attn_impl=None, fused_ctx=None, trunk_len: int = 0,
            rec=None, rec_mask: Optional[jax.Array] = None):
-    """One transformer block. Returns (new_x, (k_full, v_full)).
+    """One transformer block, in one of two forms.
 
-    A model with a state-space mixer (``cfg.has_mixer``) also takes
-    ``rec``, this layer's (SSM state, conv tail) on entry (None: the
-    empty history), and ``rec_mask`` (B, S), the window's real slots
-    (None: all); it returns (new_x, (k_full, v_full, state, tail)).
+    Over a cache (``extend``, ``verify_extend``, ``decode_step``):
+    ``cache`` is the whole STACKED cache as :func:`init_cache` lays it
+    out and ``layer`` this block's (traced) index into it. The block
+    writes the window's k/v into its layer's slots ``[cache_index,
+    cache_index + S)`` with one ``dynamic_update_slice`` on the stacked
+    buffer, attends over that layer (the fused kernels index it inside
+    the stacked operand; the dense routes read it with a dynamic index),
+    moves a mixer's state of that layer in place (:func:`_mixer`), and
+    returns (new_x, cache): the same buffers, nothing of them copied or
+    re-stacked.
+
+    Without one (``forward``, ``prefill``, ``cascade_extend``): returns
+    (new_x, (k, v)), the window's own post-rope k/v (B, S, K, hd) for the
+    caller to lay into a cache; a model with a state-space mixer
+    (``cfg.has_mixer``) starts from ``rec``, this layer's (SSM state,
+    conv tail) on entry (None: the empty history), and returns (new_x,
+    (k, v, state, tail)). ``rec_mask`` (B, S) marks the window's real
+    slots for the mixer in both forms (None: all).
 
     ``attn_impl(q, k, v, key_mask) -> (B, S, H*hd)`` replaces dense
     attention when given (the sequence-parallel path, parallel/seq_forward);
@@ -588,35 +628,45 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
         q = _apply_rope(q, sin, cos, rd)
         k = _apply_rope(k, sin, cos, rd)
 
-    if cache_kv is not None:
-        # Decode: insert this step's k/v at cache_index, attend over the
-        # full cache. Cache layout is (K, T, B, hd) — see _attention_cached.
-        ck, cv = cache_kv
-        k_t = k.transpose(2, 1, 0, 3)  # (B, 1, K, hd) -> (K, 1, B, hd)
-        v_t = v.transpose(2, 1, 0, 3)
+    if cache is not None:
+        # Insert the window's k/v at this layer's slots of the stacked
+        # cache, attend over the layer. A side is (L, K, T, B, hd) — see
+        # _attention_cached for the layer's own layout.
+        ck, cv = cache[:2]
+        # (B, S, K, hd) -> (1, K, S, B, hd)
+        k_t = k.transpose(2, 1, 0, 3)[None]
+        v_t = v.transpose(2, 1, 0, 3)[None]
+        at = (layer, 0, cache_index, 0, 0)
+
+        def of_layer(a):
+            return lax.dynamic_index_in_dim(a, layer, keepdims=False)
+
         if cfg.kv_cache_int8:
             (ckq, cks), (cvq, cvs) = ck, cv
             k_q, k_s = _quant_kv(k_t)
             v_q, v_s = _quant_kv(v_t)
-            ckq = lax.dynamic_update_slice(ckq, k_q, (0, cache_index, 0, 0))
-            cks = lax.dynamic_update_slice(cks, k_s, (0, cache_index, 0))
-            cvq = lax.dynamic_update_slice(cvq, v_q, (0, cache_index, 0, 0))
-            cvs = lax.dynamic_update_slice(cvs, v_s, (0, cache_index, 0))
+            ckq = lax.dynamic_update_slice(ckq, k_q, at)
+            cks = lax.dynamic_update_slice(cks, k_s, at[:-1])
+            cvq = lax.dynamic_update_slice(cvq, v_q, at)
+            cvs = lax.dynamic_update_slice(cvs, v_s, at[:-1])
             ck, cv = (ckq, cks), (cvq, cvs)
-            attn = _attention_cached_int8(q, ckq, cks, cvq, cvs, bias, cfg)
+            attn = _attention_cached_int8(q, of_layer(ckq), of_layer(cks),
+                                          of_layer(cvq), of_layer(cvs),
+                                          bias, cfg)
         else:
-            ck = lax.dynamic_update_slice(ck, k_t.astype(ck.dtype),
-                                          (0, cache_index, 0, 0))
-            cv = lax.dynamic_update_slice(cv, v_t.astype(cv.dtype),
-                                          (0, cache_index, 0, 0))
+            ck = lax.dynamic_update_slice(ck, k_t.astype(ck.dtype), at)
+            cv = lax.dynamic_update_slice(cv, v_t.astype(cv.dtype), at)
             if _fused_decode_ok(cfg, q.shape[0], S, fused_ctx):
                 attn = _attention_cached_flash(q, ck, cv, cfg, fused_ctx,
-                                               trunk_len=trunk_len)
+                                               trunk_len=trunk_len,
+                                               layer=layer)
             elif _fused_decode_mq_ok(cfg, q.shape[0], S, fused_ctx):
                 attn = _attention_cached_flash_mq(q, ck, cv, cfg, fused_ctx,
-                                                  trunk_len=trunk_len)
+                                                  trunk_len=trunk_len,
+                                                  layer=layer)
             else:
-                attn = _attention_cached(q, ck, cv, bias, cfg)
+                attn = _attention_cached(q, of_layer(ck), of_layer(cv),
+                                         bias, cfg)
     elif attn_impl is not None:
         # Prefill/forward: hand back this layer's (post-rope) k/v so prefill
         # can fill the cache without re-projecting them.
@@ -633,10 +683,12 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
     if cfg.has_mixer:
         # The mixer reads the same normed input as attention; the two
         # branches are summed into one residual update.
-        if rec is None:
+        if cache is not None:
+            rec = cache[2:]
+        elif rec is None:
             rec = _empty_rec(cfg, B, x.dtype)
         mix, state, tail = _mixer(h_attn_in, lp, cfg, rec[0], rec[1],
-                                  rec_mask)
+                                  rec_mask, layer=layer)
         if cfg.ssm_out_multiplier != 1.0:
             mix = mix * jnp.asarray(cfg.ssm_out_multiplier, mix.dtype)
         attn = attn + mix
@@ -773,27 +825,39 @@ def mask_positions(attn_mask: jax.Array) -> jax.Array:
 def _scan_blocks(params: Params, cfg: ModelConfig, x, sin, cos, bias,
                  cache=None, cache_index=None, key_mask=None, attn_impl=None,
                  fused_ctx=None, trunk_len: int = 0, rec_mask=None):
-    """lax.scan over the stacked layer params. ``cache`` is the (ck, cv)
-    pair, with (SSM state, conv tail) after it for a model with a mixer;
-    ``rec_mask`` marks the window's real slots for that state."""
-    def body(carry, xs):
-        h = carry
-        if cache is None:
-            lp = xs
-            h, _ = _block(h, lp, cfg, sin, cos, bias, None, None,
-                          key_mask=key_mask, attn_impl=attn_impl,
-                          rec_mask=key_mask)
-            return h, None
-        lp, layer = xs
-        h, new = _block(h, lp, cfg, sin, cos, bias, tuple(layer[:2]),
-                        cache_index, fused_ctx=fused_ctx,
-                        trunk_len=trunk_len, rec=tuple(layer[2:]) or None,
-                        rec_mask=rec_mask)
-        return h, new
+    """lax.scan over the stacked layer params. ``cache`` is the stacked
+    (ck, cv) pair, with (SSM state, conv tail) after it for a model with
+    a mixer; ``rec_mask`` marks the window's real slots for that state.
 
-    xs = params["layers"] if cache is None else (params["layers"], cache)
-    x, new_cache = lax.scan(body, x, xs)
-    return x, new_cache
+    The cache rides the loop's CARRY beside the activations, and the loop
+    runs over (layer params, layer index): each block updates its layer
+    of the stacked buffers where they lie (:func:`_block`) and the loop
+    hands back the buffers it was given. As the scan's ``xs`` / ``ys``
+    instead, every layer would be sliced out and stacked into a second
+    buffer, and a caller whose own loop carries the cache (the decode
+    loop, engine/generate._stepped) would copy the whole of it once more
+    per step. Returns (x, cache), the cache None where none came."""
+    if cache is None:
+        def plain(h, lp):
+            h, _ = _block(h, lp, cfg, sin, cos, bias, key_mask=key_mask,
+                          attn_impl=attn_impl, rec_mask=key_mask)
+            return h, None
+
+        x, _ = lax.scan(plain, x, params["layers"])
+        return x, None
+
+    def body(carry, xs):
+        h, stacked = carry
+        lp, layer = xs
+        return _block(h, lp, cfg, sin, cos, bias, cache=stacked, layer=layer,
+                      cache_index=cache_index, fused_ctx=fused_ctx,
+                      trunk_len=trunk_len, rec_mask=rec_mask), None
+
+    n_layers = jax.tree.leaves(cache)[0].shape[0]
+    (x, cache), _ = lax.scan(
+        body, (x, tuple(cache)),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, cache
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -822,9 +886,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
     """Per-layer KV cache stacked on the layer axis: (L, K, T, B, hd) pair.
 
     Head-major/batch-minor on purpose: this is the physical order XLA's
-    decode while-loop assigns to the cache anyway; storing it logically
-    row-major in that order lets the loop carry alias the prefill output
-    instead of copying the whole cache (see _attention_cached).
+    decode while-loop assigns to the cache anyway, and the (B, hd) minor
+    pair is the block the decode kernel reads (ops/flash_decode). The
+    layer axis leads because the layer loop carries these buffers whole
+    and updates them where they lie (:func:`_scan_blocks`): a step writes
+    one token slot per layer at ``(layer, 0, slot, 0, 0)`` and the kernels
+    read ``(layer, head, split, rows)`` blocks of the same operand, so
+    prefill's output, every extension and every decode step share ONE
+    buffer per side.
 
     With ``cfg.kv_cache_int8`` each side becomes a (payload int8
     (L, K, T, B, hd), scale f32 (L, K, T, B)) pair — half the HBM.
@@ -892,7 +961,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     pad_spec = ((0, 0), (0, pad), (0, 0), (0, 0))
 
     def body(h, lp):
-        h_out, new = _block(h, lp, cfg, sin, cos, bias, None, None,
+        h_out, new = _block(h, lp, cfg, sin, cos, bias,
                             key_mask=attn_mask, attn_impl=attn_impl,
                             rec_mask=attn_mask)
         k = new[0].transpose(2, 1, 0, 3)  # (B, S, K, hd) -> (K, S, B, hd)
@@ -998,7 +1067,7 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
         # row's state on entry (the remainders continue from it).
         rec = tuple(jnp.broadcast_to(a, (B,) + a.shape[1:])
                     for a in layer[2:]) or None
-        h, new = _block(h, lp, cfg, sin, cos, None, None, None,
+        h, new = _block(h, lp, cfg, sin, cos, None,
                         key_mask=rem_mask, attn_impl=impl, rec=rec,
                         rec_mask=rem_mask)
         return h, new

@@ -98,8 +98,12 @@ class ModelConfig:
     cascade_fused_suffix: bool = True
 
     # KV-cache storage: int8 with per-(head, position, row) scales halves
-    # cache HBM (the single-chip long-context limiter — a 7B's bf16 cache
-    # plus XLA's while-loop copy OOMs v5e at seq 1024, SCALE.md) and
+    # cache HBM (the single-chip long-context limiter: when SCALE.md was
+    # measured a 7B's bf16 cache plus the second copy XLA kept of it
+    # through the decode loop OOMed a v5e at seq 1024; since PR 27 the
+    # decode and layer loops carry ONE buffer per side and update it
+    # where it lies, tests/test_tpu_compile.py, so what is left of that
+    # limit is the cache itself, not re-measured) and
     # halves decode-phase cache reads. Decode attention then runs s8 x s8
     # dots with dynamic query/probability quantization, mirroring the
     # dynamic int8 weight mode. Prefill attention is unaffected (it reads

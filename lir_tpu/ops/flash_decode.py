@@ -22,6 +22,14 @@ additive bias: a key is valid iff its mask bit is set AND its mask-aware
 position does not exceed the query's; ALiBi families add
 ``slope_h * key_position`` exactly as ``decoder._causal_bias`` does.
 
+The decode step's layer loop carries the cache STACKED, (L, K, T, B, hd)
+a side, and updates it where it lies (models/decoder._scan_blocks); the
+kernel is handed that stacked operand and a ``layer`` index, prefetched
+as a scalar, and its K/V index map starts with the layer: it reads
+``(layer, head, split, rows)`` blocks in place, so no layer is ever
+sliced out of the cache for a call. One layer's (K, T, B, hd) sides are
+the L = 1 case of the same call.
+
 Block sizes align to the flash_attention edges (DEFAULT_BLOCK_K): the
 split width is the largest divisor of T no wider than the requested
 block (preferring sublane-aligned multiples of 8), falling back to a
@@ -47,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import DEFAULT_BLOCK_K
 from .lse import merge_partials
@@ -136,10 +145,14 @@ def decode_extent(need: int, batch: int, n_groups: int,
     return first
 
 
-def _decode_kernel(rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref,
-                   q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
+def _decode_kernel(layer_ref, rowb_ref, laneb_ref, qpos_ref, kpos_ref,
+                   slope_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
                    sm_scale: float, alibi: bool):
     """One (kv head, key split, batch block, window query) program.
+
+    ``layer_ref`` is the prefetched layer index: the index maps spend it
+    (they pick the layer's K/V blocks out of the stacked cache), the body
+    does not read it.
 
     The cache block is (split, bb, hd): ``bb`` batch rows ride the
     sublanes under every key slot, which is the cache's own (B, hd)
@@ -155,10 +168,11 @@ def _decode_kernel(rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref,
     sublanes."""
     # MXU operands stay in the cache dtype (bf16 on the chip: one MXU
     # pass, as the dense path's einsums run); accumulation is fp32.
-    kb = k_ref[0]                                         # (bs, bb, hd)
+    del layer_ref
+    kb = k_ref[0, 0]                                      # (bs, bb, hd)
     bs, bb, hd = kb.shape
     k = kb.reshape(bs * bb, hd)
-    v = v_ref[0].reshape(bs * bb, hd)
+    v = v_ref[0, 0].reshape(bs * bb, hd)
     q = q_ref[0, 0, 0].astype(k.dtype)                    # (R, hd)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
     kp = kpos_ref[0, 0]                                   # (1, bs*bb)
@@ -180,13 +194,19 @@ def _decode_kernel(rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref,
 
 def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
                  alibi_slopes, trunk_len: int, block_k: int,
-                 interpret: bool):
+                 interpret: bool, layer=None):
     """The one pallas_call behind all four entry points, under the entry
     point's ``name``: the name a profiler trace shows for the kernel
     (``flash_decode_trunk``, ``flash_decode_mq`` ...), pinned here so
     that renaming a jitted wrapper cannot silence the benchmark's
     ``decode_kernel_roofline``. ``q``:
-    (B, S, H, hd), ``q_positions``: (B, S). Grid (K, T/split, B/bb, S):
+    (B, S, H, hd), ``q_positions``: (B, S). ``k`` / ``v``: the STACKED
+    cache sides (L, K, T, B, hd) with ``layer`` the (traced) index of the
+    layer to attend over, prefetched as a scalar so that the K/V index
+    map names that layer's blocks where they lie: no (K, T, B, hd) slice
+    is ever made for the call. One layer's (K, T, B, hd) sides with
+    ``layer`` None are the L = 1 case (the leading axis is a free
+    reshape). Grid (K, T/split, B/bb, S):
     the window-query axis is innermost and the batch-block axis next, so
     consecutive programs that name the same K/V block skip its DMA —
     every query of a verify window reuses the block its row already
@@ -195,7 +215,10 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
     trunk's K/V leaves HBM once per (kv head, split), not once per
     row."""
     B, S, H, hd = q.shape
-    K, T = k.shape[0], k.shape[1]
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    K, T = k.shape[1], k.shape[2]
     G = H // K
     bb = batch_block(B)
     nB = B // bb
@@ -230,38 +253,45 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
     else:
         slopes = jnp.zeros((K, R, 1), jnp.float32)
 
+    # Every index map takes the prefetched layer index last; only K/V's
+    # spends it.
     if nt:
-        def kv_index(h, j, i, s):
-            return (h, j, jnp.where(j < nt, 0, i), 0)
+        def kv_index(h, j, i, s, l):
+            return (l[0], h, j, jnp.where(j < nt, 0, i), 0)
     else:
-        def kv_index(h, j, i, s):
-            return (h, j, i, 0)
+        def kv_index(h, j, i, s, l):
+            return (l[0], h, j, i, 0)
 
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                alibi=alibi)
     f32 = jnp.float32
     o_p, m_p, l_p = pl.pallas_call(
         kernel,
-        grid=(K, n_splits, nB, S),
-        in_specs=[
-            pl.BlockSpec((R, 1), lambda h, j, i, s: (0, 0)),
-            pl.BlockSpec((1, L), lambda h, j, i, s: (0, 0)),
-            pl.BlockSpec((1, 1, R, 1), lambda h, j, i, s: (i, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, L), lambda h, j, i, s: (i, j, 0, 0)),
-            pl.BlockSpec((1, R, 1), lambda h, j, i, s: (h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, R, hd),
-                         lambda h, j, i, s: (i, h, s, 0, 0)),
-            pl.BlockSpec((1, split, bb, hd), kv_index),
-            pl.BlockSpec((1, split, bb, hd), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, 1, R, hd),
-                         lambda h, j, i, s: (i, h, s, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, R, 1),
-                         lambda h, j, i, s: (i, h, s, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, R, 1),
-                         lambda h, j, i, s: (i, h, s, j, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(K, n_splits, nB, S),
+            in_specs=[
+                pl.BlockSpec((R, 1), lambda h, j, i, s, l: (0, 0)),
+                pl.BlockSpec((1, L), lambda h, j, i, s, l: (0, 0)),
+                pl.BlockSpec((1, 1, R, 1),
+                             lambda h, j, i, s, l: (i, s, 0, 0)),
+                pl.BlockSpec((1, 1, 1, L),
+                             lambda h, j, i, s, l: (i, j, 0, 0)),
+                pl.BlockSpec((1, R, 1), lambda h, j, i, s, l: (h, 0, 0)),
+                pl.BlockSpec((1, 1, 1, R, hd),
+                             lambda h, j, i, s, l: (i, h, s, 0, 0)),
+                pl.BlockSpec((1, 1, split, bb, hd), kv_index),
+                pl.BlockSpec((1, 1, split, bb, hd), kv_index),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, 1, 1, R, hd),
+                             lambda h, j, i, s, l: (i, h, s, j, 0, 0)),
+                pl.BlockSpec((1, 1, 1, 1, R, 1),
+                             lambda h, j, i, s, l: (i, h, s, j, 0, 0)),
+                pl.BlockSpec((1, 1, 1, 1, R, 1),
+                             lambda h, j, i, s, l: (i, h, s, j, 0, 0)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((nB, K, S, n_splits, R, hd), f32),
             jax.ShapeDtypeStruct((nB, K, S, n_splits, R, 1), f32),
@@ -269,7 +299,7 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
         ],
         interpret=interpret,
         name=name,
-    )(rowb, laneb, qpos, kpos, slopes, qg, k, v)
+    )(layer, rowb, laneb, qpos, kpos, slopes, qg, k, v)
 
     # Log-sum-exp combine across splits (ops/lse.merge_partials, shared
     # with the cascade-prefill merge): renormalize each partial by the
@@ -289,11 +319,16 @@ def flash_decode(
     alibi_slopes: jnp.ndarray | None = None,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """One decode step of attention, fused. Returns (B, H, hd) in q's dtype.
 
     ``q``: (B, H, hd) single query per row (post-RoPE). ``k``/``v``:
-    (K, T, B, hd) cache layout, K the kv-head count (un-repeated GQA/MQA).
+    (K, T, B, hd) cache layout, K the kv-head count (un-repeated GQA/MQA);
+    or, with ``layer`` (a scalar, traced or not), the stacked cache sides
+    (L, K, T, B, hd) of which the kernel reads that layer alone, in place
+    (the decode step's layer loop, models/decoder._block). All four entry
+    points take the pair the same way.
     ``q_positions``: (B,) mask-aware query positions. ``key_mask``: (B, T)
     {0,1} validity over cache slots (any pattern). ``key_positions``:
     (B, T) mask-aware slot positions (decoder.mask_positions of the cache
@@ -306,7 +341,7 @@ def flash_decode(
     """
     return _decode_call("flash_decode", q[:, None], k, v,
                         q_positions[:, None], key_mask, key_positions,
-                        alibi_slopes, 0, block_k, interpret)[:, 0]
+                        alibi_slopes, 0, block_k, interpret, layer)[:, 0]
 
 
 @functools.partial(jax.jit,
@@ -320,6 +355,7 @@ def flash_decode_trunk(
     trunk_len: int = 0,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Trunk-aware decode step for shared-prefix (cascade) dispatches.
 
@@ -336,7 +372,8 @@ def flash_decode_trunk(
     """
     return _decode_call("flash_decode_trunk", q[:, None], k, v,
                         q_positions[:, None], key_mask, key_positions,
-                        alibi_slopes, trunk_len, block_k, interpret)[:, 0]
+                        alibi_slopes, trunk_len, block_k, interpret,
+                        layer)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -348,6 +385,7 @@ def flash_decode_mq(
     alibi_slopes: jnp.ndarray | None = None,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Multi-query fused decode attention: S queries per row over the KV
     cache in ONE kernel launch — the speculative-decode verify path
@@ -363,7 +401,8 @@ def flash_decode_mq(
     kernel's for the same cache state (pinned by tests/test_spec_decode).
     """
     return _decode_call("flash_decode_mq", q, k, v, q_positions, key_mask,
-                        key_positions, alibi_slopes, 0, block_k, interpret)
+                        key_positions, alibi_slopes, 0, block_k, interpret,
+                        layer)
 
 
 @functools.partial(jax.jit,
@@ -377,6 +416,7 @@ def flash_decode_mq_trunk(
     trunk_len: int = 0,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Trunk-aware multi-query decode: :func:`flash_decode_mq` with the
     :func:`flash_decode_trunk` index map, so speculative verify windows
@@ -384,4 +424,4 @@ def flash_decode_mq_trunk(
     split) per verify pass."""
     return _decode_call("flash_decode_mq_trunk", q, k, v, q_positions,
                         key_mask, key_positions, alibi_slopes, trunk_len,
-                        block_k, interpret)
+                        block_k, interpret, layer)

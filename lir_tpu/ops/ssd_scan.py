@@ -30,6 +30,15 @@ row's block of heads once and writes it once, in place
 (``input_output_aliases``), and the device operation has a name the
 trace can be read by.
 
+Both kernels take the state either as one layer's ``(B, H, P, N)`` or as
+the cache's stacked ``(L, B, H, P, N)`` leaf with a ``layer`` index (a
+traced scalar, prefetched): the state's block index then starts with that
+layer, input aliased to output, so the call reads and writes that layer's
+blocks where they lie and leaves the rest of the buffer untouched. That
+is how the layer loop of ``models/decoder._scan_blocks`` uses them: the
+stacked state rides the loop's carry and no layer of it is ever sliced
+out or written back whole. One layer's state alone is the ``L`` = 1 case.
+
 Both kernels take the state in float32 and keep it there. The products
 that touch it run at ``Precision.HIGHEST``; the two that do not (``C
 B^T`` and the masked ``(Q, Q)`` weights times ``x``) take their operands
@@ -110,12 +119,16 @@ def ssd_scan_tokens(x, dt, a, b, c, state):
 # Chunked scan kernel
 # ---------------------------------------------------------------------------
 
-def _scan_kernel(x_ref, b_ref, c_ref, cs_tl_ref, dt_tl_ref, cs_hl_ref,
-                 dt_hl_ref, s0_ref, y_ref, s_ref, *, hb: int, P: int):
+def _scan_kernel(layer_ref, x_ref, b_ref, c_ref, cs_tl_ref, dt_tl_ref,
+                 cs_hl_ref, dt_hl_ref, s0_ref, y_ref, s_ref, *, hb: int,
+                 P: int):
     """One (row, head block, chunk) program; the chunk axis is the
     innermost, sequential one and the output state block (its index does
-    not depend on the chunk) carries the state between chunks."""
+    not depend on the chunk) carries the state between chunks.
+    ``layer_ref`` (prefetched) is spent by the state's index maps."""
+    del layer_ref
     f32 = jnp.float32
+    s0_ref, s_ref = s0_ref.at[0], s_ref.at[0]     # the layer's blocks
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -154,10 +167,24 @@ def _scan_kernel(x_ref, b_ref, c_ref, cs_tl_ref, dt_tl_ref, cs_hl_ref,
             preferred_element_type=f32)
 
 
+def _stacked(state, layer):
+    """(stacked float32 state (L, B, H, P, N), layer index (1,) int32):
+    one layer's state with ``layer`` None is the L = 1 case."""
+    if layer is None:
+        state, layer = state[None], 0
+    return (state.astype(jnp.float32),
+            jnp.asarray(layer, jnp.int32).reshape(1))
+
+
 def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
-             interpret: bool = False):
+             interpret: bool = False, layer=None):
     """Chunked selective scan; arguments and results as
-    :func:`ssd_scan_tokens`."""
+    :func:`ssd_scan_tokens`. With ``layer``, ``state`` is the stacked
+    (L, B, H, P, N) leaf: layer ``layer`` of it is the initial state and
+    is overwritten, in place, by the final one; the whole leaf comes
+    back."""
+    one_layer = layer is None
+    state, layer = _stacked(state, layer)
     B, T, H, P = x.shape
     G, N = b.shape[2], b.shape[3]
     hb = head_block(H, G)
@@ -179,49 +206,58 @@ def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
 
     kernel = functools.partial(_scan_kernel, hb=hb, P=P)
     per_group = H // G
+    state_spec = pl.BlockSpec((1, 1, hb, P, N),
+                              lambda i, h, k, l: (l[0], i, h, 0, 0))
     y, state = pl.pallas_call(
         kernel,
-        grid=(B, nh, nc),
-        in_specs=[
-            pl.BlockSpec((1, Q, hb * P), lambda i, h, k: (i, k, h)),
-            pl.BlockSpec((1, Q, N),
-                         lambda i, h, k: (i, k, (h * hb) // per_group)),
-            pl.BlockSpec((1, Q, N),
-                         lambda i, h, k: (i, k, (h * hb) // per_group)),
-            pl.BlockSpec((1, 1, Q, hb), lambda i, h, k: (i, h, k, 0)),
-            pl.BlockSpec((1, 1, Q, hb), lambda i, h, k: (i, h, k, 0)),
-            pl.BlockSpec((1, hb, Q), lambda i, h, k: (i, h, k)),
-            pl.BlockSpec((1, hb, Q), lambda i, h, k: (i, h, k)),
-            pl.BlockSpec((1, hb, P, N), lambda i, h, k: (i, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Q, hb * P), lambda i, h, k: (i, k, h)),
-            pl.BlockSpec((1, hb, P, N), lambda i, h, k: (i, h, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nh, nc),
+            in_specs=[
+                pl.BlockSpec((1, Q, hb * P), lambda i, h, k, l: (i, k, h)),
+                pl.BlockSpec((1, Q, N), lambda i, h, k, l:
+                             (i, k, (h * hb) // per_group)),
+                pl.BlockSpec((1, Q, N), lambda i, h, k, l:
+                             (i, k, (h * hb) // per_group)),
+                pl.BlockSpec((1, 1, Q, hb), lambda i, h, k, l: (i, h, k, 0)),
+                pl.BlockSpec((1, 1, Q, hb), lambda i, h, k, l: (i, h, k, 0)),
+                pl.BlockSpec((1, hb, Q), lambda i, h, k, l: (i, h, k)),
+                pl.BlockSpec((1, hb, Q), lambda i, h, k, l: (i, h, k)),
+                state_spec,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Q, hb * P), lambda i, h, k, l: (i, k, h)),
+                state_spec,
+            ],
+        ),
         out_shape=[jax.ShapeDtypeStruct((B, Tp, H * P), x.dtype),
-                   jax.ShapeDtypeStruct((B, H, P, N), f32)],
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # Operand 8 (the layer index is operand 0) is the state.
+        input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="ssd_scan",
-    )(pad_t(x).reshape(B, Tp, H * P), pad_t(b).reshape(B, Tp, G * N),
-      pad_t(c).reshape(B, Tp, G * N), tl(cs), tl(dt), hl(cs), hl(dt),
-      state.astype(f32))
-    return y[:, :T].reshape(B, T, H, P), state
+    )(layer, pad_t(x).reshape(B, Tp, H * P), pad_t(b).reshape(B, Tp, G * N),
+      pad_t(c).reshape(B, Tp, G * N), tl(cs), tl(dt), hl(cs), hl(dt), state)
+    return y[:, :T].reshape(B, T, H, P), state[0] if one_layer else state
 
 
 # ---------------------------------------------------------------------------
 # Single-token update kernel (decode)
 # ---------------------------------------------------------------------------
 
-def _step_kernel(dt_ref, decay_ref, x_ref, b_ref, c_ref, s_ref, y_ref,
-                 so_ref, *, hb: int, P: int):
+def _step_kernel(layer_ref, dt_ref, decay_ref, x_ref, b_ref, c_ref, s_ref,
+                 y_ref, so_ref, *, hb: int, P: int):
     """One (row, head block) program. The outer product and the read ride
     the MXU on tiles whose first row alone is live: ``x^T B`` contracts
     the tile's 8 rows (7 of them zero) and gives ``x (x) B`` without a
-    column vector; ``C S^T`` gives ``y`` as a row."""
+    column vector; ``C S^T`` gives ``y`` as a row. ``layer_ref``
+    (prefetched) is spent by the state's index maps."""
+    del layer_ref
     f32 = jnp.float32
     i, h = pl.program_id(0), pl.program_id(1)
+    s_ref, so_ref = s_ref.at[0], so_ref.at[0]     # the layer's blocks
 
     def tile(row):
         """(1, n) -> float32 (8, n) whose first row alone is live."""
@@ -243,10 +279,15 @@ def _step_kernel(dt_ref, decay_ref, x_ref, b_ref, c_ref, s_ref, y_ref,
         y_ref[0, :, j * P:(j + 1) * P] = y[0:1].astype(y_ref.dtype)
 
 
-def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False):
+def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False,
+             layer=None):
     """One token of the recurrence. x: (B, H, P); dt: (B, H) float32;
-    b, c: (B, G, N); state: (B, H, P, N) float32, updated in place.
-    Returns (y (B, H, P) in x's dtype, state)."""
+    b, c: (B, G, N); state: (B, H, P, N) float32, updated in place, or
+    with ``layer`` the stacked (L, B, H, P, N) leaf, of which that layer
+    alone is read and written. Returns (y (B, H, P) in x's dtype,
+    state)."""
+    one_layer = layer is None
+    state, layer = _stacked(state, layer)
     B, H, P = x.shape
     G, N = b.shape[1], b.shape[2]
     hb = head_block(H, G)
@@ -255,29 +296,35 @@ def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False):
     dt = dt.astype(f32)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(_step_kernel, hb=hb, P=P)
+    state_spec = pl.BlockSpec((1, 1, hb, P, N),
+                              lambda i, h, l: (l[0], i, h, 0, 0))
     y, state = pl.pallas_call(
         kernel,
-        grid=(B, H // hb),
-        in_specs=[
-            smem, smem,
-            pl.BlockSpec((1, 1, hb * P), lambda i, h: (i, 0, h)),
-            pl.BlockSpec((1, 1, N),
-                         lambda i, h: (i, 0, (h * hb) // per_group)),
-            pl.BlockSpec((1, 1, N),
-                         lambda i, h: (i, 0, (h * hb) // per_group)),
-            pl.BlockSpec((1, hb, P, N), lambda i, h: (i, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, hb * P), lambda i, h: (i, 0, h)),
-            pl.BlockSpec((1, hb, P, N), lambda i, h: (i, h, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H // hb),
+            in_specs=[
+                smem, smem,
+                pl.BlockSpec((1, 1, hb * P), lambda i, h, l: (i, 0, h)),
+                pl.BlockSpec((1, 1, N), lambda i, h, l:
+                             (i, 0, (h * hb) // per_group)),
+                pl.BlockSpec((1, 1, N), lambda i, h, l:
+                             (i, 0, (h * hb) // per_group)),
+                state_spec,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, hb * P), lambda i, h, l: (i, 0, h)),
+                state_spec,
+            ],
+        ),
         out_shape=[jax.ShapeDtypeStruct((B, 1, H * P), x.dtype),
-                   jax.ShapeDtypeStruct((B, H, P, N), f32)],
-        input_output_aliases={5: 1},
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # Operand 6 (the layer index is operand 0) is the state.
+        input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="ssm_step",
-    )(dt, jnp.exp(dt * a), x.reshape(B, 1, H * P), b.reshape(B, 1, G * N),
-      c.reshape(B, 1, G * N), state.astype(f32))
-    return y.reshape(B, H, P), state
+    )(layer, dt, jnp.exp(dt * a), x.reshape(B, 1, H * P),
+      b.reshape(B, 1, G * N), c.reshape(B, 1, G * N), state)
+    return y.reshape(B, H, P), state[0] if one_layer else state

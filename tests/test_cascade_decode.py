@@ -257,6 +257,49 @@ class TestTrunkDecodeBitwise:
         _assert_ulp_close(got, flat)
 
 
+# The four entry points reading layer l of a stacked (L, K, T, B, hd)
+# operand (the decode step's layer loop, models/decoder._block) against
+# the same call on that layer's own (K, T, B, hd) sides: one kernel, one
+# grid, only the K/V index map's leading coordinate differs, so the
+# outputs are equal to the bit under the interpreter too.
+STACKED_CASES = [
+    ("gqa", flash_decode, dict(H=4, K=2), None, {}, False),
+    ("mqa", flash_decode, dict(H=4, K=1), None, {}, False),
+    ("trunk", flash_decode_trunk, dict(H=4, K=2, shared=128), None,
+     {"trunk_len": 128}, False),
+    ("alibi", flash_decode, dict(H=4, K=4), None, {}, True),
+    ("mqa-trunk-alibi", flash_decode_trunk, dict(H=4, K=1, shared=200),
+     None, {"trunk_len": 200}, True),
+    ("mq", flash_decode_mq, dict(H=4, K=2), 3, {}, False),
+    ("mq-trunk", flash_decode_mq_trunk, dict(H=4, K=2, shared=128), 3,
+     {"trunk_len": 128}, False),
+]
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("name,fn,shape,window,static,alibi", STACKED_CASES,
+                         ids=[c[0] for c in STACKED_CASES])
+def test_stacked_operand_equals_the_layers_own_call(name, fn, shape, window,
+                                                    static, alibi, layer):
+    L = 3
+    cases = [_decode_case(10 + i, T=256, S=window, **shape)
+             for i in range(L)]
+    q, _, _, q_pos, mask, key_pos = cases[layer]
+    k = jnp.stack([c[1] for c in cases])
+    v = jnp.stack([c[2] for c in cases])
+    kw = dict(static, interpret=True)
+    if alibi:
+        kw["alibi_slopes"] = decoder.alibi_slopes(shape["H"])
+    own = jax.jit(lambda kl, vl: fn(q, kl, vl, q_pos, mask, key_pos,
+                                    **kw))(k[layer], v[layer])
+    # The layer index traced, as the layer loop hands it over.
+    got = jax.jit(lambda l: fn(q, k, v, q_pos, mask, key_pos, layer=l,
+                               **kw))(jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(own))
+    other = fn(q, k[1], v[1], q_pos, mask, key_pos, **kw)
+    assert np.abs(np.asarray(other) - np.asarray(own)).max() > 1e-3
+
+
 # ---------------------------------------------------------------------------
 # Tentpole (b): fully-fused cascade prefill vs the PR-16 two-leg path
 # ---------------------------------------------------------------------------

@@ -236,6 +236,40 @@ def test_single_token_kernel_equals_the_recurrence(B):
     assert np.abs(np.asarray(s0 - s1)).max() < 1e-5
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("length", [None, 20], ids=["ssm_step", "ssd_scan"])
+def test_stacked_state_moves_one_layer_and_no_other(layer, length):
+    """Both scan kernels over the cache's stacked (L, B, H, P, N) state
+    with a traced layer index (the layer loop's form, models/decoder.
+    _mixer): layer l's outputs and new state equal, bit for bit, the call
+    on that layer's own state, and every other layer of the buffer is
+    what it was."""
+    L, B, H, P, G, N = 3, 3, 4, 16, 2, 16
+    T = length or 1
+    x, dt, a, b, c, _ = _scan_inputs(B, T, H, P, G, N, 31 + layer)
+    stack = jnp.asarray(np.random.default_rng(5).normal(
+        size=(L, B, H, P, N)), jnp.float32)
+    if length is None:
+        def call(state, **kw):
+            return ssd_scan.ssm_step(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
+                                     state, interpret=True, **kw)
+    else:
+        def call(state, **kw):
+            return ssd_scan.ssd_scan(x, dt, a, b, c, state, chunk=8,
+                                     interpret=True, **kw)
+    # Both under jit: what XLA fuses around the kernel (exp(dt * a)) is
+    # then compiled the same way on both sides.
+    y_own, s_own = jax.jit(call)(stack[layer])
+    y, out = jax.jit(lambda s, l: call(s, layer=l))(stack, jnp.int32(layer))
+    assert out.shape == stack.shape and out.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_own))
+    np.testing.assert_array_equal(np.asarray(out[layer]), np.asarray(s_own))
+    assert np.abs(np.asarray(out[layer] - stack[layer])).max() > 1e-3
+    for other in set(range(L)) - {layer}:
+        np.testing.assert_array_equal(np.asarray(out[other]),
+                                      np.asarray(stack[other]))
+
+
 def _shared_inputs(rng, B, S, S2, shared_head=0):
     prefix, pm, _ = _rows(rng, B, S - 9, S, S)
     if shared_head:
@@ -498,8 +532,9 @@ def test_the_engines_counts_are_the_calls_its_program_makes(program,
     """``recurrent``'s forks, scan_calls and step_calls are what the host
     says of the program it dispatched. Here the dispatched program is
     traced once more with every scan window, single-token update and
-    rewind counted (times the trip count of each ``lax.scan`` around it:
-    layers, decode steps): the host's word has to be the program's."""
+    rewind counted (times the trip count of each loop around it: the
+    ``lax.scan`` over layers, ``generate._stepped`` over decode steps):
+    the host's word has to be the program's."""
     from lir_tpu.backends.fake import FakeTokenizer
     from lir_tpu.config import RuntimeConfig
     from lir_tpu.engine import scheduler, tokens
@@ -561,28 +596,46 @@ def test_the_engines_counts_are_the_calls_its_program_makes(program,
             return fn(*a, **kw)
         return call
 
-    real_scan = jax.lax.scan
-
-    def scan(f, init, xs=None, length=None, **kw):
-        """lax.scan whose body's calls count once per iteration, however
-        often the body is traced."""
-        n = (length if length is not None
-             else jax.tree.leaves(xs)[0].shape[0])
+    def times(n, body):
+        """``body`` with its calls counted once per iteration of a loop of
+        ``n``, however often the loop traces it; call ``.settle()`` after
+        the loop."""
         once = {}
 
-        def body(carry, x):
+        def counted_body(*a):
             before = dict(made)
-            out = f(carry, x)
+            out = body(*a)
             for k in made:
                 once[k], made[k] = made[k] - before[k], before[k]
             return out
 
+        def settle():
+            for k, v in once.items():
+                made[k] += n * v
+
+        counted_body.settle = settle
+        return counted_body
+
+    real_scan, real_stepped = jax.lax.scan, generate._stepped
+
+    def scan(f, init, xs=None, length=None, **kw):
+        n = (length if length is not None
+             else jax.tree.leaves(xs)[0].shape[0])
+        body = times(n, f)
         out = real_scan(body, init, xs, length, **kw)
-        for k, v in once.items():
-            made[k] += n * v
+        body.settle()
+        return out
+
+    def stepped(n, state0, emit, advance):
+        """The decode loop is a while loop that ends when every row is
+        done; with the stops off, as here, it makes its ``n`` steps."""
+        body = times(n, advance)
+        out = real_stepped(n, state0, emit, body)
+        body.settle()
         return out
 
     monkeypatch.setattr(jax.lax, "scan", scan)
+    monkeypatch.setattr(generate, "_stepped", stepped)
     monkeypatch.setattr(ssd_scan, "ssd_scan_tokens", counted(
         "scan", lambda x, dt, a, b, c, state: (jnp.zeros_like(x), state),
         lambda x, *_: x.shape[1] > 1))

@@ -15,13 +15,17 @@ never at import, in a ``skipif``, or in ``parametrize`` arguments. Keep
 every such compile in THIS file.
 """
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from lir_tpu.engine import generate
+from lir_tpu.models import decoder, quant, registry
 from lir_tpu.ops.cascade_prefill import cascade_attention
 from lir_tpu.ops.flash_attention import flash_attention
 from lir_tpu.ops.ssd_scan import ssd_scan, ssm_step
@@ -82,6 +86,22 @@ def _decode_args(layout, T, window=None, alibi=False):
     return [q, kv, kv, qpos, mask, mask, slopes]
 
 
+def _stacked(fn, n_args):
+    """``fn`` of ``n_args`` arguments with one more, trailing, argument:
+    the layer index, traced (the caller stacks the operands it picks
+    from)."""
+    def call(*args, **static):
+        return fn(*args[:n_args], layer=args[n_args], **static)
+    return call
+
+
+STACK = 4                      # layers of the stacked operand
+
+
+def _stack(arg):
+    return ((STACK,) + arg[0], arg[1])
+
+
 def _cascade_args(layout, R, Tt, alibi=False):
     H, K, hd = LAYOUTS[layout]
     bf16, i32 = jnp.bfloat16, jnp.int32
@@ -124,6 +144,34 @@ def test_flash_decode_mq_trunk_compiles(one_chip, layout, T, alibi):
     _compile(flash_decode_mq_trunk,
              _decode_args(layout, T, window=WINDOW, alibi=alibi), one_chip,
              trunk_len=T - 128)
+
+
+# The same four entry points reading one layer out of the STACKED cache
+# sides (L, K, T, B, hd), the layer index traced: the form the decode
+# step's layer loop calls (models/decoder._block). 504 is the extent the
+# sweep cells decode over.
+STACKED_DECODE = [(flash_decode, None, {}),
+                  (flash_decode_trunk, None, {"trunk_len": 64}),
+                  (flash_decode_mq, WINDOW, {}),
+                  (flash_decode_mq_trunk, WINDOW, {"trunk_len": 64})]
+
+
+@pytest.mark.parametrize("fn,window,static", STACKED_DECODE,
+                         ids=[c[0].__name__ for c in STACKED_DECODE])
+@pytest.mark.parametrize("layout,T,alibi", [("gqa", 504, False),
+                                            ("mqa", 504, False),
+                                            ("mha", 512, True)])
+def test_flash_decode_stacked_compiles(one_chip, layout, T, alibi, fn,
+                                       window, static):
+    args = _decode_args(layout, T, window=window, alibi=alibi)
+    args[1], args[2] = _stack(args[1]), _stack(args[2])
+    text = _compile(_stacked(fn, 7), args + [((), jnp.int32)], one_chip,
+                    **static).as_text()
+    assert "tpu_custom_call" in text
+    # The kernel is handed the stacked sides themselves: no layer of
+    # them is sliced out for it.
+    K, hd = LAYOUTS[layout][1:]
+    assert f"bf16[{K},{T},{BATCH},{hd}]" not in text
 
 
 # The extents a sweep dispatch really allocates since the plan tightens
@@ -226,6 +274,27 @@ def test_ssm_step_compiles(one_chip, batch):
     assert "ssm_step" in text
 
 
+@pytest.mark.parametrize("batch,length", [(BATCH, 32), (BATCH, None)])
+def test_scan_kernels_stacked_compile(one_chip, batch, length):
+    """Both selective-scan kernels over the stacked (L, B, H, P, N) state
+    with a traced layer index, state aliased in to out: the compiled
+    program holds no copy and no slice of the state."""
+    fn = ssm_step if length is None else ssd_scan
+    args = _scan_args(batch, length)
+    args[5] = _stack(args[5])
+    shaped = [jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip)
+              for a in args + [((), jnp.int32)]]
+    # The state is donated, as a loop's carry is: the aliased output then
+    # IS the argument's buffer.
+    text = jax.jit(_stacked(fn, 6), donate_argnums=(5,)).lower(
+        *shaped).compile().as_text()
+    assert fn.__name__ in text
+    H, P, _, N = MIXER
+    assert f"f32[{batch},{H},{P},{N}]" not in text
+    assert not re.search(rf"f32\[{STACK},{batch},{H},{P},{N}\]\S* copy\(",
+                         text)
+
+
 def test_compiled_text_carries_the_kernel(one_chip):
     """The compile really went through Mosaic: the executable holds a
     ``tpu_custom_call`` (not an XLA fallback)."""
@@ -286,3 +355,212 @@ def test_kernel_keeps_its_name_under_a_renamed_wrapper(one_chip, name, fn,
     assert calls, "no Mosaic call in the compiled program"
     assert any(re.fullmatch(re.escape(name) + r"(\.\d+)*", c)
                for c in calls), (name, calls)
+
+
+# ---------------------------------------------------------------------------
+# The decode loop updates the stacked cache where it lies
+# ---------------------------------------------------------------------------
+# models/decoder._scan_blocks carries the stacked cache through the layer
+# loop and engine/generate._stepped carries it through the decode loop; what
+# says that the mechanism engaged is the compiled program itself: inside its
+# loops nothing produces an array of a stacked side's shape, or of one whole
+# layer of it, but the in-place updates (the token-slot write, a kernel's
+# aliased output).
+
+def _computations(text):
+    """{computation name: its instruction lines} of a compiled module's
+    text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
+                        line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _inside_loops(comps):
+    """Names of the computations a ``while`` body reaches (its fusions,
+    its conditionals' branches, nested loops)."""
+    todo = [name for lines in comps.values() for line in lines
+            if re.search(r"\swhile\(", line)
+            for name in re.findall(r"body=%?([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for key in ("body", "condition", "to_apply", "calls",
+                        "true_computation", "false_computation"):
+                todo += re.findall(key + r"=%?([\w.\-]+)", line)
+            branches = re.search(r"branch_computations=\{([^}]*)\}", line)
+            if branches:
+                todo += [b.strip().lstrip("%")
+                         for b in branches.group(1).split(",")]
+    return seen
+
+
+_INSTR = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)")
+
+
+def _cache_moves(text, stacked, layers, whole_layer_reads=True):
+    """What the loops of a compiled program do to the stacked cache beyond
+    updating it in place, as a list of findings (empty: nothing).
+    ``stacked`` / ``layers``: HLO shape strings of the stacked sides
+    (``bf16[L,K,T,B,hd]``) and of one layer of each."""
+    comps = _computations(text)
+    loops = _inside_loops(comps)
+    assert loops, "no loop in the compiled program"
+    types = {}
+    for lines in comps.values():
+        for line in lines:
+            m = _INSTR.match(line)
+            if m:
+                types[m.group(1)] = m.group(2)
+    found = []
+    for name in sorted(loops):
+        for line in comps[name]:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            var, typ, op, rest = m.groups()
+            if op in ("parameter", "get-tuple-element", "bitcast", "tuple",
+                      "while", "conditional"):
+                continue
+            of_stack = any(typ.startswith(s) for s in stacked)
+            if of_stack and op in ("copy", "copy-start"):
+                found.append(f"copy of a stacked side: {var} = {typ}")
+            if of_stack and "AllocateBuffer" in rest:
+                found.append(f"second stacked buffer: {var} = {typ}")
+            if whole_layer_reads and any(typ.startswith(s) for s in layers):
+                found.append(f"one whole layer materialised: {var} = {typ} "
+                             f"{op}")
+            if op == "dynamic-update-slice":
+                update = re.findall(r"%([\w.\-]+)", rest)[1]
+                if any(types.get(update, "").startswith(s) for s in layers):
+                    found.append(f"whole-layer write-back: {var} <- "
+                                 f"{update} = {types[update]}")
+    return found
+
+
+DEPTH = 2                      # layers of the cut models below
+EXTENT, SLOT, TRUNK = 504, 480, 64
+
+
+def _loop_cfg(case):
+    if case == "hybrid":
+        return registry.falcon_h1_34b(n_layers=DEPTH)
+    cfg = dataclasses.replace(registry.mistral_7b(), n_layers=DEPTH)
+    return dataclasses.replace(cfg, kv_cache_int8=(case == "int8"))
+
+
+def _shaped(tree, one_chip):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+
+
+def _hlo_shape(leaf, drop=0):
+    names = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
+    return (f"{names[str(leaf.dtype)]}"
+            f"[{','.join(str(d) for d in leaf.shape[drop:])}]")
+
+
+def _cache_shapes(cfg, cache):
+    """(stacked, one-layer) shape strings of the K/V sides (payloads of an
+    int8 cache) and of a mixer's SSM state; scales and the conv tail are
+    small and read or written whole by design."""
+    big = [leaf for leaf in jax.tree.leaves(cache) if leaf.ndim == 5]
+    return ([_hlo_shape(leaf) for leaf in big],
+            [_hlo_shape(leaf, 1) for leaf in big])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The decoder's gates ask the backend; the compile is for a described
+    chip, so they are told what it is."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("case", ["gqa", "hybrid", "int8"])
+def test_decode_loop_updates_the_stacked_cache_in_place(one_chip, on_tpu,
+                                                         case):
+    """``_fused_tail`` (two steps, early stop on) at mistral's and
+    falcon-h1's published widths, batch 40, extent 504: the decode loop
+    and the layer loop inside it hold no copy and no second buffer of a
+    stacked side, materialise no whole layer of it (GQA + bf16 and the
+    hybrid; the int8 cache attends dense and a fused read of the layer is
+    XLA's to place) and write no whole layer back."""
+    cfg = _loop_cfg(case)
+    params = _shaped(jax.eval_shape(
+        lambda k: quant.random_quantized_params(cfg, k),
+        jax.random.PRNGKey(0)), one_chip)
+    cache = _shaped(jax.eval_shape(
+        lambda: decoder.init_cache(cfg, BATCH, EXTENT, jnp.bfloat16)),
+        one_chip)
+    V = cfg.vocab_size
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def tail(params, cache, logits0, mask, pos, ids, digit_ids, digit_vals,
+             stop_mask, eos_id):
+        return generate._fused_tail(
+            params, cfg, logits0, cache, mask, pos, SLOT, ids, ids,
+            digit_ids, digit_vals, 2, 20, stop_mask=stop_mask,
+            eos_id=eos_id, decode_trunk=TRUNK)
+
+    text = jax.jit(tail, donate_argnums=(1,)).lower(
+        params, cache, sd((BATCH, V), jnp.float32),
+        sd((BATCH, EXTENT), jnp.int32), sd((BATCH,), jnp.int32),
+        sd((BATCH,), jnp.int32), sd((101,), jnp.int32),
+        sd((101,), jnp.float32), sd((V,), jnp.int32),
+        sd((), jnp.int32)).compile().as_text()
+    if case != "int8":
+        assert "flash_decode_trunk" in text
+    if case == "hybrid":
+        assert "ssm_step" in text
+    stacked, layers = _cache_shapes(cfg, cache)
+    assert len(stacked) == (3 if case == "hybrid" else 2)
+    assert _cache_moves(text, stacked, layers,
+                        whole_layer_reads=(case != "int8")) == []
+
+
+def test_cascade_program_updates_the_stacked_cache_in_place(one_chip,
+                                                            on_tpu):
+    """The whole 40-row cascade dispatch program of a sweep cell (mistral's
+    widths, cut depth; both branches, early stops armed, the donated
+    scratch cache): what a two-step loop compiled alone cannot show is
+    whether a program of a dispatch's size still carries the cache by
+    loops alone. Held to the decode loops; the extension's dense read of a
+    layer is XLA's to place."""
+    cfg = _loop_cfg("gqa")
+    params = _shaped(jax.eval_shape(
+        lambda k: quant.random_quantized_params(cfg, k),
+        jax.random.PRNGKey(0)), one_chip)
+    V = cfg.vocab_size
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    args = (params, cfg, i32(BATCH, 448), i32(BATCH, 448), i32(BATCH, 32),
+            i32(BATCH, 32), i32(BATCH, 32), i32(BATCH, 32), i32(BATCH),
+            i32(BATCH), i32(101),
+            jax.ShapeDtypeStruct((101,), jnp.float32, sharding=one_chip))
+    kw = dict(stop_mask_a=i32(V), stop_mask_b=i32(V), eos_id=i32(),
+              max_new_a=4, max_new_b=8, trunk_len=TRUNK, topk=20,
+              int8_qk=False, return_cache=True)
+    fn = generate.greedy_decode_fused_shared_cascade
+    cache = fn.eval_shape(*args, scratch_cache=None, **kw)[-1]
+    text = fn.lower(*args, scratch_cache=_shaped(cache, one_chip),
+                    **kw).compile().as_text()
+    assert cache[0].shape == (DEPTH, 8, EXTENT, BATCH, 128)
+    stacked, layers = _cache_shapes(cfg, cache)
+    found = _cache_moves(text, stacked, layers, whole_layer_reads=False)
+    assert found == []
