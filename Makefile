@@ -1,6 +1,7 @@
 # Convenience targets. `make verify` is the pre-ship gate: it runs the
-# ROADMAP tier-1 suite and fails if the pass count drops below the
-# recorded floor (tools/check_tier1.py — the floor lives there).
+# tier-1 suite as the driver does (six workers, one file each) and fails
+# if the pass count drops below the driver's floor, read from
+# PERF_LEDGER.jsonl (tools/check_tier1.py holds no floor of its own).
 
 .PHONY: verify test bench lint serve-smoke prefix-smoke chaos-smoke \
 	kernel-smoke stats-smoke fleet-smoke observe-smoke elastic-smoke \

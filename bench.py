@@ -837,9 +837,17 @@ def _kernel_interp_smoke() -> dict:
     d_ids = jnp.arange(10, 30, dtype=jnp.int32)
     d_vals = jnp.arange(0.0, 20.0, dtype=jnp.float32)
     args = (prefix, pm, sfx_a, sam, sfx_b, sbm)
-    seq = generate.greedy_decode_fused_shared(
-        params, cfg, *args, yes, no, d_ids, d_vals, max_new_a=3,
-        max_new_b=5)
+
+    def dispatch(prefix, pm, **program):
+        outs, _, _ = generate.greedy_decode_dispatch(
+            params, cfg, generate.Program(max_new=(3, 5), **program),
+            generate.DispatchArgs(
+                prefix=prefix, prefix_mask=pm, sfx=(sfx_a, sfx_b),
+                sfx_mask=(sam, sbm), yes_ids=yes, no_ids=no,
+                digit_ids=d_ids, digit_vals=d_vals))
+        return outs
+
+    seq = dispatch(prefix, pm)
     carry = generate.shared_piggyback_prefill(params, cfg, *args,
                                               max_new_a=3, max_new_b=5)
     pig = generate.shared_piggyback_drain(
@@ -864,13 +872,8 @@ def _kernel_interp_smoke() -> dict:
     tails = jnp.asarray(rng.integers(3, 256, (2, 8)), jnp.int32)
     cprefix = jnp.concatenate([jnp.tile(head, (2, 1)), tails], axis=1)
     cpm = jnp.ones((2, trunk_len + 8), jnp.int32)
-    cargs = (cprefix, cpm, sfx_a, sam, sfx_b, sbm)
-    seq_c = generate.greedy_decode_fused_shared(
-        params, cfg, *cargs, yes, no, d_ids, d_vals, max_new_a=3,
-        max_new_b=5)
-    casc = generate.greedy_decode_fused_shared_cascade(
-        params, cfg, *cargs, yes, no, d_ids, d_vals, max_new_a=3,
-        max_new_b=5, trunk_len=trunk_len)
+    seq_c = dispatch(cprefix, cpm)
+    casc = dispatch(cprefix, cpm, front="cascade", trunk=trunk_len)
     cascade_ok = True
     for s, c in zip(jax.tree.leaves(seq_c), jax.tree.leaves(casc)):
         s, c = np.asarray(s), np.asarray(c)

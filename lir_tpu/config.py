@@ -217,8 +217,8 @@ class RuntimeConfig:
     # the slowest static shard. Single-process runs work identically
     # (one holder claims every shard in order).
     lease_shards: bool = False        # host-only
-    # Speculative scoring decode (engine/spec.py + generate.
-    # greedy_decode_fused_shared_spec; DEPLOY.md §1n). ON: shared-path
+    # Speculative scoring decode (engine/spec.py + the speculative tail
+    # of generate.greedy_decode_dispatch; DEPLOY.md §1n). ON: shared-path
     # dispatches draft up to spec_k tokens ahead (prompt-lookup from
     # the radix tree's token history + n-gram self-lookup, or a small
     # fleet draft model when spec_draft_model names one) and VERIFY
@@ -239,8 +239,8 @@ class RuntimeConfig:
     # PR-10 WeightCache so drafting never evicts the verifier
     # mid-dispatch). Empty = self-drafting (tree + n-gram lookup).
     spec_draft_model: str = ""
-    # Shared-prefix cascade prefill (ops/cascade_prefill + generate.
-    # greedy_decode_fused_shared_cascade; DEPLOY.md §1q). ON: a shared
+    # Shared-prefix cascade prefill (ops/cascade_prefill + the cascade
+    # front of generate.greedy_decode_dispatch; DEPLOY.md §1q). ON: a shared
     # dispatch whose rows all begin with the same trunk (LCP across the
     # dispatch, snapped to CascadeConfig.trunk_quantum) prefills that
     # trunk ONCE at batch 1 — or gathers it warm from the radix page

@@ -63,43 +63,35 @@ TOPK = 20  # the D6 top-20 logprob map — fixed across every sweep caller
 class ShapeSpec:
     """Everything that selects one compiled executable, shape-wise.
 
-    ``kind`` is "shared" (decode_fused_shared), "grouped"
-    (decode_fused_grouped), or their prefix-cache-resume variants
-    "shared_paged"/"grouped_paged" (generate.*_paged — the block-table
-    executables, selected additionally by ``window``, the remainder-
-    window edge each row recomputes while the rest of its prefix
-    gathers from the page pool). ``batch`` is the PADDED member-row
-    count the runner will dispatch (shared: the padded batch; grouped:
-    m_pad); ``groups`` the padded prefill-row count (grouped only, else
-    0). ``sfx_a``/``sfx_b`` are the right-pad suffix bucket edges
-    (grouped uses a single merged edge in ``sfx_a``). ``stops_armed``
-    records whether the stop-mask arguments are arrays or None — that
-    changes the traced pytree, hence the executable. ``scratch``
-    selects the donated-KV-cache variant (every dispatch after the
-    first of a bucket queue donates the previous cache —
-    runner._CacheHandoff; paged and unpaged variants of one shape
-    return the same cache aval, so the chain crosses them freely).
-    ``spec_k`` > 0 selects the SPECULATIVE-decode executable for that
-    verify-window size (generate.greedy_decode_fused_shared_spec /
-    _paged_spec — the verify executables are planned per (bucket,
-    batch, k)); ``spec_draft`` its fleet-draft-model variant (the
-    draft model's params ride the traced pytree). ``trunk`` > 0 selects
-    a CASCADE-prefill executable (kinds "shared_cascade"/
-    "shared_cascade_paged" — generate.greedy_decode_fused_shared_cascade
-    and its paged-trunk sibling) at that static shared-trunk extent, and
-    ``cascade_int8`` its in-kernel int8-QK^T variant; both change the
-    lowered program, so keying them here is what guarantees an
-    executable can never serve the wrong mode (a dense lookup can't
-    return a cascade program or vice versa). For the paged cascade kind,
-    ``window`` is the TRUNK's recompute-window edge (the (1, W) chunk
-    the radix resume teacher-forces), not a per-row window.
-    ``decode_trunk`` > 0 selects the CASCADE-DECODE variant of the
-    plain "shared"/"shared_paged" kinds (and their spec siblings): the
-    decode scans' trunk splits run trunk-aware
-    (ops/flash_decode.flash_decode_trunk — bitwise the flat kernels)
-    at that static trunk extent. The cascade kinds don't carry it:
-    their decode trunk IS ``trunk`` (generate._cascade_branches), so
-    ``trunk`` already keys the lowering."""
+    ``kind`` is "shared" (a pair of format branches per row:
+    runner.decode_fused_shared) or "grouped" (member rows gathered from
+    prefix groups: decode_fused_grouped) — both the ONE dispatch program
+    (generate.greedy_decode_dispatch), whose front and tail follow from
+    the fields below (:func:`dispatch_program`) — or one of the three
+    piggyback stages, or "stream_fold". ``batch`` is the PADDED
+    member-row count the runner will dispatch (shared: the padded batch;
+    grouped: m_pad); ``groups`` the padded prefill-row count (grouped
+    only, else 0). ``sfx_a``/``sfx_b`` are the right-pad suffix bucket
+    edges (grouped uses a single merged edge in ``sfx_a``).
+    ``stops_armed`` records whether the stop tables are arrays or absent
+    — that changes the traced pytree, hence the executable. ``scratch``
+    selects the donated-KV-cache variant (every dispatch after the first
+    of a bucket queue donates the previous cache — paged.CacheHandoff).
+
+    ``window`` > 0 selects a PAGED front at that recompute-window edge:
+    each row recomputes its last ``window`` real prefix tokens and
+    gathers the rest from the page pool; with ``trunk`` it is the
+    TRUNK's window (a (1, W) chunk), not a per-row one. ``trunk`` > 0
+    selects the CASCADE front at that static shared-trunk extent (which
+    is then also the decode steps' trunk) and ``cascade_int8`` its
+    in-kernel int8-QK^T variant. ``decode_trunk`` > 0 runs the decode
+    steps of a NON-cascade front trunk-aware at that extent
+    (ops/flash_decode.flash_decode_trunk — bitwise the flat kernels).
+    ``spec_k`` > 0 selects the speculative tail at that verify-window
+    size and ``spec_draft`` its fleet-draft-model variant (the draft
+    model's params ride the traced pytree). Every one of them changes
+    the lowered program, so keying them here is what guarantees an
+    executable can never serve the wrong mode."""
 
     kind: str
     bucket: int
@@ -139,73 +131,16 @@ class ShapeSpec:
                 f"/new{self.new_tokens}-{self.conf_tokens}{win}{spec}"
                 f"{casc}/{var}")
 
-
-def shared_spec(bucket: int, batch: int, sfx_a: int, sfx_b: int,
-                new_tokens: int, conf_tokens: int, stops_armed: bool,
-                scratch: bool, spec_k: int = 0,
-                spec_draft: bool = False,
-                decode_trunk: int = 0) -> ShapeSpec:
-    return ShapeSpec("shared", int(bucket), int(batch), 0, int(sfx_a),
-                     int(sfx_b), int(new_tokens), int(conf_tokens),
-                     bool(stops_armed), bool(scratch),
-                     spec_k=int(spec_k), spec_draft=bool(spec_draft),
-                     decode_trunk=int(decode_trunk))
-
-
-def grouped_spec(bucket: int, groups: int, batch: int, sfx: int,
-                 max_new: int, stops_armed: bool,
-                 scratch: bool) -> ShapeSpec:
-    return ShapeSpec("grouped", int(bucket), int(batch), int(groups),
-                     int(sfx), 0, int(max_new), 0, bool(stops_armed),
-                     bool(scratch))
-
-
-def shared_paged_spec(bucket: int, batch: int, window: int, sfx_a: int,
-                      sfx_b: int, new_tokens: int, conf_tokens: int,
-                      stops_armed: bool, scratch: bool,
-                      spec_k: int = 0,
-                      decode_trunk: int = 0) -> ShapeSpec:
-    return ShapeSpec("shared_paged", int(bucket), int(batch), 0,
-                     int(sfx_a), int(sfx_b), int(new_tokens),
-                     int(conf_tokens), bool(stops_armed), bool(scratch),
-                     int(window), spec_k=int(spec_k),
-                     decode_trunk=int(decode_trunk))
-
-
-def shared_cascade_spec(bucket: int, batch: int, trunk: int, sfx_a: int,
-                        sfx_b: int, new_tokens: int, conf_tokens: int,
-                        stops_armed: bool, scratch: bool,
-                        int8_qk: bool = False) -> ShapeSpec:
-    """Cold cascade-prefill executable (generate.greedy_decode_fused_
-    shared_cascade): batch-1 trunk prefill at the static ``trunk``
-    extent + per-row cascade remainder extension."""
-    return ShapeSpec("shared_cascade", int(bucket), int(batch), 0,
-                     int(sfx_a), int(sfx_b), int(new_tokens),
-                     int(conf_tokens), bool(stops_armed), bool(scratch),
-                     trunk=int(trunk), cascade_int8=bool(int8_qk))
-
-
-def shared_cascade_paged_spec(bucket: int, batch: int, trunk: int,
-                              window: int, sfx_a: int, sfx_b: int,
-                              new_tokens: int, conf_tokens: int,
-                              stops_armed: bool, scratch: bool,
-                              int8_qk: bool = False) -> ShapeSpec:
-    """Warm cascade executable (generate.greedy_decode_fused_shared_
-    cascade_paged): the trunk resumes from the radix page pool through a
-    (1, ``window``) recompute chunk instead of prefilling."""
-    return ShapeSpec("shared_cascade_paged", int(bucket), int(batch), 0,
-                     int(sfx_a), int(sfx_b), int(new_tokens),
-                     int(conf_tokens), bool(stops_armed), bool(scratch),
-                     int(window), trunk=int(trunk),
-                     cascade_int8=bool(int8_qk))
-
-
-def grouped_paged_spec(bucket: int, groups: int, batch: int, window: int,
-                       sfx: int, max_new: int, stops_armed: bool,
-                       scratch: bool) -> ShapeSpec:
-    return ShapeSpec("grouped_paged", int(bucket), int(batch), int(groups),
-                     int(sfx), 0, int(max_new), 0, bool(stops_armed),
-                     bool(scratch), int(window))
+    @property
+    def cache_key(self) -> "ShapeSpec":
+        """What every program returning this one's cache aval shares:
+        the donation chain's key. The front, the trunks and the stop
+        tables leave the aval alone, so cold, warm, cascade and dense
+        dispatches of one shape chain unbroken; a speculative cache is
+        LONGER (spec_k slots a decode window) and chains on its own."""
+        return dataclasses.replace(
+            self, scratch=False, window=0, trunk=0, cascade_int8=False,
+            decode_trunk=0)
 
 
 def stream_fold_spec(n_prompts: int, n_rephrase: int, batch: int,
@@ -252,67 +187,27 @@ def piggy_drain_spec(bucket: int, batch: int, sfx_a: int, sfx_b: int,
                      bool(stops_armed), False)
 
 
-def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
-               conf_tokens: int, stops_armed: bool,
-               prefix_page_size: int = 0,
-               piggyback: bool = False,
+def plan_specs(dispatches: Sequence[Any], routes: Sequence[Any],
                stream_shape: Optional[Tuple[int, int, bool]] = None,
-               spec_k: int = 0, spec_draft: bool = False,
-               cascade_trunk=None, cascade_int8: bool = False,
-               decode_trunk=None,
                ) -> List[ShapeSpec]:
     """Distinct executables a dispatch plan will call, in first-use order
     (the precompile pool works the list front-to-back, so the first
     bucket's executable compiles first and the dispatch loop rarely
-    waits). Mirrors the runner's padding/handoff behavior exactly:
-    the first dispatch of each handoff key runs the scratchless variant,
-    every consecutive same-key dispatch the donated one. A spec's
-    ``bucket`` is the prefix extent the dispatch RUNS at — the plan's
-    tight ``Dispatch.edge`` — since that is what the runner is handed.
-
-    ``prefix_page_size`` > 0 (an engine whose cross-request prefix cache
-    is enabled) additionally plans the block-table executables: for each
-    dispatch shape, one paged variant per remainder-window edge the
-    runner may pick (models/paged.window_edges) — which window a warm
-    dispatch runs depends on what the radix tree holds at dispatch
-    time, so the plan covers them all.
-
-    ``piggyback`` (an engine whose chunked prefill/decode piggybacking is
-    on) plans the chain executables for every run of CONSECUTIVE
-    same-shape shared dispatches — the exact chains the sweep forms:
-    opener (prefill-only), step (parked decode + next prefill), and
-    drain. Plain specs stay planned regardless (the runtime memory gate
-    may refuse a chain, and the recovery path re-dispatches plainly).
+    waits). ``routes[i]`` is the engine's routing of ``dispatches[i]``
+    (runner.ScoringEngine.route_dispatch); WHICH programs a dispatch may
+    run is the route's to say (``Route.planned``) — this function only
+    follows the handoff: the first dispatch of a shape runs the
+    scratchless variant, every consecutive repeat the donated one, and a
+    repeat is also what the sweep chains through the piggyback stages.
 
     ``stream_shape`` = (n_prompts, n_rephrase, numerics_guard) plans the
     streaming-statistics accumulator-update executable for every
     distinct fold width the plan's dispatches will use (shared: the
     padded member-row count; grouped: one branch's row count), so the
     sink's per-dispatch fold never pays trace-on-first-call inside the
-    timed loop either. Planned FIRST — the very first dispatch folds.
-
-    ``cascade_trunk`` (a cascade-prefill engine) maps a shared dispatch
-    to its snapped shared-trunk extent (0 = ineligible — the runner's
-    own eligibility rule, so the plan covers exactly the cascade
-    executables the loop will call); eligible dispatches plan the
-    cascade executable (plus its paged-trunk variants when the prefix
-    cache is on — the trunk's recompute window depends on what the
-    radix tree holds at dispatch time, so every trunk window edge is
-    covered). The plain shared spec stays planned regardless: a dense
-    fallback re-dispatches through it.
-
-    ``decode_trunk`` (a cascade-DECODE engine) maps a shared dispatch to
-    the static trunk extent its decode scans dedup at (0 = flat
-    kernels); eligible dispatches plan the trunk-aware variant of every
-    plain shared/paged/spec executable ALONGSIDE the flat one — which
-    variant the runner calls depends on the same per-dispatch rule, and
-    the flat specs cover the --no-cascade-decode engine and the dense
-    fallback."""
-    from ..models import paged as paged_mod
-
+    timed loop either. Planned FIRST — the very first dispatch folds."""
     specs: List[ShapeSpec] = []
     seen = set()
-    prev_key: Optional[Tuple] = None
 
     def add(spec: ShapeSpec) -> None:
         if spec not in seen:
@@ -321,315 +216,134 @@ def plan_specs(dispatches: Sequence[Any], batch_size: int, new_tokens: int,
 
     if stream_shape is not None:
         n_prompts, n_rephrase, guard = stream_shape
-        for d in dispatches:
-            _, m_pad = d.padded_rows(batch_size)
-            width = m_pad if d.kind == "shared" else len(d.items)
+        for d, route in zip(dispatches, routes):
+            width = (route.shape.batch if d.kind == "shared"
+                     else len(d.items))
             add(stream_fold_spec(n_prompts, n_rephrase, width, guard))
-    for d in dispatches:
-        g_pad, m_pad = d.padded_rows(batch_size)
-        if d.kind == "shared":
-            key = ("shared", d.edge, m_pad, d.sfx_bucket_a,
-                   d.sfx_bucket_b, new_tokens, conf_tokens)
-            scratch = key == prev_key
-            trunk = int(cascade_trunk(d)) if cascade_trunk else 0
-            # Cascade-decode extent for the PLAIN kinds: a cascade-
-            # prefill-eligible dispatch never reaches them (the cascade
-            # path takes precedence), so its dtrunk variants would be
-            # dead compiles.
-            dt = (int(decode_trunk(d))
-                  if (decode_trunk is not None and not trunk) else 0)
-            add(shared_spec(d.edge, m_pad, d.sfx_bucket_a,
-                            d.sfx_bucket_b, new_tokens, conf_tokens,
-                            stops_armed, scratch=scratch,
-                            decode_trunk=dt))
-            if spec_k:
-                # Speculative verify executables, planned per
-                # (bucket, batch, k) alongside the sequential shape
-                # (the runner falls back to it on a spec-ineligible
-                # dispatch).
-                add(shared_spec(d.edge, m_pad, d.sfx_bucket_a,
-                                d.sfx_bucket_b, new_tokens, conf_tokens,
-                                stops_armed, scratch=scratch,
-                                spec_k=spec_k, spec_draft=spec_draft,
-                                decode_trunk=dt))
-            if trunk:
-                add(shared_cascade_spec(d.edge, m_pad, trunk,
-                                        d.sfx_bucket_a, d.sfx_bucket_b,
-                                        new_tokens, conf_tokens,
-                                        stops_armed, scratch=scratch,
-                                        int8_qk=cascade_int8))
-                if prefix_page_size:
-                    for w in paged_mod.window_edges(trunk,
-                                                    prefix_page_size):
-                        add(shared_cascade_paged_spec(
-                            d.edge, m_pad, trunk, w, d.sfx_bucket_a,
-                            d.sfx_bucket_b, new_tokens, conf_tokens,
-                            stops_armed, scratch=scratch,
-                            int8_qk=cascade_int8))
-            if piggyback and scratch and not trunk:
-                # A repeat of the previous shared shape — the sweep will
-                # chain these dispatches: plan all three chain stages.
-                add(piggy_prefill_spec(d.edge, m_pad, d.sfx_bucket_a,
-                                       d.sfx_bucket_b, new_tokens,
-                                       conf_tokens))
-                add(piggy_step_spec(d.edge, m_pad, d.sfx_bucket_a,
-                                    d.sfx_bucket_b, new_tokens,
-                                    conf_tokens, stops_armed))
-                add(piggy_drain_spec(d.edge, m_pad, d.sfx_bucket_a,
-                                     d.sfx_bucket_b, new_tokens,
-                                     conf_tokens, stops_armed))
-            if prefix_page_size:
-                for w in paged_mod.window_edges(d.edge, prefix_page_size):
-                    add(shared_paged_spec(
-                        d.edge, m_pad, w, d.sfx_bucket_a, d.sfx_bucket_b,
-                        new_tokens, conf_tokens, stops_armed,
-                        scratch=scratch, decode_trunk=dt))
-                    if spec_k and not spec_draft:
-                        # Paged + speculative composes for self-drafting
-                        # only (the paged front binds slot tables, not
-                        # prefix tokens — nothing for a draft model to
-                        # prefill from).
-                        add(shared_paged_spec(
-                            d.edge, m_pad, w, d.sfx_bucket_a,
-                            d.sfx_bucket_b, new_tokens, conf_tokens,
-                            stops_armed, scratch=scratch, spec_k=spec_k,
-                            decode_trunk=dt))
-        else:
-            sfx = max(d.sfx_bucket_a, d.sfx_bucket_b)
-            max_new = max(new_tokens, conf_tokens)
-            key = ("grouped", d.edge, g_pad, m_pad, sfx, max_new)
-            scratch = key == prev_key
-            add(grouped_spec(d.edge, g_pad, m_pad, sfx, max_new,
-                             stops_armed, scratch=scratch))
-            if prefix_page_size:
-                for w in paged_mod.window_edges(d.edge, prefix_page_size):
-                    add(grouped_paged_spec(
-                        d.edge, g_pad, m_pad, w, sfx, max_new,
-                        stops_armed, scratch=scratch))
-        prev_key = key
+    prev = None
+    for route in routes:
+        repeat = route.shape == prev
+        for spec in route.planned(scratch=repeat, chain=repeat):
+            add(spec)
+        prev = route.shape
     return specs
 
 
 # ---------------------------------------------------------------------------
-# Lowering: exact aval reconstruction of the runner's call sites
+# The dispatch program's statics and arguments, from a ShapeSpec
 # ---------------------------------------------------------------------------
 
-def _spec_avals(engine, spec: ShapeSpec):
-    """The eight drafting-array avals (SpecPlan.dyn_args order) appended
-    to a speculative executable's argument list."""
-    import jax
-    import jax.numpy as jnp
+def dispatch_program(engine, spec: ShapeSpec,
+                     return_cache: bool = True):
+    """The static description (generate.Program) of the dispatch program
+    ``spec`` keys: the front follows from ``window`` and ``trunk``, the
+    layout from ``kind``, the tail from ``spec_k``."""
+    from . import generate
 
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    B = spec.batch
-    return (i32(B, spec.bucket + spec.sfx_a + spec.new_tokens), i32(B),
-            i32(B, spec.new_tokens), i32(B),
-            i32(B, spec.bucket + spec.sfx_b + spec.conf_tokens), i32(B),
-            i32(B, spec.conf_tokens), i32(B))
-
-
-def _spec_statics(engine, spec: ShapeSpec) -> dict:
-    out = dict(spec_k=spec.spec_k, ngram=int(engine.spec_cfg.ngram))
-    return out
-
-
-def _spec_draft_kwargs(engine, spec: ShapeSpec):
-    """(dynamic kwargs, statics) arming the fleet draft model in a
-    speculative executable's signature."""
-    if not spec.spec_draft:
-        return {"draft_params": None}, {"draft_cfg": None}
-    draft_params, draft_cfg, _ = engine._spec_draft
-    import jax
-
-    avals = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(tuple(a.shape), a.dtype),
-        draft_params)
-    return {"draft_params": avals}, {"draft_cfg": draft_cfg}
-
-
-def _avals_shared(engine, spec: ShapeSpec):
-    """(args, kwargs) ShapeDtypeStructs matching runner.decode_fused_shared's
-    call into generate.greedy_decode_fused_shared (or its speculative
-    sibling when ``spec.spec_k``) — one canonical layout shared with
-    :func:`_registry_call` so lowering and dispatch can never drift
-    apart."""
-    import jax
-    import jax.numpy as jnp
-
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    B = spec.batch
-    digit_ids, digit_vals = engine.digit_table
-    args = (engine.params, i32(B, spec.bucket), i32(B, spec.bucket),
-            i32(B, spec.sfx_a), i32(B, spec.sfx_a),
-            i32(B, spec.sfx_b), i32(B, spec.sfx_b),
-            i32(B), i32(B), i32(len(digit_ids)), f32(len(digit_vals)))
-    V = engine.cfg.vocab_size
-    kwargs = dict(
-        stop_mask_a=(i32(V) if spec.stops_armed else None),
-        stop_mask_b=(i32(V) if spec.stops_armed else None),
-        eos_id=(i32() if spec.stops_armed else None),
-    )
-    statics = dict(max_new_a=spec.new_tokens, max_new_b=spec.conf_tokens,
-                   topk=TOPK, prefill_fn=engine._prefill_fn,
-                   return_cache=True, decode_trunk=spec.decode_trunk)
+    if spec.trunk:
+        front = "cascade_paged" if spec.window else "cascade"
+    else:
+        front = "paged" if spec.window else "prefill"
+    grouped = spec.kind == "grouped"
+    tail = {}
     if spec.spec_k:
-        args = args + _spec_avals(engine, spec)
-        dk, ds = _spec_draft_kwargs(engine, spec)
-        kwargs.update(dk)
-        statics.update(_spec_statics(engine, spec), **ds)
-    return args, kwargs, statics
+        tail = dict(spec_k=spec.spec_k, ngram=int(engine.spec_cfg.ngram),
+                    draft_cfg=(engine._spec_draft[1] if spec.spec_draft
+                               else None))
+    return generate.Program(
+        front=front, layout="grouped" if grouped else "pair",
+        max_new=((spec.new_tokens,) if grouped
+                 else (spec.new_tokens, spec.conf_tokens)),
+        topk=TOPK, trunk=spec.trunk or spec.decode_trunk,
+        int8_qk=spec.cascade_int8,
+        prefill_fn=engine._prefill_fn if front == "prefill" else None,
+        return_cache=return_cache, **tail)
 
 
-def _avals_grouped(engine, spec: ShapeSpec):
+def dispatch_args(engine, spec: ShapeSpec,
+                  host: Optional[Dict[str, Any]] = None):
+    """The dispatch program's dynamic arguments (generate.DispatchArgs)
+    for ``spec``: from the dispatch's own arrays, ``host`` by name, when
+    the runner dispatches; from ``jax.ShapeDtypeStruct``s when the plan
+    lowers (``host`` None). ONE function for both, so a lowering can
+    never disagree with its call site; an array whose shape is not the
+    spec's raises here, before a program built for another shape could
+    be asked for. What the engine holds (stop tables, digit table, page
+    pool, a draft model's weights) it hands over itself."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    G, M = spec.groups, spec.batch
+    from . import generate
+
+    def leaf(name, shape, dtype=jnp.int32):
+        if host is None:
+            return jax.ShapeDtypeStruct(shape, dtype)
+        arr = jnp.asarray(host[name], dtype)
+        if arr.shape != shape:
+            raise ValueError(f"{spec.label}: argument {name!r} has shape "
+                             f"{arr.shape}, the program takes {shape}")
+        return arr
+
+    def held(tree):
+        if host is not None:
+            return tree
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(tuple(a.shape), a.dtype), tree)
+
+    grouped = spec.kind == "grouped"
+    M, S = spec.batch, spec.bucket
+    G = spec.groups if grouped else M
+    sfx = ((("sfx_a", spec.sfx_a),) if grouped
+           else (("sfx_a", spec.sfx_a), ("sfx_b", spec.sfx_b)))
     digit_ids, digit_vals = engine.digit_table
-    args = (engine.params, i32(G, spec.bucket), i32(G, spec.bucket),
-            i32(M, spec.sfx_a), i32(M, spec.sfx_a), i32(M),
-            i32(M), i32(M), i32(len(digit_ids)), f32(len(digit_vals)))
-    V = engine.cfg.vocab_size
-    armed = spec.stops_armed
-    kwargs = dict(
-        stop_mask=(i32(V) if armed else None),
-        stop_mask2=(i32(V) if armed else None),
-        stop_sel=(jax.ShapeDtypeStruct((M,), jnp.bool_) if armed else None),
-        eos_id=(i32() if armed else None),
-    )
-    statics = dict(max_new=spec.new_tokens, topk=TOPK,
-                   prefill_fn=engine._prefill_fn, return_cache=True)
-    return args, kwargs, statics
-
-
-def _pool_avals(engine):
-    """ShapeDtypeStruct tree of the engine's page-pool leaves (the paged
-    executables bind the pool as an ordinary pytree argument)."""
-    import jax
-
-    pool = engine.prefix_cache.pool
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(tuple(a.shape), a.dtype),
-        pool.leaves)
-
-
-def _avals_shared_paged(engine, spec: ShapeSpec):
-    """Avals for runner.decode_fused_shared's PAGED call into
-    generate.greedy_decode_fused_shared_paged (prefix-cache resume):
-    (params, pool, slot_src, win_start, prefix_mask, rem, rem_mask,
-    sfx..x4, yes, no, digit_ids, digit_vals)."""
-    import jax
-    import jax.numpy as jnp
-
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    B, W = spec.batch, spec.window
-    digit_ids, digit_vals = engine.digit_table
-    args = (engine.params, _pool_avals(engine),
-            i32(B, spec.bucket), i32(), i32(B, spec.bucket),
-            i32(B, W), i32(B, W),
-            i32(B, spec.sfx_a), i32(B, spec.sfx_a),
-            i32(B, spec.sfx_b), i32(B, spec.sfx_b),
-            i32(B), i32(B), i32(len(digit_ids)), f32(len(digit_vals)))
-    V = engine.cfg.vocab_size
-    kwargs = dict(
-        stop_mask_a=(i32(V) if spec.stops_armed else None),
-        stop_mask_b=(i32(V) if spec.stops_armed else None),
-        eos_id=(i32() if spec.stops_armed else None),
-    )
-    statics = dict(max_new_a=spec.new_tokens, max_new_b=spec.conf_tokens,
-                   topk=TOPK, return_cache=True,
-                   decode_trunk=spec.decode_trunk)
+    paged = None
+    if spec.window:
+        # Over a cascade trunk the front resumes ONE row at the trunk's
+        # extent; otherwise every prefix row at the bucket's.
+        rows, ext = (1, spec.trunk) if spec.trunk else (G, S)
+        paged = generate.PagedFront(
+            pool=held(engine.prefix_cache.pool.leaves),
+            slot_src=leaf("slot_src", (rows, ext)),
+            win_start=leaf("win_start", ()),
+            rem=leaf("rem", (rows, spec.window)),
+            rem_mask=leaf("rem_mask", (rows, spec.window)))
+    stops = None
+    if spec.stops_armed:
+        # Member rows of a grouped batch lie [bin, conf] per cell: the
+        # odd rows read the digit table.
+        stops = generate.Stops(
+            binary=held(engine.eos_stop_mask),
+            digits=held(engine.digit_stop_mask),
+            eos_id=held(jnp.int32(engine.eos_id)),
+            sel=(held(jnp.asarray(np.arange(M) % 2 == 1)) if grouped
+                 else None))
+    drafts = None
     if spec.spec_k:
-        args = args + _spec_avals(engine, spec)
-        statics.update(_spec_statics(engine, spec))
-    return args, kwargs, statics
-
-
-def _avals_shared_cascade(engine, spec: ShapeSpec):
-    """Avals for runner.decode_fused_shared's cascade call into
-    generate.greedy_decode_fused_shared_cascade: the dense shared
-    layout with the trunk extent baked static (``spec.trunk``)."""
-    import jax
-    import jax.numpy as jnp
-
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    B = spec.batch
-    digit_ids, digit_vals = engine.digit_table
-    args = (engine.params, i32(B, spec.bucket), i32(B, spec.bucket),
-            i32(B, spec.sfx_a), i32(B, spec.sfx_a),
-            i32(B, spec.sfx_b), i32(B, spec.sfx_b),
-            i32(B), i32(B), i32(len(digit_ids)), f32(len(digit_vals)))
-    V = engine.cfg.vocab_size
-    kwargs = dict(
-        stop_mask_a=(i32(V) if spec.stops_armed else None),
-        stop_mask_b=(i32(V) if spec.stops_armed else None),
-        eos_id=(i32() if spec.stops_armed else None),
-    )
-    statics = dict(max_new_a=spec.new_tokens, max_new_b=spec.conf_tokens,
-                   trunk_len=spec.trunk, topk=TOPK,
-                   int8_qk=spec.cascade_int8, return_cache=True)
-    return args, kwargs, statics
-
-
-def _avals_shared_cascade_paged(engine, spec: ShapeSpec):
-    """Avals for the warm-trunk cascade call into
-    generate.greedy_decode_fused_shared_cascade_paged: a batch-1 paged
-    front (slot table + recompute window over the TRUNK extent) ahead
-    of the dense shared layout."""
-    import jax
-    import jax.numpy as jnp
-
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    B, W, Tt = spec.batch, spec.window, spec.trunk
-    digit_ids, digit_vals = engine.digit_table
-    args = (engine.params, _pool_avals(engine),
-            i32(1, Tt), i32(), i32(1, Tt),
-            i32(1, W), i32(1, W),
-            i32(B, spec.bucket), i32(B, spec.bucket),
-            i32(B, spec.sfx_a), i32(B, spec.sfx_a),
-            i32(B, spec.sfx_b), i32(B, spec.sfx_b),
-            i32(B), i32(B), i32(len(digit_ids)), f32(len(digit_vals)))
-    V = engine.cfg.vocab_size
-    kwargs = dict(
-        stop_mask_a=(i32(V) if spec.stops_armed else None),
-        stop_mask_b=(i32(V) if spec.stops_armed else None),
-        eos_id=(i32() if spec.stops_armed else None),
-    )
-    statics = dict(max_new_a=spec.new_tokens, max_new_b=spec.conf_tokens,
-                   trunk_len=spec.trunk, topk=TOPK,
-                   int8_qk=spec.cascade_int8, return_cache=True)
-    return args, kwargs, statics
-
-
-def _avals_grouped_paged(engine, spec: ShapeSpec):
-    import jax
-    import jax.numpy as jnp
-
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    G, M, W = spec.groups, spec.batch, spec.window
-    digit_ids, digit_vals = engine.digit_table
-    args = (engine.params, _pool_avals(engine),
-            i32(G, spec.bucket), i32(), i32(G, spec.bucket),
-            i32(G, W), i32(G, W),
-            i32(M, spec.sfx_a), i32(M, spec.sfx_a), i32(M),
-            i32(M), i32(M), i32(len(digit_ids)), f32(len(digit_vals)))
-    V = engine.cfg.vocab_size
-    armed = spec.stops_armed
-    kwargs = dict(
-        stop_mask=(i32(V) if armed else None),
-        stop_mask2=(i32(V) if armed else None),
-        stop_sel=(jax.ShapeDtypeStruct((M,), jnp.bool_) if armed else None),
-        eos_id=(i32() if armed else None),
-    )
-    statics = dict(max_new=spec.new_tokens, topk=TOPK, return_cache=True)
-    return args, kwargs, statics
+        budgets = (("a", spec.sfx_a, spec.new_tokens),
+                   ("b", spec.sfx_b, spec.conf_tokens))
+        drafts = generate.Drafts(
+            ctx=tuple(leaf(f"ctx_{b}", (M, S + w + n))
+                      for b, w, n in budgets),
+            ctx_len=tuple(leaf(f"ctx_{b}_len", (M,)) for b, _, _ in budgets),
+            tokens=tuple(leaf(f"draft_{b}", (M, n)) for b, _, n in budgets),
+            lens=tuple(leaf(f"draft_{b}_len", (M,)) for b, _, _ in budgets),
+            params=(held(engine._spec_draft[0]) if spec.spec_draft
+                    else None))
+    return generate.DispatchArgs(
+        prefix_mask=leaf("prefix_mask", (G, S)),
+        sfx=tuple(leaf(name, (M, w)) for name, w in sfx),
+        sfx_mask=tuple(leaf(f"{name}_mask", (M, w)) for name, w in sfx),
+        yes_ids=leaf("yes_ids", (M,)), no_ids=leaf("no_ids", (M,)),
+        digit_ids=held(jnp.asarray(digit_ids, jnp.int32)),
+        digit_vals=held(jnp.asarray(digit_vals, jnp.float32)),
+        # The paged front binds slot tables, not tokens; a cascade front
+        # still extends the rows' remainders from them.
+        prefix=(leaf("prefix", (G, S)) if spec.trunk or not spec.window
+                else None),
+        paged=paged,
+        group_idx=leaf("group_idx", (M,)) if grouped else None,
+        stops=stops, drafts=drafts)
 
 
 def _avals_piggy(engine, spec: ShapeSpec):
@@ -699,33 +413,15 @@ def _lower(engine, spec: ShapeSpec):
         args, kwargs, statics = _avals_piggy(engine, spec)
         return fn.lower(engine.params, engine.cfg, *args, **kwargs,
                         **statics)
-    if spec.kind == "shared":
-        fn = (generate.greedy_decode_fused_shared_spec if spec.spec_k
-              else generate.greedy_decode_fused_shared)
-        args, kwargs, statics = _avals_shared(engine, spec)
-    elif spec.kind == "shared_cascade":
-        fn = generate.greedy_decode_fused_shared_cascade
-        args, kwargs, statics = _avals_shared_cascade(engine, spec)
-    elif spec.kind == "shared_cascade_paged":
-        fn = generate.greedy_decode_fused_shared_cascade_paged
-        args, kwargs, statics = _avals_shared_cascade_paged(engine, spec)
-    elif spec.kind == "shared_paged":
-        fn = (generate.greedy_decode_fused_shared_paged_spec
-              if spec.spec_k else generate.greedy_decode_fused_shared_paged)
-        args, kwargs, statics = _avals_shared_paged(engine, spec)
-    elif spec.kind == "grouped_paged":
-        fn = generate.greedy_decode_fused_grouped_paged
-        args, kwargs, statics = _avals_grouped_paged(engine, spec)
-    else:
-        fn = generate.greedy_decode_fused_grouped
-        args, kwargs, statics = _avals_grouped(engine, spec)
+    fn = generate.greedy_decode_dispatch
+    program = dispatch_program(engine, spec)
+    args = dispatch_args(engine, spec)
     scratch = None
     if spec.scratch:
-        out_shape = fn.eval_shape(args[0], engine.cfg, *args[1:],
-                                  scratch_cache=None, **kwargs, **statics)
-        scratch = out_shape[-1]  # the returned final cache's aval tree
-    return fn.lower(args[0], engine.cfg, *args[1:],
-                    scratch_cache=scratch, **kwargs, **statics)
+        scratch = fn.eval_shape(engine.params, engine.cfg, program,
+                                args)[2]   # the returned cache's avals
+    return fn.lower(engine.params, engine.cfg, program, args,
+                    scratch_cache=scratch)
 
 
 # Process-wide executable cache: the AOT analogue of jit's in-memory
@@ -970,15 +666,12 @@ def precompile_async(engine, specs: Sequence[ShapeSpec],
     return registry
 
 
-def registry_call(compiled, args: Tuple, kwargs: Dict[str, Any],
-                  scratch_cache):
-    """Invoke a registry executable with the canonical argument layout.
-
-    AOT-compiled functions take only the DYNAMIC arguments (static
-    cfg/budgets/flags were baked in at lower time), with the same
-    positional/keyword split the lowering used — args positional minus
-    cfg, stop args + scratch_cache by keyword."""
-    return compiled(*args, scratch_cache=scratch_cache, **kwargs)
+def registry_call(run, params, args, scratch_cache):
+    """Invoke a dispatch program — a registry executable, or the lazily
+    jitted function with its statics bound — on the canonical argument
+    layout. ``scratch_cache`` is DONATED: the caller's binding is dead
+    afterwards (lint/donation.py knows this call by name)."""
+    return run(params, args, scratch_cache=scratch_cache)
 
 
 def sweep_specs_for_ladder(engine, sfx_buckets: Sequence[int] = (8, 16),
@@ -1003,44 +696,20 @@ def sweep_specs_for_ladder(engine, sfx_buckets: Sequence[int] = (8, 16),
                    else min(rt.sweep_confidence_tokens, rt.max_new_tokens))
     stops_armed = (rt.sweep_early_stop and not rt.sweep_full_completions
                    and engine.digit_stop_mask is not None)
-    windows = ()
-    if getattr(engine, "prefix_cache", None) is not None:
-        from ..models import paged as paged_mod
-
-        windows = lambda b: paged_mod.window_edges(  # noqa: E731
-            b, engine.prefix_cache.page_size)
-    sk = 0
-    sdraft = False
-    if getattr(engine, "spec_supported", lambda: False)():
-        sk = rt.spec_k
-        sdraft = getattr(engine, "_spec_draft", None) is not None
-    specs = []
+    specs: List[ShapeSpec] = []
     for bucket in engine.buckets:
         for sfx in sfx_buckets:
             for batch in (batches if batches is not None
                           else (rt.batch_size,)):
+                # No rows yet, so no trunk: the route plans the dense
+                # program, its speculative sibling, and one block-table
+                # variant per remainder-window edge — a warm serve
+                # dispatch resuming from the radix cache never pays a
+                # trace either.
+                route = engine.route("shared", bucket, batch, 0, sfx, sfx,
+                                     new_tokens, conf_tokens, stops_armed)
                 for scratch in (False, True):
-                    specs.append(shared_spec(
-                        bucket, batch, sfx, sfx, new_tokens,
-                        conf_tokens, stops_armed, scratch))
-                    if sk:
-                        specs.append(shared_spec(
-                            bucket, batch, sfx, sfx, new_tokens,
-                            conf_tokens, stops_armed, scratch,
-                            spec_k=sk, spec_draft=sdraft))
-                    if windows:
-                        # Block-table variants: one per remainder-window
-                        # edge, so a warm serve dispatch resuming from
-                        # the radix cache never pays a trace either.
-                        for w in windows(bucket):
-                            specs.append(shared_paged_spec(
-                                bucket, batch, w, sfx, sfx, new_tokens,
-                                conf_tokens, stops_armed, scratch))
-                            if sk and not sdraft:
-                                specs.append(shared_paged_spec(
-                                    bucket, batch, w, sfx, sfx,
-                                    new_tokens, conf_tokens, stops_armed,
-                                    scratch, spec_k=sk))
+                    specs.extend(route.planned(scratch))
     return specs
 
 

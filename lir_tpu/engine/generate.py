@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Tuple
+from typing import Any, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -290,78 +290,6 @@ def greedy_decode_fused(params, cfg: ModelConfig, tokens: jax.Array,
     return out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "max_new", "topk", "prefill_fn",
-                                    "return_cache"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_grouped(params, cfg: ModelConfig, prefix: jax.Array,
-                                prefix_mask: jax.Array, sfx: jax.Array,
-                                sfx_mask: jax.Array, group_idx: jax.Array,
-                                yes_ids: jax.Array, no_ids: jax.Array,
-                                digit_ids: jax.Array, digit_vals: jax.Array,
-                                max_new: int, topk: int = 20,
-                                prefill_fn=None, stop_mask: jax.Array = None,
-                                stop_mask2: jax.Array = None,
-                                stop_sel: jax.Array = None,
-                                eos_id: jax.Array = None,
-                                return_cache: bool = False,
-                                scratch_cache=None):
-    """M fused greedy decodes sharing G <= M prefix prefills (cross-cell
-    prefix reuse).
-
-    Generalizes :func:`greedy_decode_fused_shared` from "two formats of one
-    row share that row's prefill" to "any member rows whose prompts share a
-    token prefix share ONE prefill": the ragged scheduler groups grid cells
-    whose tokenized prompts agree on a long prefix (all the sweep formats x
-    rephrasings of one base prompt, when the rephrasings preserve the
-    opening tokens), prefills each distinct prefix once as a (G, S)
-    RIGHT-padded batch (the canonical slot == position layout — see
-    greedy_decode_fused_shared), and ``group_idx`` (M,) maps each member
-    row to its prefix. The member suffixes (M, S2) RIGHT-padded then run one chunked
-    teacher-forced extension over the row-gathered cache, followed by the
-    fused scan. Prefill FLOPs drop by the group fan-out M/G; the gathered
-    M-row cache is the same size the ungrouped path allocates.
-
-    ``stop_mask``/``stop_mask2``/``stop_sel`` give per-row stop tables (the
-    mixed-format batch runs EOS-only stops on binary rows and the digit
-    stop on confidence rows — see _fused_tail). The pairwise special case
-    (G rows, 2 members each, ``group_idx = [0, 0, 1, 1, ...]``) scores
-    identically to greedy_decode_fused_shared (pinned by
-    tests/test_scheduler.py).
-
-    ``return_cache=True`` additionally returns the scan's final KV cache;
-    ``scratch_cache`` (DONATED) accepts the previous same-shape dispatch's
-    returned cache so XLA writes this dispatch's cache into the same HBM
-    block — one cache buffer then serves an entire bucket queue instead of
-    an alloc/free per dispatch (see runner._CacheHandoff). Results never
-    depend on the scratch contents: prefill overwrites every slot and
-    attention is masked by ``cache_mask`` regardless.
-    """
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    G, S = prefix.shape
-    M, S2 = sfx.shape
-    T0 = cache_extent(cfg, S + S2 + max_new, M)
-    pf = prefill_fn or decoder.prefill
-    _, gcache, _ = pf(params, cfg, prefix, prefix_mask, T0)
-
-    from ..models import cache as cache_mod
-
-    cache = cache_mod.gather_rows(gcache, group_idx)
-    pm = jnp.take(prefix_mask, group_idx, axis=0)              # (M, S)
-    cm = jnp.concatenate(
-        [pm, sfx_mask, jnp.zeros((M, T0 - S - S2), pm.dtype)], axis=1)
-    logits_l, cache2, pos = decoder.extend(
-        params, cfg, cache, sfx, sfx_mask, cm, S)
-    out, cache_f = _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
-                               yes_ids, no_ids, digit_ids, digit_vals,
-                               max_new, topk, stop_mask=stop_mask,
-                               eos_id=eos_id, stop_mask2=stop_mask2,
-                               stop_sel=stop_sel)
-    if return_cache:
-        return out, cache_f
-    return out
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "prefill_fn"))
 def prefill_cache(params, cfg: ModelConfig, tokens: jax.Array,
                   attn_mask: jax.Array, prefill_fn=None):
@@ -383,26 +311,28 @@ def prefill_cache(params, cfg: ModelConfig, tokens: jax.Array,
 
 
 @jax.named_scope("lir.prefill")
-def _paged_prefix(params, cfg: ModelConfig, pool, slot_src: jax.Array,
-                  win_start: jax.Array, prefix_mask: jax.Array,
-                  rem: jax.Array, rem_mask: jax.Array, total_len: int):
+def _paged_prefix(params, cfg: ModelConfig, paged: "PagedFront",
+                  prefix_mask: jax.Array, total_len: int):
     """The paged replacement for the shared-prefill step, EXACT-LAYOUT:
     assemble the cached prefix KV from the page pool (models/paged.
-    gather_slots over ``slot_src`` (B, S)) and teacher-force the
+    gather_slots over ``paged.slot_src`` (B, S)) and teacher-force the
     recompute WINDOW — slots [w0, w0 + R), each row's prefix tokens in
-    that range RIGHT-padded into ``rem``/``rem_mask`` (B, R) — via one
-    chunked extension over the S-slot cache view (decoder.extend at
-    start_index = ``win_start``, a TRACED scalar: the window is anchored
-    at the dispatch's longest real row, not the bucket edge, so rows
-    shorter than the bucket never pay recompute FLOPs for pad slots —
-    and the anchor varies per dispatch without retracing). A dispatch
+    that range RIGHT-padded into ``paged.rem``/``rem_mask`` (B, R) — via
+    one chunked extension over the S-slot cache view (decoder.extend at
+    start_index = ``paged.win_start``, a TRACED scalar: the window is
+    anchored at the dispatch's longest real row, not the bucket edge, so
+    rows shorter than the bucket never pay recompute FLOPs for pad slots
+    — and the anchor varies per dispatch without retracing). A dispatch
     then pays prefill FLOPs for R tokens per row instead of the whole
-    bucket.
+    bucket. At one row over a cascade trunk (``total_len`` == the trunk:
+    no tail pad) a warm trunk costs no quadratic recompute at all.
 
-    The layout discipline is what buys bitwise parity with the unpaged
-    path (pinned by tests/test_prefix_cache.py):
+    The layout discipline is what buys parity with the unpaged path —
+    every paged slot bitwise, the window's to the last bits a W-row
+    extension and an S-row prefill may differ by
+    (tests/test_prefix_cache.py):
 
-    - the shared-prefix paths RIGHT-pad their prefixes (slot == token
+    - the dispatch programs RIGHT-pad their prefixes (slot == token
       position, runner.decode_fused_shared), so a token's slot — and
       hence the reduction layout that computes its KV — is independent
       of its row's length: pages produced under any row back any later
@@ -425,9 +355,10 @@ def _paged_prefix(params, cfg: ModelConfig, pool, slot_src: jax.Array,
 
     decoder.refuse_recurrent(cfg, "the paged prefix path")
     S = prefix_mask.shape[1]
-    cache = paged_mod.gather_slots(pool, slot_src)          # S-slot view
-    _, cache, _ = decoder.extend(params, cfg, cache, rem, rem_mask,
-                                 prefix_mask, win_start)
+    cache = paged_mod.gather_slots(paged.pool, paged.slot_src)  # S slots
+    _, cache, _ = decoder.extend(params, cfg, cache, paged.rem,
+                                 paged.rem_mask, prefix_mask,
+                                 paged.win_start)
 
     def pad_leaf(a):
         pad = [(0, 0)] * a.ndim
@@ -435,277 +366,6 @@ def _paged_prefix(params, cfg: ModelConfig, pool, slot_src: jax.Array,
         return jnp.pad(a, pad)
 
     return jax.tree.map(pad_leaf, cache)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
-                                    "return_cache", "decode_trunk"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_shared_paged(params, cfg: ModelConfig, pool,
-                                     slot_src: jax.Array,
-                                     win_start: jax.Array,
-                                     prefix_mask: jax.Array, rem: jax.Array,
-                                     rem_mask: jax.Array, sfx_a: jax.Array,
-                                     sfx_a_mask: jax.Array, sfx_b: jax.Array,
-                                     sfx_b_mask: jax.Array,
-                                     yes_ids: jax.Array, no_ids: jax.Array,
-                                     digit_ids: jax.Array,
-                                     digit_vals: jax.Array, max_new_a: int,
-                                     max_new_b: int, topk: int = 20,
-                                     stop_mask_b: jax.Array = None,
-                                     stop_mask_a: jax.Array = None,
-                                     eos_id: jax.Array = None,
-                                     return_cache: bool = False,
-                                     decode_trunk: int = 0,
-                                     scratch_cache=None):
-    """:func:`greedy_decode_fused_shared` resuming from the cross-request
-    radix prefix cache: the quadratic prefill over each row's shared
-    binary/confidence prefix is replaced by a page-pool slot gather plus
-    one chunked extension over the per-row remainder window
-    (:func:`_paged_prefix`); the two format-suffix branches and the
-    fused scans are the unpaged path's own code at the unpaged path's
-    own shapes, which is what makes paged results BITWISE-identical to
-    the contiguous-cache path per request (pinned by
-    tests/test_prefix_cache.py). ``return_cache`` also returns the final
-    cache — callers feed it back into the pool (page insertion) and the
-    donation chain (its shape equals the unpaged path's, so cold and
-    warm dispatches share one donated buffer)."""
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    B, S = prefix_mask.shape
-    S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = cache_extent(cfg, S + max(S2a + max_new_a, S2b + max_new_b), B)
-    cache = _paged_prefix(params, cfg, pool, slot_src, win_start,
-                          prefix_mask, rem, rem_mask, T0)
-
-    empty_ids = jnp.zeros((0,), jnp.int32)
-    empty_vals = jnp.zeros((0,), jnp.float32)
-
-    def branch(cache_in, sfx, sfx_mask, new_tokens, d_ids, d_vals,
-               stop_mask=None):
-        S2 = sfx.shape[1]
-        cm = jnp.concatenate(
-            [prefix_mask, sfx_mask,
-             jnp.zeros((B, T0 - S - S2), prefix_mask.dtype)], axis=1)
-        logits_l, cache2, pos = decoder.extend(
-            params, cfg, cache_in, sfx, sfx_mask, cm, S)
-        return _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
-                           yes_ids, no_ids, d_ids, d_vals, new_tokens, topk,
-                           stop_mask=stop_mask, eos_id=eos_id,
-                           decode_trunk=decode_trunk)
-
-    out_a, cache_a = branch(cache, sfx_a, sfx_a_mask, max_new_a,
-                            empty_ids, empty_vals, stop_mask=stop_mask_a)
-    # B runs on A's K/V buffers (rewound by its mask) but starts from the
-    # recurrent state of the prefix's end, which `cache` still holds.
-    out_b, cache_b = branch(decoder.rewind(cache_a, cache), sfx_b,
-                            sfx_b_mask, max_new_b,
-                            digit_ids, digit_vals, stop_mask=stop_mask_b)
-    if return_cache:
-        return out_a, out_b, cache_b
-    return out_a, out_b
-
-
-def _cascade_branches(params, cfg: ModelConfig, tcache, trunk_len: int,
-                      prefix, prefix_mask, sfx_a, sfx_a_mask, sfx_b,
-                      sfx_b_mask, yes_ids, no_ids, digit_ids, digit_vals,
-                      max_new_a: int, max_new_b: int, topk: int,
-                      int8_qk: bool, stop_mask_b, stop_mask_a, eos_id,
-                      return_cache: bool):
-    """Shared tail of the cold/paged cascade variants: cascade-extend the
-    per-row remainders over the (L, K, trunk_len, 1, hd) trunk cache,
-    then run the two format branches as the dense shared path's OWN code
-    at its own shapes — which is what makes the cascade argmax-identical
-    to :func:`greedy_decode_fused_shared` (the PR-7 parity bar, pinned
-    by tests/test_cascade.py) and lets cold/warm cascade dispatches share
-    the dense path's donated cache buffer (same cache aval)."""
-    B, S = prefix.shape
-    S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = cache_extent(cfg, S + max(S2a + max_new_a, S2b + max_new_b), B)
-    # Static trunk split: slots [0, trunk_len) are the shared trunk
-    # (right-padded canonical layout — slot == position), the remainder
-    # is everything after, per row.
-    rem = prefix[:, trunk_len:]
-    rem_mask = prefix_mask[:, trunk_len:]
-    cache = decoder.cascade_extend(params, cfg, tcache, rem, rem_mask,
-                                   trunk_len, T0, int8_qk=int8_qk)
-
-    empty_ids = jnp.zeros((0,), jnp.int32)
-    empty_vals = jnp.zeros((0,), jnp.float32)
-
-    def branch(cache_in, sfx, sfx_mask, new_tokens, d_ids, d_vals,
-               stop_mask=None):
-        S2 = sfx.shape[1]
-        cm = jnp.concatenate(
-            [prefix_mask, sfx_mask,
-             jnp.zeros((B, T0 - S - S2), prefix_mask.dtype)], axis=1)
-        logits_l, cache2, pos = decoder.extend(
-            params, cfg, cache_in, sfx, sfx_mask, cm, S)
-        return _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
-                           yes_ids, no_ids, d_ids, d_vals, new_tokens, topk,
-                           stop_mask=stop_mask, eos_id=eos_id,
-                           decode_trunk=trunk_len)
-
-    out_a, cache_a = branch(cache, sfx_a, sfx_a_mask, max_new_a,
-                            empty_ids, empty_vals, stop_mask=stop_mask_a)
-    # B runs on A's K/V buffers (rewound by its mask) but starts from the
-    # recurrent state of the prefix's end, which `cache` still holds.
-    out_b, cache_b = branch(decoder.rewind(cache_a, cache), sfx_b,
-                            sfx_b_mask, max_new_b,
-                            digit_ids, digit_vals, stop_mask=stop_mask_b)
-    if return_cache:
-        return out_a, out_b, cache_b
-    return out_a, out_b
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "trunk_len", "max_new_a",
-                                    "max_new_b", "topk", "int8_qk",
-                                    "return_cache"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_shared_cascade(params, cfg: ModelConfig,
-                                       prefix: jax.Array,
-                                       prefix_mask: jax.Array,
-                                       sfx_a: jax.Array, sfx_a_mask: jax.Array,
-                                       sfx_b: jax.Array, sfx_b_mask: jax.Array,
-                                       yes_ids: jax.Array, no_ids: jax.Array,
-                                       digit_ids: jax.Array,
-                                       digit_vals: jax.Array,
-                                       max_new_a: int, max_new_b: int,
-                                       trunk_len: int, topk: int = 20,
-                                       int8_qk: bool = False,
-                                       stop_mask_b: jax.Array = None,
-                                       stop_mask_a: jax.Array = None,
-                                       eos_id: jax.Array = None,
-                                       return_cache: bool = False,
-                                       scratch_cache=None):
-    """:func:`greedy_decode_fused_shared` with the SHARED-TRUNK prefill
-    decomposed (ROADMAP item 1 / ops/cascade_prefill): every row of the
-    dispatch shares its first ``trunk_len`` tokens verbatim (the engine's
-    LCP gate, runner.decode_fused_shared), so the quadratic trunk prefill
-    runs ONCE at batch 1 instead of once per row, the per-row remainders
-    extend over it via cascade attention (prefix leg = one dense GEMM per
-    kv head against the shared trunk KV, suffix leg = causal window,
-    log-sum-exp merge), and the two format branches are the dense path's
-    own code. The dense path recomputes B x trunk_len^2 trunk attention;
-    this pays 1 x — the whole point of the cascade."""
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    # Trunk prefill at batch 1, EXACT trunk extent: row 0's first
-    # trunk_len tokens are byte-identical to every other row's (LCP), all
-    # real (trunk <= every row's real length), so mask is all-ones and
-    # slot t is position t — the layout cascade_extend assumes and the
-    # same layout the radix page pool stores, which is what makes the
-    # paged-warm trunk bitwise-identical to this cold one.
-    ones = jnp.ones((1, trunk_len), prefix_mask.dtype)
-    _, tcache, _ = decoder.prefill(params, cfg, prefix[:1, :trunk_len],
-                                   ones, trunk_len)
-    return _cascade_branches(params, cfg, tcache, trunk_len, prefix,
-                             prefix_mask, sfx_a, sfx_a_mask, sfx_b,
-                             sfx_b_mask, yes_ids, no_ids, digit_ids,
-                             digit_vals, max_new_a, max_new_b, topk, int8_qk,
-                             stop_mask_b, stop_mask_a, eos_id, return_cache)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "trunk_len", "max_new_a",
-                                    "max_new_b", "topk", "int8_qk",
-                                    "return_cache"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_shared_cascade_paged(params, cfg: ModelConfig, pool,
-                                             slot_src: jax.Array,
-                                             win_start: jax.Array,
-                                             trunk_mask: jax.Array,
-                                             trunk_rem: jax.Array,
-                                             trunk_rem_mask: jax.Array,
-                                             prefix: jax.Array,
-                                             prefix_mask: jax.Array,
-                                             sfx_a: jax.Array,
-                                             sfx_a_mask: jax.Array,
-                                             sfx_b: jax.Array,
-                                             sfx_b_mask: jax.Array,
-                                             yes_ids: jax.Array,
-                                             no_ids: jax.Array,
-                                             digit_ids: jax.Array,
-                                             digit_vals: jax.Array,
-                                             max_new_a: int, max_new_b: int,
-                                             trunk_len: int, topk: int = 20,
-                                             int8_qk: bool = False,
-                                             stop_mask_b: jax.Array = None,
-                                             stop_mask_a: jax.Array = None,
-                                             eos_id: jax.Array = None,
-                                             return_cache: bool = False,
-                                             scratch_cache=None):
-    """:func:`greedy_decode_fused_shared_cascade` with the TRUNK resumed
-    from the cross-request radix prefix cache: the batch-1 trunk prefill
-    becomes a page-pool slot gather plus one recompute-window extension
-    (:func:`_paged_prefix` at one row, ``total_len == trunk_len`` so no
-    tail pad) — a warm trunk costs ZERO quadratic recompute, the cascade's
-    headline win. The paged trunk cache is BITWISE the cold trunk prefill
-    (same exact-layout discipline tests/test_prefix_cache.py pins for the
-    shared path), so everything from cascade_extend on — and therefore
-    every output — is bitwise the cold cascade's."""
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    tcache = _paged_prefix(params, cfg, pool, slot_src, win_start,
-                           trunk_mask, trunk_rem, trunk_rem_mask, trunk_len)
-    return _cascade_branches(params, cfg, tcache, trunk_len, prefix,
-                             prefix_mask, sfx_a, sfx_a_mask, sfx_b,
-                             sfx_b_mask, yes_ids, no_ids, digit_ids,
-                             digit_vals, max_new_a, max_new_b, topk, int8_qk,
-                             stop_mask_b, stop_mask_a, eos_id, return_cache)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "max_new", "topk", "return_cache"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_grouped_paged(params, cfg: ModelConfig, pool,
-                                      slot_src: jax.Array,
-                                      win_start: jax.Array,
-                                      prefix_mask: jax.Array,
-                                      rem: jax.Array, rem_mask: jax.Array,
-                                      sfx: jax.Array, sfx_mask: jax.Array,
-                                      group_idx: jax.Array,
-                                      yes_ids: jax.Array, no_ids: jax.Array,
-                                      digit_ids: jax.Array,
-                                      digit_vals: jax.Array, max_new: int,
-                                      topk: int = 20,
-                                      stop_mask: jax.Array = None,
-                                      stop_mask2: jax.Array = None,
-                                      stop_sel: jax.Array = None,
-                                      eos_id: jax.Array = None,
-                                      return_cache: bool = False,
-                                      scratch_cache=None):
-    """:func:`greedy_decode_fused_grouped` resuming group prefixes from
-    the radix prefix cache: the (G, S) group prefill becomes a page-pool
-    slot gather plus one remainder-window extension
-    (:func:`_paged_prefix` at G rows, same exact-layout discipline as
-    the shared variant), then the member-row gather
-    (models/cache.gather_rows), suffix extension, and fused scan run as
-    the unpaged grouped path's own code at its own shapes. A sweep whose
-    prefix groups recur across dispatches (one base prompt's rephrasings
-    split across bucket queues, or a re-run grid on a warm engine) then
-    prefills each group prefix ONCE, not once per dispatch."""
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    G, S = prefix_mask.shape
-    M, S2 = sfx.shape
-    T0 = cache_extent(cfg, S + S2 + max_new, M)
-    gcache = _paged_prefix(params, cfg, pool, slot_src, win_start,
-                           prefix_mask, rem, rem_mask, T0)
-
-    from ..models import cache as cache_mod
-
-    cache = cache_mod.gather_rows(gcache, group_idx)
-    pm = jnp.take(prefix_mask, group_idx, axis=0)              # (M, S)
-    cm = jnp.concatenate(
-        [pm, sfx_mask, jnp.zeros((M, T0 - S - S2), pm.dtype)], axis=1)
-    logits_l, cache2, pos = decoder.extend(
-        params, cfg, cache, sfx, sfx_mask, cm, S)
-    out, cache_f = _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
-                               yes_ids, no_ids, digit_ids, digit_vals,
-                               max_new, topk, stop_mask=stop_mask,
-                               eos_id=eos_id, stop_mask2=stop_mask2,
-                               stop_sel=stop_sel)
-    if return_cache:
-        return out, cache_f
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1050,187 +710,322 @@ def _spec_tail(params, cfg: ModelConfig, logits0: jax.Array, cache,
     return out, carry["cache"], carry.get("dcache"), spec
 
 
-def _shared_spec_branches(params, cfg: ModelConfig, cache, dcache,
-                          prefix_mask, sfx_a, sfx_a_mask, sfx_b, sfx_b_mask,
-                          yes_ids, no_ids, digit_ids, digit_vals,
-                          ctx_a, ctx_a_len, draft_a, draft_a_len,
-                          ctx_b, ctx_b_len, draft_b, draft_b_len,
-                          T0: int, max_new_a: int, max_new_b: int,
-                          spec_k: int, ngram: int, topk: int,
-                          stop_mask_a, stop_mask_b, eos_id,
-                          draft_params, draft_cfg, return_cache: bool,
-                          decode_trunk: int = 0):
-    """Both format branches of a shared-prefix dispatch through the
-    speculative tail — branch B consumes branch A's cache buffer exactly
-    as the sequential path does (masks keep the branches disjoint).
+# ---------------------------------------------------------------------------
+# The dispatch program: front -> layout -> branches
+# ---------------------------------------------------------------------------
 
-    The suffix extension that produces each branch's position-0 logits
-    runs over a cache VIEW truncated to the SEQUENTIAL path's extent
-    (``T0_seq``), its suffix k/v written back into the full speculative
-    cache afterward: reduction lane grouping follows the attention
-    extent, so extending at the inflated spec extent would wobble the
-    position-0 readouts' low bits — truncation keeps the whole CONSUMED
-    readout surface bitwise the sequential path's, and only the verify
-    windows (whose interior floats are tolerance-bound anyway) reduce
-    at the longer extent."""
+@dataclasses.dataclass(frozen=True)
+class Program:
+    """The ONE static description of a dispatch program
+    (:func:`greedy_decode_dispatch`): which front fills the prefix's
+    cache, how member rows lie on it, which tail decodes. Hashable, so
+    it is the jit's static argument and, with the model config, all
+    that selects a lowering beside the arguments' shapes.
+
+    ``front`` — how slots [0, S) of the cache come to be:
+    ``"prefill"`` (``prefill_fn`` or decoder.prefill over the prefix
+    tokens), ``"paged"`` (:func:`_paged_prefix`: page gather + window
+    extension; binds slot tables, not tokens), ``"cascade"`` (the first
+    ``trunk`` tokens, which every row shares, prefilled ONCE at batch 1,
+    the per-row remainders extended over them by decoder.cascade_extend)
+    or ``"cascade_paged"`` (that trunk resumed from the page pool at one
+    row instead).
+    ``layout`` — ``"pair"``: two format branches on one cache, B from
+    ``decoder.rewind`` of A's; ``"grouped"``: member rows gathered from
+    G prefix rows by ``group_idx``, one branch with per-row stop tables.
+    ``spec_k`` — 0: the sequential tail (:func:`_fused_tail`); >= 2: the
+    draft-and-verify tail (:func:`_spec_tail`) at that window, with
+    ``ngram`` and, for a fleet draft model, ``draft_cfg``.
+    ``trunk`` — the leading slots every row shares: the extent a cascade
+    front splits at, and for every front the extent the decode steps
+    dedup at (0: flat kernels).
+    ``max_new`` — one decode budget per branch."""
+
+    front: str = "prefill"
+    layout: str = "pair"
+    max_new: Tuple[int, ...] = (50, 50)
+    topk: int = 20
+    trunk: int = 0
+    int8_qk: bool = False
+    spec_k: int = 0
+    ngram: int = 2
+    draft_cfg: Any = None
+    prefill_fn: Any = None
+    return_cache: bool = False
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedFront:
+    """What a paged front resumes from: the page pool's leaves, each
+    row's source slot per cache slot (G, S), and the recompute window —
+    slots [win_start, win_start + R) with the rows' tokens there
+    right-padded into ``rem``/``rem_mask`` (G, R)."""
+
+    pool: Any
+    slot_src: jax.Array
+    win_start: jax.Array
+    rem: jax.Array
+    rem_mask: jax.Array
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class Stops:
+    """The early stop's tables (:func:`_fused_tail`): ``binary`` the
+    EOS-only classes, ``digits`` the digit-run classes, (V,) int32 each.
+    A pair gives branch A the first and branch B the second; a grouped
+    batch gives every row ``binary`` but those where ``sel`` (M,) bool
+    is set, which read ``digits``."""
+
+    binary: jax.Array
+    digits: jax.Array
+    eos_id: jax.Array
+    sel: Any = None
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class Drafts:
+    """A speculative tail's drafting inputs, one entry per branch:
+    ``ctx`` the compacted prompts (B, S + S2 + budget) valid below
+    ``ctx_len``; ``tokens`` the host-probed continuations (B, budget)
+    valid below ``lens``; ``params`` a fleet draft model's weights."""
+
+    ctx: Tuple[jax.Array, ...]
+    ctx_len: Tuple[jax.Array, ...]
+    tokens: Tuple[jax.Array, ...]
+    lens: Tuple[jax.Array, ...]
+    params: Any = None
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class DispatchArgs:
+    """Every dynamic argument of :func:`greedy_decode_dispatch`, one
+    pytree; a part the program does not take is None and so is absent
+    from the traced tree. Prefix rows are (G, S) RIGHT-padded (slot ==
+    token position); ``sfx``/``sfx_mask`` hold one RIGHT-padded (M, S2)
+    suffix per branch; ``yes_ids``/``no_ids`` are per member row."""
+
+    prefix_mask: jax.Array
+    sfx: Tuple[jax.Array, ...]
+    sfx_mask: Tuple[jax.Array, ...]
+    yes_ids: jax.Array
+    no_ids: jax.Array
+    digit_ids: jax.Array
+    digit_vals: jax.Array
+    prefix: Any = None        # tokens; None on the "paged" front
+    paged: Any = None         # PagedFront on the paged fronts
+    group_idx: Any = None     # (M,) member row -> prefix row, grouped
+    stops: Any = None
+    drafts: Any = None
+
+
+def dispatch_extent(cfg: ModelConfig, bucket: int, sfx: Sequence[int],
+                    max_new: Sequence[int], rows: int,
+                    spec_k: int = 0) -> int:
+    """Cache slots a dispatch program allocates: the prefix edge plus the
+    widest branch's suffix edge and decode region — one slot a step, or
+    ``spec_k`` a verify window (rejected tails stay masked) — through
+    :func:`cache_extent` at ``rows`` member rows."""
+    width = spec_k or 1
+    return cache_extent(
+        cfg, bucket + max(s + n * width for s, n in zip(sfx, max_new)), rows)
+
+
+def _front(params, cfg: ModelConfig, program: Program, args: DispatchArgs,
+           total_len: int):
+    """The cache with [0, total_len) allocated and the prefix's slots
+    [0, S) filled, by ``program.front``; beside it a fleet draft
+    model's cache of the same layout, or None."""
+    a = args
+    front = program.front
+    fleet = a.drafts is not None and a.drafts.params is not None
+    if fleet and front != "prefill":
+        raise ValueError(f"a fleet draft model needs prefix tokens to "
+                         f"prefill from; the {front!r} front has none")
+    if front == "prefill":
+        pf = program.prefill_fn or decoder.prefill
+        _, cache, _ = pf(params, cfg, a.prefix, a.prefix_mask, total_len)
+        dcache = None
+        if fleet:
+            _, dcache, _ = decoder.prefill(a.drafts.params,
+                                           program.draft_cfg, a.prefix,
+                                           a.prefix_mask, total_len)
+        return cache, dcache
+    if front == "paged":
+        return _paged_prefix(params, cfg, a.paged, a.prefix_mask,
+                             total_len), None
+    # Cascade: row 0's first `trunk` tokens are byte-identical to every
+    # other row's (the engine's LCP gate) and all real, so the trunk's
+    # mask is all-ones and slot t is position t — the layout
+    # cascade_extend assumes and the page pool stores. The quadratic
+    # trunk prefill runs ONCE at batch 1 at the EXACT trunk extent (or
+    # is gathered from the pool: no recompute at all); the dense path
+    # pays it once per row.
+    t = program.trunk
+    ones = jnp.ones((1, t), a.prefix_mask.dtype)
+    if front == "cascade":
+        _, tcache, _ = decoder.prefill(params, cfg, a.prefix[:1, :t], ones,
+                                       t)
+    elif front == "cascade_paged":
+        tcache = _paged_prefix(params, cfg, a.paged, ones, t)
+    else:
+        raise ValueError(f"unknown front {front!r}")
+    return decoder.cascade_extend(params, cfg, tcache, a.prefix[:, t:],
+                                  a.prefix_mask[:, t:], t, total_len,
+                                  int8_qk=program.int8_qk), None
+
+
+def _extend_suffix(params, cfg: ModelConfig, cache, prefix_mask, sfx,
+                   sfx_mask, at: int, total_len: int, view_len: int):
+    """Teacher-force one branch's RIGHT-padded suffix into cache slots
+    [at, at + S2) behind the prefix: the mask concat and the chunked
+    extension (decoder.extend) of every branch of every program.
+    Returns (first-position logits, cache, the branch's cache mask over
+    ``total_len`` slots, next decode positions).
+
+    The extension reduces over the first ``view_len`` slots only. A
+    speculative cache is longer than the sequential one (spec_k slots a
+    window); reduction lane grouping follows the attention extent, so
+    its suffixes extend over a VIEW cut to the sequential extent, their
+    k/v written back into the full cache afterwards — the position-0
+    readouts then stay bitwise the sequential program's."""
     B, S = prefix_mask.shape
-    empty_ids = jnp.zeros((0,), jnp.int32)
-    empty_vals = jnp.zeros((0,), jnp.float32)
-    T0_seq = cache_extent(cfg, S + max(sfx_a.shape[1] + max_new_a,
-                                       sfx_b.shape[1] + max_new_b), B)
+    S2 = sfx.shape[1]
+    zeros = functools.partial(jnp.zeros, dtype=prefix_mask.dtype)
+    gap = [zeros((B, at - S))] if at > S else []
 
-    def _extend_seq_extent(ext_params, ext_cfg, cache_in, sfx, sfx_mask):
-        S2 = sfx.shape[1]
-        cm_seq = jnp.concatenate(
-            [prefix_mask, sfx_mask,
-             jnp.zeros((B, T0_seq - S - S2), prefix_mask.dtype)], axis=1)
-        view = jax.tree.map(
-            lambda a: lax.slice_in_dim(a, 0, T0_seq, axis=2), cache_in)
-        logits_l, view2, pos = decoder.extend(
-            ext_params, ext_cfg, view, sfx, sfx_mask, cm_seq, S)
-        # Write only the suffix slots back — the extension touched
-        # nothing else.
-        cache2 = jax.tree.map(
-            lambda full, v: lax.dynamic_update_slice_in_dim(
-                full, lax.slice_in_dim(v, S, S + S2, axis=2), S, axis=2),
-            cache_in, view2)
-        return logits_l, cache2, pos
+    def mask(T):
+        return jnp.concatenate(
+            [prefix_mask] + gap + [sfx_mask, zeros((B, T - at - S2))],
+            axis=1)
 
-    def branch(cache_in, dcache_in, sfx, sfx_mask, new_tokens, d_ids,
-               d_vals, ctx, ctx_len, dr, dr_len, stop_mask):
-        S2 = sfx.shape[1]
-        cm = jnp.concatenate(
-            [prefix_mask, sfx_mask,
-             jnp.zeros((B, T0 - S - S2), prefix_mask.dtype)], axis=1)
-        logits_l, cache2, pos = _extend_seq_extent(
-            params, cfg, cache_in, sfx, sfx_mask)
-        dcache2 = None
-        if dcache_in is not None:
-            _, dcache2, _ = _extend_seq_extent(
-                draft_params, draft_cfg, dcache_in, sfx, sfx_mask)
-        return _spec_tail(
-            params, cfg, logits_l, cache2, cm, pos, S + S2, yes_ids,
-            no_ids, d_ids, d_vals, new_tokens, topk, spec_k, ctx, ctx_len,
-            dr, dr_len, stop_mask=stop_mask, eos_id=eos_id, ngram=ngram,
-            draft_params=draft_params, draft_cfg=draft_cfg, dcache=dcache2,
-            decode_trunk=decode_trunk)
-
-    out_a, cache_a, dcache_a, spec_a = branch(
-        cache, dcache, sfx_a, sfx_a_mask, max_new_a, empty_ids, empty_vals,
-        ctx_a, ctx_a_len, draft_a, draft_a_len, stop_mask_a)
-    out_b, cache_b, _, spec_b = branch(
-        cache_a, dcache_a, sfx_b, sfx_b_mask, max_new_b, digit_ids,
-        digit_vals, ctx_b, ctx_b_len, draft_b, draft_b_len, stop_mask_b)
-    if return_cache:
-        return out_a, out_b, spec_a, spec_b, cache_b
-    return out_a, out_b, spec_a, spec_b
+    cm = mask(total_len)
+    if view_len == total_len:
+        logits_l, cache2, pos = decoder.extend(params, cfg, cache, sfx,
+                                               sfx_mask, cm, at)
+        return logits_l, cache2, cm, pos
+    view = jax.tree.map(
+        lambda leaf: lax.slice_in_dim(leaf, 0, view_len, axis=2), cache)
+    logits_l, view2, pos = decoder.extend(params, cfg, view, sfx, sfx_mask,
+                                          mask(view_len), at)
+    # Write only the suffix slots back — the extension touched nothing
+    # else.
+    cache2 = jax.tree.map(
+        lambda full, v: lax.dynamic_update_slice_in_dim(
+            full, lax.slice_in_dim(v, at, at + S2, axis=2), at, axis=2),
+        cache, view2)
+    return logits_l, cache2, cm, pos
 
 
-def spec_total_len(cfg: ModelConfig, batch: int, bucket: int, sfx_a: int,
-                   sfx_b: int, max_new_a: int, max_new_b: int,
-                   spec_k: int) -> int:
-    """Cache length a speculative shared dispatch allocates: each of the
-    T decode windows owns spec_k slots (rejected tails stay masked), so
-    the decode region is budget * spec_k instead of budget — on the
-    decode kernel's grid like every dispatch cache (:func:`cache_extent`,
-    monotone, so never below the sequential extent the suffix extension
-    views)."""
-    return cache_extent(cfg, bucket + max(sfx_a + max_new_a * spec_k,
-                                          sfx_b + max_new_b * spec_k),
-                        batch)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
-                                    "spec_k", "ngram", "draft_cfg",
-                                    "prefill_fn", "return_cache",
-                                    "decode_trunk"),
+@functools.partial(jax.jit, static_argnames=("cfg", "program"),
                    donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_shared_spec(
-        params, cfg: ModelConfig, prefix: jax.Array, prefix_mask: jax.Array,
-        sfx_a: jax.Array, sfx_a_mask: jax.Array, sfx_b: jax.Array,
-        sfx_b_mask: jax.Array, yes_ids: jax.Array, no_ids: jax.Array,
-        digit_ids: jax.Array, digit_vals: jax.Array,
-        ctx_a: jax.Array, ctx_a_len: jax.Array, draft_a: jax.Array,
-        draft_a_len: jax.Array, ctx_b: jax.Array, ctx_b_len: jax.Array,
-        draft_b: jax.Array, draft_b_len: jax.Array,
-        max_new_a: int, max_new_b: int, spec_k: int, ngram: int = 2,
-        topk: int = 20, prefill_fn=None, stop_mask_b: jax.Array = None,
-        stop_mask_a: jax.Array = None, eos_id: jax.Array = None,
-        draft_params=None, draft_cfg: ModelConfig = None,
-        return_cache: bool = False, decode_trunk: int = 0,
-        scratch_cache=None):
-    """:func:`greedy_decode_fused_shared` with SPECULATIVE decode tails:
-    one shared-prefix prefill, two suffix extensions, then each branch's
-    sequential greedy scan is replaced by the draft-and-verify window
-    scan (:func:`_spec_tail` — per-row accept lengths, per-row stop
-    conditions, consumed results bitwise the sequential path's,
-    per-step float rows to tolerance). ``ctx_*`` carry
-    each branch's compacted prompt tokens for the in-scan n-gram
-    drafter; ``draft_*`` the host-probed radix-tree continuations;
-    ``draft_params``/``draft_cfg`` arm fleet-model drafting instead
-    (same tokenizer/vocab as the verifier — the engine enforces it).
-    Returns (binary out, confidence out, binary SpecOut, confidence
-    SpecOut[, final cache])."""
+def greedy_decode_dispatch(params, cfg: ModelConfig, program: Program,
+                           args: DispatchArgs, scratch_cache=None):
+    """THE dispatch program of the sweep and the server: every member
+    row's fused greedy decodes behind ONE fill of the shared prefix.
+
+    The perturbation sweep scores every grid cell under two formats
+    whose prompts differ only in a short trailing instruction; the
+    reference pays two full forward passes per cell. Here the shared
+    prefix is filled once by ``program.front``, then each branch's
+    suffix runs through a teacher-forced chunked extension at ~S2/S of
+    the prefill cost (:func:`_extend_suffix`), followed by its tail.
+
+    A later branch consumes the earlier branch's final cache buffer on
+    purpose: the earlier suffix and generated slots are overwritten or
+    masked (a branch's cache mask shows only prefix + its own suffix),
+    so XLA aliases the cache update in place instead of holding two
+    caches live. That is a rewind by mask, which K/V allows and a
+    recurrent state does not: a model with a state-space mixer starts
+    every branch from the SSM state and conv tail as they stood at the
+    prefix's end (decoder.rewind: one held snapshot, one working copy).
+
+    The last branch takes the digit table (weighted confidence); with
+    stops armed, a pair's first branch takes the EOS-only table (its
+    numeric readout is position 0 and its text is EOS-trimmed
+    downstream) and its second the digit stop; a grouped batch selects
+    per row (:class:`Stops`).
+
+    Returns ``(outs, specs, cache)``: one FusedDecodeOut per branch; one
+    SpecOut per branch from a speculative tail, else None; with
+    ``program.return_cache`` the final cache, else None. ``scratch_cache``
+    (DONATED) accepts a previous same-shape dispatch's returned cache so
+    XLA writes this one into the same HBM block — one buffer per chain
+    of a sweep instead of an alloc/free per dispatch
+    (models/paged.CacheHandoff; programs of one shape return one cache
+    aval whatever their front). Results never depend on its contents:
+    the front overwrites every slot and attention is masked by the
+    cache masks regardless."""
     del scratch_cache  # donated scratch: memory reuse only, never read
-    B, S = prefix.shape
-    S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = spec_total_len(cfg, B, S, S2a, S2b, max_new_a, max_new_b, spec_k)
-    pf = prefill_fn or decoder.prefill
-    _, cache, _ = pf(params, cfg, prefix, prefix_mask, T0)
-    dcache = None
-    if draft_params is not None:
-        _, dcache, _ = decoder.prefill(draft_params, draft_cfg, prefix,
-                                       prefix_mask, T0)
-    return _shared_spec_branches(
-        params, cfg, cache, dcache, prefix_mask, sfx_a, sfx_a_mask, sfx_b,
-        sfx_b_mask, yes_ids, no_ids, digit_ids, digit_vals,
-        ctx_a, ctx_a_len, draft_a, draft_a_len, ctx_b, ctx_b_len, draft_b,
-        draft_b_len, T0, max_new_a, max_new_b, spec_k, ngram, topk,
-        stop_mask_a, stop_mask_b, eos_id, draft_params, draft_cfg,
-        return_cache, decode_trunk=decode_trunk)
+    a = args
+    k = program.spec_k
+    S = a.prefix_mask.shape[1]
+    M = a.sfx[0].shape[0]
+    widths = [s.shape[1] for s in a.sfx]
+    T0 = dispatch_extent(cfg, S, widths, program.max_new, M, k)
+    T_seq = dispatch_extent(cfg, S, widths, program.max_new, M)
+    start, dstart = _front(params, cfg, program, a, T0)
+    pm = a.prefix_mask
+    if program.layout == "grouped":
+        if k:
+            raise ValueError("no speculative tail over per-row stop "
+                             "tables (a grouped batch)")
+        from ..models import cache as cache_mod
 
+        start = cache_mod.gather_rows(start, a.group_idx)
+        pm = jnp.take(pm, a.group_idx, axis=0)                 # (M, S)
+    elif program.layout != "pair":
+        raise ValueError(f"unknown layout {program.layout!r}")
 
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
-                                    "spec_k", "ngram", "return_cache",
-                                    "decode_trunk"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_shared_paged_spec(
-        params, cfg: ModelConfig, pool, slot_src: jax.Array,
-        win_start: jax.Array, prefix_mask: jax.Array, rem: jax.Array,
-        rem_mask: jax.Array, sfx_a: jax.Array, sfx_a_mask: jax.Array,
-        sfx_b: jax.Array, sfx_b_mask: jax.Array, yes_ids: jax.Array,
-        no_ids: jax.Array, digit_ids: jax.Array, digit_vals: jax.Array,
-        ctx_a: jax.Array, ctx_a_len: jax.Array, draft_a: jax.Array,
-        draft_a_len: jax.Array, ctx_b: jax.Array, ctx_b_len: jax.Array,
-        draft_b: jax.Array, draft_b_len: jax.Array,
-        max_new_a: int, max_new_b: int, spec_k: int, ngram: int = 2,
-        topk: int = 20, stop_mask_b: jax.Array = None,
-        stop_mask_a: jax.Array = None, eos_id: jax.Array = None,
-        return_cache: bool = False, decode_trunk: int = 0,
-        scratch_cache=None):
-    """Speculative decode over the radix-paged prefill front: cached
-    prefix pages gather from the pool and only the remainder window
-    recomputes (:func:`_paged_prefix`), then both branches run the
-    speculative tail — prefill savings AND decode savings on one warm
-    dispatch (self-drafting only: the paged executable binds slot
-    tables, not prefix tokens, so there is nothing for a draft model to
-    prefill from)."""
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    B, S = prefix_mask.shape
-    S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = spec_total_len(cfg, B, S, S2a, S2b, max_new_a, max_new_b, spec_k)
-    cache = _paged_prefix(params, cfg, pool, slot_src, win_start,
-                          prefix_mask, rem, rem_mask, T0)
-    return _shared_spec_branches(
-        params, cfg, cache, None, prefix_mask, sfx_a, sfx_a_mask, sfx_b,
-        sfx_b_mask, yes_ids, no_ids, digit_ids, digit_vals,
-        ctx_a, ctx_a_len, draft_a, draft_a_len, ctx_b, ctx_b_len, draft_b,
-        draft_b_len, T0, max_new_a, max_new_b, spec_k, ngram, topk,
-        stop_mask_a, stop_mask_b, eos_id, None, None, return_cache,
-        decode_trunk=decode_trunk)
-
+    stops = a.stops
+    outs, specs = [], []
+    cache, dcache = start, dstart
+    for i, (sfx, sfx_mask, budget) in enumerate(
+            zip(a.sfx, a.sfx_mask, program.max_new)):
+        if i:
+            cache = decoder.rewind(cache, start)
+            if dcache is not None:
+                dcache = decoder.rewind(dcache, dstart)
+        if i == len(a.sfx) - 1:
+            d_ids, d_vals = a.digit_ids, a.digit_vals
+        else:
+            d_ids = jnp.zeros((0,), jnp.int32)
+            d_vals = jnp.zeros((0,), jnp.float32)
+        stop_kw = {}
+        if stops is not None and stops.sel is not None:
+            stop_kw = dict(stop_mask=stops.binary, stop_mask2=stops.digits,
+                           stop_sel=stops.sel, eos_id=stops.eos_id)
+        elif stops is not None:
+            stop_kw = dict(stop_mask=(stops.binary, stops.digits)[i],
+                           eos_id=stops.eos_id)
+        slot0 = S + sfx.shape[1]
+        logits_l, cache, cm, pos = _extend_suffix(
+            params, cfg, cache, pm, sfx, sfx_mask, S, T0, T_seq)
+        if not k:
+            out, cache = _fused_tail(
+                params, cfg, logits_l, cache, cm, pos, slot0, a.yes_ids,
+                a.no_ids, d_ids, d_vals, budget, program.topk,
+                decode_trunk=program.trunk, **stop_kw)
+        else:
+            dr = a.drafts
+            if dcache is not None:
+                _, dcache, _, _ = _extend_suffix(
+                    dr.params, program.draft_cfg, dcache, pm, sfx,
+                    sfx_mask, S, T0, T_seq)
+            out, cache, dcache, spec = _spec_tail(
+                params, cfg, logits_l, cache, cm, pos, slot0, a.yes_ids,
+                a.no_ids, d_ids, d_vals, budget, program.topk, k,
+                dr.ctx[i], dr.ctx_len[i], dr.tokens[i], dr.lens[i],
+                ngram=program.ngram, draft_params=dr.params,
+                draft_cfg=program.draft_cfg, dcache=dcache,
+                decode_trunk=program.trunk, **stop_kw)
+            specs.append(spec)
+        outs.append(out)
+    return (tuple(outs), tuple(specs) if k else None,
+            cache if program.return_cache else None)
 
 # ---------------------------------------------------------------------------
 # Chunked prefill/decode piggybacking (Sarathi-Serve-style)
@@ -1278,17 +1073,11 @@ def _piggyback_extend(params, cfg: ModelConfig, prefix, prefix_mask,
     T = cache_extent(cfg, S + S2a + max_new_a + S2b + max_new_b, B)
     pf = prefill_fn or decoder.prefill
     _, cache, _ = pf(params, cfg, prefix, prefix_mask, T)
-    zeros = functools.partial(jnp.zeros, dtype=prefix_mask.dtype)
-    cm_a = jnp.concatenate(
-        [prefix_mask, sfx_a_mask, zeros((B, T - S - S2a))], axis=1)
-    logits_a, cache, pos_a = decoder.extend(
-        params, cfg, cache, sfx_a, sfx_a_mask, cm_a, S)
-    off_b = S + S2a + max_new_a
-    cm_b = jnp.concatenate(
-        [prefix_mask, zeros((B, S2a + max_new_a)), sfx_b_mask,
-         zeros((B, T - off_b - S2b))], axis=1)
-    logits_b, cache, pos_b = decoder.extend(
-        params, cfg, cache, sfx_b, sfx_b_mask, cm_b, off_b)
+    logits_a, cache, cm_a, pos_a = _extend_suffix(
+        params, cfg, cache, prefix_mask, sfx_a, sfx_a_mask, S, T, T)
+    logits_b, cache, cm_b, pos_b = _extend_suffix(
+        params, cfg, cache, prefix_mask, sfx_b, sfx_b_mask,
+        S + S2a + max_new_a, T, T)
     return PiggybackCarry(logits_a=logits_a, logits_b=logits_b, cache=cache,
                           cm_a=cm_a, cm_b=cm_b, pos_a=pos_a, pos_b=pos_b)
 
@@ -1374,97 +1163,6 @@ def shared_piggyback_drain(params, cfg: ModelConfig, carry: PiggybackCarry,
                            digit_vals, slot0_a, slot0_b, max_new_a,
                            max_new_b, topk, stop_mask_a, stop_mask_b,
                            eos_id)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "max_new_a", "max_new_b", "topk",
-                                    "prefill_fn", "return_cache",
-                                    "decode_trunk"),
-                   donate_argnames=("scratch_cache",), keep_unused=True)
-def greedy_decode_fused_shared(params, cfg: ModelConfig, prefix: jax.Array,
-                               prefix_mask: jax.Array, sfx_a: jax.Array,
-                               sfx_a_mask: jax.Array, sfx_b: jax.Array,
-                               sfx_b_mask: jax.Array, yes_ids: jax.Array,
-                               no_ids: jax.Array, digit_ids: jax.Array,
-                               digit_vals: jax.Array, max_new_a: int,
-                               max_new_b: int, topk: int = 20,
-                               prefill_fn=None, stop_mask_b: jax.Array = None,
-                               stop_mask_a: jax.Array = None,
-                               eos_id: jax.Array = None,
-                               return_cache: bool = False,
-                               decode_trunk: int = 0,
-                               scratch_cache=None):
-    """TWO fused greedy decodes sharing ONE prefill over a common prefix.
-
-    The perturbation sweep scores every grid cell under two formats whose
-    prompts differ only in a short trailing instruction (the rephrased legal
-    text is shared — perturb_prompts.py:728-734). The reference pays two
-    full forward passes per cell; here the shared prefix (B, S) RIGHT-padded
-    (slot == token position — the canonical layout that lets the
-    cross-request prefix cache reuse this prefill's KV pages bitwise
-    across rows of different lengths; pads are masked no-ops either way)
-    is prefilled once, then each format's suffix (B, S2*) RIGHT-padded is
-    run through a teacher-forced chunked-prefill extension
-    (decoder.extend) at ~S2/S of the prefill cost, followed by the fused
-    greedy scan. Device work per cell drops from 2 prefills to ~1.
-
-    Branch B consumes branch A's final cache buffer on purpose: A's suffix
-    and generated slots are overwritten/masked (branch B's cache_mask shows
-    only prefix + its own suffix), so XLA can alias the cache update
-    in place instead of holding two full KV caches live. That is a rewind
-    by mask, which K/V allows and a recurrent state does not: a model
-    with a state-space mixer starts B from the SSM state and conv tail
-    as they stood at the prefix's end (decoder.rewind: one held
-    snapshot, one working copy).
-
-    Returns (binary FusedDecodeOut, confidence FusedDecodeOut); the
-    confidence branch gets the digit table, the binary branch skips it.
-    ``return_cache=True`` appends the final KV cache to the return value;
-    ``scratch_cache`` (DONATED) accepts the previous same-shape dispatch's
-    cache so XLA writes this one into the same HBM block — one buffer per
-    (bucket, batch) shape for a whole sweep instead of an alloc/free per
-    dispatch (runner._CacheHandoff). Results never depend on the scratch
-    contents: prefill overwrites every slot and attention is masked by
-    the cache masks regardless.
-    """
-    del scratch_cache  # donated scratch: memory reuse only, never read
-    B, S = prefix.shape
-    S2a, S2b = sfx_a.shape[1], sfx_b.shape[1]
-    T0 = cache_extent(cfg, S + max(S2a + max_new_a, S2b + max_new_b), B)
-    pf = prefill_fn or decoder.prefill
-    _, cache, _ = pf(params, cfg, prefix, prefix_mask, T0)
-
-    empty_ids = jnp.zeros((0,), jnp.int32)
-    empty_vals = jnp.zeros((0,), jnp.float32)
-
-    def branch(cache_in, sfx, sfx_mask, new_tokens, d_ids, d_vals,
-               stop_mask=None):
-        S2 = sfx.shape[1]
-        cm = jnp.concatenate(
-            [prefix_mask, sfx_mask,
-             jnp.zeros((B, T0 - S - S2), prefix_mask.dtype)], axis=1)
-        logits_l, cache2, pos = decoder.extend(
-            params, cfg, cache_in, sfx, sfx_mask, cm, S)
-        return _fused_tail(params, cfg, logits_l, cache2, cm, pos, S + S2,
-                           yes_ids, no_ids, d_ids, d_vals, new_tokens, topk,
-                           stop_mask=stop_mask, eos_id=eos_id,
-                           decode_trunk=decode_trunk)
-
-    # The binary branch (A) takes, when provided, the EOS-only stop
-    # (tokens.eos_only_stop_classes: all-transparent classes reduce the
-    # done rule to emit == eos) — its numeric readout is position 0 and
-    # its response text is EOS-trimmed downstream, so skipped trailing
-    # steps are pure EOS fill.
-    out_a, cache_a = branch(cache, sfx_a, sfx_a_mask, max_new_a,
-                            empty_ids, empty_vals, stop_mask=stop_mask_a)
-    # The confidence branch (B) takes the digit table and, when provided,
-    # the digit early stop — only its first complete integer is read.
-    out_b, cache_b = branch(decoder.rewind(cache_a, cache), sfx_b,
-                            sfx_b_mask, max_new_b,
-                            digit_ids, digit_vals, stop_mask=stop_mask_b)
-    if return_cache:
-        return out_a, out_b, cache_b
-    return out_a, out_b
 
 
 @functools.partial(jax.jit,

@@ -87,9 +87,9 @@ def _tree_bytes(tree: Any) -> int:
 class _PrefixPlan:
     """One dispatch's radix-cache resume decision (engine-internal).
 
-    ``window`` is the remainder-window edge the paged executable will
-    run (each row recomputes its last ``window`` real prefix tokens and
-    gathers everything earlier from the page pool), or None when nothing
+    ``window`` is the remainder-window edge the paged front will run
+    (each row recomputes its last ``window`` real prefix tokens and
+    gathers everything earlier from the page pool), or 0 when nothing
     useful is cached — the dispatch then runs the plain unpaged prefill
     (whose executable already exists) and only INSERTS pages afterward.
     ``matches`` hold the dispatch's page pins; every plan MUST pass
@@ -100,11 +100,113 @@ class _PrefixPlan:
     prefix_ids: List[Sequence[int]]
     matches: List[Any]
     n_real: int
-    window: Optional[int] = None
+    window: int = 0
     w0: int = 0
     slot_src: Optional[np.ndarray] = None
     rem: Optional[np.ndarray] = None
     rem_mask: Optional[np.ndarray] = None
+
+    def host_arrays(self) -> dict:
+        """What a paged front binds, under the names
+        compile_plan.dispatch_args reads them by; nothing when cold."""
+        if not self.window:
+            return {}
+        return dict(slot_src=self.slot_src, win_start=self.w0,
+                    rem=self.rem, rem_mask=self.rem_mask)
+
+
+@dataclasses.dataclass(frozen=True)
+class Route:
+    """Where one dispatch goes: THE routing rule of the dispatch
+    programs, held once. :meth:`ScoringEngine.route` fills in what the
+    engine and the dispatch's rows decide before anything is looked up;
+    :meth:`spec` then names the program that runs given what the
+    dispatch finds (a radix window, a draft plan that survived the
+    governor), and :meth:`planned` the programs it MAY run, which is
+    what the compile plan compiles. The sweep's chain keys, the
+    watchdog's price and the batcher's read ``trunk`` / ``dtrunk``.
+
+    Precedence: the cascade front (``trunk``) before the speculative
+    tail before the piggyback chain — the latter two optimize around
+    the very prefill the cascade removes. A paged front whenever the
+    radix tree holds a window. The decode steps of a dense or paged
+    front run trunk-aware at ``dtrunk``; a cascade front fixes the
+    decode trunk itself."""
+
+    shape: compile_plan.ShapeSpec   # the dense, fresh program at this shape
+    trunk: int = 0        # cascade-prefill trunk the rows share (0: none)
+    dtrunk: int = 0       # trunk the decode steps dedup at (0: flat)
+    int8: bool = False    # the cascade kernel's int8-QK^T variant
+    spec_k: int = 0       # the engine speculates at this window (0: no)
+    fleet: bool = False   # ... with a fleet draft model
+    page_size: int = 0    # radix prefix cache page (0: no cache)
+    piggyback: bool = False
+
+    def spec(self, window: int = 0, speculate: bool = False,
+             scratch: bool = False) -> compile_plan.ShapeSpec:
+        """The program to run. ``window``: the radix plan's recompute
+        window over the trunk's namespace (cascade) or the bucket's, 0
+        when cold; ``speculate``: a draft plan is at hand; ``scratch``:
+        the handoff holds a cache to donate. A fleet draft model cannot
+        ride a paged front (it binds slot tables, not prefix tokens:
+        nothing to prefill the draft cache from), so that pair runs the
+        sequential tail."""
+        shape = dataclasses.replace(self.shape, window=window,
+                                    scratch=scratch)
+        if self.trunk:
+            return dataclasses.replace(shape, trunk=self.trunk,
+                                       cascade_int8=self.int8)
+        k = self.spec_k if speculate and not (self.fleet and window) else 0
+        return dataclasses.replace(shape, decode_trunk=self.dtrunk,
+                                   spec_k=k,
+                                   spec_draft=bool(k) and self.fleet)
+
+    @property
+    def chain_key(self) -> Optional[Tuple[int, int, int, int]]:
+        """What consecutive dispatches must share to ride one piggyback
+        chain, or None when this one never chains: the engine chains
+        nothing, or the cascade front takes it (that front has no
+        parked-decode carry slot)."""
+        if not self.piggyback or self.trunk:
+            return None
+        s = self.shape
+        return s.bucket, s.batch, s.sfx_a, s.sfx_b
+
+    def planned(self, scratch: bool,
+                chain: bool = False) -> List[compile_plan.ShapeSpec]:
+        """Every program the dispatch may run, in first-use order, at
+        the handoff variant ``scratch``: which window a warm dispatch
+        runs depends on what the radix tree holds by then, so every
+        window edge is covered; the governor may shed speculation and a
+        chain may be refused, so the sequential program stays planned
+        beside them. A cascade-eligible dispatch also keeps the dense
+        program and its speculative sibling, which it never reaches
+        (ROADMAP S7 prunes them here). ``chain``: the sweep will chain
+        this dispatch to the previous one (a repeat of its shape), so
+        the three piggyback stages are planned."""
+        dense = dataclasses.replace(self, trunk=0,
+                                    dtrunk=0 if self.trunk else self.dtrunk)
+        edges = lambda extent: (  # noqa: E731
+            paged.window_edges(extent, self.page_size)
+            if self.page_size else ())
+        out = [dense.spec(0, False, scratch)]
+        if self.spec_k:
+            out.append(dense.spec(0, True, scratch))
+        if self.trunk:
+            out += [self.spec(w, False, scratch)
+                    for w in (0,) + tuple(edges(self.trunk))]
+        elif chain and self.chain_key is not None:
+            s = self.shape
+            stage = (s.bucket, s.batch, s.sfx_a, s.sfx_b, s.new_tokens,
+                     s.conf_tokens)
+            out += [compile_plan.piggy_prefill_spec(*stage),
+                    compile_plan.piggy_step_spec(*stage, s.stops_armed),
+                    compile_plan.piggy_drain_spec(*stage, s.stops_armed)]
+        for w in edges(self.shape.bucket):
+            out.append(dense.spec(w, False, scratch))
+            if self.spec_k and not self.fleet:
+                out.append(dense.spec(w, True, scratch))
+        return out
 
 
 @dataclasses.dataclass
@@ -554,34 +656,13 @@ class ScoringEngine:
         runs: the TPU backend, or CPU under the interpreter when
         decoder.CASCADE_INTERPRET_ON_CPU is armed (tier-1 and the
         cascade smoke; production CPU stays dense). Per-dispatch
-        eligibility (trunk length, row count) is
-        :meth:`cascade_trunk_for`'s."""
+        eligibility (trunk length, row count) is :meth:`shared_trunk`'s."""
         if not (self.rt.cascade_prefill and not self.encoder_decoder
                 and self._prefill_fn is None
                 and not getattr(self.cfg, "kv_cache_int8", False)):
             return False
         return (jax.default_backend() == "tpu"
                 or decoder.CASCADE_INTERPRET_ON_CPU)
-
-    def cascade_trunk_for(self, prefix_ids: Sequence[Sequence[int]],
-                          n_real: Optional[int] = None,
-                          bucket: Optional[int] = None) -> int:
-        """The dispatch's shared-trunk extent, or 0 when the dispatch
-        should run dense: the longest common token prefix across EVERY
-        row's shared prefix (pad rows repeat a real row, so the
-        all-rows LCP equals the real-rows LCP — and the broadcast-trunk
-        cache layout requires the trunk to lead every batch row),
-        snapped DOWN to the CascadeConfig.trunk_quantum grid (the trunk
-        extent is a static compiled shape — compile_plan keys
-        executables on it — so a few unshared tail tokens ride the
-        per-row remainder instead of minting a new executable), floored
-        at min_trunk, and kept strictly inside the bucket (a
-        trunk == bucket dispatch would leave a zero-width remainder).
-        ``n_real`` gates the min_rows dedup check — padding repeats
-        dedup for free but buy nothing."""
-        if not self.cascade_supported() or not prefix_ids:
-            return 0
-        return self._lcp_trunk(prefix_ids, n_real, bucket)
 
     def _lcp_trunk(self, prefix_ids: Sequence[Sequence[int]],
                    n_real: Optional[int], bucket: Optional[int]) -> int:
@@ -621,20 +702,77 @@ class ScoringEngine:
         return (jax.default_backend() == "tpu"
                 or decoder.FUSED_DECODE_INTERPRET_ON_CPU)
 
-    def decode_trunk_for(self, prefix_ids: Sequence[Sequence[int]],
-                         n_real: Optional[int] = None,
-                         bucket: Optional[int] = None) -> int:
-        """The dispatch's shared-trunk extent for DECODE-phase dedup, or
-        0 for the flat kernels: same LCP/quantum/bucket discipline as
-        :meth:`cascade_trunk_for` (the trunk slots lead every row of the
-        right-padded cache either way), but gated on the decode-side
-        support check — a dispatch can cascade its decode steps even
-        when the prefill ran dense (e.g. paged-warm prefixes), and vice
-        versa. The extent is a static compiled shape: compile_plan keys
-        decode executables on it."""
-        if not self.cascade_decode_supported() or not prefix_ids:
-            return 0
-        return self._lcp_trunk(prefix_ids, n_real, bucket)
+    def shared_trunk(self, prefix_ids: Optional[Sequence[Sequence[int]]],
+                     n_real: Optional[int] = None,
+                     bucket: Optional[int] = None) -> Tuple[int, int]:
+        """(cascade-prefill trunk, decode trunk) of a dispatch whose rows
+        carry these shared prefixes; 0 where that phase runs dense / flat.
+
+        Both are the one quantized LCP (:meth:`_lcp_trunk`: the longest
+        common token prefix across EVERY row — pad rows repeat a real
+        row, so the all-rows LCP equals the real-rows LCP, and the
+        broadcast-trunk cache layout requires the trunk to lead every
+        batch row), each behind its own gate: a dispatch can cascade its
+        decode steps even when the prefill runs dense (cascade prefill
+        off; a paged-warm prefix), and vice versa. The extent is a
+        static compiled shape — compile_plan keys executables on it.
+        ``n_real`` gates the min_rows dedup check: padding repeats dedup
+        for free but buy nothing."""
+        prefill_ok = self.cascade_supported()
+        decode_ok = self.cascade_decode_supported()
+        if not prefix_ids or not (prefill_ok or decode_ok):
+            return 0, 0
+        trunk = self._lcp_trunk(prefix_ids, n_real, bucket)
+        return (trunk if prefill_ok else 0), (trunk if decode_ok else 0)
+
+    # -- routing: which dispatch program a dispatch runs --------------------
+
+    def route(self, kind: str, edge: int, rows: int, groups: int,
+              sfx_a: int, sfx_b: int, new_tokens: int, conf_tokens: int,
+              stops_armed: bool,
+              prefix_rows: Optional[Sequence[Sequence[int]]] = None,
+              n_real: Optional[int] = None) -> Route:
+        """The :class:`Route` of one dispatch, from its facts: ``kind``
+        ("shared" | "grouped"), the prefix ``edge`` it runs at, its
+        PADDED member ``rows`` (and prefill ``groups``, grouped only),
+        suffix bucket edges, token budgets, whether the stops are armed,
+        and the rows' shared prefixes (None: no rows yet, as when the
+        serving ladder is warmed — no trunk then). Everything that asks
+        which program a dispatch runs or may run — the dispatch itself,
+        the compile plan, the sweep's chains and prices, the batcher —
+        asks here."""
+        page = (self.prefix_cache.page_size
+                if self.prefix_cache is not None else 0)
+        if kind == "grouped":
+            # Both formats ride one suffix edge and one decode budget.
+            return Route(compile_plan.ShapeSpec(
+                "grouped", int(edge), int(rows), int(groups),
+                int(max(sfx_a, sfx_b)), 0,
+                int(max(new_tokens, conf_tokens)), 0, bool(stops_armed),
+                False), page_size=page)
+        trunk, dtrunk = self.shared_trunk(prefix_rows, n_real, edge)
+        return Route(
+            compile_plan.ShapeSpec(
+                "shared", int(edge), int(rows), 0, int(sfx_a), int(sfx_b),
+                int(new_tokens), int(conf_tokens), bool(stops_armed),
+                False),
+            trunk=trunk, dtrunk=dtrunk,
+            int8=bool(self.cascade_cfg.int8_qk),
+            spec_k=int(self.rt.spec_k) if self.spec_supported() else 0,
+            fleet=self._spec_draft is not None, page_size=page,
+            piggyback=self.piggyback_supported())
+
+    def route_dispatch(self, d, new_tokens: int, conf_tokens: int,
+                       stops_armed: bool) -> Route:
+        """:meth:`route` of one scheduler.Dispatch, padded as
+        decode_fused_shared / decode_fused_grouped will pad it."""
+        g_pad, m_pad = d.padded_rows(self.rt.batch_size)
+        shared = d.kind == "shared"
+        return self.route(
+            d.kind, d.edge, m_pad, 0 if shared else g_pad, d.sfx_bucket_a,
+            d.sfx_bucket_b, new_tokens, conf_tokens, stops_armed,
+            [it.bin_ids[:it.lcp] for it in d.items] if shared else None,
+            len(d.items))
 
     def _note_cascade_decode(self, dtrunk: int, rows: int, cache,
                              new_tokens: int, conf_tokens: int) -> None:
@@ -987,11 +1125,12 @@ class ScoringEngine:
         rephrased legal text and differ only in the short trailing format
         instruction. Tokenize both, split every row at the longest common
         TOKEN prefix (tokenizer-agnostic — see tokens.shared_prefix_len),
-        left-pad the prefixes into the standard bucket and right-pad each
-        format's suffix into a small power-of-two bucket, then run
-        generate.greedy_decode_fused_shared: one prefill + two chunked
-        suffix extensions instead of two full prefills. Returns
-        (binary FusedDecodeOut, confidence FusedDecodeOut).
+        right-pad the prefixes into the standard bucket and each format's
+        suffix into a small power-of-two bucket, then run the dispatch
+        program (generate.greedy_decode_dispatch) the engine routes the
+        batch to (:meth:`route`): one fill of the prefix + two chunked
+        suffix extensions instead of two full prefills. Returns (binary
+        FusedDecodeOut, confidence FusedDecodeOut).
 
         The ragged scheduler passes ``pretokenized_a/b`` (cells were
         tokenized once at planning time), an explicit prefix ``bucket``
@@ -1004,11 +1143,11 @@ class ScoringEngine:
         True, or None on an engine whose :attr:`prefix_cache` is built),
         a ``reuse_cache`` dispatch resumes every row's shared prefix from
         the deepest cached radix node: cached pages gather from the page
-        pool into the exact slots the left-padded prefill would fill and
-        only the per-row remainder window is recomputed
-        (generate.greedy_decode_fused_shared_paged) — results BITWISE
-        identical to the unpaged path, prefill FLOPs paid only for the
-        unshared tail. Fresh full pages insert back into the pool after
+        pool into the exact slots the prefill would fill and only the
+        per-row remainder window is recomputed (the program's paged
+        front) — results equal to the unpaged path's (tokens exact,
+        floats to the last bits; bitwise in every paged slot), prefill
+        FLOPs paid only for the unshared tail. Fresh full pages insert back into the pool after
         the dispatch, so reuse spans requests, batches, and sweeps.
         ``n_real`` bounds the rows counted in PrefixCacheStats (callers
         pad dispatches by repeating the last row).
@@ -1102,385 +1241,139 @@ class ScoringEngine:
             [a[:n] for a, n in zip(bin_ids, lcp)], bucket, pad_id)
         sfx_a, sfx_a_mask = tok.right_pad_ids(sfx_a_ids, ba, pad_id)
         sfx_b, sfx_b_mask = tok.right_pad_ids(sfx_b_ids, bb, pad_id)
-        digit_ids, digit_vals = self.digit_table
-        stop_mask = self.digit_stop_mask if early_stop else None
-        kwargs = dict(
-            max_new_a=new_tokens, max_new_b=conf_tokens,
-            prefill_fn=self._prefill_fn, stop_mask_b=stop_mask,
-            stop_mask_a=(None if stop_mask is None else self.eos_stop_mask),
-            eos_id=(None if stop_mask is None
-                    else jnp.int32(self.eos_id)))
-        if reuse_cache:
-            prefix_rows = [a[:n] for a, n in zip(bin_ids, lcp)]
-            # Shared-prefix cascade prefill: an eligible dispatch takes
-            # precedence over speculation AND piggybacking (both
-            # optimize around the very prefill the cascade removes —
-            # the sweep excludes cascade-eligible dispatches from piggy
-            # chains for the same reason). Ineligible-while-enabled
-            # counts a dense fallback; the dense path runs verbatim.
-            trunk = self.cascade_trunk_for(prefix_rows, n_real, bucket)
-            if trunk:
-                return self._dispatch_shared_cascade(
-                    trunk, bucket, prefix_rows[0][:trunk], prefix,
-                    prefix_mask, sfx_a, sfx_a_mask, sfx_b, sfx_b_mask,
-                    yes_ids, no_ids, digit_ids, digit_vals, new_tokens,
-                    conf_tokens, ba, bb, early_stop,
-                    {k: kwargs[k] for k in
-                     ("stop_mask_a", "stop_mask_b", "eos_id")},
-                    use_prefix_cache, n_real)
+        host = dict(prefix=prefix, prefix_mask=prefix_mask, sfx_a=sfx_a,
+                    sfx_a_mask=sfx_a_mask, sfx_b=sfx_b,
+                    sfx_b_mask=sfx_b_mask, yes_ids=yes_ids, no_ids=no_ids)
+        B = len(bin_ids)
+        armed = early_stop and self.digit_stop_mask is not None
+        if not reuse_cache:
+            # Legacy batches: the dense program, nothing looked up, no
+            # cache handed over.
+            dense = self.route("shared", bucket, B, 0, ba, bb, new_tokens,
+                               conf_tokens, armed).shape
+            (fused, cfused), _, _ = self._run_program(dense, host,
+                                                      reuse=False)
+            return fused, cfused
+        prefix_rows = [a[:n] for a, n in zip(bin_ids, lcp)]
+        rows = B if n_real is None else n_real
+        route = self.route("shared", bucket, B, 0, ba, bb, new_tokens,
+                           conf_tokens, armed, prefix_rows, n_real)
+        splan = None
+        if route.trunk:
+            # The warm trunk lives in the TRUNK-extent radix namespace
+            # (pages are reproducible only within one attention extent —
+            # prefix_tree's per-bucket rule — and the cascade trunk
+            # prefills at extent `trunk`, not `bucket`): a one-row plan
+            # over the trunk ids, whose pages the cold dispatch inserts
+            # from cache row 0's broadcast trunk slots, so the SECOND
+            # dispatch sharing a trunk gathers it at zero recompute.
+            plan = self._prefix_plan_or_none(
+                route.trunk, [prefix_rows[0][:route.trunk]], 1, 1,
+                use_prefix_cache)
+        else:
             if self.cascade_supported():
                 self.cascade_stats.count("dense_fallbacks")
-            # Cascade DECODE without cascade prefill: a dispatch that
-            # runs its prefill dense (cascade prefill off, ineligible,
-            # or superseded by a paged-warm front) still shares its
-            # trunk slots row-for-row, so every decode step's trunk
-            # splits can read the trunk KV once per kv head instead of
-            # once per row (ops/flash_decode trunk variants — bitwise
-            # the flat kernels). The extent is a static compiled shape;
-            # compile_plan keys the shared executables on it.
-            dtrunk = self.decode_trunk_for(prefix_rows, n_real, bucket)
-            plan = self._prefix_plan_or_none(
-                bucket, prefix_rows, n_real,
-                len(bin_ids), use_prefix_cache)
+            plan = self._prefix_plan_or_none(bucket, prefix_rows, n_real, B,
+                                             use_prefix_cache)
             # Speculative decode (engine/spec.py): draft each branch's
             # continuation and verify the window in one multi-query
-            # forward. Results are bitwise the sequential executable's;
-            # a fleet draft model can't ride the paged front (the paged
-            # executable binds slot tables, not prefix tokens), so that
-            # combination falls back to the sequential paged path.
+            # forward; consumed results are bitwise the sequential
+            # program's, so shedding it is always safe.
             splan = spec_mod.build_plan(self, bin_ids, conf_ids, bucket,
                                         ba, bb, new_tokens, conf_tokens)
             if (splan is not None and self.governor is not None
                     and not self.governor.allows("spec")):
-                # Governor no_spec rung: the sequential executable is
-                # bitwise-identical, so shedding speculation is a pure
-                # HBM reclaim (the spec cache runs spec_k extra slots
-                # per window). Re-arms when pressure clears.
+                # Governor no_spec rung: a pure HBM reclaim (the spec
+                # cache runs spec_k slots per window). Re-arms when
+                # pressure clears.
                 splan = None
                 self.spec_stats.count("fallbacks")
-            paged_warm = plan is not None and plan.window is not None
-            if splan is not None and paged_warm and splan.fleet:
-                splan = None
-                self.spec_stats.count("fallbacks")
-            # Paged and unpaged dispatches of one shape return the same
-            # cache aval, so they share one handoff key — the donation
-            # chain runs unbroken across cold and warm dispatches. The
-            # speculative cache is LONGER (spec_k slots per decode
-            # window), so speculative dispatches chain on their own key.
-            key = ("shared", bucket, len(bin_ids), ba, bb, new_tokens,
-                   conf_tokens, early_stop,
-                   None if splan is None else (splan.k, splan.fleet))
-            scratch = self._handoff.take(key)
-            stop_kwargs = {k: kwargs[k] for k in
-                           ("stop_mask_a", "stop_mask_b", "eos_id")}
-            if splan is not None:
-                try:
-                    out = self._dispatch_shared_spec(
-                        splan, plan, paged_warm, bucket, prefix,
-                        prefix_mask, sfx_a, sfx_a_mask, sfx_b, sfx_b_mask,
-                        yes_ids, no_ids, digit_ids, digit_vals,
-                        new_tokens, conf_tokens, stop_kwargs, scratch,
-                        ba, bb, dtrunk)
-                except BaseException:
-                    if plan is not None:
-                        self._abort_prefix_resume(plan)
-                    raise
-                fused, cfused, spec_a, spec_b, cache = out
-                self._spec_pending.append((spec_a, spec_b))
-                self.spec_stats.count("spec_dispatches")
-                self.spec_stats.count(
-                    "spec_rows", len(bin_ids) if n_real is None else n_real)
-                self._handoff.put(key, cache)
-                self._note_handoff(cache)
-                if plan is not None:
-                    self._finish_prefix_resume(plan, cache)
-                self._note_cascade_decode(
-                    dtrunk, len(bin_ids) if n_real is None else n_real,
-                    cache, new_tokens, conf_tokens)
-                return fused, cfused
-            try:
-                if plan is not None and plan.window is not None:
-                    dyn_args = (self.params, self.prefix_cache.pool.leaves,
-                                jnp.asarray(plan.slot_src),
-                                jnp.int32(plan.w0),
-                                jnp.asarray(prefix_mask),
-                                jnp.asarray(plan.rem),
-                                jnp.asarray(plan.rem_mask),
-                                jnp.asarray(sfx_a), jnp.asarray(sfx_a_mask),
-                                jnp.asarray(sfx_b), jnp.asarray(sfx_b_mask),
-                                jnp.asarray(yes_ids, jnp.int32),
-                                jnp.asarray(no_ids, jnp.int32),
-                                jnp.asarray(digit_ids),
-                                jnp.asarray(digit_vals))
-                    exe = None
-                    if self.exec_registry is not None:
-                        exe = self.exec_registry.get(
-                            compile_plan.shared_paged_spec(
-                                bucket, len(bin_ids), plan.window, ba, bb,
-                                new_tokens, conf_tokens,
-                                stops_armed=stop_mask is not None,
-                                scratch=scratch is not None,
-                                decode_trunk=dtrunk))
-                    if exe is not None:
-                        fused, cfused, cache = compile_plan.registry_call(
-                            exe, dyn_args, stop_kwargs, scratch)
-                    else:
-                        fused, cfused, cache = (
-                            generate.greedy_decode_fused_shared_paged(
-                                dyn_args[0], self.cfg, *dyn_args[1:],
-                                max_new_a=new_tokens, max_new_b=conf_tokens,
-                                return_cache=True, scratch_cache=scratch,
-                                decode_trunk=dtrunk, **stop_kwargs))
-                else:
-                    dyn_args = (self.params, jnp.asarray(prefix),
-                                jnp.asarray(prefix_mask), jnp.asarray(sfx_a),
-                                jnp.asarray(sfx_a_mask), jnp.asarray(sfx_b),
-                                jnp.asarray(sfx_b_mask),
-                                jnp.asarray(yes_ids, jnp.int32),
-                                jnp.asarray(no_ids, jnp.int32),
-                                jnp.asarray(digit_ids),
-                                jnp.asarray(digit_vals))
-                    exe = None
-                    if self.exec_registry is not None:
-                        exe = self.exec_registry.get(compile_plan.shared_spec(
-                            bucket, len(bin_ids), ba, bb, new_tokens,
-                            conf_tokens, stops_armed=stop_mask is not None,
-                            scratch=scratch is not None,
-                            decode_trunk=dtrunk))
-                    if exe is not None:
-                        fused, cfused, cache = compile_plan.registry_call(
-                            exe, dyn_args, stop_kwargs, scratch)
-                    else:
-                        fused, cfused, cache = (
-                            generate.greedy_decode_fused_shared(
-                                dyn_args[0], self.cfg, *dyn_args[1:],
-                                return_cache=True, scratch_cache=scratch,
-                                decode_trunk=dtrunk, **kwargs))
-            except BaseException:
-                if plan is not None:
-                    self._abort_prefix_resume(plan)
-                raise
-            self._handoff.put(key, cache)
-            self._note_handoff(cache)
-            if plan is not None:
-                self._finish_prefix_resume(plan, cache)
-            self._note_cascade_decode(
-                dtrunk, len(bin_ids) if n_real is None else n_real,
-                cache, new_tokens, conf_tokens)
-            self._note_recurrent(
-                cache, len(bin_ids) if n_real is None else n_real,
-                new_tokens + conf_tokens, windows=3)
-            return fused, cfused
-        return generate.greedy_decode_fused_shared(
-            self.params, self.cfg, jnp.asarray(prefix),
-            jnp.asarray(prefix_mask), jnp.asarray(sfx_a),
-            jnp.asarray(sfx_a_mask), jnp.asarray(sfx_b),
-            jnp.asarray(sfx_b_mask),
-            jnp.asarray(yes_ids, jnp.int32), jnp.asarray(no_ids, jnp.int32),
-            jnp.asarray(digit_ids), jnp.asarray(digit_vals), **kwargs)
+        spec = route.spec(plan.window if plan is not None else 0,
+                          speculate=splan is not None)
+        if splan is not None and not spec.spec_k:
+            splan = None                  # fleet draft x paged front
+            self.spec_stats.count("fallbacks")
+        if splan is not None:
+            host.update(splan.host_arrays())
+        (fused, cfused), specs, cache = self._run_program(spec, host, plan)
+        if specs is not None:
+            self._spec_pending.append(specs)
+            self.spec_stats.count("spec_dispatches")
+            self.spec_stats.count("spec_rows", rows)
+        shared_rows = max(rows - 1, 0) if route.trunk else 0
+        if route.trunk:
+            self.cascade_stats.count("cascade_dispatches")
+            # A row counts as deduped when ALL of its trunk was shared:
+            # its K/V and, for a model with a mixer, its recurrent state
+            # at the trunk's end (the cascade front computes both once
+            # at batch 1 and seeds every row with them).
+            self.cascade_stats.count("trunk_rows_deduped", shared_rows)
+            self.cascade_stats.count(
+                "prefix_flops_saved",
+                int(cascade_prefill_flops_saved(self.cfg, rows,
+                                                route.trunk)))
+        self._note_cascade_decode(route.dtrunk, rows, cache, new_tokens,
+                                  conf_tokens)
+        # Chunked-scan windows a layer: the prefix [+ the trunk], then
+        # the two suffix extends.
+        self._note_recurrent(cache, rows, new_tokens + conf_tokens,
+                             windows=4 if route.trunk else 3,
+                             trunk_rows=shared_rows)
+        return fused, cfused
 
-    def _dispatch_shared_spec(self, splan, plan, paged_warm: bool,
-                              bucket: int, prefix, prefix_mask, sfx_a,
-                              sfx_a_mask, sfx_b, sfx_b_mask, yes_ids,
-                              no_ids, digit_ids, digit_vals,
-                              new_tokens: int, conf_tokens: int,
-                              stop_kwargs: dict, scratch, ba: int,
-                              bb: int, dtrunk: int = 0):
-        """One SPECULATIVE shared dispatch (registry executable when
-        planned, lazy jit otherwise): the unpaged prefill front or the
-        radix-paged resume front, then both branches' draft-and-verify
-        tails. ``dtrunk`` > 0 runs every verify window's trunk splits
-        trunk-aware (cascade decode — the verifier's multi-query
-        flash_decode_mq_trunk; the fleet draft model stays flat, its
-        drafts are quality-only). Returns (fused, cfused, SpecOut_a,
-        SpecOut_b, cache)."""
-        armed = stop_kwargs.get("eos_id") is not None
-        spec_args = tuple(jnp.asarray(x) for x in splan.dyn_args())
-        if paged_warm:
-            dyn_args = (self.params, self.prefix_cache.pool.leaves,
-                        jnp.asarray(plan.slot_src), jnp.int32(plan.w0),
-                        jnp.asarray(prefix_mask), jnp.asarray(plan.rem),
-                        jnp.asarray(plan.rem_mask),
-                        jnp.asarray(sfx_a), jnp.asarray(sfx_a_mask),
-                        jnp.asarray(sfx_b), jnp.asarray(sfx_b_mask),
-                        jnp.asarray(yes_ids, jnp.int32),
-                        jnp.asarray(no_ids, jnp.int32),
-                        jnp.asarray(digit_ids),
-                        jnp.asarray(digit_vals)) + spec_args
-            exe = None
-            if self.exec_registry is not None:
-                exe = self.exec_registry.get(compile_plan.shared_paged_spec(
-                    bucket, len(prefix_mask), plan.window, ba, bb,
-                    new_tokens, conf_tokens, stops_armed=armed,
-                    scratch=scratch is not None, spec_k=splan.k,
-                    decode_trunk=dtrunk))
-            if exe is not None:
-                out = compile_plan.registry_call(exe, dyn_args,
-                                                 stop_kwargs, scratch)
-            else:
-                out = generate.greedy_decode_fused_shared_paged_spec(
-                    dyn_args[0], self.cfg, *dyn_args[1:],
-                    max_new_a=new_tokens, max_new_b=conf_tokens,
-                    spec_k=splan.k, ngram=splan.ngram, return_cache=True,
-                    scratch_cache=scratch, decode_trunk=dtrunk,
-                    **stop_kwargs)
-        else:
-            draft_params, draft_cfg = None, None
-            if splan.fleet:
-                draft_params, draft_cfg, _ = self._spec_draft
-            dyn_args = (self.params, jnp.asarray(prefix),
-                        jnp.asarray(prefix_mask), jnp.asarray(sfx_a),
-                        jnp.asarray(sfx_a_mask), jnp.asarray(sfx_b),
-                        jnp.asarray(sfx_b_mask),
-                        jnp.asarray(yes_ids, jnp.int32),
-                        jnp.asarray(no_ids, jnp.int32),
-                        jnp.asarray(digit_ids),
-                        jnp.asarray(digit_vals)) + spec_args
-            exe = None
-            if self.exec_registry is not None:
-                exe = self.exec_registry.get(compile_plan.shared_spec(
-                    bucket, len(prefix_mask), ba, bb, new_tokens,
-                    conf_tokens, stops_armed=armed,
-                    scratch=scratch is not None,
-                    spec_k=splan.k, spec_draft=splan.fleet,
-                    decode_trunk=dtrunk))
-            if exe is not None:
-                out = compile_plan.registry_call(
-                    exe, dyn_args,
-                    dict(stop_kwargs, draft_params=draft_params), scratch)
-            else:
-                out = generate.greedy_decode_fused_shared_spec(
-                    dyn_args[0], self.cfg, *dyn_args[1:],
-                    max_new_a=new_tokens, max_new_b=conf_tokens,
-                    spec_k=splan.k, ngram=splan.ngram,
-                    prefill_fn=self._prefill_fn,
-                    draft_params=draft_params, draft_cfg=draft_cfg,
-                    return_cache=True, scratch_cache=scratch,
-                    decode_trunk=dtrunk, **stop_kwargs)
-        return out
+    def _hit_or_lazy(self, spec: compile_plan.ShapeSpec, lazy):
+        """The callable that runs ``spec``: the compile plan's executable
+        (statics baked in at lower time, so it takes the dynamic
+        arguments alone), else ``lazy`` — the jitted function behind the
+        same argument list, which traces on first call and is always
+        correct (an unplanned shape, a failed or stalled compile, a
+        registry dropped by the fault ladder)."""
+        exe = None
+        if self.exec_registry is not None:
+            exe = self.exec_registry.get(spec)
+        return lazy if exe is None else exe
 
-    def _dispatch_shared_cascade(self, trunk: int, bucket: int,
-                                 trunk_ids: Sequence[int], prefix,
-                                 prefix_mask, sfx_a, sfx_a_mask, sfx_b,
-                                 sfx_b_mask, yes_ids, no_ids, digit_ids,
-                                 digit_vals, new_tokens: int,
-                                 conf_tokens: int, ba: int, bb: int,
-                                 early_stop: bool, stop_kwargs: dict,
-                                 use_prefix_cache, n_real: Optional[int]):
-        """One CASCADE shared dispatch (registry executable when planned,
-        lazy jit otherwise): the batch-1 trunk prefill — cold, or resumed
-        warm from the radix page pool — then the per-row cascade
-        remainder extension and both branches' fused tails
-        (generate.greedy_decode_fused_shared_cascade[_paged]).
+    def _run_program(self, spec: compile_plan.ShapeSpec, host: dict,
+                     plan: Optional["_PrefixPlan"] = None,
+                     row_map: Optional[Sequence[int]] = None,
+                     reuse: bool = True):
+        """Run ONE dispatch program (generate.greedy_decode_dispatch) for
+        ``spec`` over the dispatch's ``host`` arrays: take the donation
+        chain's scratch cache, build the arguments, call the plan's
+        executable or the lazy jit, hand the returned cache back to the
+        chain and the ledger, and let the prefix plan insert its pages
+        from it (``row_map``: plan rows -> cache rows). Any exception
+        drops the plan's page pins and re-raises. ``reuse`` False runs
+        the program alone: no chain, no registry, no cache returned.
+        Returns the program's ``(outs, specs, cache)``."""
+        key = spec.cache_key
+        scratch = self._handoff.take(key) if reuse else None
+        spec = dataclasses.replace(spec, scratch=scratch is not None)
+        program = compile_plan.dispatch_program(self, spec,
+                                                return_cache=reuse)
 
-        The warm trunk lives in the TRUNK-extent radix namespace (pages
-        are bitwise-reproducible only within one attention extent —
-        prefix_tree's per-bucket rule — and the cascade trunk prefills
-        at extent ``trunk``, not ``bucket``): a one-row plan over the
-        trunk ids, whose pages the cold dispatch inserts from cache
-        row 0's broadcast trunk slots, so the SECOND dispatch sharing a
-        trunk gathers it at zero recompute. The cascade cache aval
-        equals the dense shared path's, so both share one donation-chain
-        key — the handoff runs unbroken across cascade and dense
-        dispatches of a bucket queue."""
-        B = len(prefix_mask)
-        plan = self._prefix_plan_or_none(trunk, [list(trunk_ids)], 1, 1,
-                                         use_prefix_cache)
-        paged_warm = plan is not None and plan.window is not None
-        key = ("shared", bucket, B, ba, bb, new_tokens, conf_tokens,
-               early_stop, None)
-        scratch = self._handoff.take(key)
-        armed = stop_kwargs.get("eos_id") is not None
-        int8 = bool(self.cascade_cfg.int8_qk)
-        statics = dict(max_new_a=new_tokens, max_new_b=conf_tokens,
-                       trunk_len=trunk, int8_qk=int8, return_cache=True)
+        def lazy(params, args, scratch_cache):
+            return generate.greedy_decode_dispatch(
+                params, self.cfg, program, args,
+                scratch_cache=scratch_cache)
+
         try:
-            if paged_warm:
-                trunk_mask = np.ones((1, trunk), np.int32)
-                dyn_args = (self.params, self.prefix_cache.pool.leaves,
-                            jnp.asarray(plan.slot_src), jnp.int32(plan.w0),
-                            jnp.asarray(trunk_mask),
-                            jnp.asarray(plan.rem),
-                            jnp.asarray(plan.rem_mask),
-                            jnp.asarray(prefix), jnp.asarray(prefix_mask),
-                            jnp.asarray(sfx_a), jnp.asarray(sfx_a_mask),
-                            jnp.asarray(sfx_b), jnp.asarray(sfx_b_mask),
-                            jnp.asarray(yes_ids, jnp.int32),
-                            jnp.asarray(no_ids, jnp.int32),
-                            jnp.asarray(digit_ids),
-                            jnp.asarray(digit_vals))
-                exe = None
-                if self.exec_registry is not None:
-                    exe = self.exec_registry.get(
-                        compile_plan.shared_cascade_paged_spec(
-                            bucket, B, trunk, plan.window, ba, bb,
-                            new_tokens, conf_tokens, stops_armed=armed,
-                            scratch=scratch is not None, int8_qk=int8))
-                if exe is not None:
-                    fused, cfused, cache = compile_plan.registry_call(
-                        exe, dyn_args, stop_kwargs, scratch)
-                else:
-                    fused, cfused, cache = (
-                        generate.greedy_decode_fused_shared_cascade_paged(
-                            dyn_args[0], self.cfg, *dyn_args[1:],
-                            scratch_cache=scratch, **stop_kwargs,
-                            **statics))
-            else:
-                dyn_args = (self.params, jnp.asarray(prefix),
-                            jnp.asarray(prefix_mask), jnp.asarray(sfx_a),
-                            jnp.asarray(sfx_a_mask), jnp.asarray(sfx_b),
-                            jnp.asarray(sfx_b_mask),
-                            jnp.asarray(yes_ids, jnp.int32),
-                            jnp.asarray(no_ids, jnp.int32),
-                            jnp.asarray(digit_ids),
-                            jnp.asarray(digit_vals))
-                exe = None
-                if self.exec_registry is not None:
-                    exe = self.exec_registry.get(
-                        compile_plan.shared_cascade_spec(
-                            bucket, B, trunk, ba, bb, new_tokens,
-                            conf_tokens, stops_armed=armed,
-                            scratch=scratch is not None, int8_qk=int8))
-                if exe is not None:
-                    fused, cfused, cache = compile_plan.registry_call(
-                        exe, dyn_args, stop_kwargs, scratch)
-                else:
-                    fused, cfused, cache = (
-                        generate.greedy_decode_fused_shared_cascade(
-                            dyn_args[0], self.cfg, *dyn_args[1:],
-                            scratch_cache=scratch, **stop_kwargs,
-                            **statics))
+            if plan is not None:
+                host = {**host, **plan.host_arrays()}
+            args = compile_plan.dispatch_args(self, spec, host)
+            run = self._hit_or_lazy(spec, lazy) if reuse else lazy
+            outs, specs, cache = compile_plan.registry_call(
+                run, self.params, args, scratch)
         except BaseException:
             if plan is not None:
                 self._abort_prefix_resume(plan)
             raise
-        self._handoff.put(key, cache)
-        self._note_handoff(cache)
+        if reuse:
+            self._handoff.put(key, cache)
+            self._note_handoff(cache)
         if plan is not None:
-            # Cache row 0's trunk slots hold the broadcast trunk KV —
-            # exactly the batch-1 trunk prefill's values — so the
-            # standard insert path pages them into the trunk namespace.
-            self._finish_prefix_resume(plan, cache)
-        rows = B if n_real is None else n_real
-        self.cascade_stats.count("cascade_dispatches")
-        # A row counts as deduped when ALL of its trunk was shared: its
-        # K/V and, for a model with a mixer, its recurrent state at the
-        # trunk's end (generate.greedy_decode_fused_shared_cascade
-        # computes both once at batch 1 and seeds every row with them).
-        self.cascade_stats.count("trunk_rows_deduped", max(rows - 1, 0))
-        self._note_recurrent(cache, rows, new_tokens + conf_tokens,
-                             windows=4, trunk_rows=max(rows - 1, 0))
-        self.cascade_stats.count(
-            "prefix_flops_saved",
-            int(cascade_prefill_flops_saved(self.cfg, rows, trunk)))
-        # The cascade dispatch's decode scans ride the trunk-aware flash
-        # kernels too (generate._cascade_branches passes the trunk
-        # through) — count that side's dedup where the kernels actually
-        # run (the decode gate, not the prefill one).
-        if self.cascade_decode_supported():
-            self._note_cascade_decode(trunk, rows, cache, new_tokens,
-                                      conf_tokens)
-        return fused, cfused
+            self._finish_prefix_resume(plan, cache, row_map=row_map)
+        return outs, specs, cache
 
     # -- chunked prefill/decode piggybacking --------------------------------
 
@@ -1600,17 +1493,13 @@ class ScoringEngine:
         dispatch_args = (jnp.asarray(prefix), jnp.asarray(prefix_mask),
                          jnp.asarray(sfx_a), jnp.asarray(sfx_a_mask),
                          jnp.asarray(sfx_b), jnp.asarray(sfx_b_mask))
+        budgets = dict(max_new_a=new_tokens, max_new_b=conf_tokens)
+        stage = (bucket, len(bin_ids), ba, bb, new_tokens, conf_tokens)
         if self._piggy is None:
-            exe = None
-            if self.exec_registry is not None:
-                exe = self.exec_registry.get(compile_plan.piggy_prefill_spec(
-                    bucket, len(bin_ids), ba, bb, new_tokens, conf_tokens))
-            if exe is not None:
-                carry = exe(self.params, *dispatch_args)
-            else:
-                carry = generate.shared_piggyback_prefill(
-                    self.params, self.cfg, *dispatch_args,
-                    max_new_a=new_tokens, max_new_b=conf_tokens)
+            carry = self._hit_or_lazy(
+                compile_plan.piggy_prefill_spec(*stage),
+                lambda p, *a: generate.shared_piggyback_prefill(
+                    p, self.cfg, *a, **budgets))(self.params, *dispatch_args)
             self._piggy = dict(key=key, carry=carry,
                                slot0_a=bucket + ba,
                                slot0_b=bucket + ba + new_tokens + bb,
@@ -1624,20 +1513,13 @@ class ScoringEngine:
         carry = self._piggy["carry"]
         stop_kwargs = self._piggy_stop_kwargs()
         digit_ids, digit_vals = self.digit_table
-        exe = None
-        if self.exec_registry is not None:
-            exe = self.exec_registry.get(compile_plan.piggy_step_spec(
-                bucket, len(bin_ids), ba, bb, new_tokens, conf_tokens,
-                stops_armed=armed))
         dyn = (self.params, carry) + dispatch_args + (
             jnp.asarray(prev_yes, jnp.int32), jnp.asarray(prev_no, jnp.int32),
             jnp.asarray(digit_ids), jnp.asarray(digit_vals))
-        if exe is not None:
-            out_a, out_b, new_carry = exe(*dyn, **stop_kwargs)
-        else:
-            out_a, out_b, new_carry = generate.shared_piggyback_step(
-                dyn[0], self.cfg, *dyn[1:], max_new_a=new_tokens,
-                max_new_b=conf_tokens, **stop_kwargs)
+        out_a, out_b, new_carry = self._hit_or_lazy(
+            compile_plan.piggy_step_spec(*stage, stops_armed=armed),
+            lambda p, *a, **kw: generate.shared_piggyback_step(
+                p, self.cfg, *a, **budgets, **kw))(*dyn, **stop_kwargs)
         self._piggy["carry"] = new_carry
         self.kernel_stats.count("piggybacked_steps")
         return out_a, out_b
@@ -1659,11 +1541,14 @@ class ScoringEngine:
         assert st is not None, "no piggyback chain to drain"
         digit_ids, digit_vals = self.digit_table
         key = st["key"]
-        exe = None
-        if self.exec_registry is not None:
-            exe = self.exec_registry.get(compile_plan.piggy_drain_spec(
+        run = self._hit_or_lazy(
+            compile_plan.piggy_drain_spec(
                 key[0], key[1], key[2], key[3], st["new_tokens"],
-                st["conf_tokens"], stops_armed=st["armed"]))
+                st["conf_tokens"], stops_armed=st["armed"]),
+            lambda p, *a, **kw: generate.shared_piggyback_drain(
+                p, self.cfg, *a, slot0_a=st["slot0_a"],
+                slot0_b=st["slot0_b"], max_new_a=st["new_tokens"],
+                max_new_b=st["conf_tokens"], **kw))
         dyn = (self.params, st["carry"],
                jnp.asarray(prev_yes, jnp.int32),
                jnp.asarray(prev_no, jnp.int32),
@@ -1671,12 +1556,7 @@ class ScoringEngine:
         stop_kwargs = self._piggy_stop_kwargs()
         self._piggy = None
         self.kernel_stats.count("chains_drained")
-        if exe is not None:
-            return exe(*dyn, **stop_kwargs)
-        return generate.shared_piggyback_drain(
-            dyn[0], self.cfg, *dyn[1:], slot0_a=st["slot0_a"],
-            slot0_b=st["slot0_b"], max_new_a=st["new_tokens"],
-            max_new_b=st["conf_tokens"], **stop_kwargs)
+        return run(*dyn, **stop_kwargs)
 
     def piggy_abort(self) -> None:
         """Drop the chain (a failed piggyback call): the parked dispatch's
@@ -1741,100 +1621,37 @@ class ScoringEngine:
         no2 = np.repeat(np.asarray(no_ids, np.int32), 2)
         yes2 = np.concatenate([yes2, np.repeat(yes2[-1:], m_pad - m)])
         no2 = np.concatenate([no2, np.repeat(no2[-1:], m_pad - m)])
-        digit_ids, digit_vals = self.digit_table
-        stop_mask = self.digit_stop_mask if early_stop else None
-        kwargs = dict(
-            max_new=max(new_tokens, conf_tokens),
-            prefill_fn=self._prefill_fn,
-            stop_mask=(None if stop_mask is None else self.eos_stop_mask),
-            stop_mask2=stop_mask,
-            stop_sel=(None if stop_mask is None else
-                      jnp.asarray(np.arange(m_pad) % 2 == 1)),
-            eos_id=(None if stop_mask is None else jnp.int32(self.eos_id)))
-        args = (self.params, self.cfg, jnp.asarray(prefix),
-                jnp.asarray(prefix_mask), jnp.asarray(sfx),
-                jnp.asarray(sfx_mask),
-                jnp.asarray(np.asarray(group_idx, np.int32)),
-                jnp.asarray(yes2), jnp.asarray(no2),
-                jnp.asarray(digit_ids), jnp.asarray(digit_vals))
-        if reuse_cache:
-            # Plan rows are the PADDED prefix rows; the final cache holds
-            # member rows, and any member of a group carries the group's
-            # prefix slots — row_map points each prefix row at its
-            # group's first member row for the page extraction.
-            first_member = []
-            acc = 0
-            for g in groups:
-                first_member.append(acc)
-                acc += 2 * len(g.items)
-            first_member += [first_member[-1]] * (g_pad - len(groups))
-            plan = self._prefix_plan_or_none(
-                bucket, prefix_ids, len(groups), g_pad, use_prefix_cache)
-            key = ("grouped", bucket, g_pad, m_pad, sfx_bucket,
-                   kwargs["max_new"], early_stop)
-            scratch = self._handoff.take(key)
-            stop_kwargs = {k: kwargs[k] for k in
-                           ("stop_mask", "stop_mask2", "stop_sel",
-                            "eos_id")}
-            try:
-                if plan is not None and plan.window is not None:
-                    dyn_args = (self.params, self.prefix_cache.pool.leaves,
-                                jnp.asarray(plan.slot_src),
-                                jnp.int32(plan.w0),
-                                jnp.asarray(prefix_mask),
-                                jnp.asarray(plan.rem),
-                                jnp.asarray(plan.rem_mask),
-                                args[4], args[5], args[6], args[7],
-                                args[8], args[9], args[10])
-                    exe = None
-                    if self.exec_registry is not None:
-                        exe = self.exec_registry.get(
-                            compile_plan.grouped_paged_spec(
-                                bucket, g_pad, m_pad, plan.window,
-                                sfx_bucket, kwargs["max_new"],
-                                stops_armed=stop_mask is not None,
-                                scratch=scratch is not None))
-                    if exe is not None:
-                        out, cache = compile_plan.registry_call(
-                            exe, dyn_args, stop_kwargs, scratch)
-                    else:
-                        out, cache = (
-                            generate.greedy_decode_fused_grouped_paged(
-                                dyn_args[0], self.cfg, *dyn_args[1:],
-                                max_new=kwargs["max_new"],
-                                return_cache=True, scratch_cache=scratch,
-                                **stop_kwargs))
-                else:
-                    exe = None
-                    if self.exec_registry is not None:
-                        exe = self.exec_registry.get(compile_plan.grouped_spec(
-                            bucket, g_pad, m_pad, sfx_bucket,
-                            kwargs["max_new"],
-                            stops_armed=stop_mask is not None,
-                            scratch=scratch is not None))
-                    if exe is not None:
-                        out, cache = compile_plan.registry_call(
-                            exe, (args[0],) + args[2:], stop_kwargs, scratch)
-                    else:
-                        out, cache = generate.greedy_decode_fused_grouped(
-                            *args, return_cache=True, scratch_cache=scratch,
-                            **kwargs)
-            except BaseException:
-                if plan is not None:
-                    self._abort_prefix_resume(plan)
-                raise
-            self._handoff.put(key, cache)
-            self._note_handoff(cache)
-            # A member row is one format branch of one cell: the row
-            # gather hands each REAL member its own copy of its group's
-            # state (the padded rows repeat the last member's).
-            self._note_recurrent(cache, rows=0, steps=kwargs["max_new"],
-                                 windows=2, forks=m)
-            if plan is not None:
-                self._finish_prefix_resume(plan, cache,
-                                           row_map=first_member)
-        else:
-            out = generate.greedy_decode_fused_grouped(*args, **kwargs)
+        host = dict(prefix=prefix, prefix_mask=prefix_mask, sfx_a=sfx,
+                    sfx_a_mask=sfx_mask,
+                    group_idx=np.asarray(group_idx, np.int32),
+                    yes_ids=yes2, no_ids=no2)
+        route = self.route(
+            "grouped", bucket, m_pad, g_pad, sfx_bucket, 0, new_tokens,
+            conf_tokens, early_stop and self.digit_stop_mask is not None)
+        if not reuse_cache:
+            (out,), _, _ = self._run_program(route.shape, host, reuse=False)
+            return out, m
+        # Plan rows are the PADDED prefix rows; the final cache holds
+        # member rows, and any member of a group carries the group's
+        # prefix slots — row_map points each prefix row at its group's
+        # first member row for the page extraction.
+        first_member = []
+        acc = 0
+        for g in groups:
+            first_member.append(acc)
+            acc += 2 * len(g.items)
+        first_member += [first_member[-1]] * (g_pad - len(groups))
+        plan = self._prefix_plan_or_none(
+            bucket, prefix_ids, len(groups), g_pad, use_prefix_cache)
+        (out,), _, cache = self._run_program(
+            route.spec(plan.window if plan is not None else 0), host, plan,
+            row_map=first_member)
+        # A member row is one format branch of one cell: the row gather
+        # hands each REAL member its own copy of its group's state (the
+        # padded rows repeat the last member's).
+        self._note_recurrent(cache, rows=0,
+                             steps=max(new_tokens, conf_tokens),
+                             windows=2, forks=m)
         return out, m
 
     def decode_completion(self, generated_ids: np.ndarray) -> str:

@@ -29,7 +29,8 @@ process):
    long prefix (the sweep formats x rephrasings of one base prompt, when
    rephrasings preserve the opening tokens) are grouped; each group's
    prefix is prefilled ONCE and every member row extends from a
-   row-gathered copy of that cache (generate.greedy_decode_fused_grouped)
+   row-gathered copy of that cache (generate.greedy_decode_dispatch's
+   grouped layout)
    — generalizing decode_fused_shared's pairwise binary/confidence
    sharing to arbitrary fan-out.
 

@@ -67,10 +67,12 @@ class SpecPlan:
     fleet: bool = False
     tree_rows: int = 0
 
-    def dyn_args(self) -> Tuple[np.ndarray, ...]:
-        """The eight drafting arrays in generate.*_spec argument order."""
-        return (self.ctx_a, self.ctx_a_len, self.draft_a, self.draft_a_len,
-                self.ctx_b, self.ctx_b_len, self.draft_b, self.draft_b_len)
+    def host_arrays(self) -> dict:
+        """The eight drafting arrays under the names
+        compile_plan.dispatch_args reads them by."""
+        names = ("ctx_a", "ctx_a_len", "draft_a", "draft_a_len",
+                 "ctx_b", "ctx_b_len", "draft_b", "draft_b_len")
+        return {n: getattr(self, n) for n in names}
 
 
 def _ctx_arrays(ids_rows: Sequence[Sequence[int]], width: int,

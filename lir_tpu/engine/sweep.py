@@ -686,6 +686,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                   and not engine.encoder_decoder)
     occupancy = None
     stop_armed = False
+    routes: list = []       # one runner.Route per ragged dispatch
     with tracing.span("sweep/plan", stage="schedule"):
         if ragged:
             dispatches, occupancy = _plan_ragged(engine, todo, new_tokens,
@@ -697,36 +698,20 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
             # the first bucket streams — the dispatch loop then consumes
             # precompiled executables (runner.exec_registry) instead of
             # paying trace-on-first-call serially inside the timed loop.
+            # Where each dispatch goes is the engine's to say, once
+            # (ScoringEngine.route): the compile plan compiles what the
+            # routes may run, and the chain keys and watchdog prices
+            # below read the same routes.
+            routes = [engine.route_dispatch(d, new_tokens, conf_tokens,
+                                            stop_armed)
+                      for d in dispatches]
             engine.exec_registry = None
             if engine.rt.aot_precompile:
                 specs = compile_plan.plan_specs(
-                    dispatches, B, new_tokens, conf_tokens, stop_armed,
-                    prefix_page_size=(engine.prefix_cache.page_size
-                                      if engine.prefix_cache is not None
-                                      else 0),
-                    piggyback=engine.piggyback_supported(),
+                    dispatches, routes,
                     stream_shape=(None if sink is None else
                                   (sink.n_prompts, sink.n_rephrase,
-                                   sink.guard)),
-                    spec_k=(engine.rt.spec_k
-                            if engine.spec_supported() else 0),
-                    spec_draft=getattr(engine, "_spec_draft", None)
-                    is not None,
-                    cascade_trunk=(
-                        (lambda d: engine.cascade_trunk_for(
-                            [it.bin_ids[:it.lcp] for it in d.items],
-                            len(d.items), d.edge))
-                        if getattr(engine, "cascade_supported",
-                                   lambda: False)() else None),
-                    cascade_int8=bool(
-                        getattr(engine, "cascade_cfg", None) is not None
-                        and engine.cascade_cfg.int8_qk),
-                    decode_trunk=(
-                        (lambda d: engine.decode_trunk_for(
-                            [it.bin_ids[:it.lcp] for it in d.items],
-                            len(d.items), d.edge))
-                        if getattr(engine, "cascade_decode_supported",
-                                   lambda: False)() else None))
+                                   sink.guard)))
                 engine.exec_registry = compile_plan.precompile_async(
                     engine, specs, max_workers=engine.rt.precompile_workers)
                 log.info("compile plan: precompiling %d executable shapes "
@@ -959,39 +944,16 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     # decode never waits on a host gap behind a full prefill. Results are
     # identical per row (tests/test_kernels.py); any failure falls back
     # to the plain recovered path, which recomputes both dispatches.
-    use_piggy = (ragged
-                 and getattr(engine, "piggyback_supported",
-                             lambda: False)())
     fused_dec = engine.rt.fused_decode
     # Speculative dispatches price their decode floor at the verify-
     # window constant (scheduler.DECODE_TOKEN_COST_SPEC); the watchdog's
     # widened seed headroom covers a zero-accept dispatch degenerating
     # to sequential cost.
     spec_on = getattr(engine, "spec_supported", lambda: False)()
-    # Cascade-eligible dispatches take the shared-prefix path inside
-    # decode_fused_shared (runner._dispatch_shared_cascade) — they never
-    # ride the piggyback chain (the cascade prefill has no parked-decode
-    # carry slot), mirroring compile_plan's `piggyback and not trunk`
-    # spec planning. Their trunk length also discounts the watchdog
-    # prefill price below.
-    cascade_on = getattr(engine, "cascade_supported", lambda: False)()
-    cascade_trunks = []
-    piggy_keys = []
-    with tracing.span("sweep/plan", stage="trunks"):
-        for d in (dispatches if ragged else ()):
-            if d.kind == "shared":
-                n = len(d.items)
-                trunk = (engine.cascade_trunk_for(
-                    [it.bin_ids[:it.lcp] for it in d.items], n, d.edge)
-                    if cascade_on else 0)
-                cascade_trunks.append(trunk)
-                piggy_keys.append(
-                    None if trunk else
-                    (d.edge, B if n == B else _tail_batch(n, B),
-                     d.sfx_bucket_a, d.sfx_bucket_b))
-            else:
-                cascade_trunks.append(0)
-                piggy_keys.append(None)
+    # Which dispatches may chain is the route's to say (None: never);
+    # a cascade trunk also discounts the watchdog prefill price below.
+    cascade_trunks = [r.trunk for r in routes]
+    piggy_keys = [r.chain_key for r in routes]
     pending: List[Optional[dict]] = [None]   # the parked dispatch's meta
 
     def _watched(call, cost):
@@ -1095,7 +1057,7 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                 # dispatch opens a run the NEXT dispatch will ride.
                 # Cascade-eligible dispatches carry a None key — two of
                 # them must not chain through the None == None trap.
-                chainable = use_piggy and piggy_keys[i] is not None and (
+                chainable = piggy_keys[i] is not None and (
                     (pending[0] is not None
                      and pending[0]["key"] == piggy_keys[i])
                     or (pending[0] is None and i + 1 < len(dispatches)

@@ -275,7 +275,7 @@ def shared_prefix_len(a: Sequence[int], b: Sequence[int]) -> int:
 
 def common_prefix_len(rows: Sequence[Sequence[int]]) -> int:
     """Longest common token prefix across ALL rows — the shared-trunk
-    extent of a dispatch (runner.cascade_trunk_for snaps it to the
+    extent of a dispatch (runner.ScoringEngine.shared_trunk snaps it to the
     trunk-quantum grid). Unlike :func:`shared_prefix_len` there is no
     keep-a-suffix cap: a row whose whole prefix IS the trunk simply
     contributes zero remainder tokens to the cascade extension (its

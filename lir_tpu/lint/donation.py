@@ -46,13 +46,14 @@ from .core import (Finding, LintPass, Module, Project, arg_names,
 JIT_NAMES = {"jit", "pjit"}
 
 # Donors the registry scan can't see syntactically: compile_plan.
-# registry_call feeds its ``scratch`` argument to an AOT-compiled
-# executable whose donation signature mirrors the lazy-jit fallback's —
-# the caller's scratch binding is just as dead afterwards.
+# registry_call feeds its ``scratch_cache`` argument to a dispatch
+# program (an AOT-compiled executable, or the lazy jit behind the same
+# argument list) that donates it — the caller's scratch binding is dead
+# afterwards.
 EXTRA_DONORS = {
-    "registry_call": ("exe", "dyn_args", "stop_kwargs", "scratch"),
+    "registry_call": ("run", "params", "args", "scratch_cache"),
 }
-EXTRA_DONATED = {"registry_call": {"scratch"}}
+EXTRA_DONATED = {"registry_call": {"scratch_cache"}}
 
 
 @dataclasses.dataclass

@@ -49,8 +49,7 @@ DEVICE_FNS = {
     "decode_fused", "decode_fused_shared", "decode_fused_grouped",
     "decode_fused_shared_piggy", "piggy_drain", "prefill",
     "readout_from_fused", "readout_from_step_logits", "sample_decode",
-    "greedy_decode_fused_shared", "greedy_decode_fused_grouped",
-    "greedy_decode_fused_shared_paged", "greedy_decode_fused_grouped_paged",
+    "greedy_decode_dispatch",
     "gather_slots", "scatter_pages", "flash_attention", "flash_decode",
     # Streaming-statistics sink (engine/stream_stats.py): the fold
     # update returns the live device accumulator; touching it host-side

@@ -157,27 +157,17 @@ class ContinuousBatcher:
         cap = self._batch_cap()
         return cap if self.pad_full else _tail_batch(n, cap)
 
-    def _cascade_trunk(self, rows: List["Pending"], bucket: int) -> int:
-        """Shared-trunk tokens the engine's cascade-prefill path would
-        dedupe for these queued rows (0 when cascade is off or the rows
-        are ineligible). Advisory pricing input only — the dispatch
-        itself re-derives eligibility from the same rows, so the price
-        model and the routing can never disagree on the discount."""
-        fn = getattr(self.engine, "cascade_trunk_for", None)
+    def _shared_trunk(self, rows: List["Pending"],
+                      bucket: int) -> Tuple[int, int]:
+        """(cascade-prefill trunk, decode trunk) the engine would dedupe
+        for these queued rows, 0 where that phase runs dense. Advisory
+        pricing input only — the dispatch routes itself from the same
+        rows through the same rule (ScoringEngine.shared_trunk), so the
+        price model and the routing can never disagree on the
+        discount."""
+        fn = getattr(self.engine, "shared_trunk", None)
         if fn is None or len(rows) < 2:
-            return 0
-        return fn([list(p.bin_ids[:p.lcp]) for p in rows],
-                  len(rows), bucket)
-
-    def _decode_trunk(self, rows: List["Pending"], bucket: int) -> int:
-        """Shared-trunk tokens the engine's cascade-DECODE path would
-        dedupe per decode step for these queued rows (0 when cascade
-        decode is off or the rows are ineligible). Advisory pricing
-        input, like :meth:`_cascade_trunk` — the dispatch re-derives
-        the extent from the same rows."""
-        fn = getattr(self.engine, "decode_trunk_for", None)
-        if fn is None or len(rows) < 2:
-            return 0
+            return 0, 0
         return fn([list(p.bin_ids[:p.lcp]) for p in rows],
                   len(rows), bucket)
 
@@ -209,8 +199,7 @@ class ContinuousBatcher:
                 cached = (sum(q[i].cached_hint for i in range(n))
                           if self.prefix_cache else 0)
                 picked = [q[i] for i in range(n)]
-                trunk = self._cascade_trunk(picked, edge)
-                dtrunk = self._decode_trunk(picked, edge)
+                trunk, dtrunk = self._shared_trunk(picked, edge)
                 per_row = sched_mod.bucket_cost(
                     self._dispatch_rows(n), edge, self.batch,
                     self.decode_cost, cached_tokens=cached,

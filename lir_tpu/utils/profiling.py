@@ -1435,7 +1435,7 @@ class StreamStats:
 @dataclasses.dataclass
 class SpecStats:
     """Speculative-decode counters (engine/spec.py over generate.
-    greedy_decode_fused_shared_spec): how many tokens were drafted,
+    _spec_tail): how many tokens were drafted,
     where the drafts came from, how many survived greedy verification,
     and how many sequential decode forwards the verify windows
     replaced. Thread-safe — the sweep dispatch thread folds while the

@@ -35,6 +35,9 @@ from lir_tpu.ops.cascade_prefill import (DEFAULT_BLOCK_N, cascade_attention,
                                          pick_block_n)
 from lir_tpu.ops.lse import merge_partials
 
+from dispatch_helpers import (assert_paged_equals_cold, fused_shared,
+                              fused_shared_cascade)
+
 
 def _tiny_cfg(**kw) -> ModelConfig:
     base = dict(name="cascade-tiny", vocab_size=128, hidden_size=32,
@@ -323,9 +326,9 @@ class TestCascadeSharedDecode:
         d = _shared_trunk_dispatch(1)
         ro = self._readout()
         na, nb = 3, 5
-        dense = generate.greedy_decode_fused_shared(
+        dense = fused_shared(
             params, cfg, *d, *ro, max_new_a=na, max_new_b=nb)
-        casc = generate.greedy_decode_fused_shared_cascade(
+        casc = fused_shared_cascade(
             params, cfg, *d, *ro, max_new_a=na, max_new_b=nb,
             trunk_len=32)
         _assert_fused_out_close(dense, casc, atol=5e-5)
@@ -337,9 +340,9 @@ class TestCascadeSharedDecode:
                                      dtype=jnp.float32)
         d = _shared_trunk_dispatch(2, B=2, S=64, trunk=48)
         ro = self._readout(B=2)
-        dense = generate.greedy_decode_fused_shared(
+        dense = fused_shared(
             params, cfg, *d, *ro, max_new_a=2, max_new_b=3)
-        casc = generate.greedy_decode_fused_shared_cascade(
+        casc = fused_shared_cascade(
             params, cfg, *d, *ro, max_new_a=2, max_new_b=3, trunk_len=48)
         _assert_fused_out_close(dense, casc, atol=5e-5)
 
@@ -355,9 +358,9 @@ class TestCascadeSharedDecode:
         eos = jnp.int32(2)
         kw = dict(max_new_a=3, max_new_b=5, stop_mask_b=stop,
                   stop_mask_a=jnp.zeros((128,), jnp.int32), eos_id=eos)
-        dense = generate.greedy_decode_fused_shared(
+        dense = fused_shared(
             params, cfg, *d, yes, no, d_ids, d_vals, **kw)
-        casc = generate.greedy_decode_fused_shared_cascade(
+        casc = fused_shared_cascade(
             params, cfg, *d, yes, no, d_ids, d_vals, trunk_len=32, **kw)
         _assert_fused_out_close(dense, casc, atol=5e-5)
 
@@ -369,9 +372,9 @@ class TestCascadeSharedDecode:
                                      dtype=jnp.float32)
         d = _shared_trunk_dispatch(4)
         ro = self._readout()
-        f32 = generate.greedy_decode_fused_shared_cascade(
+        f32 = fused_shared_cascade(
             params, cfg, *d, *ro, max_new_a=3, max_new_b=5, trunk_len=32)
-        i8 = generate.greedy_decode_fused_shared_cascade(
+        i8 = fused_shared_cascade(
             params, cfg, *d, *ro, max_new_a=3, max_new_b=5, trunk_len=32,
             int8_qk=True)
         for x, y in zip(jax.tree.leaves(f32[0]) + jax.tree.leaves(f32[1]),
@@ -425,7 +428,7 @@ class TestEngineRouting:
         off = _fake_engine(rt=RuntimeConfig(batch_size=4,
                                             cascade_prefill=False))
         assert not off.cascade_supported()
-        assert off.cascade_trunk_for(_trunk_rows(), 4, 64) == 0
+        assert off.shared_trunk(_trunk_rows(), 4, 64)[0] == 0
 
     def test_gate_needs_interpret_hook_on_cpu(self):
         eng = _fake_engine()
@@ -434,13 +437,13 @@ class TestEngineRouting:
     def test_trunk_derivation(self, cascade_interpret):
         eng = _fake_engine()
         rows = _trunk_rows(trunk=39)           # LCP 39 -> snaps to 32
-        assert eng.cascade_trunk_for(rows, 4, 64) == 32
-        assert eng.cascade_trunk_for(rows, 1, 64) == 0      # min_rows
+        assert eng.shared_trunk(rows, 4, 64)[0] == 32
+        assert eng.shared_trunk(rows, 1, 64)[0] == 0      # min_rows
         short = _trunk_rows(trunk=20)          # below min_trunk
-        assert eng.cascade_trunk_for(short, 4, 64) == 0
+        assert eng.shared_trunk(short, 4, 64)[0] == 0
         # trunk must stay strictly inside the bucket
         ident = [list(range(3, 67))] * 4
-        t = eng.cascade_trunk_for(ident, 4, 64)
+        t = eng.shared_trunk(ident, 4, 64)[0]
         assert 0 < t < 64 and t % 16 == 0
 
     def test_dispatch_matches_dense_and_counts(self, cascade_interpret):
@@ -487,7 +490,9 @@ class TestEngineRouting:
     def test_paged_warm_trunk_bitwise_equals_cold(self, cascade_interpret):
         """Dispatch twice with the same shared trunk on a prefix-cached
         engine: the second gathers the trunk from the radix page pool
-        and its payloads are BITWISE the cold dispatch's."""
+        and its tokens and decisions are the cold dispatch's, its
+        floats within the CPU's few ulps between a window extension
+        and a prefill (dispatch_helpers.assert_paged_equals_cold)."""
         from lir_tpu.config import RuntimeConfig
 
         eng = _fake_engine(rt=RuntimeConfig(batch_size=4,
@@ -510,9 +515,8 @@ class TestEngineRouting:
         warm = dispatch()
         assert eng.cascade_stats.cascade_dispatches == 2
         assert eng.prefix_stats.hits >= 1
-        for a, b in zip(cold, warm):
-            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        for a, b in zip(warm, cold):
+            assert_paged_equals_cold(a, b)
 
 
 # ---------------------------------------------------------------------------

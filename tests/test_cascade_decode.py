@@ -15,7 +15,7 @@ Parity contracts pinned here:
   path at every trunk extent of the cascade matrix;
 - generate-level: greedy_decode_fused_shared(decode_trunk=N) and the
   speculative sibling are BITWISE their decode_trunk=0 selves;
-- engine routing: cascade_decode_supported gates, decode_trunk_for LCP
+- engine routing: cascade_decode_supported gates, shared_trunk LCP
   reuse, CascadeStats decode counters (dispatches + analytic deduped
   trunk bytes), and the --no-cascade-decode static-config mirror;
 - scheduler: decode_floor's decode_trunk_frac discount with defaults
@@ -37,6 +37,9 @@ from lir_tpu.ops.flash_decode import (flash_decode, flash_decode_mq,
                                       flash_decode_mq_trunk,
                                       flash_decode_trunk, pick_split)
 from lir_tpu.ops.lse import merge_partials
+
+from dispatch_helpers import (fused_shared, fused_shared_cascade,
+                              fused_shared_spec)
 
 
 def _tiny_cfg(**kw) -> ModelConfig:
@@ -398,9 +401,9 @@ class TestGenerateDecodeTrunk:
         params = decoder.init_params(cfg, jax.random.PRNGKey(0),
                                      dtype=jnp.float32)
         args = _trunk_shared_args(0)
-        flat = generate.greedy_decode_fused_shared(
+        flat = fused_shared(
             params, cfg, *args, max_new_a=3, max_new_b=5)
-        trunked = generate.greedy_decode_fused_shared(
+        trunked = fused_shared(
             params, cfg, *args, max_new_a=3, max_new_b=5, decode_trunk=96)
         _assert_trees_bitwise(flat, trunked)
 
@@ -417,11 +420,11 @@ class TestGenerateDecodeTrunk:
         decoder.CASCADE_INTERPRET_ON_CPU = True
         try:
             args = _trunk_shared_args(1)
-            on = generate.greedy_decode_fused_shared_cascade(
+            on = fused_shared_cascade(
                 params, cfg, *args, max_new_a=2, max_new_b=3, trunk_len=96)
             cfg_on = dataclasses.replace(cfg, name="cascdec-gate-on",
                                          cascade_decode=True)
-            on2 = generate.greedy_decode_fused_shared_cascade(
+            on2 = fused_shared_cascade(
                 params, cfg_on, *args, max_new_a=2, max_new_b=3,
                 trunk_len=96)
         finally:
@@ -444,9 +447,9 @@ class TestGenerateDecodeTrunk:
               jnp.zeros((B, Ta), jnp.int32), jnp.zeros((B,), jnp.int32),
               jnp.asarray(ctx), jnp.asarray(lens),
               jnp.zeros((B, Tb), jnp.int32), jnp.zeros((B,), jnp.int32))
-        flat = generate.greedy_decode_fused_shared_spec(
+        flat = fused_shared_spec(
             params, cfg, *args, *si, max_new_a=Ta, max_new_b=Tb, spec_k=k)
-        trunked = generate.greedy_decode_fused_shared_spec(
+        trunked = fused_shared_spec(
             params, cfg, *args, *si, max_new_a=Ta, max_new_b=Tb, spec_k=k,
             decode_trunk=96)
         _assert_trees_bitwise(flat, trunked)
@@ -481,11 +484,11 @@ class TestEngineDecodeTrunk:
 
         eng = _fake_engine()
         assert eng.cascade_decode_supported()
-        assert eng.decode_trunk_for(_trunk_rows(), 4, 128) == 96
+        assert eng.shared_trunk(_trunk_rows(), 4, 128)[1] == 96
         off = _fake_engine(rt=RuntimeConfig(batch_size=4,
                                             cascade_decode=False))
         assert not off.cascade_decode_supported()
-        assert off.decode_trunk_for(_trunk_rows(), 4, 128) == 0
+        assert off.shared_trunk(_trunk_rows(), 4, 128)[1] == 0
         # the static model flag mirrors the runtime opt-out, so stale
         # executables can never serve the other mode
         assert off.cfg.cascade_decode is False
@@ -494,7 +497,7 @@ class TestEngineDecodeTrunk:
     def test_gate_needs_fused_decode_kernels(self):
         eng = _fake_engine()          # hook not armed, CPU backend
         assert not eng.cascade_decode_supported()
-        assert eng.decode_trunk_for(_trunk_rows(), 4, 128) == 0
+        assert eng.shared_trunk(_trunk_rows(), 4, 128)[1] == 0
 
     def test_fused_suffix_flag_mirrors(self):
         from lir_tpu.config import RuntimeConfig
@@ -504,14 +507,14 @@ class TestEngineDecodeTrunk:
         assert eng.cfg.cascade_fused_suffix is False
 
     def test_trunk_reuses_lcp_discipline(self, fused_decode_interpret):
-        """decode_trunk_for is the SAME quantized-LCP ladder the cascade
+        """the decode trunk is the SAME quantized-LCP ladder the cascade
         prefill keys on: quantum snap, min_rows, bucket clamp."""
         eng = _fake_engine()
         rows = _trunk_rows(trunk=39)
-        assert eng.decode_trunk_for(rows, 4, 64) == 32    # snap to 32
-        assert eng.decode_trunk_for(rows, 1, 64) == 0     # min_rows
+        assert eng.shared_trunk(rows, 4, 64)[1] == 32    # snap to 32
+        assert eng.shared_trunk(rows, 1, 64)[1] == 0     # min_rows
         ident = [list(range(3, 131))] * 4
-        t = eng.decode_trunk_for(ident, 4, 128)
+        t = eng.shared_trunk(ident, 4, 128)[1]
         assert 0 < t < 128                                # bucket clamp
 
     def test_dispatch_counters_and_parity(self, fused_decode_interpret):
@@ -557,7 +560,7 @@ class TestEngineDecodeTrunk:
 
 class TestCompilePlanDecodeTrunk:
     def test_spec_label_and_keying(self):
-        from lir_tpu.engine import compile_plan as cp
+        import dispatch_helpers as cp
 
         flat = cp.shared_spec(128, 4, 8, 8, 3, 4, False, False)
         trunked = cp.shared_spec(128, 4, 8, 8, 3, 4, False, False,

@@ -4,7 +4,7 @@ Pins the cache-keying contract the cold-start tentpole relies on:
 - the manifest key separates every input that changes an executable
   (model config, quant mode, mesh, bucket ladder, runtime budgets) — no
   stale-executable reuse is possible across configurations;
-- plan_specs mirrors the runner's padding and cache-handoff variant
+- plan_specs follows the runner's padding and cache-handoff variant
   selection exactly, so every planned executable is the one dispatched;
 - same-shape dispatches reuse ONE registry executable (and the donated
   variant is a distinct one);
@@ -26,6 +26,8 @@ from lir_tpu.engine import compile_plan, scheduler as sched_mod
 from lir_tpu.engine import tokens as tok
 from lir_tpu.utils import compile_cache
 from lir_tpu.utils.profiling import CompileStats, OccupancyStats
+
+from dispatch_helpers import grouped_spec, plan_specs, shared_spec
 
 
 @pytest.fixture(autouse=True)
@@ -87,7 +89,7 @@ def test_quant_mode_fingerprint():
 
 
 # ---------------------------------------------------------------------------
-# plan_specs mirrors the runner: padding + handoff variants
+# plan_specs follows the engine's routes: padding + handoff variants
 # ---------------------------------------------------------------------------
 
 def _items(lengths, fmt_len=6):
@@ -110,8 +112,11 @@ def test_plan_specs_variants_and_order():
                                         stats=OccupancyStats())
     dispatches = planner.schedule(_items([30] * 12))
     assert len(dispatches) == 3
-    specs = compile_plan.plan_specs(dispatches, 4, new_tokens=4,
-                                    conf_tokens=8, stops_armed=False)
+    engine = _tiny_engine(RuntimeConfig(batch_size=4, max_seq_len=256,
+                                        spec_decode=False,
+                                        piggyback_prefill=False))
+    specs = plan_specs(engine, dispatches, new_tokens=4, conf_tokens=8,
+                       stops_armed=False)
     assert len(specs) == 2
     assert [s.scratch for s in specs] == [False, True]
     assert all(s.kind == "shared" and s.batch == 4 for s in specs)
@@ -120,9 +125,9 @@ def test_plan_specs_variants_and_order():
     # The padded tail dispatch (13th cell -> power-of-two pad) is its own
     # shape; stops_armed flips every spec (different traced pytree).
     d13 = planner.schedule(_items([30] * 13))
-    specs13 = compile_plan.plan_specs(d13, 4, 4, 8, stops_armed=False)
+    specs13 = plan_specs(engine, d13, 4, 8, stops_armed=False)
     assert {s.batch for s in specs13} == {4, 1}
-    armed = compile_plan.plan_specs(d13, 4, 4, 8, stops_armed=True)
+    armed = plan_specs(engine, d13, 4, 8, stops_armed=True)
     assert set(armed).isdisjoint(specs13)
 
 
@@ -298,9 +303,9 @@ def test_donated_scratch_cache_really_aliases_the_output(kind):
     The decode programs keep unused arguments, so the compiled donated
     variant must carry an input/output alias for the cache pair."""
     engine = _tiny_engine(RuntimeConfig(batch_size=4, max_seq_len=128))
-    spec = (compile_plan.shared_spec(64, 4, 8, 8, 4, 8, False, True)
+    spec = (shared_spec(64, 4, 8, 8, 4, 8, False, True)
             if kind == "shared" else
-            compile_plan.grouped_spec(64, 2, 4, 8, 4, False, True))
+            grouped_spec(64, 2, 4, 8, 4, False, True))
     text = compile_plan._lower_compile(engine, spec).as_text()
     assert "input_output_alias" in text
     assert text[text.index("input_output_alias"):][:400].count(

@@ -237,6 +237,8 @@ import dataclasses as _dc
 
 from lir_tpu.models.registry import ModelConfig as _MC
 
+from dispatch_helpers import fused_shared
+
 
 @pytest.mark.parametrize("family,int8kv", [
     ("llama", False),   # rotary + RMSNorm + gated MLP
@@ -285,7 +287,7 @@ def test_shared_prefix_decode_matches_full_prompts(family, int8kv):
     pre, pre_mask = tok.left_pad_ids(prefix_ids, 32, 0)
     sa, sa_mask = tok.right_pad_ids(sa_ids, 8, 0)
     sb, sb_mask = tok.right_pad_ids(sb_ids, 8, 0)
-    out_a, out_b = generate.greedy_decode_fused_shared(
+    out_a, out_b = fused_shared(
         params, cfg, jnp.asarray(pre), jnp.asarray(pre_mask),
         jnp.asarray(sa), jnp.asarray(sa_mask), jnp.asarray(sb),
         jnp.asarray(sb_mask), jnp.asarray(yes_ids), jnp.asarray(no_ids),

@@ -31,6 +31,8 @@ from lir_tpu.models import cache as cache_mod  # noqa: E402
 from lir_tpu.models import decoder, registry  # noqa: E402
 from lir_tpu.ops import ssd_scan  # noqa: E402
 
+from dispatch_helpers import fused_shared, fused_shared_cascade
+
 SEED = 2**31 + 26
 VOCAB = 512
 
@@ -293,7 +295,7 @@ def test_two_branches_after_a_shared_prefix_equal_two_full_passes(model,
     B, S, S2, new = 4, 24, 6, 3
     args = _shared_inputs(rng, B, S, S2)
     with jax.default_matmul_precision("highest"):
-        out_a, out_b = generate.greedy_decode_fused_shared(
+        out_a, out_b = fused_shared(
             params, cfg, *args, max_new_a=new, max_new_b=new, topk=5)
     prefix, pm, sa, sam, sb, sbm = (np.asarray(a) for a in args[:6])
     for out, sfx, sm in ((out_a, sa, sam), (out_b, sb, sbm)):
@@ -323,10 +325,10 @@ def test_cascade_program_equals_the_dense_shared_program(model, kernels):
         B, S, S2, new, trunk = 4, 32, 6, 3, 16
         args = _shared_inputs(rng, B, S, S2, shared_head=trunk)
         with jax.default_matmul_precision("highest"):
-            dense = generate.greedy_decode_fused_shared(
+            dense = fused_shared(
                 params, cfg, *args, max_new_a=new, max_new_b=new, topk=5,
                 return_cache=True)
-            casc = generate.greedy_decode_fused_shared_cascade(
+            casc = fused_shared_cascade(
                 params, cfg, *args, max_new_a=new, max_new_b=new,
                 trunk_len=trunk, topk=5, return_cache=True)
     finally:
@@ -365,7 +367,7 @@ def test_the_ssm_state_is_float32_in_a_bfloat16_engine(model, kernels):
         _, cache = decoder.decode_step(params, cfg, cache, ones(B), pos,
                                        S + S2, cm)
         held.append(cache)
-        shared = generate.greedy_decode_fused_shared(
+        shared = fused_shared(
             params, cfg, ones(B, S), ones(B, S), ones(B, S2), ones(B, S2),
             ones(B, S2), ones(B, S2), ones(B), ones(B),
             jnp.arange(3, 13, dtype=jnp.int32),
@@ -429,8 +431,9 @@ def test_what_cannot_hold_recurrent_state_refuses(model, what):
                 "Rt", [("prefix_cache_pages", int, 8)])()
             ScoringEngine.enable_prefix_cache(eng)
         elif what == "paged":
-            generate._paged_prefix(params, cfg, None, None, 0, tok, tok,
-                                   tok, 8)
+            generate._paged_prefix(
+                params, cfg, generate.PagedFront(None, None, 0, tok, tok),
+                tok, 8)
         else:
             generate.shared_piggyback_prefill(
                 params, cfg, tok, tok, tok, tok, tok, tok, max_new_a=2,
@@ -445,13 +448,13 @@ def test_an_absent_mixer_leaves_the_older_programs_as_they_were(family):
     assert not cfg.has_mixer
     params = decoder.init_params(cfg, jax.random.PRNGKey(0))
     args = _shared_inputs(np.random.default_rng(4), 2, 16, 4)
-    lowered = generate.greedy_decode_fused_shared.lower(
+    lowered = fused_shared(
         params, cfg, *args, max_new_a=2, max_new_b=2, topk=5,
-        return_cache=True)
+        return_cache=True, lower=True)
     text = lowered.as_text()
     assert "ssd_scan" not in text and "ssm_step" not in text
     out = jax.eval_shape(
-        lambda p: generate.greedy_decode_fused_shared(
+        lambda p: fused_shared(
             p, cfg, *args, max_new_a=2, max_new_b=2, topk=5,
             return_cache=True), params)
     assert len(out[2]) == 2
@@ -550,12 +553,11 @@ def test_the_engines_counts_are_the_calls_its_program_makes(program,
     engine = ScoringEngine(params, cfg, FakeTokenizer(),
                            RuntimeConfig(batch_size=4, max_seq_len=256))
     dispatched = []
-    for name in ("greedy_decode_fused_shared", "greedy_decode_fused_grouped",
-                 "greedy_decode_fused_shared_cascade"):
-        def spy(*a, _fn=getattr(generate, name), **kw):
-            dispatched.append((_fn, a, dict(kw, scratch_cache=None)))
-            return _fn(*a, **kw)
-        monkeypatch.setattr(generate, name, spy)
+
+    def spy(*a, _fn=generate.greedy_decode_dispatch, **kw):
+        dispatched.append((_fn, a, dict(kw, scratch_cache=None)))
+        return _fn(*a, **kw)
+    monkeypatch.setattr(generate, "greedy_decode_dispatch", spy)
 
     head = "coverage policy flood water damage claim insurer premium " * 5
     mains = [(head if program != "shared" else "") + f"row {i} " * (3 + i)
@@ -584,7 +586,9 @@ def test_the_engines_counts_are_the_calls_its_program_makes(program,
                                    reuse_cache=True, use_prefix_cache=False)
         rows = 3
     (fn, args, kwargs), = dispatched
-    assert fn.__name__.endswith(program), fn.__name__
+    assert (args[2].front, args[2].layout) == {
+        "shared": ("prefill", "pair"), "cascade": ("cascade", "pair"),
+        "grouped": ("prefill", "grouped")}[program], args[2]
     rec = engine.recurrent_stats
     assert rec.dispatches == 1
 

@@ -27,6 +27,8 @@ from lir_tpu.models import decoder, quant
 from lir_tpu.models.registry import ModelConfig
 from lir_tpu.ops import flash_decode, pick_split
 
+from dispatch_helpers import fused_shared
+
 
 def _tiny_cfg(**kw) -> ModelConfig:
     base = dict(name="kernels-tiny", vocab_size=128, hidden_size=32,
@@ -431,7 +433,7 @@ class TestPiggyback:
         d_vals = jnp.arange(0.0, 20.0, dtype=jnp.float32)
         na, nb = 3, 5
         ds = [self._dispatch(s) for s in (1, 2, 3)]
-        seq = [generate.greedy_decode_fused_shared(
+        seq = [fused_shared(
             params, cfg, *d, yes, no, d_ids, d_vals, max_new_a=na,
             max_new_b=nb) for d in ds]
 

@@ -42,6 +42,8 @@ from lir_tpu.models.registry import ModelConfig, tiny
 from lir_tpu.serve import migrate as mig
 from lir_tpu.serve import (ReplicaRouter, ScoringServer, ServeRequest)
 
+from dispatch_helpers import assert_paged_equals_cold
+
 CFG = tiny("llama")
 PARAMS = decoder.init_params(CFG, jax.random.PRNGKey(1))
 TOKZ = FakeTokenizer(vocab=CFG.vocab_size)
@@ -348,7 +350,9 @@ def test_forget_tail_rolls_back_and_notifies():
 
 
 # ---------------------------------------------------------------------------
-# Migrated decode == colocated decode (bitwise)
+# Migrated decode == colocated decode (dispatch_helpers
+# .assert_paged_equals_cold: tokens exact, floats to the CPU's ulp bar;
+# the int8 flavor compares paged with paged and stays bitwise)
 # ---------------------------------------------------------------------------
 
 def _migrated_vs_colocated(early_stop=False, params=PARAMS, cfg=CFG):
@@ -364,19 +368,19 @@ def _migrated_vs_colocated(early_stop=False, params=PARAMS, cfg=CFG):
     ref = _engine(False, params=params, cfg=cfg)
     want = _shared(ref, bps, cps, False, early_stop=early_stop)
     for k in (0, 1):
-        assert_fused_bitwise(got[k], want[k])
+        assert_paged_equals_cold(got[k], want[k])
     _assert_pins_released(dst)
 
 
 def test_migrated_decode_bitwise_cold():
     """Decode resuming from migrated pages == the colocated unpaged
-    run, bitwise (the destination never prefilled this prefix)."""
+    run (the destination never prefilled this prefix)."""
     _migrated_vs_colocated()
 
 
 def test_migrated_decode_bitwise_warm_repeat():
     """Second dispatch on the destination (fully warm, migrated pages
-    now mixed with locally-inserted ones) stays bitwise."""
+    now mixed with locally-inserted ones) stays equal."""
     bps, cps = _prompts(4, seed=5)
     prefixes = _prefixes(bps, cps)
     src = _engine(True)
@@ -389,7 +393,7 @@ def test_migrated_decode_bitwise_warm_repeat():
     want = _shared(ref, bps, cps, False)
     for got in (first, second):
         for k in (0, 1):
-            assert_fused_bitwise(got[k], want[k])
+            assert_paged_equals_cold(got[k], want[k])
 
 
 def test_migrated_decode_bitwise_early_stop():

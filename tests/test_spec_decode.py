@@ -28,6 +28,8 @@ from lir_tpu.engine.runner import ScoringEngine
 from lir_tpu.models import decoder, paged
 from lir_tpu.models.registry import ModelConfig
 
+from dispatch_helpers import fused_shared, fused_shared_spec, plan_specs
+
 VOCAB = 256
 CFG = ModelConfig(name="spec-tiny", vocab_size=VOCAB, hidden_size=32,
                   n_layers=1, n_heads=2, n_kv_heads=2,
@@ -89,7 +91,7 @@ def _shared_args(prefixes, sfx_a_ids, sfx_b_ids, bucket=32, sb=8):
 
 
 def _seq(args, Ta=4, Tb=8, **kw):
-    return jax.device_get(generate.greedy_decode_fused_shared(
+    return jax.device_get(fused_shared(
         PARAMS, CFG, *args, max_new_a=Ta, max_new_b=Tb, **kw))
 
 
@@ -125,7 +127,7 @@ def _spec_inputs(prefixes, sfx_a_ids, sfx_b_ids, Ta, Tb, bucket=32, sb=8,
 
 
 def _spec(args, spec_inputs, Ta=4, Tb=8, k=4, **kw):
-    out = generate.greedy_decode_fused_shared_spec(
+    out = fused_shared_spec(
         PARAMS, CFG, *args, *spec_inputs, max_new_a=Ta, max_new_b=Tb,
         spec_k=k, **kw)
     return jax.device_get(out)
@@ -556,7 +558,7 @@ def test_plan_specs_covers_spec_variants_per_bucket_batch_k():
                                      conf_ids=tuple(ids + [2]),
                                      lcp=n))
     dispatches = planner.schedule(items)
-    specs = compile_plan.plan_specs(dispatches, 4, 4, 8, False, spec_k=4)
+    specs = plan_specs(_engine(True), dispatches, 4, 8, False)
     spec_specs = [s for s in specs if s.spec_k]
     assert spec_specs, "no speculative executables planned"
     assert all(s.spec_k == 4 and not s.spec_draft for s in spec_specs)

@@ -549,17 +549,22 @@ def test_cascade_program_updates_the_stacked_cache_in_place(one_chip,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    args = (params, cfg, i32(BATCH, 448), i32(BATCH, 448), i32(BATCH, 32),
-            i32(BATCH, 32), i32(BATCH, 32), i32(BATCH, 32), i32(BATCH),
-            i32(BATCH), i32(101),
-            jax.ShapeDtypeStruct((101,), jnp.float32, sharding=one_chip))
-    kw = dict(stop_mask_a=i32(V), stop_mask_b=i32(V), eos_id=i32(),
-              max_new_a=4, max_new_b=8, trunk_len=TRUNK, topk=20,
-              int8_qk=False, return_cache=True)
-    fn = generate.greedy_decode_fused_shared_cascade
-    cache = fn.eval_shape(*args, scratch_cache=None, **kw)[-1]
-    text = fn.lower(*args, scratch_cache=_shaped(cache, one_chip),
-                    **kw).compile().as_text()
+    args = (params, cfg,
+            generate.Program(front="cascade", max_new=(4, 8), topk=20,
+                             trunk=TRUNK, return_cache=True),
+            generate.DispatchArgs(
+                prefix=i32(BATCH, 448), prefix_mask=i32(BATCH, 448),
+                sfx=(i32(BATCH, 32), i32(BATCH, 32)),
+                sfx_mask=(i32(BATCH, 32), i32(BATCH, 32)),
+                yes_ids=i32(BATCH), no_ids=i32(BATCH), digit_ids=i32(101),
+                digit_vals=jax.ShapeDtypeStruct((101,), jnp.float32,
+                                                sharding=one_chip),
+                stops=generate.Stops(binary=i32(V), digits=i32(V),
+                                     eos_id=i32())))
+    fn = generate.greedy_decode_dispatch
+    cache = fn.eval_shape(*args, scratch_cache=None)[-1]
+    text = fn.lower(*args, scratch_cache=_shaped(cache, one_chip)
+                    ).compile().as_text()
     assert cache[0].shape == (DEPTH, 8, EXTENT, BATCH, 128)
     stacked, layers = _cache_shapes(cfg, cache)
     found = _cache_moves(text, stacked, layers, whole_layer_reads=False)

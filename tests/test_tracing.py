@@ -31,6 +31,10 @@ from lir_tpu.observe import registry as reg_mod
 from lir_tpu.observe import tracing
 from lir_tpu.utils.profiling import CompileStats
 
+from dispatch_helpers import (grouped_paged_spec, grouped_spec,
+                              shared_cascade_paged_spec, shared_cascade_spec,
+                              shared_paged_spec, shared_spec)
+
 REPO = Path(__file__).resolve().parents[1]
 
 
@@ -360,8 +364,7 @@ def test_plan_ends_before_the_first_dispatch(traced_sweep):
     events, _ = traced_sweep
     plans = [e for e in events if e["name"] == "sweep/plan"]
     first = min(e["t0"] for e in events if e["name"] == "sweep/dispatch")
-    assert {e["args"]["stage"] for e in plans} == {"grid", "schedule",
-                                                   "trunks"}
+    assert {e["args"]["stage"] for e in plans} == {"grid", "schedule"}
     assert max(e["t1"] for e in plans) <= first
     # the process-wide plan was compiled by the first call: nothing loads
     assert not [e for e in events if e["name"] == "engine/compile_load"]
@@ -437,23 +440,23 @@ def _family_engine(family):
 ALL_PHASES = {"lir.prefill", "lir.extend", "lir.decode", "lir.readout"}
 FAMILIES = {
     # family: (spec, exactly the scopes the lowered text must name)
-    "shared": (lambda: compile_plan.shared_spec(
+    "shared": (lambda: shared_spec(
         64, 4, 8, 8, 4, 8, False, False), ALL_PHASES),
-    "shared_donated": (lambda: compile_plan.shared_spec(
+    "shared_donated": (lambda: shared_spec(
         64, 4, 8, 8, 4, 8, False, True), ALL_PHASES),
-    "spec": (lambda: compile_plan.shared_spec(
+    "spec": (lambda: shared_spec(
         64, 4, 8, 8, 4, 8, False, False, spec_k=4), ALL_PHASES),
-    "grouped": (lambda: compile_plan.grouped_spec(
+    "grouped": (lambda: grouped_spec(
         64, 2, 4, 8, 8, False, False), ALL_PHASES),
-    "cascade": (lambda: compile_plan.shared_cascade_spec(
+    "cascade": (lambda: shared_cascade_spec(
         64, 4, 32, 8, 8, 4, 8, False, False), ALL_PHASES),
-    "paged": (lambda: compile_plan.shared_paged_spec(
+    "paged": (lambda: shared_paged_spec(
         64, 4, 16, 8, 8, 4, 8, False, False), ALL_PHASES),
-    "paged_spec": (lambda: compile_plan.shared_paged_spec(
+    "paged_spec": (lambda: shared_paged_spec(
         64, 4, 16, 8, 8, 4, 8, False, False, spec_k=4), ALL_PHASES),
-    "cascade_paged": (lambda: compile_plan.shared_cascade_paged_spec(
+    "cascade_paged": (lambda: shared_cascade_paged_spec(
         64, 4, 32, 16, 8, 8, 4, 8, False, False), ALL_PHASES),
-    "grouped_paged": (lambda: compile_plan.grouped_paged_spec(
+    "grouped_paged": (lambda: grouped_paged_spec(
         64, 2, 4, 16, 8, 8, False, False), ALL_PHASES),
     "piggy_prefill": (lambda: compile_plan.piggy_prefill_spec(
         64, 4, 8, 8, 4, 8), {"lir.prefill", "lir.extend"}),
@@ -519,9 +522,10 @@ from test_tracing import _tiny_engine
 from lir_tpu.config import RuntimeConfig
 from lir_tpu.engine import compile_plan
 from lir_tpu.utils import compile_cache
+from dispatch_helpers import shared_spec
 compile_cache.enable_persistent_cache(sys.argv[1])
 engine = _tiny_engine(RuntimeConfig(batch_size=4, max_seq_len=256))
-spec = compile_plan.shared_spec(64, 4, 8, 8, 4, 8, False, False)
+spec = shared_spec(64, 4, 8, 8, 4, 8, False, False)
 registry = compile_plan.ExecutableRegistry(engine.cache_manifest_key,
                                            engine.compile_stats)
 with ThreadPoolExecutor(1, thread_name_prefix="compile-plan") as pool:
@@ -561,7 +565,7 @@ def test_scope_table_survives_a_warm_persistent_cache(tmp_path):
     warm = _table_in_a_fresh_process(tmp_path / "xla", "scoped")
     assert cold["hits_at_load"] == 0 and warm["hits_at_load"] >= 1
     for table in (cold, warm):
-        assert table["module"].startswith("jit_greedy_decode_fused_shared")
+        assert table["module"].startswith("jit_greedy_decode_dispatch")
         assert not table["recompiled"]
         assert all(table["counts"].get(s, 0) >= 1 for s in SCOPES), table
     assert warm["counts"] == cold["counts"]

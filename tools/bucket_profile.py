@@ -5,7 +5,7 @@ old conf=16 budget; r4 cut the confidence decode to 8 tokens and the
 profile went stale — nothing measured said where the e2e-vs-isolated gap
 (31.7 vs 41.0 p/s) now comes from or what the new device-bound ceiling
 is. This tool re-measures the components of one production sweep bucket
-(the shared-prefix two-format scorer, generate.greedy_decode_fused_shared)
+(the shared-prefix two-format scorer, generate.greedy_decode_dispatch)
 with repeats INSIDE one jitted lax.scan, so per-iteration time contains
 zero host/dispatch overhead. Differencing two scan lengths cancels the fixed entry cost.
 
@@ -104,10 +104,13 @@ def main() -> None:
     @functools.partial(jax.jit, static_argnames=("reps", "bin_t", "conf_t"))
     def scan_full(params, prefix, reps, bin_t, conf_t):
         def body(carry, _):
-            out_a, out_b = generate.greedy_decode_fused_shared(
-                params, cfg, _vary(prefix, carry), pmask, sfx, smask, sfx,
-                smask, yes_ids, no_ids, digit_ids, digit_vals,
-                max_new_a=bin_t, max_new_b=conf_t)
+            (out_a, out_b), _, _ = generate.greedy_decode_dispatch(
+                params, cfg, generate.Program(max_new=(bin_t, conf_t)),
+                generate.DispatchArgs(
+                    prefix=_vary(prefix, carry), prefix_mask=pmask,
+                    sfx=(sfx, sfx), sfx_mask=(smask, smask),
+                    yes_ids=yes_ids, no_ids=no_ids, digit_ids=digit_ids,
+                    digit_vals=digit_vals))
             # Consume every output so nothing is dead-code-eliminated.
             chk = (out_a.p_yes.sum() + out_b.weighted_confidence.sum()
                    + out_a.generated.sum() + out_b.generated.sum())
