@@ -569,3 +569,59 @@ def test_cascade_program_updates_the_stacked_cache_in_place(one_chip,
     stacked, layers = _cache_shapes(cfg, cache)
     found = _cache_moves(text, stacked, layers, whole_layer_reads=False)
     assert found == []
+
+
+# ---------------------------------------------------------------------------
+# The exact GELU stays a short epilogue of the up-projection
+# ---------------------------------------------------------------------------
+# ``decoder._act(., "gelu")`` runs on every element the up-projection makes
+# (falcon-7b: 15360 x 18176 a prefill call). Written through ``erfc`` (what
+# jax.nn.gelu(approximate=False) is) XLA expands it inside the matmul's
+# fusion into both of erfc's branches: 29 multiply + 21 add + 4 select + 4
+# compare + 2 divide + 1 exponential an element, a quarter of the call's time
+# on the chip. What says the short form is still the compiled one is the
+# fusion itself; a jax upgrade that expands it again fails here.
+
+_NOT_ARITHMETIC = {"parameter", "constant", "broadcast", "convert",
+                   "convolution", "bitcast", "fusion", "copy", "reshape",
+                   "transpose"}
+_TRANSCENDENTAL = {"erf", "exponential", "exponential-minus-one", "log",
+                   "log-plus-one", "tanh", "logistic", "power", "sqrt",
+                   "rsqrt", "cbrt", "sine", "cosine", "atan2"}
+
+
+def _up_projection(x, q, scale):
+    up = quant.matmul(x, quant.QuantTensor(q=q, scale=scale))
+    return decoder._act(up, "gelu")
+
+
+@pytest.mark.parametrize("rows", [384, 32], ids=["prefill", "extend"])
+def test_gelu_is_a_short_epilogue_of_the_up_projection(one_chip, rows):
+    cfg = registry.falcon_7b()
+    d, ffn = cfg.hidden_size, cfg.intermediate_size
+    assert (d, ffn, cfg.activation) == (4544, 18176, "gelu")
+    text = _compile(_up_projection,
+                    [((BATCH, rows, d), jnp.bfloat16), ((d, ffn), jnp.int8),
+                     ((ffn,), jnp.float32)], one_chip).as_text()
+    comps = _computations(text)
+    out = f"[{BATCH},{rows},{ffn}]"
+
+    holders = [name for name, lines in comps.items()
+               if any(" convolution(" in line for line in lines)]
+    assert len(holders) == 1, holders
+    ops = [m.group(3) for m in map(_INSTR.match, comps[holders[0]]) if m]
+    arithmetic = [op for op in ops if op not in _NOT_ARITHMETIC]
+    transcendental = [op for op in arithmetic if op in _TRANSCENDENTAL]
+    assert len(arithmetic) > 1, ops     # beyond the scale: the activation
+    assert "divide" not in ops, ops
+    assert len(transcendental) <= 1, transcendental
+    assert len(arithmetic) <= 30, sorted(arithmetic)
+
+    calls = [line for lines in comps.values() for line in lines
+             if re.search(rf"calls=%?{re.escape(holders[0])}\b", line)]
+    assert len(calls) == 1 and "kind=kOutput" in calls[0], calls
+    assert _INSTR.match(calls[0]).group(2).startswith("bf16" + out), calls[0]
+    # ... and nothing of the output's extent in float32 leaves it for HBM.
+    outside = [line.strip()[:120] for name, lines in comps.items()
+               if name != holders[0] for line in lines if "f32" + out in line]
+    assert outside == []
