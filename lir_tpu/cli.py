@@ -232,6 +232,15 @@ def _add_engine_tuning_flags(p) -> None:
     p.add_argument("--sweep-group-min-cells", type=int, default=None,
                    help="cross-cell prefix grouping: minimum cells per "
                         "group (default 4; 0 disables grouping)")
+    p.add_argument("--dispatch-tokens", type=int, default=None,
+                   help="most tokens one pass of a sweep dispatch may "
+                        "hold: its rows times what each runs beyond the "
+                        "prefix they share (default 0 = uncapped; set it "
+                        "for 16k-token documents, DEPLOY.md §1b)")
+    p.add_argument("--donate-first", action="store_true",
+                   help="hand a shape's first dispatch an empty cache to "
+                        "donate: one program a shape, not two (DEPLOY.md "
+                        "§1b)")
     p.add_argument("--no-aot-precompile", action="store_true",
                    help="disable background AOT precompilation of the "
                         "planned dispatch shapes (every shape then pays "
@@ -281,6 +290,10 @@ def _engine_rt_kw(args, rt_kw: dict) -> None:
         rt_kw["sweep_group_min_prefix"] = args.sweep_group_min_prefix
     if getattr(args, "sweep_group_min_cells", None) is not None:
         rt_kw["sweep_group_min_cells"] = args.sweep_group_min_cells
+    if getattr(args, "dispatch_tokens", None) is not None:
+        rt_kw["dispatch_tokens"] = args.dispatch_tokens
+    if getattr(args, "donate_first", False):
+        rt_kw["donate_first"] = True
     if getattr(args, "no_aot_precompile", False):
         rt_kw["aot_precompile"] = False
     if getattr(args, "precompile_workers", None) is not None:

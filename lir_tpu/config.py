@@ -54,6 +54,18 @@ class RuntimeConfig:
     scan_positions: int = 10          # MAX_LOOK_AHEAD, compare_base_vs_instruct.py:187
     topk_match: int = 2               # top-2 yes/no match rule, :270-273
     batch_size: int = 32              # padded scoring batch per device step
+    # 0, or the most tokens one pass of a sweep dispatch may hold: its rows
+    # times what each runs beyond the prefix they all share. Long rows
+    # (a 16k-token document) then ride together only where they share a
+    # trunk, which the cascade front runs once at one row; a row that
+    # shares none is dispatched alone (scheduler.RaggedScheduler).
+    dispatch_tokens: int = 0
+    # True: the first dispatch of a shape is handed an empty cache to
+    # donate, so a shape has ONE program (the donated variant) and not two.
+    # The program never reads what it is handed (generate.
+    # greedy_decode_dispatch); a 9B model's program is a minute of
+    # compiling a variant.
+    donate_first: bool = False
     max_seq_len: int = 1024           # legal prompt + format ≲ 700 tokens (SURVEY §5)
     remat: bool = False               # jax.checkpoint the blocks for big models
 

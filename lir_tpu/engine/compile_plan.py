@@ -666,6 +666,23 @@ def precompile_async(engine, specs: Sequence[ShapeSpec],
     return registry
 
 
+def empty_scratch(run):
+    """A cache for the compiled dispatch program ``run`` to donate where
+    the chain holds none yet (RuntimeConfig.donate_first): zeros of what it
+    takes as ``scratch_cache``, placed where it takes them. The program
+    never reads it. None where ``run`` is the lazily jitted function, which
+    then traces its scratchless signature."""
+    import jax
+    import jax.numpy as jnp
+
+    info = getattr(run, "args_info", None)
+    if info is None or info[1].get("scratch_cache") is None:
+        return None
+    return jax.tree.map(
+        lambda a, where: jnp.zeros(a.shape, a.dtype, device=where),
+        info[1]["scratch_cache"], run.input_shardings[1]["scratch_cache"])
+
+
 def registry_call(run, params, args, scratch_cache):
     """Invoke a dispatch program — a registry executable, or the lazily
     jitted function with its statics bound — on the canonical argument

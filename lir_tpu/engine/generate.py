@@ -973,6 +973,12 @@ def greedy_decode_dispatch(params, cfg: ModelConfig, program: Program,
         if k:
             raise ValueError("no speculative tail over per-row stop "
                              "tables (a grouped batch)")
+        if cfg.layer_kinds:
+            raise NotImplementedError(
+                f"{cfg.name}: a grouped batch gathers member rows out of "
+                "prefix rows, and a cache of layers that differ in kind is "
+                "not laid out by row (models/mixed.py); the sweep plans "
+                "none for such a model")
         from ..models import cache as cache_mod
 
         start = cache_mod.gather_rows(start, a.group_idx)

@@ -14,6 +14,8 @@ pair and every subsequent batch reuses the cache.
 
 from __future__ import annotations
 
+import math
+
 import dataclasses
 import threading
 from typing import Any, List, Optional, Sequence, Tuple
@@ -28,7 +30,7 @@ from ..models import decoder, paged, quant
 from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CompileStats, FaultStats,
                                GuardStats, KernelStats, PrefixCacheStats,
-                               RecurrentStats, SpecStats,
+                               RecurrentStats, SparseStats, SpecStats,
                                cascade_decode_bytes_saved,
                                cascade_prefill_flops_saved)
 from . import (compile_plan, generate, hbm, prefix_tree,
@@ -141,6 +143,14 @@ class Route:
     fleet: bool = False   # ... with a fleet draft model
     page_size: int = 0    # radix prefix cache page (0: no cache)
     piggyback: bool = False
+    # False: beside a cascade front the dense program is not planned. It
+    # would run every row's whole prefix in one pass, which the engine's
+    # token cap (RuntimeConfig.dispatch_tokens) forbids: at 40 rows of
+    # 16k tokens the compiler itself refuses it.
+    plan_dense: bool = True
+    # True: the first dispatch of a shape donates an empty cache
+    # (RuntimeConfig.donate_first), so only donated variants are planned.
+    donate_first: bool = False
 
     def spec(self, window: int = 0, speculate: bool = False,
              scratch: bool = False) -> compile_plan.ShapeSpec:
@@ -184,14 +194,17 @@ class Route:
         (ROADMAP S7 prunes them here). ``chain``: the sweep will chain
         this dispatch to the previous one (a repeat of its shape), so
         the three piggyback stages are planned."""
+        scratch = scratch or self.donate_first
         dense = dataclasses.replace(self, trunk=0,
                                     dtrunk=0 if self.trunk else self.dtrunk)
         edges = lambda extent: (  # noqa: E731
             paged.window_edges(extent, self.page_size)
             if self.page_size else ())
-        out = [dense.spec(0, False, scratch)]
-        if self.spec_k:
-            out.append(dense.spec(0, True, scratch))
+        out = []
+        if self.plan_dense or not self.trunk:
+            out.append(dense.spec(0, False, scratch))
+            if self.spec_k:
+                out.append(dense.spec(0, True, scratch))
         if self.trunk:
             out += [self.spec(w, False, scratch)
                     for w in (0,) + tuple(edges(self.trunk))]
@@ -275,6 +288,10 @@ class ScoringEngine:
         # bytes per dispatch cache, forks, kernel calls, trunk states
         # shared (profiling.RecurrentStats; metrics source "recurrent").
         self.recurrent_stats = RecurrentStats()
+        # Block-sparse attention with a selection step (models/mixed.py):
+        # blocks offered and kept, dense queries, pooled-key bytes
+        # (profiling.SparseStats; metrics source "sparse").
+        self.sparse_stats = SparseStats()
         self._spec_draft = None
         self._spec_pending: List[Any] = []
         self.spec_fault_plan = None
@@ -538,6 +555,47 @@ class ScoringEngine:
         self.governor.register(
             f"handoff:{getattr(self.cfg, 'name', 'model')}", nbytes)
 
+    def _note_prefilled(self, trunk: int, prefix_lens: Sequence[int],
+                        sfx_a: Sequence[Sequence[int]],
+                        sfx_b: Sequence[Sequence[int]], cache: Any,
+                        new_tokens: int, conf_tokens: int) -> None:
+        """Count one shared dispatch's real prompt tokens (a trunk once)
+        and, for a model whose softmax layers select blocks, what its
+        queries were offered and kept: host ints from the rows' lengths
+        and the decode budgets (each branch runs its budget of steps
+        unless every row stops first)."""
+        rows = list(zip(prefix_lens, sfx_a, sfx_b))
+        prefilled = trunk + sum(n - trunk + len(a) + len(b)
+                                for n, a, b in rows)
+        stats = self.cascade_stats
+        stats.count("trunk_tokens_prefilled", trunk)
+        stats.count("tokens_prefilled", prefilled)
+        cfg = self.cfg
+        if not getattr(cfg, "layer_kinds", ()):
+            return
+        from ..ops import sparse_attention as sparse
+
+        sizes = dict(block=cfg.sparse_block, topk=cfg.sparse_topk,
+                     init_blocks=cfg.sparse_init_blocks,
+                     window=cfg.sparse_window, dense_len=cfg.sparse_dense_len)
+        total = np.zeros(3, np.int64)
+        if trunk:
+            total += sparse.kept_blocks(np.arange(trunk), trunk, **sizes)
+        for n, a, b in rows:
+            pos = [np.arange(trunk, n)]
+            pos += [np.arange(n, n + len(s) + steps)
+                    for s, steps in ((a, new_tokens), (b, conf_tokens))]
+            total += sparse.kept_blocks(np.concatenate(pos), trunk or n,
+                                        **sizes)
+        layers = cfg.kind_layers("sparse")
+        queries = prefilled + len(rows) * (new_tokens + conf_tokens)
+        sp = self.sparse_stats
+        sp.count("blocks_kept", int(total[0]) * layers)
+        sp.count("blocks_offered", int(total[1]) * layers)
+        sp.count("dense_queries", int(total[2]) * layers)
+        sp.count("queries", queries * layers)
+        sp.count("pooled_key_bytes", _tree_bytes(cache[3]))
+
     def _note_recurrent(self, cache: Any, rows: int, steps: int,
                         windows: int, trunk_rows: int = 0,
                         forks: Optional[int] = None) -> None:
@@ -548,13 +606,22 @@ class ScoringEngine:
         ``windows`` is the number of chunked-scan windows the program
         runs per layer (prefix [+ trunk], the suffix extends), ``steps``
         the decode budget, each step a single-token update per layer."""
-        if not getattr(self.cfg, "has_mixer", False):
+        if not getattr(self.cfg, "carries_state", False):
             return
-        L = self.cfg.n_layers
+        if self.cfg.layer_kinds:
+            # K/V for the softmax layers only, state for the lightning
+            # layers only; only the latter scan.
+            from ..models import mixed
+
+            L = self.cfg.kind_layers("lightning")
+            kv, state = mixed.cache_kinds(cache)
+        else:
+            L = self.cfg.n_layers
+            kv, state = cache[:2], cache[2:]
         stats = self.recurrent_stats
         stats.count("dispatches")
-        stats.count("kv_bytes", _tree_bytes(cache[:2]))
-        stats.count("state_bytes", _tree_bytes(cache[2:]))
+        stats.count("kv_bytes", _tree_bytes(kv))
+        stats.count("state_bytes", _tree_bytes(state))
         stats.count("forks", 2 * rows if forks is None else forks)
         stats.count("scan_calls", windows * L)
         stats.count("step_calls", steps * L)
@@ -600,7 +667,7 @@ class ScoringEngine:
                 and self._prefill_fn is None
                 # no roll-back of recurrent state to the last accepted
                 # token yet (decoder.refuse_recurrent)
-                and not getattr(self.cfg, "has_mixer", False))
+                and not getattr(self.cfg, "carries_state", False))
 
     def set_spec_draft(self, params: Any, cfg: Any, name: str = "") -> None:
         """Arm fleet-model drafting: the small model's (params, cfg)
@@ -675,6 +742,10 @@ class ScoringEngine:
         if rows_real < max(cc.min_rows, 2):
             return 0
         q = max(int(cc.trunk_quantum), 1)
+        if getattr(self.cfg, "layer_kinds", ()):
+            # The selection's blocks and pooling kernels sit on whole
+            # blocks of main keys (models/mixed.py).
+            q = math.lcm(q, int(self.cfg.sparse_block))
         trunk = (tok.common_prefix_len(prefix_ids) // q) * q
         if bucket is not None and trunk >= bucket:
             trunk = ((bucket - 1) // q) * q
@@ -749,8 +820,20 @@ class ScoringEngine:
                 "grouped", int(edge), int(rows), int(groups),
                 int(max(sfx_a, sfx_b)), 0,
                 int(max(new_tokens, conf_tokens)), 0, bool(stops_armed),
-                False), page_size=page)
+                False), page_size=page,
+                donate_first=bool(self.rt.donate_first))
         trunk, dtrunk = self.shared_trunk(prefix_rows, n_real, edge)
+        if trunk and getattr(self.cfg, "layer_kinds", ()):
+            # Behind a trunk every row's own slots must lie inside the
+            # selection's local window (models/mixed.py); where they do
+            # not, each row's whole prefix is its own main part.
+            from ..models import mixed
+
+            extent = generate.dispatch_extent(
+                self.cfg, int(edge), (int(sfx_a), int(sfx_b)),
+                (int(new_tokens), int(conf_tokens)), int(rows))
+            if not mixed.tail_fits(self.cfg, trunk, extent - trunk):
+                trunk = dtrunk = 0
         return Route(
             compile_plan.ShapeSpec(
                 "shared", int(edge), int(rows), 0, int(sfx_a), int(sfx_b),
@@ -760,7 +843,11 @@ class ScoringEngine:
             int8=bool(self.cascade_cfg.int8_qk),
             spec_k=int(self.rt.spec_k) if self.spec_supported() else 0,
             fleet=self._spec_draft is not None, page_size=page,
-            piggyback=self.piggyback_supported())
+            piggyback=self.piggyback_supported(),
+            plan_dense=not (self.rt.dispatch_tokens
+                            and int(rows) * int(edge)
+                            > self.rt.dispatch_tokens),
+            donate_first=bool(self.rt.donate_first))
 
     def route_dispatch(self, d, new_tokens: int, conf_tokens: int,
                        stops_armed: bool) -> Route:
@@ -1312,6 +1399,9 @@ class ScoringEngine:
                 "prefix_flops_saved",
                 int(cascade_prefill_flops_saved(self.cfg, rows,
                                                 route.trunk)))
+        self._note_prefilled(route.trunk, lcp[:rows], sfx_a_ids[:rows],
+                             sfx_b_ids[:rows], cache, new_tokens,
+                             conf_tokens)
         self._note_cascade_decode(route.dtrunk, rows, cache, new_tokens,
                                   conf_tokens)
         # Chunked-scan windows a layer: the prefix [+ the trunk], then
@@ -1348,7 +1438,9 @@ class ScoringEngine:
         Returns the program's ``(outs, specs, cache)``."""
         key = spec.cache_key
         scratch = self._handoff.take(key) if reuse else None
-        spec = dataclasses.replace(spec, scratch=scratch is not None)
+        first = scratch is None and reuse and self.rt.donate_first
+        spec = dataclasses.replace(spec,
+                                   scratch=first or scratch is not None)
         program = compile_plan.dispatch_program(self, spec,
                                                 return_cache=reuse)
 
@@ -1362,6 +1454,8 @@ class ScoringEngine:
                 host = {**host, **plan.host_arrays()}
             args = compile_plan.dispatch_args(self, spec, host)
             run = self._hit_or_lazy(spec, lazy) if reuse else lazy
+            if first:
+                scratch = compile_plan.empty_scratch(run)
             outs, specs, cache = compile_plan.registry_call(
                 run, self.params, args, scratch)
         except BaseException:
@@ -1388,7 +1482,7 @@ class ScoringEngine:
                 and not self.encoder_decoder
                 and self._prefill_fn is None
                 and self.prefix_cache is None
-                and not getattr(self.cfg, "has_mixer", False)
+                and not getattr(self.cfg, "carries_state", False)
                 and "decode_fused_shared" not in self.__dict__)
 
     def _piggyback_fits(self, bsz: int, total_len: int) -> bool:

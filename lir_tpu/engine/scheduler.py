@@ -318,10 +318,7 @@ def build_items(bin_ids: Sequence[Sequence[int]],
 
 
 def _lcp(a: Sequence[int], b: Sequence[int]) -> int:
-    n, cap = 0, min(len(a), len(b))
-    while n < cap and a[n] == b[n]:
-        n += 1
-    return n
+    return tok.lcp(a, b)
 
 
 class RaggedScheduler:
@@ -349,6 +346,12 @@ class RaggedScheduler:
         tokens AND on at least half of each member's prefill — shorter
         shared prefixes don't amortize the extra suffix-extension FLOPs.
     group_cells: 0 disables cross-cell grouping entirely.
+    token_cap: 0, or the most tokens one pass of a shared dispatch may
+        hold (RuntimeConfig.dispatch_tokens): the rows of a dispatch times
+        what each runs beyond the prefix they all share. Long rows then
+        ride together only where they share a trunk (the cascade front
+        runs the trunk once, at one row), and a row that shares none
+        with its neighbours is dispatched alone.
     cached_probe: optional ``(item, bucket_edge) -> cached tokens`` hook
         into the cross-request radix prefix cache (engine/prefix_tree.
         match_len). The slot-refill rule then prices cached-prefix
@@ -368,7 +371,8 @@ class RaggedScheduler:
                  group_cells: bool = True,
                  cached_probe=None,
                  fused_decode: bool = True,
-                 stats: Optional[OccupancyStats] = None):
+                 stats: Optional[OccupancyStats] = None,
+                 token_cap: int = 0):
         self.buckets = tuple(sorted(set(int(b) for b in buckets)))
         self.batch = int(batch_size)
         self.new_budget = int(new_budget)
@@ -382,6 +386,7 @@ class RaggedScheduler:
         self.min_group_cells = int(min_group_cells)
         self.group_cells = group_cells
         self.cached_probe = cached_probe
+        self.token_cap = int(token_cap)
         self.stats = stats if stats is not None else OccupancyStats()
 
     def _cached_tokens(self, items: Sequence[Tuple[SweepItem, bool]],
@@ -464,6 +469,25 @@ class RaggedScheduler:
 
     # -- bucket queues + slot refill ----------------------------------------
 
+    def _chunk_rows(self, q: List[Tuple[SweepItem, bool]], edge: int) -> int:
+        """Rows the next dispatch takes off the front of a bucket's queue:
+        a batch, or under a token cap as many as fit one pass beside the
+        first row at what they all share with it."""
+        n = min(self.batch, len(q))
+        if not self.token_cap:
+            return n
+        first = q[0][0]
+        head = first.bin_ids[:first.lcp]
+        rows = 1
+        while rows < n:
+            it = q[rows][0]
+            shared = _lcp(head, it.bin_ids[:it.lcp])
+            if (rows + 1) * (edge - shared) > self.token_cap:
+                break
+            head = head[:shared]
+            rows += 1
+        return rows
+
     def _plan_shared(self, items: List[SweepItem]) -> List[Dispatch]:
         queues: Dict[int, List[Tuple[SweepItem, bool]]] = {
             b: [] for b in self.buckets}
@@ -475,8 +499,11 @@ class RaggedScheduler:
         B = self.batch
         for bi, edge in enumerate(self.buckets):
             q = queues[edge]
-            while len(q) >= B:
-                chunk, q = q[:B], q[B:]
+            while len(q) >= B or (self.token_cap and q):
+                n = self._chunk_rows(q, edge)
+                if n == len(q) < B:
+                    break                  # the ragged tail, priced below
+                chunk, q = q[:n], q[n:]
                 out.append(Dispatch(
                     kind="shared", bucket=edge,
                     items=[it for it, _ in chunk],

@@ -610,10 +610,13 @@ def _plan_ragged(engine, todo, new_tokens, conf_tokens):
         edge_grid=edge_grid,
         min_group_prefix=engine.rt.sweep_group_min_prefix,
         min_group_cells=engine.rt.sweep_group_min_cells,
-        group_cells=engine.rt.sweep_group_min_cells > 0,
+        # A grouped batch gathers cache rows; a cache of layers that
+        # differ in kind is not laid out by row (models/mixed.py).
+        group_cells=(engine.rt.sweep_group_min_cells > 0
+                     and not getattr(engine.cfg, "layer_kinds", ())),
         cached_probe=cached_probe,
         fused_decode=engine.rt.fused_decode,
-        stats=stats)
+        stats=stats, token_cap=engine.rt.dispatch_tokens)
     dispatches = planner.schedule(items)
     engine.occupancy = stats
     log.info(

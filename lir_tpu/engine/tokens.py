@@ -258,6 +258,23 @@ def right_pad_ids(ids_list: Sequence[Sequence[int]], max_len: int,
     return tokens, mask
 
 
+def lcp(a: Sequence[int], b: Sequence[int]) -> int:
+    """Longest common prefix of two id sequences, by bisection on slice
+    equality (a C loop): a 16k-token row costs microseconds, not the
+    milliseconds of an element-by-element Python loop."""
+    cap = min(len(a), len(b))
+    if a[:cap] == b[:cap]:
+        return cap
+    lo, hi = 0, cap                       # a[:lo] == b[:lo], a[:hi] != b[:hi]
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        if a[lo:mid] == b[lo:mid]:
+            lo = mid
+        else:
+            hi = mid
+    return lo
+
+
 def shared_prefix_len(a: Sequence[int], b: Sequence[int]) -> int:
     """Longest common token prefix of two prompts, capped so BOTH suffixes
     keep at least one real token (decoder.extend reads its branch logits
@@ -267,10 +284,7 @@ def shared_prefix_len(a: Sequence[int], b: Sequence[int]) -> int:
     boundary) is tokenizer-agnostic: BPE merges that cross the text split
     point simply shorten the shared prefix by a token or two."""
     cap = min(len(a), len(b)) - 1
-    n = 0
-    while n < cap and a[n] == b[n]:
-        n += 1
-    return max(n, 0)
+    return max(min(lcp(a, b), cap), 0)
 
 
 def common_prefix_len(rows: Sequence[Sequence[int]]) -> int:
@@ -282,13 +296,9 @@ def common_prefix_len(rows: Sequence[Sequence[int]]) -> int:
     remainder slots are masked, the standard pad-slot discipline)."""
     if not rows:
         return 0
-    n = min(len(r) for r in rows)
-    first = rows[0]
-    for i in range(n):
-        t = first[i]
-        for r in rows[1:]:
-            if r[i] != t:
-                return i
+    n = len(rows[0])
+    for r in rows[1:]:
+        n = lcp(rows[0][:n], r)
     return n
 
 

@@ -162,8 +162,12 @@ def parent_map(tree: ast.AST) -> Dict[ast.AST, ast.AST]:
 
 
 def iter_functions(module: Module):
-    """Yield (qualname, FunctionDef) for every def in the module, with
-    Class.method / outer.inner qualnames."""
+    """(qualname, FunctionDef) for every def in the module, with
+    Class.method / outer.inner qualnames. Walked once a module and kept
+    on it: the passes ask thousands of times a run."""
+    found = getattr(module, "_functions", None)
+    if found is not None:
+        return found
 
     def walk(node: ast.AST, prefix: str):
         for child in ast.iter_child_nodes(node):
@@ -176,7 +180,8 @@ def iter_functions(module: Module):
             else:
                 yield from walk(child, prefix)
 
-    yield from walk(module.tree, "")
+    module._functions = found = list(walk(module.tree, ""))
+    return found
 
 
 def const_str_tuple(node: ast.AST) -> Tuple[str, ...]:

@@ -59,6 +59,10 @@ CASCADE_INTERPRET_ON_CPU = False
 # CPU; production CPU runs the recurrence token by token in XLA.
 SSM_INTERPRET_ON_CPU = False
 
+# Same hook for the block-sparse attention kernel (ops/sparse_attention)
+# of a model whose softmax layers select blocks (models/mixed.py).
+SPARSE_INTERPRET_ON_CPU = False
+
 
 # ---------------------------------------------------------------------------
 # Param init (random weights for tests; real weights come from models/loader.py)
@@ -66,6 +70,10 @@ SSM_INTERPRET_ON_CPU = False
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     """Random-normal init with the exact tree layout the loader fills."""
+    if cfg.layer_kinds:
+        from . import mixed
+
+        return mixed.init_params(cfg, key, dtype)
     k = iter(jax.random.split(key, 64))
     D, H, K, hd, F, L = (cfg.hidden_size, cfg.n_heads, cfg.n_kv_heads,
                          cfg.head_dim, cfg.intermediate_size, cfg.n_layers)
@@ -744,11 +752,11 @@ def refuse_recurrent(cfg, what: str) -> None:
     accepted token), the piggyback chain (one parked cache, two branches),
     tiering and migration (pages again). They refuse; none answers
     wrongly."""
-    if getattr(cfg, "has_mixer", False):
+    if getattr(cfg, "carries_state", False):
         raise NotImplementedError(
-            f"{cfg.name}: {what} cannot carry a state-space mixer's "
-            "recurrent state (SSM state + conv tail) yet; it holds K/V "
-            "only (ROADMAP M4)")
+            f"{cfg.name}: {what} cannot carry a recurrent state (a "
+            "state-space mixer's SSM state + conv tail, a linear-attention "
+            "layer's state) yet; it holds K/V only (ROADMAP M4)")
 
 
 def rewind(cache, snapshot):
@@ -865,6 +873,18 @@ def _scan_blocks(params: Params, cfg: ModelConfig, x, sin, cos, bias,
     return x, cache
 
 
+def _mixed(cfg: ModelConfig, attn_impl=None):
+    """models/mixed.py, which runs a model whose layers differ in kind
+    behind every entry point below (``cfg.layer_kinds``)."""
+    from . import mixed
+
+    if attn_impl is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: sequence-parallel attention over layers that "
+            "differ in kind")
+    return mixed
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             attn_mask: Optional[jax.Array] = None,
             attn_impl=None) -> jax.Array:
@@ -876,6 +896,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     """
     if attn_mask is None:
         attn_mask = jnp.ones_like(tokens)
+    if cfg.layer_kinds:
+        return _mixed(cfg, attn_impl).forward(params, cfg, tokens, attn_mask)
     positions = mask_positions(attn_mask)
     x = _embed(params, cfg, tokens, positions)
     sin = cos = None
@@ -902,7 +924,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
 
     With ``cfg.kv_cache_int8`` each side becomes a (payload int8
     (L, K, T, B, hd), scale f32 (L, K, T, B)) pair — half the HBM.
+
+    A model whose layers differ in kind has its own six leaves
+    (models/mixed.init_cache): here, ``max_len`` main slots a row.
     """
+    if cfg.layer_kinds:
+        return _mixed(cfg).init_cache(cfg, batch, max_len, 0, dtype)
     shape = (cfg.n_layers, cfg.n_kv_heads, max_len, batch, cfg.head_dim)
     if cfg.kv_cache_int8:
         def side():
@@ -946,6 +973,9 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     (parallel/seq_forward): the quadratic phase runs seq-sharded, and the
     returned cache holds the same per-layer k/v for ordinary decode.
     """
+    if cfg.layer_kinds:
+        return _mixed(cfg, attn_impl).prefill(params, cfg, tokens, attn_mask,
+                                              max_len)
     B, S = tokens.shape
     positions = mask_positions(attn_mask)
     x = _embed(params, cfg, tokens, positions)
@@ -1005,6 +1035,9 @@ def extend(params: Params, cfg: ModelConfig, cache, suffix_tokens: jax.Array,
     so attention never sees them. Returns (last-valid-position logits
     (B, V) fp32, new_cache, next_positions (B,)).
     """
+    if cfg.layer_kinds:
+        return _mixed(cfg).extend(params, cfg, cache, suffix_tokens,
+                                  suffix_mask, cache_mask, start_index)
     B, S2 = suffix_tokens.shape
     key_positions = mask_positions(cache_mask)
     qpos = lax.dynamic_slice_in_dim(key_positions, start_index, S2, axis=1)
@@ -1050,6 +1083,10 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
     gates routing, runner.cascade_supported).
     """
     assert not cfg.kv_cache_int8, "cascade prefill needs a float KV cache"
+    if cfg.layer_kinds:
+        return _mixed(cfg).cascade_extend(params, cfg, trunk_cache,
+                                          rem_tokens, rem_mask, trunk_len,
+                                          total_len)
     B, R = rem_tokens.shape
     L, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     qpos = trunk_len + mask_positions(rem_mask)                  # (B, R)
@@ -1168,6 +1205,9 @@ def decode_step(params: Params, cfg: ModelConfig, cache, token: jax.Array,
     per kv head for all rows — bitwise the flat kernel.
     Returns (logits (B, V) fp32, new_cache).
     """
+    if cfg.layer_kinds:
+        return _mixed(cfg).decode_step(params, cfg, cache, token, position,
+                                       step_index, prompt_mask)
     B = token.shape[0]
     x = _embed(params, cfg, token[:, None], position[:, None])
     sin = cos = None

@@ -140,6 +140,41 @@ class ModelConfig:
     mlp_multipliers: tuple = (1.0, 1.0)          # gate pre-activation, output
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)   # z, x, B, C, dt
 
+    # Layers that differ in kind (MiniCPM-SALA): ``layer_kinds`` names
+    # every layer's mixer in order, ``"sparse"`` (softmax attention with
+    # InfLLM-v2 block selection past ``sparse_dense_len`` tokens of
+    # context; the ``n_heads`` / ``n_kv_heads`` / ``head_dim`` above) or
+    # ``"lightning"`` (scalar-decay linear attention, ``lightning_heads``
+    # heads of ``lightning_head_dim``, a float32 (head_dim, head_dim)
+    # state a head, decay ``exp(-2^(-8(h+1)/H))``). Each layer has ONE
+    # mixer, then the MLP. ``params["layers"]`` is then a dict of groups,
+    # one a kind, each stacked over its own layers; the layer loop runs
+    # :attr:`layer_runs`; the cache holds K/V for the sparse layers only
+    # and a state for the lightning layers only (models/mixed.py). Empty:
+    # every layer alike, every model before this one bit for bit.
+    layer_kinds: tuple = ()
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_chunk: int = 128
+    qk_norm: bool = False             # per-head RMSNorm on q and k
+    attn_rope: bool = True            # False: the softmax layers rotate nothing
+    lightning_rope: bool = True
+    output_gate: bool = False         # y = (o * sigmoid(h Wg)) Wo, both kinds
+    residual_scale: float = 1.0       # x + residual_scale * branch(norm(x))
+    # InfLLM-v2 selection (ops/sparse_attention.py): keys pooled over
+    # ``sparse_kernel`` tokens at ``sparse_stride``, scored, max-pooled to
+    # blocks of ``sparse_block``; a query past ``sparse_dense_len`` tokens
+    # of context keeps ``sparse_init_blocks`` first blocks, the blocks
+    # reaching into its last ``sparse_window`` positions and the
+    # ``sparse_topk`` best of the rest.
+    sparse_block: int = 64
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+
     def __post_init__(self) -> None:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.n_heads)
@@ -158,6 +193,14 @@ class ModelConfig:
                            tuple(self.mlp_multipliers))
         object.__setattr__(self, "ssm_multipliers",
                            tuple(self.ssm_multipliers))
+        object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
+        if self.layer_kinds:
+            assert len(self.layer_kinds) == self.n_layers, self.name
+            assert set(self.layer_kinds) <= {"sparse", "lightning"}, self.name
+            if self.has_mixer or self.kv_cache_int8 or self.parallel_block:
+                raise ValueError(
+                    f"{self.name}: layers that differ in kind carry one "
+                    "mixer a layer in a sequential block with a float cache")
         if self.has_mixer and self.kv_cache_int8:
             raise ValueError(
                 f"{self.name}: kv_cache_int8 has no recurrent-state side; "
@@ -170,6 +213,29 @@ class ModelConfig:
     @property
     def has_mixer(self) -> bool:
         return self.ssm_heads > 0
+
+    @property
+    def carries_state(self) -> bool:
+        """A recurrent state rides the cache beside (or instead of) K/V:
+        what no mask can rewind (decoder.rewind, decoder.refuse_recurrent)."""
+        return self.has_mixer or "lightning" in self.layer_kinds
+
+    def kind_layers(self, kind: str) -> int:
+        return sum(k == kind for k in self.layer_kinds)
+
+    @property
+    def layer_runs(self) -> tuple:
+        """The published order as runs of one kind: ``(kind, first index
+        within the kind's own stack, layers)``."""
+        runs, seen = [], {}
+        for kind in self.layer_kinds:
+            n = seen.get(kind, 0)
+            if runs and runs[-1][0] == kind:
+                runs[-1] = (kind, runs[-1][1], runs[-1][2] + 1)
+            else:
+                runs.append((kind, n, 1))
+            seen[kind] = n + 1
+        return tuple(runs)
 
     @property
     def ssm_inner(self) -> int:
@@ -328,6 +394,35 @@ def falcon_h1_34b_l8() -> ModelConfig:
     return falcon_h1_34b(n_layers=8)
 
 
+SALA_MIXERS = tuple(
+    "sparse" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "lightning"
+    for i in range(32))
+
+
+def minicpm_sala() -> ModelConfig:
+    """openbmb/MiniCPM-SALA (config.json, ``model_type`` minicpm_sala):
+    32 layers in the published ``mixer_types`` order, 8 of softmax
+    attention (32 query / 2 key-value heads of 128, no rotation,
+    InfLLM-v2 block selection past 8192 tokens) among 24 of lightning
+    linear attention (32 heads of 128, rotated); per-head RMSNorm on q and
+    k and a sigmoid output gate in both; gated SiLU MLP of 16384; the
+    MiniCPM scales (embedding x 12, residual x 1.4 / sqrt(32), logits /
+    (4096 / 256)); untied 73448-row head. The selection's sizes are
+    MiniCPM4's ``sparse_config`` (the published file has no key:
+    benchmarks/configs/minicpm-sala.json ``assumed``)."""
+    return ModelConfig(
+        name="minicpm-sala", vocab_size=73448, hidden_size=4096, n_layers=32,
+        n_heads=32, n_kv_heads=2, head_dim=128, intermediate_size=16384,
+        max_seq_len=524288, rope_theta=10000.0, norm_eps=1e-6,
+        use_flash_attention=False, layer_kinds=SALA_MIXERS,
+        lightning_heads=32, lightning_head_dim=128, lightning_chunk=128,
+        qk_norm=True, attn_rope=False, lightning_rope=True, output_gate=True,
+        residual_scale=1.4 / 32 ** 0.5, embedding_multiplier=12.0,
+        lm_head_multiplier=256 / 4096,
+        sparse_block=64, sparse_kernel=32, sparse_stride=16, sparse_topk=64,
+        sparse_init_blocks=1, sparse_window=2048, sparse_dense_len=8192)
+
+
 def bloom_7b1() -> ModelConfig:
     return ModelConfig(
         name="bloom-7b1", vocab_size=250880, hidden_size=4096, n_layers=30,
@@ -395,6 +490,19 @@ def tiny(family: str) -> ModelConfig:
             key_multiplier=0.5, ssm_in_multiplier=0.5,
             ssm_out_multiplier=0.75, mlp_multipliers=(0.5, 0.75),
             ssm_multipliers=(0.5, 0.75, 1.25, 1.5, 0.5), **base)
+    if family == "sala":
+        # One layer of each kind twice over, a selection small enough to
+        # be live on rows of ~100 tokens.
+        return ModelConfig(
+            name="tiny-sala", n_kv_heads=2, head_dim=16,
+            layer_kinds=("sparse", "lightning", "lightning", "sparse"),
+            lightning_heads=4, lightning_head_dim=16, lightning_chunk=16,
+            qk_norm=True, attn_rope=False, output_gate=True,
+            residual_scale=0.7, embedding_multiplier=2.0,
+            lm_head_multiplier=0.5, sparse_block=8, sparse_kernel=4,
+            sparse_stride=2, sparse_topk=2, sparse_init_blocks=1,
+            sparse_window=32, sparse_dense_len=32,
+            **{**base, "n_layers": 4, "max_seq_len": 256})
     if family == "bloom":
         return ModelConfig(name="tiny-bloom", pos_embedding="alibi", norm="layernorm",
                            activation="gelu_new", gated_mlp=False, embedding_norm=True,
@@ -412,6 +520,7 @@ REGISTRY = {
     "gpt2": gpt2, "gptneox": gptneox, "llama2-7b": llama2_7b,
     "mistral-7b": mistral_7b, "qwen-7b": qwen_7b, "baichuan2-7b": baichuan2_7b,
     "falcon-7b": falcon_7b, "falcon-h1-34b": falcon_h1_34b,
-    "falcon-h1-34b-l8": falcon_h1_34b_l8, "bloom-7b1": bloom_7b1, "opt": opt,
+    "falcon-h1-34b-l8": falcon_h1_34b_l8, "minicpm-sala": minicpm_sala,
+    "bloom-7b1": bloom_7b1, "opt": opt,
     "t5-v1_1": t5_v1_1, "flan-t5": flan_t5, "t0-3b": t0_3b,
 }

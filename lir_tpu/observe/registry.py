@@ -113,7 +113,12 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "CascadeStats": (
         "cascade_dispatches", "dense_fallbacks", "trunk_rows_deduped",
         "prefix_flops_saved", "cascade_decode_dispatches",
-        "trunk_bytes_deduped",
+        "trunk_bytes_deduped", "tokens_prefilled",
+        "trunk_tokens_prefilled",
+    ),
+    "SparseStats": (
+        "blocks_kept", "blocks_offered", "queries", "dense_queries",
+        "pooled_key_bytes",
     ),
     "RecurrentStats": (
         "dispatches", "state_bytes", "kv_bytes", "forks", "scan_calls",
@@ -286,6 +291,8 @@ def engine_registry(engine, sink=None,
         reg.register("cascade", engine.cascade_stats)
     if getattr(engine, "recurrent_stats", None) is not None:
         reg.register("recurrent", engine.recurrent_stats)
+    if getattr(engine, "sparse_stats", None) is not None:
+        reg.register("sparse", engine.sparse_stats)
     if getattr(engine, "governor", None) is not None:
         # HBM-governor gauges (engine/hbm.py): ledger/pressure/rung
         # land in the snapshot next to device_memory_stats(), so budget

@@ -71,10 +71,14 @@ _TN = (((0,), (0,)), ((), ()))        # a.T @ b
 
 def head_block(n_heads: int, n_groups: int) -> int:
     """Heads per program: at most :data:`HEAD_BLOCK`, a divisor of the
-    heads of one group (a program reads one group's ``B`` and ``C``)."""
+    heads of one group (a program reads one group's ``B`` and ``C``).
+    Where every head is a group of its own (lightning attention: ``B`` is
+    the head's key, ``C`` its query) a program carries a block of heads
+    with each head's own ``B`` and ``C``."""
     per_group = n_heads // n_groups
-    hb = min(HEAD_BLOCK, per_group)
-    while per_group % hb:
+    span = n_heads if per_group == 1 else per_group
+    hb = min(HEAD_BLOCK, span)
+    while span % hb:
         hb -= 1
     return hb
 
@@ -121,27 +125,36 @@ def ssd_scan_tokens(x, dt, a, b, c, state):
 
 def _scan_kernel(layer_ref, x_ref, b_ref, c_ref, cs_tl_ref, dt_tl_ref,
                  cs_hl_ref, dt_hl_ref, s0_ref, y_ref, s_ref, *, hb: int,
-                 P: int):
+                 P: int, own_group: bool = False):
     """One (row, head block, chunk) program; the chunk axis is the
     innermost, sequential one and the output state block (its index does
     not depend on the chunk) carries the state between chunks.
-    ``layer_ref`` (prefetched) is spent by the state's index maps."""
+    ``layer_ref`` (prefetched) is spent by the state's index maps.
+    ``own_group``: the ``B`` / ``C`` blocks hold one (Q, N) slice a head
+    of the block instead of one for all of them."""
     del layer_ref
     f32 = jnp.float32
     s0_ref, s_ref = s0_ref.at[0], s_ref.at[0]     # the layer's blocks
+    N = s_ref.shape[-1]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = s0_ref[...]
 
-    bm, cm = b_ref[0], c_ref[0]                               # (Q, N)
-    Q = bm.shape[0]
-    cb = lax.dot_general(cm, bm, _NT, preferred_element_type=f32)
+    def group(j):
+        bm, cm = b_ref[0, :, j * N:(j + 1) * N], c_ref[0, :, j * N:(j + 1) * N]
+        return (lax.dot_general(cm, bm, _NT, preferred_element_type=f32),
+                cm.astype(f32), bm.astype(f32))
+
+    Q = b_ref.shape[1]
+    if not own_group:
+        cb, cm32, bm32 = group(0)
     rows = lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     cols = lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     lower = rows >= cols
-    cm32, bm32 = cm.astype(f32), bm.astype(f32)
     for j in range(hb):
+        if own_group:
+            cb, cm32, bm32 = group(j)
         x = x_ref[0, :, j * P:(j + 1) * P]                    # (Q, P)
         cs_c = cs_tl_ref[0, 0, :, j:j + 1]                    # (Q, 1)
         dt_c = dt_tl_ref[0, 0, :, j:j + 1]
@@ -177,7 +190,7 @@ def _stacked(state, layer):
 
 
 def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
-             interpret: bool = False, layer=None):
+             interpret: bool = False, layer=None, name: str = "ssd_scan"):
     """Chunked selective scan; arguments and results as
     :func:`ssd_scan_tokens`. With ``layer``, ``state`` is the stacked
     (L, B, H, P, N) leaf: layer ``layer`` of it is the initial state and
@@ -204,8 +217,14 @@ def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
     tl = lambda v: v.reshape(B, Tp, nh, hb).transpose(0, 2, 1, 3)  # noqa: E731
     hl = lambda v: v.transpose(0, 2, 1)  # noqa: E731
 
-    kernel = functools.partial(_scan_kernel, hb=hb, P=P)
     per_group = H // G
+    own = per_group == 1 and hb > 1
+    kernel = functools.partial(_scan_kernel, hb=hb, P=P, own_group=own)
+    # One group's (Q, N) block, or with a group a head the block's heads'.
+    bc_spec = (pl.BlockSpec((1, Q, hb * N), lambda i, h, k, l: (i, k, h))
+               if own else
+               pl.BlockSpec((1, Q, N), lambda i, h, k, l:
+                            (i, k, (h * hb) // per_group)))
     state_spec = pl.BlockSpec((1, 1, hb, P, N),
                               lambda i, h, k, l: (l[0], i, h, 0, 0))
     y, state = pl.pallas_call(
@@ -215,10 +234,7 @@ def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
             grid=(B, nh, nc),
             in_specs=[
                 pl.BlockSpec((1, Q, hb * P), lambda i, h, k, l: (i, k, h)),
-                pl.BlockSpec((1, Q, N), lambda i, h, k, l:
-                             (i, k, (h * hb) // per_group)),
-                pl.BlockSpec((1, Q, N), lambda i, h, k, l:
-                             (i, k, (h * hb) // per_group)),
+                bc_spec, bc_spec,
                 pl.BlockSpec((1, 1, Q, hb), lambda i, h, k, l: (i, h, k, 0)),
                 pl.BlockSpec((1, 1, Q, hb), lambda i, h, k, l: (i, h, k, 0)),
                 pl.BlockSpec((1, hb, Q), lambda i, h, k, l: (i, h, k)),
@@ -237,7 +253,7 @@ def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="ssd_scan",
+        name=name,
     )(layer, pad_t(x).reshape(B, Tp, H * P), pad_t(b).reshape(B, Tp, G * N),
       pad_t(c).reshape(B, Tp, G * N), tl(cs), tl(dt), hl(cs), hl(dt), state)
     return y[:, :T].reshape(B, T, H, P), state[0] if one_layer else state
@@ -248,7 +264,7 @@ def ssd_scan(x, dt, a, b, c, state, *, chunk: int = DEFAULT_CHUNK,
 # ---------------------------------------------------------------------------
 
 def _step_kernel(layer_ref, dt_ref, decay_ref, x_ref, b_ref, c_ref, s_ref,
-                 y_ref, so_ref, *, hb: int, P: int):
+                 y_ref, so_ref, *, hb: int, P: int, own_group: bool = False):
     """One (row, head block) program. The outer product and the read ride
     the MXU on tiles whose first row alone is live: ``x^T B`` contracts
     the tile's 8 rows (7 of them zero) and gives ``x (x) B`` without a
@@ -266,8 +282,13 @@ def _step_kernel(layer_ref, dt_ref, decay_ref, x_ref, b_ref, c_ref, s_ref,
         return jnp.where(live, jnp.broadcast_to(row.astype(f32), (8, n)),
                          0.0)
 
-    b8, c8 = tile(b_ref[0]), tile(c_ref[0])                   # (8, N)
+    N = s_ref.shape[-1]
+    if not own_group:
+        b8, c8 = tile(b_ref[0]), tile(c_ref[0])               # (8, N)
     for j in range(hb):
+        if own_group:           # each head of the block its own B and C
+            b8 = tile(b_ref[0, :, j * N:(j + 1) * N])
+            c8 = tile(c_ref[0, :, j * N:(j + 1) * N])
         dt, decay = dt_ref[i, h * hb + j], decay_ref[i, h * hb + j]
         x8 = tile(x_ref[0, :, j * P:(j + 1) * P])             # (8, P)
         outer = lax.dot_general(x8, b8, _TN, precision=_HIGHEST,
@@ -280,7 +301,7 @@ def _step_kernel(layer_ref, dt_ref, decay_ref, x_ref, b_ref, c_ref, s_ref,
 
 
 def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False,
-             layer=None):
+             layer=None, name: str = "ssm_step"):
     """One token of the recurrence. x: (B, H, P); dt: (B, H) float32;
     b, c: (B, G, N); state: (B, H, P, N) float32, updated in place, or
     with ``layer`` the stacked (L, B, H, P, N) leaf, of which that layer
@@ -295,7 +316,12 @@ def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False,
     f32 = jnp.float32
     dt = dt.astype(f32)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    kernel = functools.partial(_step_kernel, hb=hb, P=P)
+    own = per_group == 1 and hb > 1
+    kernel = functools.partial(_step_kernel, hb=hb, P=P, own_group=own)
+    bc_spec = (pl.BlockSpec((1, 1, hb * N), lambda i, h, l: (i, 0, h))
+               if own else
+               pl.BlockSpec((1, 1, N), lambda i, h, l:
+                            (i, 0, (h * hb) // per_group)))
     state_spec = pl.BlockSpec((1, 1, hb, P, N),
                               lambda i, h, l: (l[0], i, h, 0, 0))
     y, state = pl.pallas_call(
@@ -306,10 +332,7 @@ def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False,
             in_specs=[
                 smem, smem,
                 pl.BlockSpec((1, 1, hb * P), lambda i, h, l: (i, 0, h)),
-                pl.BlockSpec((1, 1, N), lambda i, h, l:
-                             (i, 0, (h * hb) // per_group)),
-                pl.BlockSpec((1, 1, N), lambda i, h, l:
-                             (i, 0, (h * hb) // per_group)),
+                bc_spec, bc_spec,
                 state_spec,
             ],
             out_specs=[
@@ -324,7 +347,7 @@ def ssm_step(x, dt, a, b, c, state, *, interpret: bool = False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-        name="ssm_step",
+        name=name,
     )(layer, dt, jnp.exp(dt * a), x.reshape(B, 1, H * P),
       b.reshape(B, 1, G * N), c.reshape(B, 1, G * N), state)
     return y.reshape(B, H, P), state[0] if one_layer else state

@@ -804,6 +804,12 @@ class CascadeStats:
     prefix_flops_saved: int = 0
     cascade_decode_dispatches: int = 0
     trunk_bytes_deduped: int = 0
+    # Real prompt tokens the shared dispatches ran through the layers
+    # (prefix + both format suffixes, a shared trunk counted once), and
+    # how many of them were trunk tokens: a trunk kept across dispatches
+    # would lower the second.
+    tokens_prefilled: int = 0
+    trunk_tokens_prefilled: int = 0
 
     def __post_init__(self) -> None:
         import threading
@@ -826,6 +832,52 @@ class CascadeStats:
                 "prefix_flops_saved": self.prefix_flops_saved,
                 "cascade_decode_dispatches": self.cascade_decode_dispatches,
                 "trunk_bytes_deduped": self.trunk_bytes_deduped,
+            }
+
+
+@dataclasses.dataclass
+class SparseStats:
+    """Counters of block-sparse attention with a selection step
+    (ops/sparse_attention; metrics source ``sparse``). All stay 0 for a
+    model without such layers. Each is the host's word about the programs
+    it dispatched, from row lengths and budgets alone (what a query keeps
+    is decided by its position: ops/sparse_attention.kept_blocks), summed
+    over the sparse layers; tests/test_sala_model.py holds them to the
+    masks a dispatched program computes.
+
+    - ``blocks_offered`` / ``blocks_kept``: per query and sparse layer,
+      the blocks of main keys it may see, and those it attends.
+    - ``queries`` / ``dense_queries``: queries run, and those with at most
+      ``dense_len`` tokens of context (they keep every block).
+    - ``pooled_key_bytes``: bytes of the pooled keys the dispatch caches
+      held (computed once a dispatch, with the main keys).
+    """
+
+    blocks_kept: int = 0
+    blocks_offered: int = 0
+    queries: int = 0
+    dense_queries: int = 0
+    pooled_key_bytes: int = 0
+
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+
+    def count(self, field: str, n: int = 1) -> None:
+        with self._lock:
+            setattr(self, field, getattr(self, field) + n)
+
+    def summary(self) -> Dict[str, object]:
+        with self._lock:
+            return {
+                "blocks_kept": self.blocks_kept,
+                "blocks_offered": self.blocks_offered,
+                "kept_share": (self.blocks_kept / self.blocks_offered
+                               if self.blocks_offered else 0.0),
+                "queries": self.queries,
+                "dense_queries": self.dense_queries,
+                "pooled_key_bytes": self.pooled_key_bytes,
             }
 
 

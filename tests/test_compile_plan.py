@@ -224,6 +224,47 @@ def test_same_shape_dispatches_reuse_one_executable(tmp_path):
     assert reg.manifest_key == engine.cache_manifest_key
 
 
+def test_donate_first_plans_and_runs_one_variant_a_shape(tmp_path):
+    """``RuntimeConfig.donate_first``: the first dispatch of a shape is
+    handed an empty cache to donate, so the same 3-dispatch plan holds the
+    donated variants alone (sequential + speculative + the fold: three
+    executables where the default compiles five), every dispatch runs
+    precompiled, and the rows are the default's bit for bit."""
+    from lir_tpu.engine.sweep import run_perturbation_sweep
+
+    lp, perts = _grid(12)
+
+    def run(donate_first, sub):
+        compile_plan.exec_cache_clear()
+        engine = _tiny_engine(RuntimeConfig(
+            batch_size=4, max_seq_len=256, piggyback_prefill=False,
+            donate_first=donate_first))
+        rows = run_perturbation_sweep(engine, "cp", lp, perts,
+                                      tmp_path / sub / "r.xlsx",
+                                      checkpoint_every=100)
+        return rows, engine
+
+    rows, engine = run(True, "one")
+    shared = [s for s in engine.exec_registry._futures if s.kind == "shared"]
+    assert len(shared) == 2 and all(s.scratch for s in shared)
+    assert len(engine.exec_registry) == 3
+    assert engine.compile_stats.aot_hits == 6
+    assert engine.compile_stats.lazy_misses == 0
+    assert all("/donated" in label or label.startswith("stream_fold")
+               for label in engine.compile_stats.shapes)
+    both, _ = run(False, "two")
+    key = lambda r: (r.original_main, r.rephrased_main)  # noqa: E731
+    by_key = {key(r): r for r in both}
+    assert len(rows) == 12 and set(map(key, rows)) == set(by_key)
+    for r in rows:
+        other = by_key[key(r)]
+        assert r.token_1_prob == other.token_1_prob
+        assert r.weighted_confidence == other.weighted_confidence
+        assert r.log_probabilities == other.log_probabilities
+    # Where no executable is at hand the lazy function runs scratchless.
+    assert compile_plan.empty_scratch(lambda *a, **k: None) is None
+
+
 def test_piggyback_chain_runs_precompiled(tmp_path):
     """With piggybacking ON (the default), the same 3-dispatch plan chains
     through the piggyback executables: the plan additionally covers the

@@ -295,6 +295,65 @@ def test_scan_kernels_stacked_compile(one_chip, batch, length):
                          text)
 
 
+# MiniCPM-SALA at its published sizes, on the shapes of its 16k-document
+# cell: the lightning layers' scan with a group a head (32 heads of 128, a
+# 128 x 128 state), over the stacked state of 24 layers; the softmax layers'
+# block-masked attention (32 query / 2 kv heads of 128, blocks of 64) over a
+# 16,000-token trunk read by every row's queries, over one row's own 16,128
+# keys, and a decode step's 40 single queries.
+LIGHTNING = (32, 128)          # heads, head dim (= state width)
+
+
+def _lightning_args(batch, length=None, layers=24):
+    H, P = LIGHTNING
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    t = () if length is None else (length,)
+    qkv = ((batch, *t, H, P), bf16)
+    return [qkv, ((batch, *t, H), f32), ((H,), f32), qkv, qkv,
+            ((layers, batch, H, P, P), f32), ((), jnp.int32)]
+
+
+@pytest.mark.parametrize("batch,length", [(1, 16000), (BATCH, 128),
+                                          (BATCH, 32), (BATCH, None),
+                                          (1, None)])
+def test_lightning_kernels_compile(one_chip, batch, length):
+    fn, name = ((ssm_step, "lightning_step") if length is None
+                else (ssd_scan, "lightning_scan"))
+    shaped = [jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip)
+              for a in _lightning_args(batch, length)]
+    text = jax.jit(functools.partial(_stacked(fn, 6), name=name),
+                   donate_argnums=(5,)).lower(*shaped).compile().as_text()
+    assert name in text and "tpu_custom_call" in text
+    H, P = LIGHTNING
+    assert f"f32[{batch},{H},{P},{P}]" not in text   # no layer sliced out
+
+
+@pytest.mark.parametrize("case,rows,queries,keys", [
+    ("trunk", 1, 16000, 16000), ("windows", 1, BATCH * 128, 16000),
+    ("decode", 1, BATCH, 16000), ("own_prefix", 1, 16128, 16128),
+    ("own_decode", 1, 1, 16128)])
+def test_sparse_attention_kernel_compiles(one_chip, case, rows, queries,
+                                          keys):
+    from lir_tpu.ops import sparse_attention
+
+    K, G, hd, layers = 2, 16, 128, 8
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    kv = ((layers, rows, K, keys, hd), bf16)
+    args = [((rows, K, G, queries, hd), bf16), kv, kv,
+            ((rows, K, queries, -(-keys // 64)), jnp.bool_),
+            ((rows, queries), i32), ((), i32)]
+    name = "sparse_decode" if "decode" in case else "sparse_prefill"
+
+    def fn(q, k, v, keep, bound, layer):
+        return sparse_attention.attend_main(q, k, v, keep, bound, block=64,
+                                            layer=layer, name=name)
+
+    text = _compile(fn, args, one_chip).as_text()
+    assert name in text and "tpu_custom_call" in text
+    # The stacked keys are read where they lie: no layer of them copied.
+    assert f"bf16[{rows},{K},{keys},{hd}]" not in text
+
+
 def test_compiled_text_carries_the_kernel(one_chip):
     """The compile really went through Mosaic: the executable holds a
     ``tpu_custom_call`` (not an XLA fallback)."""
@@ -568,6 +627,57 @@ def test_cascade_program_updates_the_stacked_cache_in_place(one_chip,
     assert cache[0].shape == (DEPTH, 8, EXTENT, BATCH, 128)
     stacked, layers = _cache_shapes(cfg, cache)
     found = _cache_moves(text, stacked, layers, whole_layer_reads=False)
+    assert found == []
+
+
+def test_mixed_layer_loop_updates_its_leaves_in_place(one_chip, on_tpu):
+    """MiniCPM-SALA's widths, six layers in three rounds (a kind's run of
+    one, two and none), the selection live past 512 tokens: the 40-row
+    cascade program over a 1,024-token trunk. ``mixed._run_layers`` carries
+    the six leaves through a scan over rounds and, inside it, a loop a
+    kind whose trip count is the round's; no loop of the program may copy
+    a stacked leaf, hold a second one, or write a whole layer back."""
+    from lir_tpu.models import mixed
+
+    cfg = dataclasses.replace(
+        registry.minicpm_sala(), n_layers=6, layer_kinds=(
+            "sparse", "lightning", "lightning", "sparse", "lightning",
+            "sparse"), sparse_dense_len=512, sparse_window=256,
+        sparse_topk=4)
+    assert mixed.layer_rounds(cfg)[1].tolist() == [[1, 2], [1, 1], [1, 0]]
+    params = _shaped(jax.eval_shape(
+        lambda k: mixed.init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)), one_chip)
+    V, trunk = cfg.vocab_size, 1024
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    args = (params, cfg,
+            generate.Program(front="cascade", max_new=(4, 8), topk=20,
+                             trunk=trunk, return_cache=True),
+            generate.DispatchArgs(
+                prefix=i32(BATCH, trunk + 128),
+                prefix_mask=i32(BATCH, trunk + 128),
+                sfx=(i32(BATCH, 32), i32(BATCH, 32)),
+                sfx_mask=(i32(BATCH, 32), i32(BATCH, 32)),
+                yes_ids=i32(BATCH), no_ids=i32(BATCH), digit_ids=i32(101),
+                digit_vals=jax.ShapeDtypeStruct((101,), jnp.float32,
+                                                sharding=one_chip),
+                stops=generate.Stops(binary=i32(V), digits=i32(V),
+                                     eos_id=i32())))
+    fn = generate.greedy_decode_dispatch
+    cache = fn.eval_shape(*args, scratch_cache=None)[-1]
+    text = fn.lower(*args, scratch_cache=_shaped(cache, one_chip)
+                    ).compile().as_text()
+    for kernel in ("lightning_scan", "lightning_step", "sparse_prefill",
+                   "sparse_decode"):
+        assert kernel in text
+    big = [leaf for leaf in jax.tree.leaves(cache) if leaf.size > 1 << 19]
+    assert len(big) == 5                      # all but the pooled keys
+    found = _cache_moves(text, [_hlo_shape(leaf) for leaf in big],
+                         [_hlo_shape(leaf, 1) for leaf in big],
+                         whole_layer_reads=False)
     assert found == []
 
 
