@@ -67,8 +67,10 @@ class ShapeSpec:
     runner.decode_fused_shared) or "grouped" (member rows gathered from
     prefix groups: decode_fused_grouped) — both the ONE dispatch program
     (generate.greedy_decode_dispatch), whose front and tail follow from
-    the fields below (:func:`dispatch_program`) — or one of the three
-    piggyback stages, or "stream_fold". ``batch`` is the PADDED
+    the fields below (:func:`dispatch_program`) — or "trunk" (the trunk
+    program, generate.greedy_decode_trunk: ``bucket`` its extent, one
+    row), or one of the three piggyback stages, or "stream_fold".
+    ``batch`` is the PADDED
     member-row count the runner will dispatch (shared: the padded batch;
     grouped: m_pad); ``groups`` the padded prefill-row count (grouped
     only, else 0). ``sfx_a``/``sfx_b`` are the right-pad suffix bucket
@@ -83,8 +85,11 @@ class ShapeSpec:
     gathers the rest from the page pool; with ``trunk`` it is the
     TRUNK's window (a (1, W) chunk), not a per-row one. ``trunk`` > 0
     selects the CASCADE front at that static shared-trunk extent (which
-    is then also the decode steps' trunk) and ``cascade_int8`` its
-    in-kernel int8-QK^T variant. ``decode_trunk`` > 0 runs the decode
+    is then also the decode steps' trunk), ``cascade_int8`` its
+    in-kernel int8-QK^T variant and ``held`` the front that takes the
+    trunk's cache as an argument instead of prefilling it (the engine
+    holds it: runner.ScoringEngine.route).
+    ``decode_trunk`` > 0 runs the decode
     steps of a NON-cascade front trunk-aware at that extent
     (ops/flash_decode.flash_decode_trunk — bitwise the flat kernels).
     ``spec_k`` > 0 selects the speculative tail at that verify-window
@@ -109,9 +114,12 @@ class ShapeSpec:
     trunk: int = 0
     cascade_int8: bool = False
     decode_trunk: int = 0
+    held: bool = False
 
     @property
     def label(self) -> str:
+        if self.kind == "trunk":
+            return f"trunk/t{self.bucket}x{self.batch}"
         sfx = (f"{self.sfx_a}+{self.sfx_b}"
                if self.kind.startswith(("shared", "piggy"))
                else str(self.sfx_a))
@@ -123,8 +131,9 @@ class ShapeSpec:
                                             else "")
         casc = ""
         if self.trunk:
-            casc = f"/trunk{self.trunk}" + ("+i8" if self.cascade_int8
-                                            else "")
+            casc = (f"/trunk{self.trunk}" + ("+i8" if self.cascade_int8
+                                             else "")
+                    + ("+held" if self.held else ""))
         if self.decode_trunk:
             casc += f"/dtrunk{self.decode_trunk}"
         return (f"{self.kind}/b{self.bucket}x{self.batch}/sfx{sfx}"
@@ -140,7 +149,20 @@ class ShapeSpec:
         LONGER (spec_k slots a decode window) and chains on its own."""
         return dataclasses.replace(
             self, scratch=False, window=0, trunk=0, cascade_int8=False,
-            decode_trunk=0)
+            decode_trunk=0, held=False)
+
+
+def handoff_key(spec: ShapeSpec, kinds: bool = False) -> ShapeSpec:
+    """What consecutive dispatches must share for one to donate the
+    other's returned cache: ``spec.cache_key``, and for a model whose
+    layers differ in kind (``kinds``) the trunk too — there a cascade
+    front returns the trunk at one row with the rows' own slots behind
+    it and a dense front every row's whole prefix (models/mixed.py):
+    two avals, two chains. The runner keys the handoff on it and
+    :func:`plan_specs` follows it, so the plan's donated variants are
+    the ones that run."""
+    key = spec.cache_key
+    return dataclasses.replace(key, trunk=spec.trunk) if kinds else key
 
 
 def stream_fold_spec(n_prompts: int, n_rephrase: int, batch: int,
@@ -154,6 +176,12 @@ def stream_fold_spec(n_prompts: int, n_rephrase: int, batch: int,
     guarded and unguarded sinks can never share an executable."""
     return ShapeSpec("stream_fold", int(n_prompts), int(batch),
                      int(n_rephrase), 0, 0, 0, 0, bool(guard), False)
+
+
+def trunk_spec(trunk: int) -> ShapeSpec:
+    """The trunk program (generate.greedy_decode_trunk) at ``trunk``
+    tokens: one row, no suffix, no tail, nothing donated."""
+    return ShapeSpec("trunk", int(trunk), 1, 0, 0, 0, 0, 0, False, False)
 
 
 def piggy_prefill_spec(bucket: int, batch: int, sfx_a: int, sfx_b: int,
@@ -196,8 +224,9 @@ def plan_specs(dispatches: Sequence[Any], routes: Sequence[Any],
     waits). ``routes[i]`` is the engine's routing of ``dispatches[i]``
     (runner.ScoringEngine.route_dispatch); WHICH programs a dispatch may
     run is the route's to say (``Route.planned``) — this function only
-    follows the handoff: the first dispatch of a shape runs the
-    scratchless variant, every consecutive repeat the donated one, and a
+    follows the handoff (``Route.handoff_key``): the first dispatch of a
+    shape runs the scratchless variant, every consecutive repeat the
+    donated one, and a
     repeat is also what the sweep chains through the piggyback stages.
 
     ``stream_shape`` = (n_prompts, n_rephrase, numerics_guard) plans the
@@ -222,10 +251,10 @@ def plan_specs(dispatches: Sequence[Any], routes: Sequence[Any],
             add(stream_fold_spec(n_prompts, n_rephrase, width, guard))
     prev = None
     for route in routes:
-        repeat = route.shape == prev
+        repeat = route.handoff_key == prev
         for spec in route.planned(scratch=repeat, chain=repeat):
             add(spec)
-        prev = route.shape
+        prev = route.handoff_key
     return specs
 
 
@@ -241,7 +270,8 @@ def dispatch_program(engine, spec: ShapeSpec,
     from . import generate
 
     if spec.trunk:
-        front = "cascade_paged" if spec.window else "cascade"
+        front = ("cascade_held" if spec.held
+                 else "cascade_paged" if spec.window else "cascade")
     else:
         front = "paged" if spec.window else "prefill"
     grouped = spec.kind == "grouped"
@@ -269,7 +299,9 @@ def dispatch_args(engine, spec: ShapeSpec,
     never disagree with its call site; an array whose shape is not the
     spec's raises here, before a program built for another shape could
     be asked for. What the engine holds (stop tables, digit table, page
-    pool, a draft model's weights) it hands over itself."""
+    pool, a draft model's weights) it hands over itself; the held
+    trunk's cache comes with the dispatch (``host["trunk_cache"]``: the
+    runner made sure these rows start with it)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -342,8 +374,24 @@ def dispatch_args(engine, spec: ShapeSpec,
         prefix=(leaf("prefix", (G, S)) if spec.trunk or not spec.window
                 else None),
         paged=paged,
+        trunk_cache=((trunk_cache_avals(engine, spec.trunk) if host is None
+                      else host["trunk_cache"]) if spec.held else None),
         group_idx=leaf("group_idx", (M,)) if grouped else None,
         stops=stops, drafts=drafts)
+
+
+def trunk_cache_avals(engine, trunk: int):
+    """What the trunk program returns at ``trunk`` tokens (tracing only,
+    no device work): the avals a ``"cascade_held"`` front is lowered
+    over."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import generate
+
+    return generate.greedy_decode_trunk.eval_shape(
+        engine.params, engine.cfg,
+        jax.ShapeDtypeStruct((1, int(trunk)), jnp.int32))
 
 
 def _avals_piggy(engine, spec: ShapeSpec):
@@ -396,8 +444,8 @@ def _lower(engine, spec: ShapeSpec):
     """Lower one spec; returns the jax Lowered program.
 
     The donated variant needs the KV-cache aval, which is exactly the
-    scratchless variant's returned cache — recovered via eval_shape
-    (tracing only, no device work)."""
+    cache the program returns — generate.dispatch_cache_avals (tracing
+    only, of the front alone; no device work)."""
     from . import generate
 
     if spec.kind == "stream_fold":
@@ -406,6 +454,13 @@ def _lower(engine, spec: ShapeSpec):
         return stream_stats.lower_fold(
             spec.bucket, spec.groups, spec.batch, TOPK,
             spec.stops_armed)
+    if spec.kind == "trunk":
+        import jax
+        import jax.numpy as jnp
+
+        return generate.greedy_decode_trunk.lower(
+            engine.params, engine.cfg,
+            jax.ShapeDtypeStruct((spec.batch, spec.bucket), jnp.int32))
     if spec.kind.startswith("piggy"):
         fn = {"piggy_prefill": generate.shared_piggyback_prefill,
               "piggy_step": generate.shared_piggyback_step,
@@ -418,8 +473,8 @@ def _lower(engine, spec: ShapeSpec):
     args = dispatch_args(engine, spec)
     scratch = None
     if spec.scratch:
-        scratch = fn.eval_shape(engine.params, engine.cfg, program,
-                                args)[2]   # the returned cache's avals
+        scratch = generate.dispatch_cache_avals(engine.params, engine.cfg,
+                                                program, args)
     return fn.lower(engine.params, engine.cfg, program, args,
                     scratch_cache=scratch)
 

@@ -310,6 +310,25 @@ def prefill_cache(params, cfg: ModelConfig, tokens: jax.Array,
     return cache
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def greedy_decode_trunk(params, cfg: ModelConfig, tokens: jax.Array):
+    """THE trunk program: the cache of a shared trunk, prefilled ONCE a
+    call at one row and the trunk's exact extent, every slot real (slot
+    t is position t) — what the ``"cascade"`` front computes inside its
+    own program, as a value the ``"cascade_held"`` front takes instead
+    (:func:`_front`), dispatch after dispatch. ``tokens`` is (1, t).
+    Whatever ``decoder.prefill`` returns for the family: K/V, with a
+    mixer its state and conv tail at the trunk's end, for layers that
+    differ in kind the six leaves with no tail (models/mixed.py).
+
+    Named as the dispatch programs are, so a reader of device time that
+    matches ``jit_greedy_decode*`` keeps seeing all of a sweep's."""
+    ones = jnp.ones(tokens.shape, jnp.int32)
+    _, cache, _ = decoder.prefill(params, cfg, tokens, ones,
+                                  tokens.shape[1])
+    return cache
+
+
 @jax.named_scope("lir.prefill")
 def _paged_prefix(params, cfg: ModelConfig, paged: "PagedFront",
                   prefix_mask: jax.Array, total_len: int):
@@ -727,9 +746,11 @@ class Program:
     tokens), ``"paged"`` (:func:`_paged_prefix`: page gather + window
     extension; binds slot tables, not tokens), ``"cascade"`` (the first
     ``trunk`` tokens, which every row shares, prefilled ONCE at batch 1,
-    the per-row remainders extended over them by decoder.cascade_extend)
-    or ``"cascade_paged"`` (that trunk resumed from the page pool at one
-    row instead).
+    the per-row remainders extended over them by decoder.cascade_extend),
+    ``"cascade_paged"`` (that trunk resumed from the page pool at one
+    row instead) or ``"cascade_held"`` (that trunk's cache handed in,
+    ``DispatchArgs.trunk_cache``: :func:`greedy_decode_trunk` ran it once
+    and every dispatch of the call that starts with it reads it).
     ``layout`` — ``"pair"``: two format branches on one cache, B from
     ``decoder.rewind`` of A's; ``"grouped"``: member rows gathered from
     G prefix rows by ``group_idx``, one branch with per-row stop tables.
@@ -817,6 +838,8 @@ class DispatchArgs:
     digit_vals: jax.Array
     prefix: Any = None        # tokens; None on the "paged" front
     paged: Any = None         # PagedFront on the paged fronts
+    trunk_cache: Any = None   # the held trunk's cache ("cascade_held");
+    #                           read, never donated: later dispatches too
     group_idx: Any = None     # (M,) member row -> prefix row, grouped
     stops: Any = None
     drafts: Any = None
@@ -862,8 +885,8 @@ def _front(params, cfg: ModelConfig, program: Program, args: DispatchArgs,
     # mask is all-ones and slot t is position t — the layout
     # cascade_extend assumes and the page pool stores. The quadratic
     # trunk prefill runs ONCE at batch 1 at the EXACT trunk extent (or
-    # is gathered from the pool: no recompute at all); the dense path
-    # pays it once per row.
+    # is gathered from the pool, or was handed in: no recompute at
+    # all); the dense path pays it once per row.
     t = program.trunk
     ones = jnp.ones((1, t), a.prefix_mask.dtype)
     if front == "cascade":
@@ -871,6 +894,8 @@ def _front(params, cfg: ModelConfig, program: Program, args: DispatchArgs,
                                        t)
     elif front == "cascade_paged":
         tcache = _paged_prefix(params, cfg, a.paged, ones, t)
+    elif front == "cascade_held":
+        tcache = a.trunk_cache
     else:
         raise ValueError(f"unknown front {front!r}")
     return decoder.cascade_extend(params, cfg, tcache, a.prefix[:, t:],
@@ -920,6 +945,54 @@ def _extend_suffix(params, cfg: ModelConfig, cache, prefix_mask, sfx,
     return logits_l, cache2, cm, pos
 
 
+def _start(params, cfg: ModelConfig, program: Program, args: DispatchArgs):
+    """What every branch of a dispatch program starts from: the cache
+    the front filled, laid out by member row (and a fleet draft model's
+    beside it, or None), the member rows' prefix mask, and the two
+    extents (the program's, and the sequential one a speculative cache
+    is viewed at). The cache a program returns has these leaves' shapes
+    whatever its tail does, so a plan that needs them to lower a donated
+    variant (:func:`dispatch_cache_avals`) traces this much and no
+    more."""
+    a = args
+    k = program.spec_k
+    S = a.prefix_mask.shape[1]
+    M = a.sfx[0].shape[0]
+    widths = [s.shape[1] for s in a.sfx]
+    T0 = dispatch_extent(cfg, S, widths, program.max_new, M, k)
+    T_seq = dispatch_extent(cfg, S, widths, program.max_new, M)
+    start, dstart = _front(params, cfg, program, a, T0)
+    pm = a.prefix_mask
+    if program.layout == "grouped":
+        if k:
+            raise ValueError("no speculative tail over per-row stop "
+                             "tables (a grouped batch)")
+        if cfg.layer_kinds:
+            raise NotImplementedError(
+                f"{cfg.name}: a grouped batch gathers member rows out of "
+                "prefix rows, and a cache of layers that differ in kind is "
+                "not laid out by row (models/mixed.py); the sweep plans "
+                "none for such a model")
+        from ..models import cache as cache_mod
+
+        start = cache_mod.gather_rows(start, a.group_idx)
+        pm = jnp.take(pm, a.group_idx, axis=0)                 # (M, S)
+    elif program.layout != "pair":
+        raise ValueError(f"unknown layout {program.layout!r}")
+    return start, dstart, pm, T0, T_seq
+
+
+def dispatch_cache_avals(params, cfg: ModelConfig, program: Program,
+                         args: DispatchArgs):
+    """Shapes and dtypes of the cache :func:`greedy_decode_dispatch`
+    returns for these arguments: what its ``scratch_cache`` takes.
+    Tracing only, and of the front alone: a whole trace of the program
+    to learn its cache's avals made every donated variant cost two (a
+    third of what loading one costs on a cache hit)."""
+    return jax.eval_shape(
+        lambda p, a: _start(p, cfg, program, a)[0], params, args)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "program"),
                    donate_argnames=("scratch_cache",), keep_unused=True)
 def greedy_decode_dispatch(params, cfg: ModelConfig, program: Program,
@@ -956,35 +1029,15 @@ def greedy_decode_dispatch(params, cfg: ModelConfig, program: Program,
     XLA writes this one into the same HBM block — one buffer per chain
     of a sweep instead of an alloc/free per dispatch
     (models/paged.CacheHandoff; programs of one shape return one cache
-    aval whatever their front). Results never depend on its contents:
+    aval whatever their front — for layers that differ in kind, one a
+    trunk: compile_plan.handoff_key). Results never depend on its contents:
     the front overwrites every slot and attention is masked by the
     cache masks regardless."""
     del scratch_cache  # donated scratch: memory reuse only, never read
     a = args
     k = program.spec_k
     S = a.prefix_mask.shape[1]
-    M = a.sfx[0].shape[0]
-    widths = [s.shape[1] for s in a.sfx]
-    T0 = dispatch_extent(cfg, S, widths, program.max_new, M, k)
-    T_seq = dispatch_extent(cfg, S, widths, program.max_new, M)
-    start, dstart = _front(params, cfg, program, a, T0)
-    pm = a.prefix_mask
-    if program.layout == "grouped":
-        if k:
-            raise ValueError("no speculative tail over per-row stop "
-                             "tables (a grouped batch)")
-        if cfg.layer_kinds:
-            raise NotImplementedError(
-                f"{cfg.name}: a grouped batch gathers member rows out of "
-                "prefix rows, and a cache of layers that differ in kind is "
-                "not laid out by row (models/mixed.py); the sweep plans "
-                "none for such a model")
-        from ..models import cache as cache_mod
-
-        start = cache_mod.gather_rows(start, a.group_idx)
-        pm = jnp.take(pm, a.group_idx, axis=0)                 # (M, S)
-    elif program.layout != "pair":
-        raise ValueError(f"unknown layout {program.layout!r}")
+    start, dstart, pm, T0, T_seq = _start(params, cfg, program, a)
 
     stops = a.stops
     outs, specs = [], []

@@ -18,7 +18,8 @@ Three pieces:
 
 - **Ledger.** Every HBM consumer registers projected bytes under a
   stable name (``register``/``update``/``unregister``): engine params,
-  the KV page pool, the dispatch/handoff donation caches, spec-draft
+  the KV page pool, the dispatch/handoff donation caches, the one held
+  trunk (``trunk:<model>``, engine/runner.py), spec-draft
   pins, fleet weight-cache residency, the streaming accumulator
   lattice. ``admit`` checks a projected allocation against the budget
   BEFORE the bytes exist (counters ``admits``/``denials``), and the

@@ -27,6 +27,7 @@ import numpy as np
 from ..config import CascadeConfig, GovernorConfig, RuntimeConfig, SpecConfig
 from ..guard.watchdog import DispatchWatchdog
 from ..models import decoder, paged, quant
+from ..observe import tracing
 from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CompileStats, FaultStats,
                                GuardStats, KernelStats, PrefixCacheStats,
@@ -128,7 +129,8 @@ class Route:
     what the compile plan compiles. The sweep's chain keys, the
     watchdog's price and the batcher's read ``trunk`` / ``dtrunk``.
 
-    Precedence: the cascade front (``trunk``) before the speculative
+    Precedence: the cascade front (``trunk``; ``held`` where the trunk
+    is worth holding across dispatches) before the speculative
     tail before the piggyback chain — the latter two optimize around
     the very prefill the cascade removes. A paged front whenever the
     radix tree holds a window. The decode steps of a dense or paged
@@ -151,6 +153,25 @@ class Route:
     # True: the first dispatch of a shape donates an empty cache
     # (RuntimeConfig.donate_first), so only donated variants are planned.
     donate_first: bool = False
+    # True: the cascade front takes the trunk's cache as an argument
+    # ("cascade_held"); the engine holds it from dispatch to dispatch.
+    held: bool = False
+    # ... and this dispatch must have the trunk program run first: the
+    # engine does not hold these tokens' trunk when it starts.
+    trunk_run: bool = False
+    # The trunk the engine holds once this dispatch has run (its token
+    # ids; None: none): the next dispatch's route is made from it, in
+    # the plan as at dispatch time. A value carried, not a fact compared.
+    held_ids: Optional[Tuple[int, ...]] = dataclasses.field(
+        default=None, compare=False, repr=False)
+    # The model's layers differ in kind: a cascade front's cache is not
+    # a dense front's (compile_plan.handoff_key).
+    kinds: bool = False
+
+    @property
+    def handoff_key(self) -> compile_plan.ShapeSpec:
+        """What the dispatch chains its cache on (the donation chain)."""
+        return compile_plan.handoff_key(self.spec(), self.kinds)
 
     def spec(self, window: int = 0, speculate: bool = False,
              scratch: bool = False) -> compile_plan.ShapeSpec:
@@ -165,7 +186,8 @@ class Route:
                                     scratch=scratch)
         if self.trunk:
             return dataclasses.replace(shape, trunk=self.trunk,
-                                       cascade_int8=self.int8)
+                                       cascade_int8=self.int8,
+                                       held=self.held)
         k = self.spec_k if speculate and not (self.fleet and window) else 0
         return dataclasses.replace(shape, decode_trunk=self.dtrunk,
                                    spec_k=k,
@@ -195,6 +217,13 @@ class Route:
         this dispatch to the previous one (a repeat of its shape), so
         the three piggyback stages are planned."""
         scratch = scratch or self.donate_first
+        if self.held:
+            # The trunk's own program where it has to run, then the one
+            # dispatch program behind it: a held trunk is never behind a
+            # radix window, and the dense program is not its fallback.
+            return ([compile_plan.trunk_spec(self.trunk)]
+                    if self.trunk_run else []) + [
+                        self.spec(0, False, scratch)]
         dense = dataclasses.replace(self, trunk=0,
                                     dtrunk=0 if self.trunk else self.dtrunk)
         edges = lambda extent: (  # noqa: E731
@@ -402,6 +431,10 @@ class ScoringEngine:
         # sweep's scheduler counters (profiling.OccupancyStats) — set by
         # sweep.run_perturbation_sweep, read by bench.py.
         self._handoff = _CacheHandoff()
+        # The ONE held trunk (ids, cache): what generate.
+        # greedy_decode_trunk returned for these tokens, read by every
+        # consecutive dispatch that starts with them (:meth:`route`).
+        self._trunk: Optional[Tuple[Tuple[int, ...], Any]] = None
         self.occupancy = None
         # In-flight piggyback chain (chunked prefill/decode piggybacking):
         # the parked dispatch whose decode scans ride the next same-shape
@@ -495,6 +528,41 @@ class ScoringEngine:
         if getattr(self, "governor", None) is not None:
             self.governor.unregister(
                 f"handoff:{getattr(self.cfg, 'name', 'model')}")
+        self._drop_trunk()
+
+    def _drop_trunk(self) -> None:
+        """Let go of the held trunk (its buffers are freed once the last
+        dispatch reading them is done) and of its ledger entry."""
+        self._trunk = None
+        if getattr(self, "governor", None) is not None:
+            self.governor.unregister(
+                f"trunk:{getattr(self.cfg, 'name', 'model')}")
+
+    def _hold_trunk(self, ids: Tuple[int, ...]) -> None:
+        """Run the trunk program over ``ids`` and hold what it returns.
+        The trunk held before is dropped FIRST, so two are never alive."""
+        self._drop_trunk()
+        spec = compile_plan.trunk_spec(len(ids))
+        tokens = jnp.asarray(np.asarray(ids, np.int32)[None, :])
+        with tracing.span("sweep/trunk", tokens=len(ids)):
+            run = self._hit_or_lazy(
+                spec, lambda params, toks: generate.greedy_decode_trunk(
+                    params, self.cfg, toks))
+            cache = run(self.params, tokens)
+        self._trunk = (ids, cache)
+        if self.governor is not None:
+            self.governor.register(
+                f"trunk:{getattr(self.cfg, 'name', 'model')}",
+                _tree_bytes(cache))
+        self.cascade_stats.count("trunk_programs")
+        self._note_prefilled(len(ids), (), (), (), cache, 0, 0)
+        # One chunked scan a recurrent layer, no dispatch of rows.
+        self.recurrent_stats.count("scan_calls", self._recurrent_layers())
+
+    @property
+    def held_trunk(self) -> Optional[Tuple[int, ...]]:
+        """Token ids of the trunk this engine holds now, or None."""
+        return None if self._trunk is None else self._trunk[0]
 
     def release_params_ledger(self) -> None:
         """The fleet weight cache now owns this engine's param bytes —
@@ -558,17 +626,22 @@ class ScoringEngine:
     def _note_prefilled(self, trunk: int, prefix_lens: Sequence[int],
                         sfx_a: Sequence[Sequence[int]],
                         sfx_b: Sequence[Sequence[int]], cache: Any,
-                        new_tokens: int, conf_tokens: int) -> None:
+                        new_tokens: int, conf_tokens: int,
+                        trunk_inside: bool = True) -> None:
         """Count one shared dispatch's real prompt tokens (a trunk once)
         and, for a model whose softmax layers select blocks, what its
         queries were offered and kept: host ints from the rows' lengths
         and the decode budgets (each branch runs its budget of steps
-        unless every row stops first)."""
+        unless every row stops first). ``trunk_inside`` False: the
+        dispatch program took the trunk as a value, and the trunk
+        program's run counted its tokens and its queries' blocks
+        (:meth:`_hold_trunk`: a trunk and no rows)."""
         rows = list(zip(prefix_lens, sfx_a, sfx_b))
-        prefilled = trunk + sum(n - trunk + len(a) + len(b)
-                                for n, a, b in rows)
+        own = trunk if trunk_inside else 0
+        prefilled = own + sum(n - trunk + len(a) + len(b)
+                              for n, a, b in rows)
         stats = self.cascade_stats
-        stats.count("trunk_tokens_prefilled", trunk)
+        stats.count("trunk_tokens_prefilled", own)
         stats.count("tokens_prefilled", prefilled)
         cfg = self.cfg
         if not getattr(cfg, "layer_kinds", ()):
@@ -579,7 +652,7 @@ class ScoringEngine:
                      init_blocks=cfg.sparse_init_blocks,
                      window=cfg.sparse_window, dense_len=cfg.sparse_dense_len)
         total = np.zeros(3, np.int64)
-        if trunk:
+        if own:
             total += sparse.kept_blocks(np.arange(trunk), trunk, **sizes)
         for n, a, b in rows:
             pos = [np.arange(trunk, n)]
@@ -596,6 +669,17 @@ class ScoringEngine:
         sp.count("queries", queries * layers)
         sp.count("pooled_key_bytes", _tree_bytes(cache[3]))
 
+    def _recurrent_layers(self) -> int:
+        """Layers that carry a recurrent state: every layer of a model
+        with a mixer, the lightning layers where layers differ in kind
+        (K/V for the softmax layers only; only the latter scan), none
+        otherwise."""
+        if not getattr(self.cfg, "carries_state", False):
+            return 0
+        if self.cfg.layer_kinds:
+            return self.cfg.kind_layers("lightning")
+        return self.cfg.n_layers
+
     def _note_recurrent(self, cache: Any, rows: int, steps: int,
                         windows: int, trunk_rows: int = 0,
                         forks: Optional[int] = None) -> None:
@@ -608,15 +692,12 @@ class ScoringEngine:
         the decode budget, each step a single-token update per layer."""
         if not getattr(self.cfg, "carries_state", False):
             return
+        L = self._recurrent_layers()
         if self.cfg.layer_kinds:
-            # K/V for the softmax layers only, state for the lightning
-            # layers only; only the latter scan.
             from ..models import mixed
 
-            L = self.cfg.kind_layers("lightning")
             kv, state = mixed.cache_kinds(cache)
         else:
-            L = self.cfg.n_layers
             kv, state = cache[:2], cache[2:]
         stats = self.recurrent_stats
         stats.count("dispatches")
@@ -802,18 +883,32 @@ class ScoringEngine:
               sfx_a: int, sfx_b: int, new_tokens: int, conf_tokens: int,
               stops_armed: bool,
               prefix_rows: Optional[Sequence[Sequence[int]]] = None,
-              n_real: Optional[int] = None) -> Route:
+              n_real: Optional[int] = None,
+              held: Optional[Sequence[int]] = None) -> Route:
         """The :class:`Route` of one dispatch, from its facts: ``kind``
         ("shared" | "grouped"), the prefix ``edge`` it runs at, its
         PADDED member ``rows`` (and prefill ``groups``, grouped only),
         suffix bucket edges, token budgets, whether the stops are armed,
-        and the rows' shared prefixes (None: no rows yet, as when the
-        serving ladder is warmed — no trunk then). Everything that asks
+        the rows' shared prefixes (None: no rows yet, as when the
+        serving ladder is warmed — no trunk then), and ``held``: the
+        trunk the engine holds when the dispatch starts (its token ids;
+        the plan carries ``Route.held_ids`` from each route to the next,
+        a dispatch reads :attr:`held_trunk`). Everything that asks
         which program a dispatch runs or may run — the dispatch itself,
         the compile plan, the sweep's chains and prices, the batcher —
-        asks here."""
+        asks here.
+
+        A trunk is HELD (its cache an argument, the trunk program run
+        once for every consecutive dispatch that starts with it) where
+        it is at least half of the tokens the dispatch would prefill:
+        ``trunk >= rows * (edge - trunk)``, the scheduler's grouping rule
+        turned on a trunk. A dispatch too small to split at a trunk of
+        its own (under ``min_rows``) takes the held one where every row
+        starts with it. Under a radix cache the paged fronts own the
+        trunk; across a device mesh nothing lays such a value out."""
         page = (self.prefix_cache.page_size
                 if self.prefix_cache is not None else 0)
+        held = None if held is None else tuple(held)
         if kind == "grouped":
             # Both formats ride one suffix edge and one decode budget.
             return Route(compile_plan.ShapeSpec(
@@ -821,8 +916,19 @@ class ScoringEngine:
                 int(max(sfx_a, sfx_b)), 0,
                 int(max(new_tokens, conf_tokens)), 0, bool(stops_armed),
                 False), page_size=page,
-                donate_first=bool(self.rt.donate_first))
+                donate_first=bool(self.rt.donate_first), held_ids=held)
         trunk, dtrunk = self.shared_trunk(prefix_rows, n_real, edge)
+        can_hold = (self.cascade_supported() and not page
+                    and _params_span(self.params) <= 1)
+        taken = bool(
+            can_hold and not trunk and held is not None and prefix_rows
+            and len(held) < int(edge)
+            and all(tuple(r[:len(held)]) == held for r in prefix_rows))
+        if taken:
+            # Too few rows for a trunk of their own, and each starts
+            # with the one the dispatch before them left.
+            trunk = len(held)
+            dtrunk = trunk if self.cascade_decode_supported() else 0
         if trunk and getattr(self.cfg, "layer_kinds", ()):
             # Behind a trunk every row's own slots must lie inside the
             # selection's local window (models/mixed.py); where they do
@@ -834,6 +940,9 @@ class ScoringEngine:
                 (int(new_tokens), int(conf_tokens)), int(rows))
             if not mixed.tail_fits(self.cfg, trunk, extent - trunk):
                 trunk = dtrunk = 0
+        hold = bool(trunk) and (taken or (
+            can_hold and trunk >= int(rows) * (int(edge) - trunk)))
+        ids = tuple(prefix_rows[0][:trunk]) if hold else None
         return Route(
             compile_plan.ShapeSpec(
                 "shared", int(edge), int(rows), 0, int(sfx_a), int(sfx_b),
@@ -847,19 +956,38 @@ class ScoringEngine:
             plan_dense=not (self.rt.dispatch_tokens
                             and int(rows) * int(edge)
                             > self.rt.dispatch_tokens),
-            donate_first=bool(self.rt.donate_first))
+            donate_first=bool(self.rt.donate_first),
+            held=hold, trunk_run=hold and ids != held,
+            held_ids=ids if hold else held,
+            kinds=bool(getattr(self.cfg, "layer_kinds", ())))
 
     def route_dispatch(self, d, new_tokens: int, conf_tokens: int,
-                       stops_armed: bool) -> Route:
+                       stops_armed: bool,
+                       held: Optional[Sequence[int]] = None) -> Route:
         """:meth:`route` of one scheduler.Dispatch, padded as
-        decode_fused_shared / decode_fused_grouped will pad it."""
+        decode_fused_shared / decode_fused_grouped will pad it, behind
+        the trunk ``held`` by then."""
         g_pad, m_pad = d.padded_rows(self.rt.batch_size)
         shared = d.kind == "shared"
         return self.route(
             d.kind, d.edge, m_pad, 0 if shared else g_pad, d.sfx_bucket_a,
             d.sfx_bucket_b, new_tokens, conf_tokens, stops_armed,
             [it.bin_ids[:it.lcp] for it in d.items] if shared else None,
-            len(d.items))
+            len(d.items), held)
+
+    def route_plan(self, dispatches, new_tokens: int, conf_tokens: int,
+                   stops_armed: bool) -> List[Route]:
+        """The routes of a call's dispatches, in the order they will run
+        on a fresh chain (:meth:`fresh_handoff`: nothing held): each is
+        made behind the trunk its predecessor leaves held, which is what
+        the dispatch itself will find (:attr:`held_trunk`)."""
+        routes: List[Route] = []
+        held = None
+        for d in dispatches:
+            routes.append(self.route_dispatch(d, new_tokens, conf_tokens,
+                                              stops_armed, held))
+            held = routes[-1].held_ids
+        return routes
 
     def _note_cascade_decode(self, dtrunk: int, rows: int, cache,
                              new_tokens: int, conf_tokens: int) -> None:
@@ -1344,9 +1472,17 @@ class ScoringEngine:
         prefix_rows = [a[:n] for a, n in zip(bin_ids, lcp)]
         rows = B if n_real is None else n_real
         route = self.route("shared", bucket, B, 0, ba, bb, new_tokens,
-                           conf_tokens, armed, prefix_rows, n_real)
+                           conf_tokens, armed, prefix_rows, n_real,
+                           held=self.held_trunk)
         splan = None
-        if route.trunk:
+        plan = None
+        if route.held:
+            # The trunk is a value: prefilled by its own program unless
+            # the engine holds these very tokens' already, then read.
+            if route.trunk_run:
+                self._hold_trunk(route.held_ids)
+            host["trunk_cache"] = self._trunk[1]
+        elif route.trunk:
             # The warm trunk lives in the TRUNK-extent radix namespace
             # (pages are reproducible only within one attention extent —
             # prefix_tree's per-bucket rule — and the cascade trunk
@@ -1387,28 +1523,38 @@ class ScoringEngine:
             self._spec_pending.append(specs)
             self.spec_stats.count("spec_dispatches")
             self.spec_stats.count("spec_rows", rows)
-        shared_rows = max(rows - 1, 0) if route.trunk else 0
+        # Rows whose trunk somebody else prefilled: all but one where
+        # the trunk was run for this dispatch (inside its program or by
+        # the trunk program just before it), every row where the
+        # dispatch took a trunk it found held.
+        found = route.held and not route.trunk_run
+        shared_rows = (rows if found else max(rows - 1, 0)
+                       ) if route.trunk else 0
         if route.trunk:
             self.cascade_stats.count("cascade_dispatches")
+            if route.held:
+                self.cascade_stats.count("trunk_held_dispatches")
             # A row counts as deduped when ALL of its trunk was shared:
             # its K/V and, for a model with a mixer, its recurrent state
-            # at the trunk's end (the cascade front computes both once
-            # at batch 1 and seeds every row with them).
+            # at the trunk's end (the trunk is computed once at batch 1
+            # and seeds every row with both).
             self.cascade_stats.count("trunk_rows_deduped", shared_rows)
             self.cascade_stats.count(
                 "prefix_flops_saved",
-                int(cascade_prefill_flops_saved(self.cfg, rows,
+                int(cascade_prefill_flops_saved(self.cfg, shared_rows + 1,
                                                 route.trunk)))
+        # The trunk program counted its own tokens and scans.
         self._note_prefilled(route.trunk, lcp[:rows], sfx_a_ids[:rows],
                              sfx_b_ids[:rows], cache, new_tokens,
-                             conf_tokens)
+                             conf_tokens, trunk_inside=not route.held)
         self._note_cascade_decode(route.dtrunk, rows, cache, new_tokens,
                                   conf_tokens)
-        # Chunked-scan windows a layer: the prefix [+ the trunk], then
-        # the two suffix extends.
-        self._note_recurrent(cache, rows, new_tokens + conf_tokens,
-                             windows=4 if route.trunk else 3,
-                             trunk_rows=shared_rows)
+        # Chunked-scan windows a layer: the prefix [+ the trunk, where
+        # this program runs it], then the two suffix extends.
+        self._note_recurrent(
+            cache, rows, new_tokens + conf_tokens,
+            windows=4 if route.trunk and not route.held else 3,
+            trunk_rows=shared_rows)
         return fused, cfused
 
     def _hit_or_lazy(self, spec: compile_plan.ShapeSpec, lazy):
@@ -1436,7 +1582,8 @@ class ScoringEngine:
         drops the plan's page pins and re-raises. ``reuse`` False runs
         the program alone: no chain, no registry, no cache returned.
         Returns the program's ``(outs, specs, cache)``."""
-        key = spec.cache_key
+        key = compile_plan.handoff_key(
+            spec, bool(getattr(self.cfg, "layer_kinds", ())))
         scratch = self._handoff.take(key) if reuse else None
         first = scratch is None and reuse and self.rt.donate_first
         spec = dataclasses.replace(spec,

@@ -705,9 +705,8 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
             # (ScoringEngine.route): the compile plan compiles what the
             # routes may run, and the chain keys and watchdog prices
             # below read the same routes.
-            routes = [engine.route_dispatch(d, new_tokens, conf_tokens,
-                                            stop_armed)
-                      for d in dispatches]
+            routes = engine.route_plan(dispatches, new_tokens, conf_tokens,
+                                       stop_armed)
             engine.exec_registry = None
             if engine.rt.aot_precompile:
                 specs = compile_plan.plan_specs(

@@ -114,7 +114,8 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "cascade_dispatches", "dense_fallbacks", "trunk_rows_deduped",
         "prefix_flops_saved", "cascade_decode_dispatches",
         "trunk_bytes_deduped", "tokens_prefilled",
-        "trunk_tokens_prefilled",
+        "trunk_tokens_prefilled", "trunk_programs",
+        "trunk_held_dispatches",
     ),
     "SparseStats": (
         "blocks_kept", "blocks_offered", "queries", "dense_queries",

@@ -779,8 +779,15 @@ class CascadeStats:
       cache, ...). A high fallback fraction on a shared-trunk workload
       means the eligibility knobs (CascadeConfig) are mistuned.
     - ``trunk_rows_deduped``: rows whose quadratic trunk prefill was NOT
-      recomputed (rows - 1 per cascade dispatch; the dense path pays all
-      of them) — the dedup the cascade exists for.
+      recomputed (rows - 1 per cascade dispatch whose trunk was run for
+      it, every row of one that found its trunk held; the dense path
+      pays all of them) — the dedup the cascade exists for.
+    - ``trunk_programs``: runs of the trunk program (generate.
+      greedy_decode_trunk): a trunk worth holding is prefilled once for
+      all the consecutive dispatches that start with it.
+    - ``trunk_held_dispatches``: cascade dispatches that took the
+      trunk's cache as an argument (the ``"cascade_held"`` front)
+      instead of prefilling it inside their own program.
     - ``prefix_flops_saved``: analytic matmul FLOPs those deduped trunk
       rows would have cost (the dense prefill's attention + projection
       terms over trunk tokens) — THE perf number; bench.py's ``cascade``
@@ -810,6 +817,8 @@ class CascadeStats:
     # would lower the second.
     tokens_prefilled: int = 0
     trunk_tokens_prefilled: int = 0
+    trunk_programs: int = 0
+    trunk_held_dispatches: int = 0
 
     def __post_init__(self) -> None:
         import threading

@@ -81,13 +81,15 @@ def fused_shared(params, cfg, prefix, prefix_mask, sfx_a, sfx_a_mask, sfx_b,
                  stop_mask_b=None, stop_mask_a=None, eos_id=None,
                  return_cache=False, decode_trunk=0, scratch_cache=None,
                  trunk_len=0, int8_qk=False, drafts=None, spec_k=0, ngram=2,
-                 draft_cfg=None, lower=False):
+                 draft_cfg=None, lower=False, trunk_cache=None):
     """greedy_decode_fused_shared's list; the keyword tail selects the
-    cascade front (``trunk_len``) and the speculative tail (``drafts`` +
+    cascade front (``trunk_len``; with ``trunk_cache`` the front that
+    takes the trunk as a value) and the speculative tail (``drafts`` +
     ``spec_k``). The paged fronts are driven through the engine
     (tests/test_prefix_cache.py, tests/test_dispatch_program.py)."""
     program = generate.Program(
-        front="cascade" if trunk_len else "prefill",
+        front=("cascade_held" if trunk_cache is not None
+               else "cascade" if trunk_len else "prefill"),
         max_new=(max_new_a, max_new_b), topk=topk,
         trunk=trunk_len or decode_trunk, int8_qk=int8_qk, spec_k=spec_k,
         ngram=ngram, draft_cfg=draft_cfg, prefill_fn=prefill_fn,
@@ -96,7 +98,8 @@ def fused_shared(params, cfg, prefix, prefix_mask, sfx_a, sfx_a_mask, sfx_b,
         prefix=prefix, prefix_mask=prefix_mask, sfx=(sfx_a, sfx_b),
         sfx_mask=(sfx_a_mask, sfx_b_mask), yes_ids=yes_ids, no_ids=no_ids,
         digit_ids=digit_ids, digit_vals=digit_vals,
-        stops=_stops(stop_mask_a, stop_mask_b, eos_id), drafts=drafts)
+        stops=_stops(stop_mask_a, stop_mask_b, eos_id), drafts=drafts,
+        trunk_cache=trunk_cache)
     return _call(params, cfg, program, args, scratch_cache, lower)
 
 
@@ -167,6 +170,6 @@ def plan_specs(engine, dispatches, new_tokens, conf_tokens, stops_armed,
     routes every dispatch, the plan compiles what the routes may run."""
     from lir_tpu.engine import compile_plan
 
-    routes = [engine.route_dispatch(d, new_tokens, conf_tokens, stops_armed)
-              for d in dispatches]
+    routes = engine.route_plan(dispatches, new_tokens, conf_tokens,
+                               stops_armed)
     return compile_plan.plan_specs(dispatches, routes, stream_shape)

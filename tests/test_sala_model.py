@@ -474,30 +474,42 @@ def test_a_sweep_shares_the_trunk_and_counts_what_it_dispatched(
     casc, rec, sp = (engine.cascade_stats, engine.recurrent_stats,
                      engine.sparse_stats)
     # 8 rows of 141 tokens in the 256 bucket under a cap of 256 beside a
-    # 128-token trunk: dispatches of 2, each one trunk at one row.
+    # 128-token trunk: dispatches of 2. The trunk is more than half of
+    # what a dispatch would prefill (128 >= 2 x 64), so it is a value:
+    # ONE trunk program for the call, every dispatch takes its cache.
     n = casc.cascade_dispatches
     assert n == 4 and casc.dense_fallbacks == 0
-    assert casc.trunk_tokens_prefilled == n * 128
-    assert casc.tokens_prefilled == n * 128 + 8 * (13 + 5 + 8)
-    assert rec.trunk_states_shared == casc.trunk_rows_deduped == 8 - n
+    assert casc.trunk_programs == 1 and casc.trunk_held_dispatches == n
+    assert casc.trunk_tokens_prefilled == 128
+    assert casc.tokens_prefilled == 128 + 8 * (13 + 5 + 8)
+    # The first dispatch had the trunk run for it (rows - 1), the others
+    # found it held (rows).
+    assert rec.trunk_states_shared == casc.trunk_rows_deduped == 8 - 1
     assert rec.forks == 2 * 8
-    # The program as traced: per dispatch a trunk fill, the windows, two
-    # extends (scans: one a lightning layer each) and two decode loops
-    # whose body is traced once and runs its budget of steps.
-    programs = len([p for p in passes if p[2]])     # traced side by side
-    assert all(p[:2] == (1, 128) for p in passes if p[2])
-    scans = [p for p in passes if p[1] > 1]
+    # The programs as traced: the trunk program is a fill at one row and
+    # nothing else; a dispatch program the windows, two extends (scans:
+    # one a lightning layer each) and two decode loops whose body is
+    # traced once and runs its budget of steps, and NO fill.
+    fills = [p for p in passes if p[2]]
+    assert fills and all(p[:2] == (1, 128) for p in fills)
+    windows = [p for p in passes if p[1] == 64 and not p[2]]
+    extends = [p for p in passes if 1 < p[1] < 64]
     steps = [p for p in passes if p[1] == 1]
-    assert len(scans) == 4 * programs and len(steps) == 2 * programs
+    programs = len(steps) // 2
+    assert len(extends) == len(steps) == 2 * programs
+    # ... and the front alone once more where a donated variant is lowered
+    # over the cache's avals (generate.dispatch_cache_avals).
+    assert programs <= len(windows) <= 2 * programs
+    assert len(windows) + len(extends) + len(steps) + len(fills) == len(passes)
     lightning = cfg.kind_layers("lightning")
     budget = engine.rt.sweep_decode_tokens + engine.rt.sweep_confidence_tokens
-    assert rec.scan_calls == n * 4 * lightning
+    assert rec.scan_calls == (n * 3 + 1) * lightning
     assert rec.step_calls == n * budget * lightning
     assert rec.state_bytes > 0 and rec.kv_bytes > 0
     assert sp.queries == (casc.tokens_prefilled + 8 * budget) * cfg.kind_layers(
         "sparse")
     assert 0 < sp.blocks_kept < sp.blocks_offered
-    assert sp.dense_queries == n * spec.dense_len and sp.pooled_key_bytes > 0
+    assert sp.dense_queries == spec.dense_len and sp.pooled_key_bytes > 0
     snap = metrics_mod.engine_registry(engine).snapshot(device_memory=False)
     assert snap["sources"]["sparse"]["fields"]["blocks_kept"] == sp.blocks_kept
     assert 0 < snap["sources"]["sparse"]["summary"]["kept_share"] < 1

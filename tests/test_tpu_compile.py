@@ -682,6 +682,92 @@ def test_mixed_layer_loop_updates_its_leaves_in_place(one_chip, on_tpu):
 
 
 # ---------------------------------------------------------------------------
+# The trunk as a value, at the published sizes (ISSUE 32)
+# ---------------------------------------------------------------------------
+# minicpm-sala.sweep-doc16k's three new programs, every layer, batch 40, a
+# 16,000-token trunk, as the engine routes and the compile plan lowers
+# them: the trunk program, and the dispatch program that takes its cache
+# at 40 rows and at ONE row. The chip reports 16.9 GB to the program
+# (PERF.md section 4); weights 9.5 of them.
+
+HBM = 16.9e9
+DOC_TRUNK, DOC_EDGE = 16_000, 16_128
+
+
+def _doc16k_engine(one_chip):
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+    sys.path.insert(0, str(bench))
+    from harness import builders
+    from references import sala as ref
+
+    raw = json.loads((bench / "configs" / "minicpm-sala.json").read_text())
+    spec = ref.spec_from_config("minicpm-sala", raw)
+    cfg = builders.program_config(spec, ref)
+    params = _shaped(jax.eval_shape(lambda: builders.wrap_quantized(
+        ref.weights(spec, ref.seed_key(0)))), one_chip)
+    return builders.build_engine(params, cfg, raw["lir_tpu"]["runtime"])
+
+
+@pytest.mark.parametrize("program", ["trunk", "held-40", "held-1"])
+def test_the_held_trunk_programs_compile_at_the_published_sizes(
+        one_chip, on_tpu, program):
+    from lir_tpu.engine import compile_plan
+
+    engine = _doc16k_engine(one_chip)
+    doc = tuple(range(DOC_TRUNK))
+    trunk_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(
+            compile_plan.trunk_cache_avals(engine, DOC_TRUNK)))
+    assert 0.18e9 < trunk_bytes < 0.2e9       # K/V 131, pooled 8, state 50 MB
+    if program == "trunk":
+        spec = compile_plan.trunk_spec(DOC_TRUNK)
+    else:
+        rows = 40 if program == "held-40" else 1
+        route = engine.route(
+            "shared", DOC_EDGE, rows, 0, 64, 64, 4, 8, True,
+            [doc + (20_000 + r,) * 100 for r in range(rows)], rows, held=doc)
+        assert route.held and not route.trunk_run and route.trunk == DOC_TRUNK
+        (spec,) = route.planned(False)
+        assert spec.held and spec.scratch      # donate_first: one variant
+    compiled = compile_plan._lower(engine, spec).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    if program == "trunk":
+        # It runs while the 40-row dispatch cache (2.2 GB) lies parked in
+        # the handoff, and what it returns is the held trunk.
+        assert 0 <= mem.output_size_in_bytes - trunk_bytes < 1 << 20
+        assert held + trunk_bytes + 2.3e9 < HBM
+        assert "lightning_scan" in text and "sparse_prefill" in text
+        assert "lightning_step" not in text and "sparse_decode" not in text
+        assert re.search(r"\[(1,)?16000,4096\]", text)
+        return
+    # The held trunk is one of the arguments; the donated cache comes back.
+    assert held < HBM
+    assert mem.alias_size_in_bytes > trunk_bytes
+    assert 0 <= mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+    for kernel in ("lightning_scan", "lightning_step", "sparse_prefill",
+                   "sparse_decode"):
+        assert kernel in text
+    # No pass over 16,000 tokens is left in a dispatch program (the dense
+    # one-row program and the trunk program hold hundreds of these).
+    assert not re.search(r"\[(1,)?16(000|128),4096\]", text)
+    cache = generate.greedy_decode_dispatch.eval_shape(
+        engine.params, engine.cfg,
+        compile_plan.dispatch_program(engine, spec),
+        compile_plan.dispatch_args(engine, spec))[-1]
+    assert cache[4].shape[1] == 1 and cache[4].shape[3] == DOC_TRUNK
+    big = [leaf for leaf in jax.tree.leaves(cache) if leaf.size > 1 << 19]
+    found = _cache_moves(text, [_hlo_shape(leaf) for leaf in big],
+                         [_hlo_shape(leaf, 1) for leaf in big],
+                         whole_layer_reads=False)
+    assert found == []
+
+
+# ---------------------------------------------------------------------------
 # The exact GELU stays a short epilogue of the up-projection
 # ---------------------------------------------------------------------------
 # ``decoder._act(., "gelu")`` runs on every element the up-projection makes
