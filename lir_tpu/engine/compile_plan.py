@@ -217,7 +217,7 @@ def piggy_drain_spec(bucket: int, batch: int, sfx_a: int, sfx_b: int,
 
 def plan_specs(dispatches: Sequence[Any], routes: Sequence[Any],
                stream_shape: Optional[Tuple[int, int, bool]] = None,
-               ) -> List[ShapeSpec]:
+               after: Any = None) -> List[ShapeSpec]:
     """Distinct executables a dispatch plan will call, in first-use order
     (the precompile pool works the list front-to-back, so the first
     bucket's executable compiles first and the dispatch loop rarely
@@ -228,6 +228,9 @@ def plan_specs(dispatches: Sequence[Any], routes: Sequence[Any],
     shape runs the scratchless variant, every consecutive repeat the
     donated one, and a
     repeat is also what the sweep chains through the piggyback stages.
+    ``after``: the route of the dispatch that runs before the first of
+    these (the last of the plan window before this one), None at the
+    start of a call.
 
     ``stream_shape`` = (n_prompts, n_rephrase, numerics_guard) plans the
     streaming-statistics accumulator-update executable for every
@@ -249,7 +252,7 @@ def plan_specs(dispatches: Sequence[Any], routes: Sequence[Any],
             width = (route.shape.batch if d.kind == "shared"
                      else len(d.items))
             add(stream_fold_spec(n_prompts, n_rephrase, width, guard))
-    prev = None
+    prev = None if after is None else after.handoff_key
     for route in routes:
         repeat = route.handoff_key == prev
         for spec in route.planned(scratch=repeat, chain=repeat):
@@ -530,6 +533,12 @@ class ExecutableRegistry:
     def __len__(self) -> int:
         return len(self._futures)
 
+    def loaded(self) -> bool:
+        """True when no submitted executable is still compiling or
+        loading."""
+        with self._lock:
+            return all(f.done() for f in self._futures.values())
+
     def submit(self, spec: ShapeSpec, engine, executor) -> None:
         with self._lock:
             if spec in self._futures:
@@ -682,33 +691,39 @@ def scope_table(hlo_text: str) -> Tuple[str, Dict[str, str], int]:
 
 
 def precompile_async(engine, specs: Sequence[ShapeSpec],
-                     max_workers: int = 0) -> ExecutableRegistry:
+                     max_workers: int = 0,
+                     registry: Optional[ExecutableRegistry] = None,
+                     ) -> ExecutableRegistry:
     """Kick off background compilation of every spec (dispatch order) and
     return the registry immediately — the sweep's first dispatches stream
     while later buckets' executables compile concurrently. The pool's
-    threads outlive this call; registry futures own the results."""
-    stats = getattr(engine, "compile_stats", None) or CompileStats()
-    rt = getattr(engine, "rt", None)
-    timeout = None
-    if rt is not None and getattr(rt, "watchdog_multiple", 0) > 0:
-        # The compile deadline mirrors the dispatch watchdog's shape:
-        # floor * multiple — generous enough for a real 7B executable,
-        # bounded enough that a wedged compiler thread costs one lazy
-        # fallback instead of parking the dispatch loop forever.
-        timeout = rt.watchdog_floor_s * max(rt.watchdog_multiple, 1.0)
-    registry = ExecutableRegistry(engine.cache_manifest_key, stats,
-                                  compile_timeout_s=timeout,
-                                  guard_stats=getattr(engine,
-                                                      "guard_stats", None))
-    if not specs:
-        return registry
+    threads outlive this call; registry futures own the results.
+    ``registry``: the one an earlier plan window of the same call made;
+    the specs it does not hold yet are added to it (``submit``: a shape
+    planned in two windows is compiled, and counted, once)."""
     from ..utils import compile_cache
 
-    compile_cache.write_manifest(engine.cache_manifest_key, {
-        "model": engine.cfg, "runtime": engine.rt,
-        "buckets": engine.buckets,
-        "quant": compile_cache.quant_mode(engine.params),
-        "shapes": [s.label for s in specs]})
+    if registry is None:
+        stats = getattr(engine, "compile_stats", None) or CompileStats()
+        rt = getattr(engine, "rt", None)
+        timeout = None
+        if rt is not None and getattr(rt, "watchdog_multiple", 0) > 0:
+            # The compile deadline mirrors the dispatch watchdog's shape:
+            # floor * multiple — generous enough for a real 7B executable,
+            # bounded enough that a wedged compiler thread costs one lazy
+            # fallback instead of parking the dispatch loop forever.
+            timeout = rt.watchdog_floor_s * max(rt.watchdog_multiple, 1.0)
+        registry = ExecutableRegistry(
+            engine.cache_manifest_key, stats, compile_timeout_s=timeout,
+            guard_stats=getattr(engine, "guard_stats", None))
+        if specs:
+            compile_cache.write_manifest(engine.cache_manifest_key, {
+                "model": engine.cfg, "runtime": engine.rt,
+                "buckets": engine.buckets,
+                "quant": compile_cache.quant_mode(engine.params),
+                "shapes": [s.label for s in specs]})
+    if not specs:
+        return registry
     import os
 
     workers = max_workers or min(len(specs), max(2, (os.cpu_count() or 4)))

@@ -30,6 +30,7 @@ from ..models import decoder, paged, quant
 from ..observe import tracing
 from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CompileStats, FaultStats,
+                               FillStats,
                                GuardStats, KernelStats, PrefixCacheStats,
                                RecurrentStats, SparseStats, SpecStats,
                                cascade_decode_bytes_saved,
@@ -313,6 +314,7 @@ class ScoringEngine:
         # policy + the dedup counters bench.py's "cascade" key reads.
         self.cascade_cfg = cascade_config or CascadeConfig()
         self.cascade_stats = CascadeStats()
+        self.fill_stats = FillStats()
         # Recurrent state beside K/V (models with a state-space mixer):
         # bytes per dispatch cache, forks, kernel calls, trunk states
         # shared (profiling.RecurrentStats; metrics source "recurrent").
@@ -976,13 +978,15 @@ class ScoringEngine:
             len(d.items), held)
 
     def route_plan(self, dispatches, new_tokens: int, conf_tokens: int,
-                   stops_armed: bool) -> List[Route]:
-        """The routes of a call's dispatches, in the order they will run
-        on a fresh chain (:meth:`fresh_handoff`: nothing held): each is
-        made behind the trunk its predecessor leaves held, which is what
-        the dispatch itself will find (:attr:`held_trunk`)."""
+                   stops_armed: bool,
+                   held: Optional[Sequence[int]] = None) -> List[Route]:
+        """The routes of a call's dispatches, in the order they will run:
+        each is made behind the trunk its predecessor leaves held, which
+        is what the dispatch itself will find (:attr:`held_trunk`).
+        ``held``: what the dispatch before the first of them leaves (the
+        last ``Route.held_ids`` of the plan window before this one); None
+        on a fresh chain (:meth:`fresh_handoff`: nothing held)."""
         routes: List[Route] = []
-        held = None
         for d in dispatches:
             routes.append(self.route_dispatch(d, new_tokens, conf_tokens,
                                               stops_armed, held))

@@ -7,9 +7,11 @@ are fixed-shape. The legacy path batched cells in todo order and padded
 every batch to the longest row's bucket — on a mixed-length grid nearly
 every batch contains one long prompt, so nearly every batch pays the
 largest bucket and short prompts burn their FLOPs on left-padding. This
-module sits between the grid and the engine and plans the whole sweep's
+module sits between the grid and the engine and plans the sweep's
 dispatches up front (the grid is fully known — there is no online arrival
-process):
+process), the whole grid at once or, where a token cap keeps the rows of
+different prompts apart anyway, a closed window of prompts at a time
+(:meth:`RaggedScheduler.closed`, :class:`EdgeFloors`):
 
 1. **Bucket ladder** (tokens.bucket_ladder): cells are sorted into
    ~sqrt(2)-spaced prompt-length buckets by their real tokenized prefix
@@ -74,7 +76,7 @@ def prefix_edges(length: int, bucket: int,
     ladder ``bucket`` may be dispatched at — which one is known only once
     the plan is final (the key's longest row decides). The radix cache
     keeps one namespace per extent, so a plan-time probe for a row's warm
-    pages looks under each of these (engine/sweep._plan_ragged)."""
+    pages looks under each of these (engine/sweep._ragged_planner)."""
     return tuple(range(_grid_edge(length, bucket, grid), bucket,
                        grid)) + (bucket,)
 
@@ -302,6 +304,29 @@ class Dispatch:
             return b, b
         return (_tail_batch(len(self.groups), batch_size),
                 _tail_batch(2 * n, 2 * batch_size))
+
+
+@dataclasses.dataclass
+class EdgeFloors:
+    """The longest format suffixes and the longest prefix each ``(kind,
+    bucket)`` key has carried so far in a call that is planned a window
+    at a time (engine/sweep._fill_windows). :meth:`RaggedScheduler.
+    schedule` plans at no less and raises them to what it planned, so
+    the edges of a call only ever grow and a key keeps one compiled
+    shape from window to window wherever the rows allow it."""
+
+    sfx_a: Dict[Tuple[str, int], int] = dataclasses.field(
+        default_factory=dict)
+    sfx_b: Dict[Tuple[str, int], int] = dataclasses.field(
+        default_factory=dict)
+    longest: Dict[Tuple[str, int], int] = dataclasses.field(
+        default_factory=dict)
+
+    def carry(self, key: Tuple[str, int], la: int, lb: int,
+              lp: int) -> None:
+        self.sfx_a[key] = max(self.sfx_a.get(key, 1), la)
+        self.sfx_b[key] = max(self.sfx_b.get(key, 1), lb)
+        self.longest[key] = max(self.longest.get(key, 1), lp)
 
 
 def build_items(bin_ids: Sequence[Sequence[int]],
@@ -565,10 +590,41 @@ class RaggedScheduler:
 
     # -- public entry --------------------------------------------------------
 
-    def schedule(self, items: Sequence[SweepItem]) -> List[Dispatch]:
+    def closed(self, items: Sequence[SweepItem]) -> bool:
+        """True where no row of ``items`` could share a dispatch with a
+        row it shares no trunk with: under the token cap two such rows
+        do not fit one pass (the rule of :meth:`_chunk_rows` at nothing
+        shared). A plan of these rows alone is then the part of a larger
+        plan that holds them, up to the edges (:class:`EdgeFloors`), so
+        the sweep plans and dispatches them before it has tokenized what
+        comes after. Never without a cap, nor where cells are grouped
+        across rows (groups of several prompts share a dispatch)."""
+        if not self.token_cap or (self.group_cells
+                                  and self.min_group_cells > 1):
+            return False
+        return all(
+            2 * tok.assign_bucket(it.prefix_len, self.buckets)
+            > self.token_cap for it in items)
+
+    def foresee(self, items: Sequence[SweepItem],
+                floors: EdgeFloors) -> None:
+        """Raise ``floors`` to what ``items`` would carry as shared rows
+        of their own buckets: rows a later window will plan, looked at
+        early so that the windows before it already run at their edges
+        (a prompt's format suffixes are the same on every one of its
+        rows, so one row a prompt tells them all)."""
+        for it in items:
+            floors.carry(
+                ("shared", tok.assign_bucket(it.prefix_len, self.buckets)),
+                len(it.bin_ids) - it.lcp, len(it.conf_ids) - it.lcp,
+                it.prefix_len)
+
+    def schedule(self, items: Sequence[SweepItem],
+                 floors: Optional[EdgeFloors] = None) -> List[Dispatch]:
         """Plan every dispatch for ``items``. Total and deterministic:
         each item appears in exactly one dispatch; identical inputs plan
-        identical schedules."""
+        identical schedules. ``floors``: the edges of the windows planned
+        before this one, raised in place to this plan's."""
         items = list(items)
         if self.group_cells and self.min_group_cells > 1:
             groups, rest = self._form_groups(items)
@@ -581,11 +637,8 @@ class RaggedScheduler:
         # longest suffix, the prefix edge from the longest prefix on the
         # edge grid — never above the ladder bucket, which is also what an
         # over-long row (truncated into the largest bucket) keeps.
-        sfx_a: Dict[Tuple[str, int], int] = {}
-        sfx_b: Dict[Tuple[str, int], int] = {}
-        longest: Dict[Tuple[str, int], int] = {}
+        edges = floors if floors is not None else EdgeFloors()
         for d in dispatches:
-            key = (d.kind, d.bucket)
             if d.kind == "shared":
                 la = max(len(it.bin_ids) - it.lcp for it in d.items)
                 lb = max(len(it.conf_ids) - it.lcp for it in d.items)
@@ -595,14 +648,15 @@ class RaggedScheduler:
                     max(len(it.bin_ids), len(it.conf_ids)) - g.plen
                     for g in d.groups for it in g.items)
                 lp = max(g.plen for g in d.groups)
-            sfx_a[key] = max(sfx_a.get(key, 1), la)
-            sfx_b[key] = max(sfx_b.get(key, 1), lb)
-            longest[key] = max(longest.get(key, 1), lp)
+            edges.carry((d.kind, d.bucket), la, lb, lp)
         for d in dispatches:
             key = (d.kind, d.bucket)
-            d.sfx_bucket_a = tok.pick_bucket([sfx_a[key]], self.suffix_buckets)
-            d.sfx_bucket_b = tok.pick_bucket([sfx_b[key]], self.suffix_buckets)
-            d.edge = _grid_edge(longest[key], d.bucket, self.edge_grid)
+            d.sfx_bucket_a = tok.pick_bucket([edges.sfx_a[key]],
+                                             self.suffix_buckets)
+            d.sfx_bucket_b = tok.pick_bucket([edges.sfx_b[key]],
+                                             self.suffix_buckets)
+            d.edge = _grid_edge(edges.longest[key], d.bucket,
+                                self.edge_grid)
 
         self._account(dispatches)
         return dispatches

@@ -18,7 +18,8 @@ import re
 import threading
 import time
 from pathlib import Path
-from typing import Callable, List, Optional, Sequence
+from typing import (Callable, Iterator, List, NamedTuple, Optional,
+                    Sequence)
 
 import jax
 import jax.numpy as jnp
@@ -572,18 +573,9 @@ def _steps_used(gen_row: np.ndarray, eos_id) -> int:
     return int(hits[0]) + 1 if hits.size else int(len(gen_row))
 
 
-def _plan_ragged(engine, todo, new_tokens, conf_tokens):
-    """Tokenize the pending grid ONCE and plan every dispatch through the
-    ragged scheduler (bucket ladder + slot refill + prefix groups). The
-    plan and its occupancy counters hang off ``engine.occupancy`` for the
-    bench/operators."""
-    with engine._tok_lock:
-        bin_ids = [engine.tokenizer(c.binary_prompt).input_ids
-                   for c in todo]
-        conf_ids = [engine.tokenizer(c.confidence_prompt).input_ids
-                    for c in todo]
-    items = sched_mod.build_items(bin_ids, conf_ids, todo)
-    stats = OccupancyStats()
+def _ragged_planner(engine, new_tokens, conf_tokens):
+    """The ragged scheduler (bucket ladder + slot refill + prefix groups)
+    of one sweep call, as the engine's runtime configures it."""
     max_extent = (engine.cfg.max_seq_len
                   if getattr(engine.cfg, "pos_embedding", None) == "learned"
                   else None)
@@ -603,7 +595,7 @@ def _plan_ragged(engine, todo, new_tokens, conf_tokens):
             return max(engine.prefix_cache.match_len(e, ids)
                        for e in sched_mod.prefix_edges(len(ids), b,
                                                        edge_grid))
-    planner = sched_mod.RaggedScheduler(
+    return sched_mod.RaggedScheduler(
         engine.buckets, engine.rt.batch_size,
         new_budget=max(new_tokens, conf_tokens),
         decode_cost=new_tokens + conf_tokens, max_extent=max_extent,
@@ -616,19 +608,197 @@ def _plan_ragged(engine, todo, new_tokens, conf_tokens):
                      and not getattr(engine.cfg, "layer_kinds", ())),
         cached_probe=cached_probe,
         fused_decode=engine.rt.fused_decode,
-        stats=stats, token_cap=engine.rt.dispatch_tokens)
-    dispatches = planner.schedule(items)
-    engine.occupancy = stats
-    log.info(
-        "ragged schedule: %d cells -> %d dispatches over buckets %s at "
-        "edges %s (occupancy %.1f%%, padding waste %.1f%%, edge trim "
-        "%.1f%%, refilled %d, grouped %d)", len(todo), len(dispatches),
-        sorted({d.bucket for d in dispatches}),
-        sorted({d.edge for d in dispatches}), stats.occupancy_pct,
-        stats.padding_waste_pct, stats.edge_trim_pct,
-        sum(b.refilled for b in stats.buckets.values()),
-        stats.grouped_cells)
-    return dispatches, stats
+        stats=OccupancyStats(), token_cap=engine.rt.dispatch_tokens)
+
+
+class _Window(NamedTuple):
+    """One plan window of a sweep call: its dispatches, the engine's
+    route of each, and whether cells of the call are left behind it."""
+
+    dispatches: list
+    routes: list
+    more: bool
+
+
+def _fill_windows(engine, todo, new_tokens, conf_tokens, stop_armed, sink,
+                  stop) -> Iterator[_Window]:
+    """The fill of one sweep call, a plan window at a time, in grid
+    order: tokenize the window's cells ONCE, plan their dispatches
+    through the ragged scheduler, route them (runner.ScoringEngine.
+    route_plan) and hand their shapes to the compile plan
+    (``engine.exec_registry``: lower + compile in background threads, so
+    the dispatch loop consumes precompiled executables instead of paying
+    trace-on-first-call inside the timed loop).
+
+    A window grows a prompt at a time (the grid is prompt-major) and is
+    planned as soon as it is CLOSED (scheduler.RaggedScheduler.closed):
+    every row in it is so long that the token cap keeps it out of any
+    dispatch with a row of another prompt, so nothing tokenized later
+    could change its dispatches. The caller dispatches it while the
+    windows after it are filled (:class:`_FillAhead`). From the first
+    prompt with a row that is not, the rest of the grid is one window; a
+    call with no token cap, or with short rows, is ONE window and its
+    plan the whole grid's.
+
+    What a window's plan owes the others, it is handed: the edges of a
+    call only grow (scheduler.EdgeFloors; where a window closes with
+    prompts left, one cell of each of those is tokenized first, whose
+    format suffixes are its prompt's, so the first window already plans
+    at the edges the whole grid would have given it); the routes start
+    behind the trunk the window before left held; ONE executable
+    registry a call takes every window's shapes (a shape planned twice
+    is compiled and counted once); one OccupancyStats
+    (``engine.occupancy``). ``stop`` (a threading.Event) ends the fill
+    between two cells. The seconds of each window go to
+    ``engine.fill_stats``."""
+    planner = _ragged_planner(engine, new_tokens, conf_tokens)
+    stats = engine.occupancy = planner.stats
+    floors = sched_mod.EdgeFloors()
+    prompts = [list(cells) for _, cells in itertools.groupby(
+        todo, key=lambda c: c.prompt_idx)]
+    stream_shape = (None if sink is None else
+                    (sink.n_prompts, sink.n_rephrase, sink.guard))
+    known: dict = {}      # id(cell) -> its item, until its window takes it
+    registry = None
+    held = last_route = None
+
+    def tokenize(cells) -> bool:
+        """False where the fill was stopped."""
+        for c in cells:
+            if id(c) in known:
+                continue
+            if stop.is_set():
+                return False
+            with engine._tok_lock:
+                b = engine.tokenizer(c.binary_prompt).input_ids
+                f = engine.tokenizer(c.confidence_prompt).input_ids
+            known[id(c)] = sched_mod.build_items([b], [f], [c])[0]
+        return True
+
+    items: list = []
+    closed = True
+    planned = 0
+    t0 = time.perf_counter()
+    for i, cells in enumerate(prompts):
+        if not tokenize(cells):
+            return
+        got = [known.pop(id(c)) for c in cells]
+        items += got
+        closed = closed and planner.closed(got)
+        more = i + 1 < len(prompts)
+        if more and not closed:
+            continue
+        if more and not planned:
+            heads = [p[0] for p in prompts[i + 1:]]
+            if not tokenize(heads):
+                return
+            planner.foresee([known[id(c)] for c in heads], floors)
+        dispatches = planner.schedule(items, floors)
+        routes = engine.route_plan(dispatches, new_tokens, conf_tokens,
+                                   stop_armed, held=held)
+        if engine.rt.aot_precompile:
+            specs = compile_plan.plan_specs(
+                dispatches, routes, stream_shape=stream_shape,
+                after=last_route)
+            registry = compile_plan.precompile_async(
+                engine, specs, max_workers=engine.rt.precompile_workers,
+                registry=registry)
+            if not planned:
+                _take_registry(engine, registry, sink)
+            log.info("compile plan: precompiling %d executable shapes "
+                     "in the background (manifest %s)", len(specs),
+                     registry.manifest_key)
+        if routes:
+            held, last_route = routes[-1].held_ids, routes[-1]
+        log.info(
+            "ragged schedule, window %d: %d cells -> %d dispatches over "
+            "buckets %s at edges %s (the call so far: occupancy %.1f%%, "
+            "padding waste %.1f%%, edge trim %.1f%%, refilled %d, grouped "
+            "%d)", planned, len(items), len(dispatches),
+            sorted({d.bucket for d in dispatches}),
+            sorted({d.edge for d in dispatches}), stats.occupancy_pct,
+            stats.padding_waste_pct, stats.edge_trim_pct,
+            sum(b.refilled for b in stats.buckets.values()),
+            stats.grouped_cells)
+        engine.fill_stats.add(time.perf_counter() - t0, ahead=planned > 0)
+        yield _Window(dispatches, routes, more)
+        t0 = time.perf_counter()
+        items, planned = [], planned + 1
+
+
+def _take_registry(engine, registry, sink) -> None:
+    """The call's executable registry becomes the engine's, and the
+    sink's: it consumes its planned accumulator-update executables
+    through the same registry (lazy-jit fallback on any miss, as
+    everywhere else)."""
+    engine.exec_registry = registry
+    if sink is None:
+        return
+
+    def _stream_exec(width, _topk):
+        return registry.get(compile_plan.stream_fold_spec(
+            sink.n_prompts, sink.n_rephrase, width, sink.guard))
+
+    sink.registry_get = _stream_exec
+
+
+class _FillAhead:
+    """The windows after a call's first, filled on a thread of their own
+    while the device works on the ones before (pure Python; it shares
+    the GIL with the dispatch and writer threads, and the device needs
+    none to run what is queued). At most one finished window waits, so
+    the fill runs no more than two windows ahead of the dispatch loop
+    and a grid of any size is never held tokenized whole. Each window's
+    fill is one ``sweep/plan_ahead`` span: an idle gap it fails to hide
+    shows under that name."""
+
+    def __init__(self, windows, stop, stats):
+        self._windows, self._stop, self._stats = windows, stop, stats
+        self._ready: "queue.Queue" = queue.Queue(maxsize=1)
+        self._parent = tracing.current_span()   # the call's span
+        self._thread = threading.Thread(target=self._run, name="sweep-fill",
+                                        daemon=True)
+
+    def start(self) -> None:
+        """Once the call's first dispatch is with the device (before
+        that the fill would only take the interpreter from it), or at
+        once where that dispatch has to wait for its program anyway."""
+        if self._thread.ident is None:
+            self._thread.start()
+
+    def _run(self) -> None:
+        tracing.adopt(self._parent)
+        try:
+            more = True
+            while more and not self._stop.is_set():
+                with tracing.span("sweep/plan_ahead"):
+                    window = next(self._windows, None)
+                if window is None:
+                    return                  # stopped between two cells
+                self._ready.put(window)
+                more = window.more
+        except BaseException as err:        # noqa: BLE001 — raised by take()
+            self._ready.put(err)
+
+    def take(self) -> _Window:
+        """The next window; a failure of its fill is raised here, on the
+        caller's thread."""
+        self.start()
+        t0 = time.perf_counter()
+        window = self._ready.get()
+        self._stats.waited(time.perf_counter() - t0)
+        if isinstance(window, BaseException):
+            raise window
+        return window
+
+    def close(self) -> None:
+        """Stop the fill (between two cells) and see its thread out."""
+        self._stop.set()
+        while self._thread.is_alive():
+            try:
+                self._ready.get(timeout=0.05)   # a window nobody will run
+            except queue.Empty:
+                pass
 
 
 def _run_pipelined(engine, model_name, todo, target_ids, results_path,
@@ -659,6 +829,18 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     change, and the manifest keys rows by cell identity so resume is
     unaffected.
 
+    The fill (tokenize, plan, route, hand shapes to the compile plan) is
+    taken in PLAN WINDOWS in grid order (:func:`_fill_windows`): only the
+    call's first window is filled before anything is dispatched, and the
+    windows after it on a fill thread while the device works on the ones
+    before (:class:`_FillAhead`, started once the first dispatch is with
+    the device, or at once in a call whose programs are still loading,
+    and never more than two windows ahead). A window closes at a
+    prompt's end where the token cap keeps each of its rows out of any
+    dispatch with a row of another prompt (scheduler.RaggedScheduler.
+    closed); a call with no cap, or with short rows, is one window: the
+    whole grid, planned at once.
+
     The queue is bounded (depth 2) so at most ~3 buckets of decode outputs
     are live on device — outputs are small (generated ids + top-20 maps),
     but unbounded dispatch-ahead would also tokenize the whole grid up
@@ -667,10 +849,15 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     bucket boundary and re-raises on the caller's thread; rows scored but
     not yet flushed when an earlier flush failed are NOT marked done, so a
     resumed sweep re-scores at most ``checkpoint_every`` cells (the same
-    write-ahead guarantee as the synchronous loop).
+    write-ahead guarantee as the synchronous loop). A failure of a later
+    window's fill is raised on the caller's thread once the windows
+    before it have been dispatched, with the same guarantee.
 
     Trace spans (observe/tracing): ``sweep/plan`` up to the first
-    enqueue; per dispatch, numbered by ``dispatch=`` in the queue item,
+    enqueue (the first window's fill: what the device waited for);
+    ``sweep/plan_ahead`` on the fill thread, one a later window (its
+    seconds also in ``engine.fill_stats``, metrics source ``fill``);
+    per dispatch, numbered by ``dispatch=`` in the queue item,
     ``sweep/dispatch`` on the main thread and, caused by it,
     ``sweep/drain`` on the writer with ``sweep/drain_wait`` around the
     read-back (the drain's self time is the writer's host work);
@@ -689,47 +876,28 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
                   and not engine.encoder_decoder)
     occupancy = None
     stop_armed = False
-    routes: list = []       # one runner.Route per ragged dispatch
+    first = ahead = None    # the call's first plan window; the rest of them
+    fill_stop = threading.Event()
     with tracing.span("sweep/plan", stage="schedule"):
         if ragged:
-            dispatches, occupancy = _plan_ragged(engine, todo, new_tokens,
-                                                 conf_tokens)
             stop_armed = early_stop and engine.digit_stop_mask is not None
             engine.fresh_handoff()  # fresh donation chain per sweep
-            # Compile plan: the schedule fixes every dispatch shape, so lower
-            # + compile ALL bucket executables in background threads while
-            # the first bucket streams — the dispatch loop then consumes
-            # precompiled executables (runner.exec_registry) instead of
-            # paying trace-on-first-call serially inside the timed loop.
+            engine.exec_registry = None
             # Where each dispatch goes is the engine's to say, once
             # (ScoringEngine.route): the compile plan compiles what the
             # routes may run, and the chain keys and watchdog prices
             # below read the same routes.
-            routes = engine.route_plan(dispatches, new_tokens, conf_tokens,
-                                       stop_armed)
-            engine.exec_registry = None
-            if engine.rt.aot_precompile:
-                specs = compile_plan.plan_specs(
-                    dispatches, routes,
-                    stream_shape=(None if sink is None else
-                                  (sink.n_prompts, sink.n_rephrase,
-                                   sink.guard)))
-                engine.exec_registry = compile_plan.precompile_async(
-                    engine, specs, max_workers=engine.rt.precompile_workers)
-                log.info("compile plan: precompiling %d executable shapes "
-                         "in the background (manifest %s)", len(specs),
-                         engine.exec_registry.manifest_key)
-            if sink is not None and engine.exec_registry is not None:
-                # The sink consumes its planned accumulator-update
-                # executables through the same registry (lazy-jit fallback
-                # on any miss, as everywhere else).
-                registry = engine.exec_registry
-
-                def _stream_exec(width, _topk, _registry=registry):
-                    return _registry.get(compile_plan.stream_fold_spec(
-                        sink.n_prompts, sink.n_rephrase, width, sink.guard))
-
-                sink.registry_get = _stream_exec
+            windows = _fill_windows(engine, todo, new_tokens, conf_tokens,
+                                    stop_armed, sink, fill_stop)
+            first = next(windows)
+            occupancy = engine.occupancy
+    if first is not None and first.more:
+        ahead = _FillAhead(windows, fill_stop, engine.fill_stats)
+        if (engine.exec_registry is not None
+                and not engine.exec_registry.loaded()):
+            # The first dispatch will wait for its program to load: fill
+            # meanwhile, so the later windows' programs load beside it.
+            ahead.start()
 
     def _drain(origin, batch, fused, res, cfused, spec_rec=None):
         seq, cause = origin   # the dispatch's number and its span
@@ -878,6 +1046,8 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     def _enqueue(*item):
         """Hand one dispatch's result handles to the writer; blocks
         while the queue is full (the writer is behind)."""
+        if ahead is not None:
+            ahead.start()       # the device has work: fill behind it
         with tracing.span("sweep/writer_wait", stage="put"):
             work_q.put(item)
 
@@ -954,8 +1124,6 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     spec_on = getattr(engine, "spec_supported", lambda: False)()
     # Which dispatches may chain is the route's to say (None: never);
     # a cascade trunk also discounts the watchdog prefill price below.
-    cascade_trunks = [r.trunk for r in routes]
-    piggy_keys = [r.chain_key for r in routes]
     pending: List[Optional[dict]] = [None]   # the parked dispatch's meta
 
     def _watched(call, cost):
@@ -1034,7 +1202,11 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
         pending[0] = None
         _emit(meta, fused, cfused)
 
-    def _dispatch_ragged():
+    def _dispatch_ragged(dispatches, routes):
+        """One plan window's dispatches; a piggyback chain ends with it
+        (the look-ahead below reads the window's own routes)."""
+        cascade_trunks = [r.trunk for r in routes]
+        piggy_keys = [r.chain_key for r in routes]
         for i, d in enumerate(dispatches):
             if failed.is_set():
                 return
@@ -1168,10 +1340,17 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
     wt.start()
     try:
         if ragged:
-            _dispatch_ragged()
+            window = first
+            while True:
+                _dispatch_ragged(window.dispatches, window.routes)
+                if not window.more or failed.is_set():
+                    break
+                window = ahead.take()
         else:
             _dispatch_legacy()
     finally:
+        if ahead is not None:
+            ahead.close()
         with tracing.span("sweep/writer_wait", stage="join"):
             work_q.put(None)
             wt.join()

@@ -117,6 +117,9 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "trunk_tokens_prefilled", "trunk_programs",
         "trunk_held_dispatches",
     ),
+    "FillStats": (
+        "fill_s", "ahead_s", "windows", "windows_ahead", "wait_s",
+    ),
     "SparseStats": (
         "blocks_kept", "blocks_offered", "queries", "dense_queries",
         "pooled_key_bytes",
@@ -290,6 +293,8 @@ def engine_registry(engine, sink=None,
         reg.register("spec", engine.spec_stats)
     if getattr(engine, "cascade_stats", None) is not None:
         reg.register("cascade", engine.cascade_stats)
+    if getattr(engine, "fill_stats", None) is not None:
+        reg.register("fill", engine.fill_stats)
     if getattr(engine, "recurrent_stats", None) is not None:
         reg.register("recurrent", engine.recurrent_stats)
     if getattr(engine, "sparse_stats", None) is not None:

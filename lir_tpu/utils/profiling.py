@@ -845,6 +845,65 @@ class CascadeStats:
 
 
 @dataclasses.dataclass
+class FillStats:
+    """The host's fill of the sweep's calls (engine/sweep._fill_windows;
+    metrics source ``fill``): a call's pending grid is tokenized, planned,
+    routed and handed to the compile plan in plan windows, and only the
+    first of them before anything is dispatched. Lasts the engine's life
+    and only grows (a reader takes the difference of two snapshots); all
+    stay where they are in a call that never goes through the ragged
+    plan.
+
+    - ``fill_s``: seconds of that work, every window.
+    - ``ahead_s``: those of them spent on the windows after a call's
+      first, on the fill thread, while the device has the windows before
+      to work on (span ``sweep/plan_ahead``). 0 in a call that is ONE
+      window: no token cap, or rows short enough to share a dispatch with
+      a stranger (scheduler.RaggedScheduler.closed).
+    - ``windows`` / ``windows_ahead``: windows planned, and those of them
+      planned behind the device.
+    - ``wait_s``: seconds the dispatch loop waited for a window that was
+      not ready. The device may still have had work queued, so this
+      bounds from above what of ``ahead_s`` it stood idle for.
+    """
+
+    fill_s: float = 0.0
+    ahead_s: float = 0.0
+    windows: int = 0
+    windows_ahead: int = 0
+    wait_s: float = 0.0
+
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+
+    def add(self, seconds: float, ahead: bool) -> None:
+        with self._lock:
+            self.fill_s += seconds
+            self.windows += 1
+            if ahead:
+                self.ahead_s += seconds
+                self.windows_ahead += 1
+
+    def waited(self, seconds: float) -> None:
+        with self._lock:
+            self.wait_s += seconds
+
+    def summary(self) -> Dict[str, object]:
+        with self._lock:
+            return {
+                "fill_s": round(self.fill_s, 4),
+                "ahead_s": round(self.ahead_s, 4),
+                "ahead_share": (round(self.ahead_s / self.fill_s, 4)
+                                if self.fill_s else 0.0),
+                "windows": self.windows,
+                "windows_ahead": self.windows_ahead,
+                "wait_s": round(self.wait_s, 4),
+            }
+
+
+@dataclasses.dataclass
 class SparseStats:
     """Counters of block-sparse attention with a selection step
     (ops/sparse_attention; metrics source ``sparse``). All stay 0 for a

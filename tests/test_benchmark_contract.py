@@ -53,11 +53,11 @@ def test_the_cell_is_found_by_its_names(files):
                                     "min_served_tokens"}
     reports = [m["name"] for m in bench["per_layer"]
                if CELL["name"] in m["workloads"]]
-    assert len(reports) == 19 and {
+    assert len(reports) == 20 and {
         "lightning_scan_roofline", "lightning_step_roofline",
         "sparse_prefill_roofline", "sparse_decode_roofline",
         "sparse_blocks_kept_pct.sweep", "trunk_prefill_share_pct.sweep",
-        "trunk_held_dispatch_pct.sweep",
+        "trunk_held_dispatch_pct.sweep", "fill_hidden_pct.sweep",
         "recurrent_state_share_pct.sweep", "state_forks_per_dispatch.sweep",
         "step_mfu_pct.sweep"} <= set(reports)
     for name in reports:

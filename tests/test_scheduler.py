@@ -233,6 +233,150 @@ def test_flash_prefill_engine_plans_on_the_kernel_block(grid, want):
         range(want[768], 768, grid)) + (768,)
 
 
+# ---------------------------------------------------------------------------
+# Plan windows (ISSUE 35): a closed window's plan is its part of the
+# whole grid's
+# ---------------------------------------------------------------------------
+
+def _doc_rows(prompt, doc_len, n_rows, sfx=(2, 2), tail=9):
+    """Rows of one prompt: a ``doc_len``-token document of its own, a
+    short tail a row, format suffixes of ``sfx`` tokens."""
+    doc = [1000 * (prompt + 1) + j for j in range(doc_len)]
+    rows = []
+    for r in range(n_rows):
+        ids = tuple(doc + [20 + r] * (tail + r % 3))
+        rows.append(sched_mod.SweepItem(
+            cell=(prompt, r), bin_ids=ids + (5,) * sfx[0],
+            conf_ids=ids + (6,) * sfx[1], lcp=len(ids)))
+    return rows
+
+
+def _planner(cap, **kw):
+    return sched_mod.RaggedScheduler(
+        tok.bucket_ladder(256), 4, new_budget=8, decode_cost=12,
+        group_cells=False, token_cap=cap, stats=OccupancyStats(), **kw)
+
+
+def _plan_in_windows(planner, prompts):
+    """What engine/sweep._fill_windows does with the scheduler: a window
+    grows a prompt at a time, is planned once closed, and the first one
+    to close with prompts left looks at one row of each of them."""
+    floors = sched_mod.EdgeFloors()
+    windows, items, closed = [], [], True
+    for i, rows in enumerate(prompts):
+        items += rows
+        closed = closed and planner.closed(rows)
+        more = i + 1 < len(prompts)
+        if more and not closed:
+            continue
+        if more and not windows:
+            planner.foresee([p[0] for p in prompts[i + 1:]], floors)
+        windows.append(planner.schedule(items, floors))
+        items = []
+    return windows
+
+
+def _shapes(dispatches):
+    return [(d.kind, d.bucket, d.edge, d.sfx_bucket_a, d.sfx_bucket_b,
+             d.refilled, d.cells) for d in dispatches]
+
+
+# Rows a prompt (the original + whole groups of 4), and the format
+# suffixes of each: doc16k-shaped traffic has groups on three of five
+# prompts and two lone originals; which prompt comes first, and which has
+# the longest format, differs by seed.
+WINDOW_GRIDS = {
+    "lone-original-first": ([1, 9, 5, 1, 5], [(2, 2)] * 5),
+    "group-first": ([9, 1, 5, 5, 1], [(2, 2)] * 5),
+    "longest-format-last": ([1, 9, 5, 1, 5],
+                            [(2, 2), (3, 2), (2, 2), (2, 2), (11, 9)]),
+    "longest-format-on-a-lone-original": (
+        [5, 9, 1, 5, 1], [(2, 2), (2, 2), (12, 2), (2, 2), (2, 2)]),
+    "two-prompts": ([5, 5], [(2, 2), (9, 2)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_GRIDS))
+def test_long_rows_under_a_cap_plan_a_prompt_at_a_time(name):
+    """160-token documents in the 256 bucket under a cap of 384: two rows
+    of different prompts never fit one pass, so every prompt is a closed
+    window, and the windows' dispatches, in order, are the whole grid's to
+    the last field: cells, edges, suffix buckets."""
+    counts, sfx = WINDOW_GRIDS[name]
+    prompts = [_doc_rows(p, 160, n, sfx[p]) for p, n in enumerate(counts)]
+    whole = _planner(384).schedule([it for rows in prompts for it in rows])
+    planner = _planner(384)
+    windows = _plan_in_windows(planner, prompts)
+    assert len(windows) == len(prompts)
+    assert _shapes([d for w in windows for d in w]) == _shapes(whole)
+    assert {d.edge for d in whole} == {192}
+    # ONE OccupancyStats a call: the windows add up to the whole grid's.
+    one = _planner(384)
+    one.schedule([it for rows in prompts for it in rows])
+    assert planner.stats.summary() == one.stats.summary()
+
+
+@pytest.mark.parametrize("case,cap,kw,lengths,want", [
+    ("no-cap", 0, {}, [170, 170], False),
+    ("long-rows", 384, {}, [170, 200], True),
+    ("one-short-row", 384, {}, [170, 100], False),   # bucket 128: 2 fit
+    ("cap-two-rows-fit", 512, {}, [170, 170], False),
+    ("cells-grouped", 384, {"group_cells": True}, [170, 170], False),
+])
+def test_a_window_is_closed_only_where_the_cap_keeps_strangers_apart(
+        case, cap, kw, lengths, want):
+    planner = sched_mod.RaggedScheduler(
+        tok.bucket_ladder(256), 4, token_cap=cap,
+        **{"group_cells": False, **kw})
+    assert planner.closed(_items(lengths)) is want
+
+
+@pytest.mark.parametrize("counts,cap", [
+    ([9, 5, 5], 0),            # no cap: trunk512-shaped
+    ([5, 9, 5], 512),          # a cap two strangers fit under
+])
+def test_a_grid_that_never_closes_is_one_window(counts, cap):
+    prompts = [_doc_rows(p, 160, n) for p, n in enumerate(counts)]
+    planner = _planner(cap)
+    windows = _plan_in_windows(planner, prompts)
+    assert len(windows) == 1
+    assert _shapes(windows[0]) == _shapes(
+        _planner(cap).schedule([it for rows in prompts for it in rows]))
+
+
+def test_short_rows_after_long_ones_end_the_windows():
+    """Closed prompts are windows of their own up to the first prompt
+    with a row a stranger could ride with; the rest is one window."""
+    prompts = [_doc_rows(0, 160, 5), _doc_rows(1, 160, 1),
+               _doc_rows(2, 60, 5), _doc_rows(3, 160, 5)]
+    windows = _plan_in_windows(_planner(384), prompts)
+    assert [sorted({c[0] for d in w for c in d.cells}) for w in windows] == [
+        [0], [1], [2, 3]]
+    cells = [c for w in windows for d in w for c in d.cells]
+    assert sorted(cells) == sorted(it.cell for rows in prompts
+                                   for it in rows)
+
+
+def test_the_edges_of_a_call_only_grow():
+    """A window plans at no less than the windows before it: a longer
+    suffix or prefix met later raises the edges from there on, and never
+    lowers what a key already ran at."""
+    planner = _planner(384)
+    floors = sched_mod.EdgeFloors()
+    first = planner.schedule(_doc_rows(0, 160, 5, sfx=(9, 2)), floors)
+    assert {(d.edge, d.sfx_bucket_a, d.sfx_bucket_b) for d in first} == {
+        (192, 16, 8)}
+    second = planner.schedule(_doc_rows(1, 160, 5, sfx=(2, 9), tail=40),
+                              floors)
+    assert {(d.edge, d.sfx_bucket_a, d.sfx_bucket_b) for d in second} == {
+        (256, 16, 16)}
+    third = planner.schedule(_doc_rows(2, 160, 5), floors)
+    assert {(d.edge, d.sfx_bucket_a, d.sfx_bucket_b) for d in third} == {
+        (256, 16, 16)}
+    # Without floors a plan owes nothing to the one before it.
+    assert {d.edge for d in planner.schedule(_doc_rows(2, 160, 5))} == {192}
+
+
 def test_slot_refill_promotes_ragged_tail_once():
     # 9 short cells at batch 4: two full dispatches + a 1-cell tail. The
     # cost model promotes the tail into the 96 bucket (1 * 96 < 1-slot
