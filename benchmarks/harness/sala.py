@@ -6,11 +6,13 @@ kernels (``CALLS``, ``window_calls``) for ``lightning_scan`` /
 the blocks a query keeps).
 
 ``spec`` is ``references/sala.SalaSpec``. Nothing here looks at what the
-engine dispatched: the sizes come from the traffic (one entry per shape of
-dispatch it needs: the originals, one row each with no shared trunk; the
-rephrasings, in groups of ``group_rows`` sharing ``head_words`` tokens),
-real rows and real tokens only, so padding and every other choice of the
-engine count against the kernel.
+engine dispatched: the sizes come from the traffic, at ONE trunk a prompt
+a call (one entry per shape of dispatch it needs: the trunk's own pass,
+one row, once for each prompt with rows in the window; the originals, one
+row each, and the rephrasings, in groups of ``group_rows``, which read
+that trunk's ``head_words`` tokens and do not compute them), real rows and
+real tokens only, so padding and every other choice of the engine count
+against the kernel.
 
 The scan: per token and head five operations a state element (decay, the
 outer product's multiply, the add, the read's multiply-add); bytes are v
@@ -53,24 +55,33 @@ def dispatch_shapes(spec, mix: dict, prompts: list, perts: list,
     share ``shared`` tokens between their two formats, of which the first
     ``trunk`` are one document for all rows; ``sfx`` the two formats' own
     tokens; ``steps`` the greedy tokens read a branch; ``dispatches`` how
-    many the window holds."""
+    many the window holds. First the trunk's own pass where the mix has a
+    trunk: one row of ``head_words`` tokens and nothing behind them, once
+    for each prompt (every prompt has its original in the window). The
+    originals and the groups after it are ``held``: they read that pass
+    and leave its calls to it."""
     def mean_lengths(pairs):
         got = np.asarray([_pair_lengths(p, m) for p, m in pairs], float)
         return [float(v) for v in got.mean(axis=0)]
 
     steps = tuple(steps or (0, 0))
+    head = mix["head_words"]
     out = []
+    if head and prompts:
+        out.append({"rows": 1, "shared": head, "trunk": 0, "sfx": (),
+                    "steps": (0, 0), "dispatches": len(prompts)})
+    held = bool(out)
     originals = [(p, p.main) for p in prompts]
     if originals:
         n, a, b = mean_lengths(originals)
-        out.append({"rows": 1, "shared": n, "trunk": 0, "sfx": (a, b),
-                    "steps": steps, "dispatches": len(originals)})
+        out.append({"rows": 1, "shared": n, "trunk": head, "held": held,
+                    "sfx": (a, b), "steps": steps,
+                    "dispatches": len(originals)})
     long_rows = [(p, m) for p, mains in zip(prompts, perts) for m in mains]
     if long_rows:
         n, a, b = mean_lengths(long_rows)
-        out.append({"rows": mix["group_rows"], "shared": n,
-                    "trunk": mix["head_words"], "sfx": (a, b),
-                    "steps": steps,
+        out.append({"rows": mix["group_rows"], "shared": n, "trunk": head,
+                    "held": held, "sfx": (a, b), "steps": steps,
                     "dispatches": len(long_rows) / mix["group_rows"]})
     return out
 
@@ -95,12 +106,12 @@ def scan_window(spec, rows: float, tokens: float) -> tuple:
             + 2.0 * rows * _state_elements(spec) * 4)
 
 
-def scan_calls(spec, rows, shared, trunk, sfx, steps) -> list:
-    """Each scan call a dispatch needs in a lightning layer: the trunk
-    once at one row, the rows' own prefix tokens, the two format
-    suffixes."""
+def scan_calls(spec, rows, shared, trunk, sfx, steps, held=False) -> list:
+    """Each scan call a dispatch needs in a lightning layer: the rows' own
+    prefix tokens, the trunk once at one row (not where it is ``held``:
+    the trunk's own pass makes that call), the two format suffixes."""
     calls = [scan_window(spec, rows, rows * (shared - trunk))]
-    if trunk:
+    if trunk and not held:
         calls.append(scan_window(spec, 1, trunk))
     return calls + [scan_window(spec, rows, rows * s) for s in sfx]
 
@@ -147,15 +158,17 @@ def _attend(spec, positions, main_len: int, distinct: float) -> tuple:
     return flops, 2.0 * width * 2 * n + kv_key * distinct + pooled
 
 
-def prefill_calls(spec, rows, shared, trunk, sfx, steps) -> list:
+def prefill_calls(spec, rows, shared, trunk, sfx, steps,
+                  held=False) -> list:
     """Each ``sparse_prefill`` call a dispatch needs in a softmax layer:
-    the trunk over itself (one row), the rows' own prefix tokens over the
-    trunk and themselves, the two format suffixes over all before them."""
+    the trunk over itself (one row; not where it is ``held``: the trunk's
+    own pass makes that call), the rows' own prefix tokens over the trunk
+    and themselves, the two format suffixes over all before them."""
     shared, trunk, rows = int(round(shared)), int(trunk), int(rows)
     main = trunk or shared
     own = np.arange(trunk, shared)
     calls = []
-    if trunk:
+    if trunk and not held:
         calls.append(_attend(spec, np.arange(trunk), trunk, trunk))
     calls.append(_attend(spec, np.tile(own, rows), main,
                          (trunk if trunk else 0) + rows * len(own)))
@@ -189,9 +202,16 @@ CALLS = {"lightning_scan_call": scan_calls,
 
 def window_calls(spec, mix: dict, prompts: list, perts: list,
                  steps=None) -> dict:
-    """Every kernel reads the same shapes of dispatch. Empty for a model
-    of another family."""
+    """The scan and the prefill attention read every shape of dispatch;
+    the two decode kernels the shapes that decode (a trunk's own pass
+    reads no token and makes them no call, so ``held`` says nothing to
+    them and is left out). Empty for a model of another family."""
     if not hasattr(spec, "l_heads"):
         return {}
     shapes = dispatch_shapes(spec, mix, prompts, perts, steps)
-    return {name: shapes for name in CALLS} if shapes else {}
+    if not shapes:
+        return {}
+    decoding = [{k: v for k, v in s.items() if k != "held"}
+                for s in shapes if any(s["steps"])]
+    return {"lightning_scan_call": shapes, "sparse_prefill_call": shapes,
+            "lightning_step_call": decoding, "sparse_decode_call": decoding}

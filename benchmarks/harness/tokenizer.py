@@ -10,12 +10,16 @@ once if the two ever part (every logit would differ).
 
 from __future__ import annotations
 
+import functools
 import hashlib
 
 PAD, YES, NO, RESERVED = 0, 1, 2, 3
 
 
+@functools.lru_cache(maxsize=1 << 16)
 def word_id(word: str, vocab: int) -> int:
+    """A word's id; kept, since a 16,000-word row repeats a few thousand
+    words and the window's rows are encoded again after it closes."""
     if word == "Yes":
         return YES
     if word == "No":

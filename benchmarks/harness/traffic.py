@@ -1,10 +1,12 @@
 """One general traffic generator. A mix is a data file under ``traffic/``;
 ``kind`` picks the window driver, every other key is a parameter here.
 
-Every seed gets the same set of sizes and arrivals in another order: the
-group order, the request lengths and the gaps between arrivals are fixed
-multisets shuffled by the seed, and only the resampled words differ. So
-no seed makes a run do more work than another.
+A sweep window is what the mix says (``window_groups``, ``trace_groups``),
+dealt to the prompts in one order for every seed: two seeds give the same
+groups a prompt in the same grid order, and only the resampled words
+differ. A serve window's request lengths and the gaps between arrivals are
+fixed multisets shuffled by the seed. So no seed makes a run do more work
+than another, and no sweep seed does it in another order.
 """
 
 from __future__ import annotations
@@ -43,6 +45,14 @@ def load_mix(name: str) -> dict:
     return mix
 
 
+def mix_names(kind: str) -> list:
+    """The mixes of one kind, by file name."""
+    docs = {path.stem: json.loads(path.read_text())
+            for path in (ROOT / "traffic").glob("*.json")}
+    return sorted(name for name, doc in docs.items()
+                  if isinstance(doc, dict) and doc.get("kind") == kind)
+
+
 def load_prompts(mix: dict) -> list:
     rows = json.loads((ROOT / "traffic" / mix["prompts"]).read_text())
     return [Prompt(r["main"], r["response_format"],
@@ -68,10 +78,11 @@ def sweep_groups(mix: dict, prompts: list, seed: int, n_groups: int,
     widest answer format among the long rows, and prompts x rephrasing
     slots for its accumulator. So the ANCHOR prompt (the one with the
     longest answer format) gets ``max_groups_per_prompt`` groups first, in
-    every call, and the rest are dealt round to the other prompts in a
-    seeded order: every call from that many groups up to all prompts full
-    then has the same shapes, and a warm pass of the anchor alone warms
-    them all. ``stream`` keeps the calls of one run on different words."""
+    every call, and the rest are dealt round to the other prompts in
+    index order, whatever the seed: every call from that many groups up to
+    all prompts full then has the same shapes, and a warm pass of the
+    anchor alone warms them all. The seed draws the words alone;
+    ``stream`` keeps the calls of one run on different words."""
     cap = mix["max_groups_per_prompt"]
     if n_groups > cap * len(prompts):
         raise ValueError(f"{n_groups} groups do not fit {len(prompts)} "
@@ -79,7 +90,7 @@ def sweep_groups(mix: dict, prompts: list, seed: int, n_groups: int,
     rng = np.random.default_rng([int(seed), int(stream)])
     anchor = max(range(len(prompts)),
                  key=lambda i: len(prompts[i].response_format.split()))
-    others = [int(i) for i in rng.permutation(len(prompts)) if i != anchor]
+    others = [i for i in range(len(prompts)) if i != anchor]
     counts = [0] * len(prompts)
     counts[anchor] = min(cap, n_groups)
     left, i = n_groups - counts[anchor], 0

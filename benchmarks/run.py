@@ -45,8 +45,12 @@ class Context:
     check_config: bool = True
     setup_s: float = 0.0
 
+    def elapsed(self) -> float:
+        """Seconds since the process started, on ``setup_s``' clock."""
+        return time.perf_counter() - PROCESS_START
+
     def setup_done(self) -> None:
-        self.setup_s = time.perf_counter() - PROCESS_START
+        self.setup_s = self.elapsed()
 
 
 def load_cell(name: str) -> tuple:

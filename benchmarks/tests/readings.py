@@ -5,8 +5,9 @@
         --seconds 6 --controls int8,fp8
 
 For each seed, in ONE process (set-up is long): build the cell as
-``run.py`` does, drive a short window through the timed entry, free the
-program, then read the comparison's numbers for the program and for each
+``run.py`` does, drive a window through the timed entry (``--seconds``
+sizes a serve window; a sweep window is the mix's ``window_groups``), free
+the program, then read the comparison's numbers for the program and for each
 control (the reference put in the program's place, one precision down).
 One JSON line per seed, and a summary line last. The benchmark's own runs
 never run this.

@@ -32,7 +32,7 @@ def main() -> None:
     files["spec"] = dataclasses.replace(
         files["spec"], vocab=2048, d=512, layers=2, heads=4, kv_heads=2,
         head_dim=128, ffn=1024)
-    files["mix"] = dict(files["mix"], trace_seconds=1)
+    files["mix"] = dict(files["mix"], trace_groups=3)
     out = bench_run.drive(cell, bench, files, 5, 1.0, True, devices[:1],
                           check_config=False)
     src = trace.Tracer(HERE / ".out" / cell["name"] / "trace").file()

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 import run as bench_run
-from harness import builders, flops, peaks, readers
+from harness import builders, flops, peaks, readers, traffic
 from references import decoder, hybrid
 
 CONFIGS = {"mistral-7b": decoder, "falcon-7b": decoder,
@@ -337,7 +337,7 @@ def test_kernel_sizes_are_the_ones_the_window_used_to_record():
     until PR 30, on real traffic: the same means, digit for digit."""
     import statistics
 
-    from harness import tokenizer, traffic
+    from harness import tokenizer
 
     spec = _spec("mistral-7b")
     mix = traffic.load_mix("sweep-trunk512")
@@ -362,3 +362,108 @@ def test_kernel_sizes_are_the_ones_the_window_used_to_record():
         "cascade_prefill_call": [{"batch": 40,
                                   "length": mean(sizes["shared"]),
                                   "trunk": 64, "dispatches": 1}]}
+
+
+# ---------------------------------------------------------------------------
+# (e) a sweep window is the mix's, reckoned at one trunk a prompt a call
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", traffic.mix_names("sweep"))
+def test_a_sweep_mix_names_its_windows(name):
+    """The driver takes the window's groups from the mix and from nothing
+    the program does; no sweep mix keeps a length in seconds."""
+    mix = traffic.load_mix(name)
+    cap = mix["max_groups_per_prompt"]
+    prompts = traffic.load_prompts(mix)
+    for key in ("window_groups", "trace_groups"):
+        assert isinstance(mix[key], int)
+        assert cap <= mix[key] <= cap * len(prompts)
+    assert "trace_seconds" not in mix
+    text = (bench_run.REPO / "benchmarks/harness/sweep_window.py"
+            ).read_text()
+    assert "ctx.seconds" not in text and "trace_seconds" not in text
+
+
+def test_needed_flops_count_one_trunk_a_prompt_by_hand():
+    """Two prompts, three groups of two rows: prompt A two groups, prompt
+    B one. Each prompt's original is counted whole; its other rows, the
+    first of each group too, behind the four head words."""
+    from harness import sweep_window
+
+    spec = _spec("mistral-7b")
+    fmt = dict(response_format="Answer Yes or No",
+               target_tokens=("Yes", "No"),
+               confidence_format="Answer from 0 to 100 now")
+    a = traffic.Prompt(main="a b c d e f g", **fmt)
+    b = traffic.Prompt(main="h i j k l m", **fmt)
+    mix = {"head_words": 4, "group_rows": 2}
+    perts = [["a b c d x y z w", "a b c d y z", "a b c d z x y",
+              "a b c d w w w"], ["h i j k q r", "h i j k r s t"]]
+    need, offered = sweep_window.needed_flops(spec, mix, [a, b], perts, 2, 3)
+
+    def cell(words, trunk):
+        # the formats share "Answer": shared = words + 1; 4 and 6 own words
+        return flops.scoring_cell_flops(spec, words + 1, words + 4,
+                                        words + 6, 2, 3, trunk)
+
+    want = (cell(7, 0) + cell(8, 4) + cell(6, 4) + cell(7, 4) + cell(7, 4)
+            + cell(6, 0) + cell(6, 4) + cell(7, 4))
+    assert need == want
+    assert offered == sum(2 * w + 10 - (w + 1) for w in
+                          (7, 8, 6, 7, 7, 6, 6, 7))
+    # at one trunk a GROUP (the count until PR 36) three more rows were whole
+    old = want + 3 * flops.tokens_flops(spec, 0, 4)
+    assert need < old
+
+
+def test_the_long_document_kernels_are_sized_at_one_trunk_pass_a_prompt():
+    from harness import sala
+    from references import sala as sala_ref
+
+    raw = json.loads((bench_run.HERE / "configs" / "minicpm-sala.json"
+                      ).read_text())
+    spec = sala_ref.spec_from_config("minicpm-sala", raw)
+    mix = traffic.load_mix("sweep-doc16k")
+    prompts = traffic.load_prompts(mix)
+    # the cell's own deal at one row a group (the sizes are means)
+    perts = [[p.main] * (40 * n) for p, n in zip(prompts, (1, 3, 1, 1, 1))]
+    calls = sala.window_calls(spec, mix, prompts, perts, (4, 8))
+    for name in ("lightning_scan_call", "sparse_prefill_call"):
+        trunk, originals, groups = calls[name]
+        assert trunk == {"rows": 1, "shared": 16000, "trunk": 0, "sfx": (),
+                         "steps": (0, 0), "dispatches": 5}
+        assert originals["rows"] == 1 and originals["dispatches"] == 5
+        assert groups["rows"] == 40 and groups["dispatches"] == 7
+        assert originals["held"] and groups["held"]
+        assert originals["trunk"] == groups["trunk"] == 16000
+    # The trunk's pass is one call over 16,000 tokens at one row; what
+    # reads it makes the window's and the suffixes' calls and no other.
+    state = 32 * 128 * 128
+    sizes = lambda s: {k: v for k, v in s.items()  # noqa: E731
+                       if k != "dispatches"}
+    trunk, originals, groups = calls["lightning_scan_call"]
+    assert sala.scan_calls(spec, **sizes(trunk)) == [
+        (5.0 * state * 16000, (4 * 4096 * 2 + 128) * 16000 + 2.0 * state * 4)]
+    assert len(sala.scan_calls(spec, **sizes(groups))) == 3
+    assert len(sala.scan_calls(spec, **sizes(originals))) == 3
+    one = sala.prefill_calls(spec, **sizes(trunk))
+    assert one == [sala._attend(spec, np.arange(16000), 16000, 16000)]
+    assert len(sala.prefill_calls(spec, **sizes(groups))) == 3
+    # 16k tokens of scan a prompt, not a group and an original: 5 passes
+    # where 12 were reckoned.
+    scanned = sum(s["dispatches"] * sum(
+        f for f, _ in sala.scan_calls(spec, **sizes(s)))
+        for s in calls["lightning_scan_call"])
+    own = 5 * (originals["shared"] - 16000) + 280 * (groups["shared"] - 16000)
+    sfx = 5 * sum(originals["sfx"]) + 280 * sum(groups["sfx"])
+    assert scanned == pytest.approx(
+        5.0 * state * (5 * 16000 + own + sfx), rel=1e-12)
+    # The decode kernels make no call in a trunk's pass.
+    for name in ("lightning_step_call", "sparse_decode_call"):
+        assert [s["rows"] for s in calls[name]] == [1, 40]
+        assert not any("held" in s for s in calls[name])
+    # A mix without a trunk has no such pass and holds nothing.
+    plain = sala.window_calls(spec, dict(mix, head_words=0), prompts, perts,
+                              (4, 8))
+    assert [s["trunk"] for s in plain["lightning_scan_call"]] == [0, 0]
+    assert not any(s["held"] for s in plain["lightning_scan_call"])

@@ -1,6 +1,7 @@
 """The yardstick's own arithmetic: peaks, FLOPs and bytes, traffic."""
 
 import dataclasses
+import shutil
 
 import numpy as np
 import pytest
@@ -101,6 +102,100 @@ def test_traffic_repeats_from_a_seed(seed):
         assert all(m.split()[:len(head)] == head and
                    len(m.split()) == mix["rephrasing_words"] for m in mains)
         assert len(set(mains)) == len(mains)
+
+
+SWEEP_MIXES = traffic.mix_names("sweep")
+
+
+def _deal(mix, dealt):
+    return [len(mains) // mix["group_rows"] for mains in dealt]
+
+
+@pytest.mark.parametrize("name", SWEEP_MIXES)
+@pytest.mark.parametrize("key", ["window_groups", "trace_groups"])
+def test_two_seeds_are_dealt_the_same_window_in_other_words(name, key):
+    """The mix fixes the window; the seed changes only the words: equal
+    groups a prompt in the same grid order, the anchor full, the groups
+    that are left dealt to the other prompts by index."""
+    mix = traffic.load_mix(name)
+    prompts = traffic.load_prompts(mix)
+    a, b = (traffic.sweep_groups(mix, prompts, seed, mix[key], stream=2)
+            for seed in (11, 2**31 + 5))
+    assert _deal(mix, a) == _deal(mix, b)
+    assert sum(_deal(mix, a)) == mix[key]
+    cap = mix["max_groups_per_prompt"]
+    anchor = _deal(mix, a).index(cap)
+    others = [n for i, n in enumerate(_deal(mix, a)) if i != anchor]
+    assert others == sorted(others, reverse=True)       # index order
+    assert max(others) - min(others) <= 1 or max(others) == cap
+    for p, rows_a, rows_b in zip(prompts, a, b):
+        head = p.main.split()[:mix["head_words"]]
+        for m in rows_a[:2] + rows_b[:2]:
+            assert m.split()[:len(head)] == head
+        assert not set(rows_a) & set(rows_b)            # other words
+
+
+def test_the_window_sizes_are_the_ones_the_cells_were_measured_at():
+    trunk = traffic.load_mix("sweep-trunk512")
+    assert (trunk["window_groups"], trunk["trace_groups"]) == (15, 6)
+    assert trunk["window_groups"] == 3 * 5              # the cap: 605 cells
+    doc = traffic.load_mix("sweep-doc16k")
+    assert (doc["window_groups"], doc["trace_groups"]) == (7, 7)
+
+
+def test_the_long_document_window_holds_no_prompt_without_a_group():
+    """A lone original was the cut's doing, not the users': the researcher
+    sweeps rephrasings of every prompt."""
+    mix = traffic.load_mix("sweep-doc16k")
+    prompts = traffic.load_prompts(mix)
+    for key in ("window_groups", "trace_groups"):
+        dealt = traffic.sweep_groups(mix, prompts, 3, mix[key], stream=2)
+        assert _deal(mix, dealt) == [1, 3, 1, 1, 1]
+        assert sum(1 + len(mains) for mains in dealt) == 285
+
+
+def test_the_warm_rate_sizes_no_window(monkeypatch):
+    """``sweep_window.run`` at a tiny size with the timed warm pass made to
+    look 0.7 and 1.4 times as fast: the same groups, cells and deal."""
+    import jax
+
+    import run as bench_run
+    import tiny
+    from harness import sweep_window
+    from lir_tpu.models import decoder
+
+    for hook in ("FUSED_DECODE_INTERPRET_ON_CPU", "CASCADE_INTERPRET_ON_CPU"):
+        monkeypatch.setattr(decoder, hook, True)
+    real = sweep_window._sweep
+    windows = []
+    for scale in (1.0, 0.7, 1.4):
+        def faster(*args, _scale=scale):
+            rows, seconds = real(*args)
+            return rows, seconds / _scale
+
+        monkeypatch.setattr(sweep_window, "_sweep", faster)
+        cell, bench, files = tiny.files_for("mistral-7b", "sweep-trunk512")
+        out = bench_run.HERE / ".out" / f"{cell['name']}.rate"
+        if out.exists():
+            shutil.rmtree(out)
+        out.mkdir(parents=True)
+        ctx = bench_run.Context(
+            spec=files["spec"], ref=files["ref"], mix=files["mix"],
+            runtime=files["runtime"], seed=9, seconds=2.0, out=out,
+            check_config=False)
+        window = sweep_window.run(ctx)["window"]
+        windows.append(window)
+        assert set(window["setup_stages"]) == {
+            "imports_and_devices", "weights", "engine", "warm_pass_1",
+            "plan_wait", "warm_pass_2", "window_dealt"}
+        assert ctx.setup_s == pytest.approx(
+            sum(window["setup_stages"].values()), abs=0.05)
+    jax.clear_caches()
+    for w in windows:
+        assert (w["groups"], w["cells"], w["deal"]) == (
+            4, 5 + 4 * 8, windows[0]["deal"])
+    assert sum(windows[0]["deal"]) == 4
+    assert windows[2]["warm_rate"] > 1.5 * windows[1]["warm_rate"]
 
 
 def test_sweep_grid_shape_is_the_same_for_every_group_count():

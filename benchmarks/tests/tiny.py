@@ -20,7 +20,8 @@ def files_for(config: str, mix: str) -> tuple:
     files = bench_run.load_files(cell, limits=dict(LIMITS))
     files["spec"] = files["ref"].tiny(files["spec"])
     files["runtime"] = dict(files["runtime"], batch_size=8)
-    files["mix"] = dict(files["mix"], group_rows=8, reference_rows=6)
+    files["mix"] = dict(files["mix"], group_rows=8, reference_rows=6,
+                        window_groups=4, trace_groups=3)
     return cell, bench, files
 
 
