@@ -57,6 +57,10 @@ class FusedDecodeOut:
     topk_logprobs: jax.Array  # (B, K) fp32 at position 0 (D6 log-prob map)
     topk_ids: jax.Array       # (B, K) int32
     weighted_confidence: jax.Array  # (B,) fp32 E[v] over digit ids at pos 0
+    # A model with experts: the dispatch's routing counts and window key
+    # slots (models/moe.RouteCounts) on its LAST branch, read back with
+    # the answers; None otherwise.
+    expert_counts: Any = None
 
 
 def is_per_row_keys(key: jax.Array) -> bool:
@@ -896,6 +900,12 @@ def _front(params, cfg: ModelConfig, program: Program, args: DispatchArgs,
         tcache = _paged_prefix(params, cfg, a.paged, ones, t)
     elif front == "cascade_held":
         tcache = a.trunk_cache
+        if cfg.num_experts:
+            # The trunk program's routing was its own run's, not this
+            # dispatch's.
+            from ..models import moe
+
+            tcache = tuple(tcache[:2]) + (moe.empty_counts(cfg),)
     else:
         raise ValueError(f"unknown front {front!r}")
     return decoder.cascade_extend(params, cfg, tcache, a.prefix[:, t:],
@@ -1083,6 +1093,8 @@ def greedy_decode_dispatch(params, cfg: ModelConfig, program: Program,
                 decode_trunk=program.trunk, **stop_kw)
             specs.append(spec)
         outs.append(out)
+    if cfg.num_experts:
+        outs[-1] = dataclasses.replace(outs[-1], expert_counts=cache[-1])
     return (tuple(outs), tuple(specs) if k else None,
             cache if program.return_cache else None)
 

@@ -30,10 +30,10 @@ from ..models import decoder, paged, quant
 from ..observe import tracing
 from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CheckpointStats, CompileStats,
-                               FaultStats, FillStats,
+                               ExpertStats, FaultStats, FillStats,
                                GuardStats, KernelStats, PrefixCacheStats,
                                RecurrentStats, SparseStats, SpecStats,
-                               cascade_decode_bytes_saved,
+                               WindowStats, cascade_decode_bytes_saved,
                                cascade_prefill_flops_saved)
 from . import (compile_plan, generate, hbm, prefix_tree,
                scheduler as scheduler_mod, score, spec as spec_mod,
@@ -326,6 +326,14 @@ class ScoringEngine:
         # blocks offered and kept, dense queries, pooled-key bytes
         # (profiling.SparseStats; metrics source "sparse").
         self.sparse_stats = SparseStats()
+        # Expert layers (models/moe.py): picks, held picks, grouped-matmul
+        # rows, read back with each dispatch's answers
+        # (profiling.ExpertStats; metrics source "moe"); the key slots the
+        # window layers' decode kernel fetched against those it needed,
+        # read back the same way (profiling.WindowStats; metrics source
+        # "window").
+        self.expert_stats = ExpertStats()
+        self.window_stats = WindowStats()
         self._spec_draft = None
         self._spec_pending: List[Any] = []
         self.spec_fault_plan = None
@@ -753,8 +761,11 @@ class ScoringEngine:
                 and not self.encoder_decoder
                 and self._prefill_fn is None
                 # no roll-back of recurrent state to the last accepted
-                # token yet (decoder.refuse_recurrent)
-                and not getattr(self.cfg, "carries_state", False))
+                # token yet, no routing counts beside the verify window's
+                # K/V, no window in its kernel (decoder.refuse_recurrent)
+                and not getattr(self.cfg, "carries_state", False)
+                and not getattr(self.cfg, "num_experts", 0)
+                and not getattr(self.cfg, "layer_windowed", ()))
 
     def set_spec_draft(self, params: Any, cfg: Any, name: str = "") -> None:
         """Arm fleet-model drafting: the small model's (params, cfg)
@@ -1671,6 +1682,7 @@ class ScoringEngine:
                 and self._prefill_fn is None
                 and self.prefix_cache is None
                 and not getattr(self.cfg, "carries_state", False)
+                and not getattr(self.cfg, "num_experts", 0)
                 and "decode_fused_shared" not in self.__dict__)
 
     def _piggyback_fits(self, bsz: int, total_len: int) -> bool:

@@ -937,8 +937,12 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
         with tracing.span("sweep/drain_wait"):
             res_h, lp_vals, lp_ids, gen_host = jax.device_get(
                 (res, fused.topk_logprobs, fused.topk_ids, fused.generated))
-            wconf, cgen_host = jax.device_get(
-                (cfused.weighted_confidence, cfused.generated))
+            wconf, cgen_host, routed = jax.device_get(
+                (cfused.weighted_confidence, cfused.generated,
+                 cfused.expert_counts))
+        if routed is not None:
+            engine.expert_stats.fold(routed.counts, engine.cfg.expert_top_k)
+            engine.window_stats.fold(routed.keys)
         if last_readback is not None:
             last_readback[:] = [time.monotonic()]
         if spec_rec is not None:

@@ -51,9 +51,13 @@ def gather_rows(cache: Any, row_idx: jax.Array) -> Any:
     """
     import jax.numpy as jnp
 
+    from .moe import RouteCounts
+
     kv = jax.tree.map(
         lambda a: jnp.take(a, row_idx, axis=KV_BATCH_AXIS), tuple(cache[:2]))
-    return kv + tuple(jnp.take(a, row_idx, axis=REC_BATCH_AXIS)
+    # A model with experts counts its routing beside K/V: no rows there.
+    return kv + tuple(a if isinstance(a, RouteCounts)
+                      else jnp.take(a, row_idx, axis=REC_BATCH_AXIS)
                       for a in cache[2:])
 
 

@@ -63,6 +63,14 @@ SSM_INTERPRET_ON_CPU = False
 # of a model whose softmax layers select blocks (models/mixed.py).
 SPARSE_INTERPRET_ON_CPU = False
 
+# Same hook for the grouped expert matmul (ops/expert_matmul) of a model
+# with experts (models/moe.py); production CPU runs lax.ragged_dot.
+MOE_INTERPRET_ON_CPU = False
+
+# The window of a global layer where windows are per layer: no key is
+# that far behind a query.
+NO_WINDOW = 1 << 30
+
 
 # ---------------------------------------------------------------------------
 # Param init (random weights for tests; real weights come from models/loader.py)
@@ -74,6 +82,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
         from . import mixed
 
         return mixed.init_params(cfg, key, dtype)
+    if cfg.num_experts:
+        raise NotImplementedError(
+            f"{cfg.name}: the served tree of a model with experts is its "
+            "reference module's (benchmarks/references/exaone_moe.weights)")
     k = iter(jax.random.split(key, 64))
     D, H, K, hd, F, L = (cfg.hidden_size, cfg.n_heads, cfg.n_kv_heads,
                          cfg.head_dim, cfg.intermediate_size, cfg.n_layers)
@@ -317,7 +329,8 @@ def _fused_decode_ok(cfg: ModelConfig, batch: int, S: int,
 
 def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
                             cfg: ModelConfig, fused_ctx,
-                            trunk_len: int = 0, layer=None) -> jax.Array:
+                            trunk_len: int = 0, layer=None,
+                            window=None) -> jax.Array:
     """Decode-step attention through the fused Pallas flash-decode kernel
     (ops/flash_decode): the (B, H, 1, T) score row, fp32 softmax, and
     probability row stay in VMEM instead of round-tripping HBM between
@@ -336,7 +349,11 @@ def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     read K/V from the first batch block ONCE per kv head for all rows'
     queries — the flat kernel's arithmetic exactly (the split ladder,
     per-split arithmetic and merge are unchanged; only the trunk tiles'
-    HBM reads dedup)."""
+    HBM reads dedup). ``window`` (a traced scalar, or None): each query
+    attends keys whose position lies within ``window`` of its own, the
+    kernel skips the key splits no row's window reaches, and the call
+    returns ``(out, keys)``, ``keys`` the key slots it fetched and needed
+    (ops/flash_decode._window_keys)."""
     from ..ops.flash_decode import flash_decode, flash_decode_trunk
 
     B, S, H, hd = q.shape
@@ -345,15 +362,19 @@ def _attention_cached_flash(q: jax.Array, k: jax.Array, v: jax.Array,
                  and jax.default_backend() != "tpu")
     slopes = (alibi_slopes(cfg.n_heads) if cfg.pos_embedding == "alibi"
               else None)
+    win = {} if window is None else {"window": window}
     if trunk_len > 0:
         out = flash_decode_trunk(q[:, 0], k, v, q_pos, key_mask,
                                  key_positions=key_positions,
                                  alibi_slopes=slopes, trunk_len=trunk_len,
-                                 interpret=interpret, layer=layer)
+                                 interpret=interpret, layer=layer, **win)
     else:
         out = flash_decode(q[:, 0], k, v, q_pos, key_mask,
                            key_positions=key_positions, alibi_slopes=slopes,
-                           interpret=interpret, layer=layer)
+                           interpret=interpret, layer=layer, **win)
+    if window is not None:
+        out, keys = out
+        return out.reshape(B, S, H * hd), keys
     return out.reshape(B, S, H * hd)
 
 
@@ -408,7 +429,8 @@ def _attention_cached_flash_mq(q: jax.Array, k: jax.Array, v: jax.Array,
 def _attention_cascade(q: jax.Array, k: jax.Array, v: jax.Array,
                        trunk_kv: Tuple[jax.Array, jax.Array],
                        suffix_mask: jax.Array, q_positions: jax.Array,
-                       cfg: ModelConfig, int8_qk: bool) -> jax.Array:
+                       cfg: ModelConfig, int8_qk: bool,
+                       window=None) -> jax.Array:
     """Cascade-aware sibling of :func:`_attention_cached` for the
     shared-trunk PREFILL window (ops/cascade_prefill): the dispatch's
     remainder queries split into a prefix leg over the single-row shared
@@ -426,10 +448,11 @@ def _attention_cascade(q: jax.Array, k: jax.Array, v: jax.Array,
     slopes = (alibi_slopes(cfg.n_heads) if cfg.pos_embedding == "alibi"
               else None)
     tk, tv = trunk_kv
+    win = {} if window is None else {"window": window}
     out = cascade_attention(q, k, v, tk, tv, suffix_mask, q_positions,
                             alibi_slopes=slopes, int8_qk=int8_qk,
                             interpret=interpret,
-                            fused_suffix=cfg.cascade_fused_suffix)
+                            fused_suffix=cfg.cascade_fused_suffix, **win)
     return out.reshape(B, R, H * hd)
 
 
@@ -582,7 +605,8 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
            cache_index: Optional[jax.Array] = None,
            key_mask: Optional[jax.Array] = None,
            attn_impl=None, fused_ctx=None, trunk_len: int = 0,
-           rec=None, rec_mask: Optional[jax.Array] = None):
+           rec=None, rec_mask: Optional[jax.Array] = None,
+           window=None, routed: bool = False):
     """One transformer block, in one of two forms.
 
     Over a cache (``extend``, ``verify_extend``, ``decode_step``):
@@ -614,11 +638,20 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
     ``trunk_len`` (static) marks the cache's leading shared-trunk slots
     for the trunk-aware fused decode kernels (cascade decode) — 0 on
     every non-shared dispatch and whenever the fused route is off.
+
+    A model with windows or experts (:func:`_layer_runs`): ``window``
+    is this layer's (traced) window for the kernels, ``bias`` already
+    the layer's own; ``routed`` (static) runs the expert layer
+    (models/moe.py) in place of the MLP. Over a cache its routing counts,
+    and the key slots the windowed decode kernel fetched, add into the
+    cache's :class:`~lir_tpu.models.moe.RouteCounts` leaf; without one
+    the block returns the routing counts after (k, v).
     """
     B, S, _ = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    keys = None
 
-    h_attn_in = _norm(x, lp["ln1"], cfg)
+    h_attn_in = x if cfg.post_norm else _norm(x, lp["ln1"], cfg)
     h_qkv = h_attn_in
     if cfg.attention_in_multiplier != 1.0:
         h_qkv = h_qkv * jnp.asarray(cfg.attention_in_multiplier, x.dtype)
@@ -636,6 +669,11 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
     q = q.reshape(B, S, H, hd)
     k = k.reshape(B, S, K, hd)
     v = v.reshape(B, S, K, hd)
+    if cfg.qk_norm:
+        from .mixed import _head_norm
+
+        q = _head_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = _head_norm(k, lp["k_norm"], cfg.norm_eps)
     if cfg.pos_embedding == "rotary":
         rd = cfg.rotary_dim
         q = _apply_rope(q, sin, cos, rd)
@@ -672,7 +710,9 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
             if _fused_decode_ok(cfg, q.shape[0], S, fused_ctx):
                 attn = _attention_cached_flash(q, ck, cv, cfg, fused_ctx,
                                                trunk_len=trunk_len,
-                                               layer=layer)
+                                               layer=layer, window=window)
+                if window is not None:
+                    attn, keys = attn
             elif _fused_decode_mq_ok(cfg, q.shape[0], S, fused_ctx):
                 attn = _attention_cached_flash_mq(q, ck, cv, cfg, fused_ctx,
                                                   trunk_len=trunk_len,
@@ -684,11 +724,14 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
         # Prefill/forward: hand back this layer's (post-rope) k/v so prefill
         # can fill the cache without re-projecting them.
         ck, cv = k, v
-        attn = attn_impl(q, k, v, key_mask)
+        attn = (attn_impl(q, k, v, key_mask) if window is None
+                else attn_impl(q, k, v, key_mask, window))
     else:
         ck, cv = k, v
         attn = _attention(q, k, v, bias, cfg, key_mask=key_mask)
     attn = _mm(attn, lp["wo"])
+    if cfg.post_norm:
+        attn = _norm(attn, lp["ln1"], cfg)
     if cfg.attn_out_bias:
         attn = attn + lp["bo"]
     if cfg.attention_out_multiplier != 1.0:
@@ -710,8 +753,35 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
         mlp_in = h_attn_in if cfg.shared_block_ln else _norm(x, lp["ln2"], cfg)
     else:
         x = x + attn
-        mlp_in = _norm(x, lp["ln2"], cfg)
+        mlp_in = x if cfg.post_norm else _norm(x, lp["ln2"], cfg)
 
+    if routed:
+        from . import moe
+
+        mlp, counts = moe.expert_layer(mlp_in, lp, cfg, mask=rec_mask)
+    else:
+        mlp = _mlp(mlp_in, lp, cfg)
+    if cfg.post_norm:
+        mlp = _norm(mlp, lp["ln2"], cfg)
+
+    out = x + attn + mlp if cfg.parallel_block else x + mlp
+    if cfg.has_mixer:
+        return out, (ck, cv, state, tail)
+    if cfg.num_experts:
+        if cache is None:
+            return out, (ck, cv) + ((counts,) if routed else ())
+        held = cache[2]
+        if routed:
+            held = dataclasses.replace(held, counts=held.counts.at[
+                layer - cfg.first_dense_layers].add(counts))
+        if keys is not None:
+            held = dataclasses.replace(held, keys=held.keys + keys)
+        return out, (ck, cv, held)
+    return out, (ck, cv)
+
+
+def _mlp(mlp_in: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
+    """The dense MLP of a block."""
     # Gated MLPs share one quantized copy of mlp_in across w_up/w_gate.
     mlp_q = (_shared_quant(mlp_in, lp["w_up"], lp["w_gate"])
              if cfg.gated_mlp else mlp_in)
@@ -730,11 +800,7 @@ def _block(x: jax.Array, lp: Params, cfg: ModelConfig, sin, cos,
         mlp = mlp + lp["b_down"]
     if cfg.mlp_multipliers[1] != 1.0:
         mlp = mlp * jnp.asarray(cfg.mlp_multipliers[1], mlp.dtype)
-
-    out = x + attn + mlp if cfg.parallel_block else x + mlp
-    if cfg.has_mixer:
-        return out, (ck, cv, state, tail)
-    return out, (ck, cv)
+    return mlp
 
 
 def _empty_rec(cfg: ModelConfig, batch: int, dtype) -> Tuple:
@@ -757,6 +823,12 @@ def refuse_recurrent(cfg, what: str) -> None:
             f"{cfg.name}: {what} cannot carry a recurrent state (a "
             "state-space mixer's SSM state + conv tail, a linear-attention "
             "layer's state) yet; it holds K/V only (ROADMAP M4)")
+    if getattr(cfg, "num_experts", 0) or getattr(cfg, "layer_windowed", ()):
+        raise NotImplementedError(
+            f"{cfg.name}: {what} holds K/V only and attends every key, and "
+            "a model with experts carries its routing counts beside K/V "
+            "(models/moe.RouteCounts), one with window layers a window a "
+            "layer (ROADMAP M1, M3)")
 
 
 def rewind(cache, snapshot):
@@ -765,8 +837,15 @@ def rewind(cache, snapshot):
     mask (the first branch's slots are masked away and overwritten), so
     the buffers carry over; recurrent state cannot be rewound, so it is
     taken from ``snapshot``, the state as it stood at the shared prefix's
-    end. A cache without recurrent state passes through."""
-    return cache if len(cache) == 2 else tuple(cache[:2]) + tuple(snapshot[2:])
+    end. Routing counts (a model with experts) carry on from ``cache``:
+    they count the whole dispatch. A cache with neither passes through."""
+    if len(cache) == 2:
+        return cache
+    from .moe import RouteCounts
+
+    if isinstance(cache[-1], RouteCounts):
+        return tuple(cache)
+    return tuple(cache[:2]) + tuple(snapshot[2:])
 
 
 def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -806,11 +885,13 @@ def _unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def _causal_bias(attn_mask: jax.Array, positions: jax.Array, cfg: ModelConfig,
                  key_positions: Optional[jax.Array] = None,
-                 key_mask: Optional[jax.Array] = None) -> jax.Array:
+                 key_mask: Optional[jax.Array] = None,
+                 window: int = 0) -> jax.Array:
     """Additive fp32 attention bias (B, H|1, S, T).
 
     ``positions`` are mask-aware indices (pads get 0). Causality compares
     positions, so left-padded batches behave exactly like unpadded prompts.
+    ``window`` > 0 keeps a query at position p to keys at (p - window, p].
     """
     if key_positions is None:
         key_positions, key_mask = positions, attn_mask
@@ -818,6 +899,8 @@ def _causal_bias(attn_mask: jax.Array, positions: jax.Array, cfg: ModelConfig,
     qp = positions[:, :, None]           # (B, S, 1)
     kp = key_positions[:, None, :]       # (B, 1, T)
     allowed = (kp <= qp) & (key_mask[:, None, :] > 0)
+    if window:
+        allowed = allowed & (kp > qp - window)
     bias = jnp.where(allowed, 0.0, neg)[:, None, :, :]  # (B, 1, S, T)
     if cfg.pos_embedding == "alibi":
         slopes = alibi_slopes(cfg.n_heads)  # (H,)
@@ -829,6 +912,117 @@ def _causal_bias(attn_mask: jax.Array, positions: jax.Array, cfg: ModelConfig,
 def mask_positions(attn_mask: jax.Array) -> jax.Array:
     """Mask-aware position ids: pads -> 0, tokens -> 0..n-1 (left-pad safe)."""
     return jnp.maximum(jnp.cumsum(attn_mask, axis=-1) - 1, 0)
+
+
+# ---------------------------------------------------------------------------
+# Layers that differ in window or in MLP (K-EXAONE): runs of layers
+# ---------------------------------------------------------------------------
+
+def _runs(cfg: ModelConfig) -> bool:
+    """Whether a model's layers differ in window or in MLP, so that the
+    layer loops scan more than one run (:func:`_layer_runs`) and take each
+    layer's window and rotation from :func:`_of_layer`."""
+    return bool(cfg.num_experts or cfg.layer_windowed)
+
+
+def _layer_runs(params: Params, cfg: ModelConfig):
+    """``(stacked params, first layer, routed)`` of each run of layers
+    one scan of a layer loop covers: every layer, or for a model with
+    experts the ``first_dense_layers`` of ``params["dense_layers"]`` and
+    then the expert layers of ``params["layers"]``. The cache keeps ONE
+    stack of every layer; ``first`` is where a run's layers lie in it."""
+    if not cfg.num_experts:
+        return ((params["layers"], 0, False),)
+    # The held experts are not scanned: each layer reads its own out of
+    # the whole stack (:func:`_of_layer`).
+    routed = {k: v for k, v in params["layers"].items() if k != "experts"}
+    return ((params["dense_layers"], 0, False),
+            (routed, cfg.first_dense_layers, True))
+
+
+def _run_xs(cfg: ModelConfig, lp: Params, first: int,
+            indexed: bool) -> tuple:
+    """What a run's scan goes over: ``(stacked params, layer, windowed)``,
+    ``layer`` each layer's index in the cache (where ``indexed`` or an
+    expert layer needs it, else None) and ``windowed`` whether each layer
+    attends a window (None where windows are not per layer)."""
+    n = jax.tree.leaves(lp)[0].shape[0]
+    layer = windowed = None
+    if indexed or cfg.num_experts:
+        layer = jnp.arange(n, dtype=jnp.int32)
+        if first:
+            layer = layer + first
+    if cfg.layer_windowed:
+        windowed = jnp.asarray(cfg.layer_windowed[first:first + n])
+    return lp, layer, windowed
+
+
+def _of_layer(params: Params, cfg: ModelConfig, xs: tuple, routed: bool,
+              sin, cos, bias):
+    """(params, sin, cos, bias, window) of one layer of a run from its
+    scanned ``xs`` (:func:`_run_xs`); the run's own where its layers are
+    all alike.
+
+    An expert layer's params carry the stack of every expert layer's held
+    experts, which the grouped matmul reads in place (a scanned slice of
+    it would be copied out for the kernel, a layer's 0.6 GB a pass), and
+    this layer's index in that stack (models/moe.py). A window layer takes
+    the window's bias (``bias`` is then the (global, window) pair
+    :func:`_biases` made) and ``sliding_window``; a global one takes
+    :data:`NO_WINDOW` and the identity rotation (sin 0, cos 1: exact)."""
+    lp, layer, windowed = xs
+    if routed:
+        lp = {**lp, "experts": params["layers"]["experts"],
+              "expert_layer": layer - cfg.first_dense_layers}
+    if windowed is None:
+        return lp, sin, cos, bias, None
+    if sin is not None:
+        sin = jnp.where(windowed, sin, jnp.zeros_like(sin))
+        cos = jnp.where(windowed, cos, jnp.ones_like(cos))
+    if isinstance(bias, tuple):
+        bias = jnp.where(windowed, bias[1], bias[0])
+    window = jnp.where(windowed, jnp.int32(cfg.sliding_window),
+                       jnp.int32(NO_WINDOW))
+    return lp, sin, cos, bias, window
+
+
+def _join_runs(cfg: ModelConfig, outs: list, counts=None) -> tuple:
+    """The per-layer outputs of a loop's runs (each ``(k, v, ...)``
+    stacked over its layers) as one stack: K/V joined on the layer axis
+    and, for a model with experts, the routing counts leaf, ``counts``
+    (the leaf that came in; None, zero) plus the expert run's. A loop of
+    one run over layers alike hands its output back as it is."""
+    if not _runs(cfg):
+        return outs[0]
+    kv = tuple(jax.tree.map(lambda *a: jnp.concatenate(a, axis=0),
+                            *[o[i] for o in outs]) for i in (0, 1))
+    if not cfg.num_experts:
+        return kv
+    from . import moe
+
+    if counts is None:
+        counts = moe.empty_counts(cfg)
+    routed = sum(o[2] for o in outs if len(o) > 2)
+    return kv + (dataclasses.replace(counts,
+                                     counts=counts.counts + routed),)
+
+
+def _biases(cfg: ModelConfig, attn_mask, positions, key_positions=None,
+            key_mask=None):
+    """:func:`_causal_bias`, and for a model with window layers the pair
+    (global bias, window bias) each layer picks from (:func:`_of_layer`)."""
+    bias = _causal_bias(attn_mask, positions, cfg, key_positions, key_mask)
+    if not cfg.layer_windowed:
+        return bias
+    return bias, _causal_bias(attn_mask, positions, cfg, key_positions,
+                              key_mask, window=cfg.sliding_window)
+
+
+def _no_seq_parallel(cfg: ModelConfig, attn_impl) -> None:
+    if attn_impl is not None and _runs(cfg):
+        raise NotImplementedError(
+            f"{cfg.name}: sequence-parallel attention over layers with "
+            "windows or experts")
 
 
 # ---------------------------------------------------------------------------
@@ -849,27 +1043,37 @@ def _scan_blocks(params: Params, cfg: ModelConfig, x, sin, cos, bias,
     instead, every layer would be sliced out and stacked into a second
     buffer, and a caller whose own loop carries the cache (the decode
     loop, engine/generate._stepped) would copy the whole of it once more
-    per step. Returns (x, cache), the cache None where none came."""
-    if cache is None:
-        def plain(h, lp):
-            h, _ = _block(h, lp, cfg, sin, cos, bias, key_mask=key_mask,
-                          attn_impl=attn_impl, rec_mask=key_mask)
-            return h, None
+    per step. Returns (x, cache), the cache None where none came.
 
-        x, _ = lax.scan(plain, x, params["layers"])
-        return x, None
+    A model whose layers differ in window or in MLP scans one run of
+    :func:`_layer_runs` after the other, the cache riding the carry
+    through all of them, each layer's window, rotation, bias and experts
+    from :func:`_of_layer`."""
+    _no_seq_parallel(cfg, attn_impl)
+    for lp, first, routed in _layer_runs(params, cfg):
+        if cache is None:
+            def plain(h, xs, routed=routed):
+                lpl, s, c, b, w = _of_layer(params, cfg, xs, routed, sin,
+                                            cos, bias)
+                h, _ = _block(h, lpl, cfg, s, c, b, key_mask=key_mask,
+                              attn_impl=attn_impl, rec_mask=key_mask,
+                              window=w, routed=routed)
+                return h, None
 
-    def body(carry, xs):
-        h, stacked = carry
-        lp, layer = xs
-        return _block(h, lp, cfg, sin, cos, bias, cache=stacked, layer=layer,
-                      cache_index=cache_index, fused_ctx=fused_ctx,
-                      trunk_len=trunk_len, rec_mask=rec_mask), None
+            x, _ = lax.scan(plain, x, _run_xs(cfg, lp, first, False))
+            continue
 
-    n_layers = jax.tree.leaves(cache)[0].shape[0]
-    (x, cache), _ = lax.scan(
-        body, (x, tuple(cache)),
-        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+        def body(carry, xs, routed=routed):
+            h, stacked = carry
+            lpl, s, c, b, w = _of_layer(params, cfg, xs, routed, sin, cos,
+                                        bias)
+            return _block(h, lpl, cfg, s, c, b, cache=stacked, layer=xs[1],
+                          cache_index=cache_index, fused_ctx=fused_ctx,
+                          trunk_len=trunk_len, rec_mask=rec_mask, window=w,
+                          routed=routed), None
+
+        (x, cache), _ = lax.scan(body, (x, tuple(cache)),
+                                 _run_xs(cfg, lp, first, True))
     return x, cache
 
 
@@ -903,7 +1107,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     sin = cos = None
     if cfg.pos_embedding == "rotary":
         sin, cos = _rope_sincos(positions, cfg.rotary_dim, cfg.rope_theta)
-    bias = None if attn_impl is not None else _causal_bias(attn_mask, positions, cfg)
+    bias = None if attn_impl is not None else _biases(cfg, attn_mask, positions)
     x, _ = _scan_blocks(params, cfg, x, sin, cos, bias, key_mask=attn_mask,
                         attn_impl=attn_impl)
     return _unembed(params, cfg, x)
@@ -937,6 +1141,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
                     jnp.zeros(shape[:-1], jnp.float32))
         return (side(), side())
     kv = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    if cfg.num_experts:
+        # Beside K/V, the routing counts of the dispatch (models/moe.py).
+        from . import moe
+
+        return kv + (moe.empty_counts(cfg),)
     if cfg.has_mixer:
         # Beside K/V, per layer and row: the SSM state (float32) and the
         # conv's last inputs. Batch is axis 1 here (models/cache.
@@ -982,7 +1191,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     sin = cos = None
     if cfg.pos_embedding == "rotary":
         sin, cos = _rope_sincos(positions, cfg.rotary_dim, cfg.rope_theta)
-    bias = None if attn_impl is not None else _causal_bias(attn_mask, positions, cfg)
+    bias = None if attn_impl is not None else _biases(cfg, attn_mask, positions)
 
     # Scan layers, capturing each block's (post-rope) k/v — returned by
     # _block itself, no re-projection — into a (L, ...) stack. Each layer's
@@ -994,23 +1203,30 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # exactly what used to OOM a 7B at batch 32 / seq 1024 on one chip.
     pad = max_len - S
     pad_spec = ((0, 0), (0, pad), (0, 0), (0, 0))
+    _no_seq_parallel(cfg, attn_impl)
+    outs = []
+    for lp, first, routed in _layer_runs(params, cfg):
+        def body(h, xs, routed=routed):
+            lpl, s, c, b, w = _of_layer(params, cfg, xs, routed, sin, cos,
+                                        bias)
+            h_out, new = _block(h, lpl, cfg, s, c, b,
+                                key_mask=attn_mask, attn_impl=attn_impl,
+                                rec_mask=attn_mask, window=w, routed=routed)
+            k = new[0].transpose(2, 1, 0, 3)  # (B, S, K, hd) -> (K, S, B, hd)
+            v = new[1].transpose(2, 1, 0, 3)
+            if cfg.kv_cache_int8:
+                def side(x):
+                    xq, xs = _quant_kv(x)
+                    return (jnp.pad(xq, pad_spec), jnp.pad(xs, pad_spec[:-1]))
+                return h_out, (side(k), side(v))
+            # A mixer's state and conv tail (new[2:]) stack beside K/V as
+            # they stand after each row's last real token; an expert
+            # layer's routing counts likewise.
+            return h_out, (jnp.pad(k, pad_spec), jnp.pad(v, pad_spec)) + new[2:]
 
-    def body(h, lp):
-        h_out, new = _block(h, lp, cfg, sin, cos, bias,
-                            key_mask=attn_mask, attn_impl=attn_impl,
-                            rec_mask=attn_mask)
-        k = new[0].transpose(2, 1, 0, 3)  # (B, S, K, hd) -> (K, S, B, hd)
-        v = new[1].transpose(2, 1, 0, 3)
-        if cfg.kv_cache_int8:
-            def side(x):
-                xq, xs = _quant_kv(x)
-                return (jnp.pad(xq, pad_spec), jnp.pad(xs, pad_spec[:-1]))
-            return h_out, (side(k), side(v))
-        # A mixer's state and conv tail (new[2:]) stack beside K/V as
-        # they stand after each row's last real token.
-        return h_out, (jnp.pad(k, pad_spec), jnp.pad(v, pad_spec)) + new[2:]
-
-    x, cache = lax.scan(body, x, params["layers"])
+        x, out = lax.scan(body, x, _run_xs(cfg, lp, first, False))
+        outs.append(out)
+    cache = _join_runs(cfg, outs)
     logits = _unembed(params, cfg, x[:, -1:, :])[:, 0, :]
     next_positions = positions[:, -1] + 1
     return logits, cache, next_positions
@@ -1045,8 +1261,7 @@ def extend(params: Params, cfg: ModelConfig, cache, suffix_tokens: jax.Array,
     sin = cos = None
     if cfg.pos_embedding == "rotary":
         sin, cos = _rope_sincos(qpos, cfg.rotary_dim, cfg.rope_theta)
-    bias = _causal_bias(suffix_mask, qpos, cfg,
-                        key_positions=key_positions, key_mask=cache_mask)
+    bias = _biases(cfg, suffix_mask, qpos, key_positions, cache_mask)
     x, new_cache = _scan_blocks(params, cfg, x, sin, cos, bias,
                                 cache=cache, cache_index=start_index,
                                 rec_mask=suffix_mask)
@@ -1095,26 +1310,40 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
     if cfg.pos_embedding == "rotary":
         sin, cos = _rope_sincos(qpos, cfg.rotary_dim, cfg.rope_theta)
     tck, tcv = trunk_cache[:2]                            # (L, K, Tt, 1, hd)
+    # A model with experts: the trunk's routing counts are the leaf's
+    # start; its per-layer leaves are K/V alone.
+    counts = trunk_cache[-1] if cfg.num_experts else None
+    trunk = tuple(trunk_cache[:2]) if cfg.num_experts else tuple(trunk_cache)
+    runs = _layer_runs(params, cfg)
+    outs = []
+    for lp, first, routed in runs:
+        def body(h, xs, routed=routed):
+            run_xs, layer = xs
+            lpl, s, c, _, w = _of_layer(params, cfg, run_xs, routed, sin,
+                                        cos, None)
+            tk, tv = layer[:2]
 
-    def body(h, xs):
-        lp, layer = xs
-        tk, tv = layer[:2]
+            def impl(q, k, v, key_mask, window=None):
+                return _attention_cascade(q, k, v,
+                                          (tk[:, :, 0, :], tv[:, :, 0, :]),
+                                          rem_mask, qpos, cfg, int8_qk,
+                                          window=window)
 
-        def impl(q, k, v, key_mask):
-            return _attention_cascade(q, k, v,
-                                      (tk[:, :, 0, :], tv[:, :, 0, :]),
-                                      rem_mask, qpos, cfg, int8_qk)
+            # The trunk's recurrent state, computed once at batch 1, is every
+            # row's state on entry (the remainders continue from it).
+            rec = tuple(jnp.broadcast_to(a, (B,) + a.shape[1:])
+                        for a in layer[2:]) or None
+            h, new = _block(h, lpl, cfg, s, c, None,
+                            key_mask=rem_mask, attn_impl=impl, rec=rec,
+                            rec_mask=rem_mask, window=w, routed=routed)
+            return h, new
 
-        # The trunk's recurrent state, computed once at batch 1, is every
-        # row's state on entry (the remainders continue from it).
-        rec = tuple(jnp.broadcast_to(a, (B,) + a.shape[1:])
-                    for a in layer[2:]) or None
-        h, new = _block(h, lp, cfg, sin, cos, None,
-                        key_mask=rem_mask, attn_impl=impl, rec=rec,
-                        rec_mask=rem_mask)
-        return h, new
-
-    _, new = lax.scan(body, x, (params["layers"], tuple(trunk_cache)))
+        n = jax.tree.leaves(lp)[0].shape[0]
+        part = trunk if len(runs) == 1 else tuple(
+            a[first:first + n] for a in trunk)
+        x, out = lax.scan(body, x, (_run_xs(cfg, lp, first, False), part))
+        outs.append(out)
+    new = _join_runs(cfg, outs, counts)
     rk, rv = new[:2]
 
     # Assemble the B-row cache in the (L, K, T, B, hd) layout: the trunk
@@ -1177,8 +1406,7 @@ def verify_extend(params: Params, cfg: ModelConfig, cache,
     if cfg.pos_embedding == "rotary":
         sin, cos = _rope_sincos(qpos, cfg.rotary_dim, cfg.rope_theta)
     ones = jnp.ones((B, S2), jnp.int32)
-    bias = _causal_bias(ones, qpos, cfg,
-                        key_positions=key_positions, key_mask=cache_mask)
+    bias = _biases(cfg, ones, qpos, key_positions, cache_mask)
     x, new_cache = _scan_blocks(params, cfg, x, sin, cos, bias,
                                 cache=cache, cache_index=start_index,
                                 fused_ctx=(qpos, cache_mask,
@@ -1215,8 +1443,8 @@ def decode_step(params: Params, cfg: ModelConfig, cache, token: jax.Array,
         sin, cos = _rope_sincos(position[:, None], cfg.rotary_dim, cfg.rope_theta)
 
     key_positions = mask_positions(prompt_mask)
-    bias = _causal_bias(jnp.ones((B, 1), jnp.int32), position[:, None], cfg,
-                        key_positions=key_positions, key_mask=prompt_mask)
+    bias = _biases(cfg, jnp.ones((B, 1), jnp.int32), position[:, None],
+                   key_positions, prompt_mask)
     # The fused flash-decode route consumes the mask/positions directly
     # (the kernel owns causality + ALiBi); the bias tensor feeds only the
     # dense/int8 fallback and is dead code XLA drops when the kernel

@@ -175,6 +175,32 @@ class ModelConfig:
     sparse_window: int = 2048
     sparse_dense_len: int = 8192
 
+    # Layers that attend a window (K-EXAONE): ``layer_windowed`` marks, per
+    # layer, the layers whose query at position p attends keys at
+    # positions (p - sliding_window, p]; the others attend every earlier
+    # key and rotate nothing (the family's global NoPE). Empty: no window
+    # anywhere, every model before this one bit for bit.
+    layer_windowed: tuple = ()
+    sliding_window: int = 0
+    # Norms on each branch's OUTPUT (``x + norm(attn)``, ``x + norm(mlp)``)
+    # and none on its input: EXAONE 4's QK-Reorder-Norm.
+    post_norm: bool = False
+    # Routed experts (models/moe.py): after ``first_dense_layers`` layers
+    # with the dense MLP, every layer routes each token to ``expert_top_k``
+    # of ``num_experts`` gated SiLU experts of width ``expert_size`` by a
+    # sigmoid router with a selection bias, the chosen scores normalized
+    # and scaled by ``router_scale``, beside ``shared_experts`` shared
+    # ones. The program holds the ``experts_held`` (first, count) slice of
+    # the experts and computes only their terms. 0 experts: no expert
+    # layer, every model before this one bit for bit.
+    num_experts: int = 0
+    experts_held: tuple = (0, 0)
+    expert_top_k: int = 0
+    expert_size: int = 0
+    shared_experts: int = 0
+    router_scale: float = 1.0
+    first_dense_layers: int = 0
+
     def __post_init__(self) -> None:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.n_heads)
@@ -205,6 +231,25 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: kv_cache_int8 has no recurrent-state side; "
                 "a model with a state-space mixer keeps a float cache")
+        object.__setattr__(self, "layer_windowed",
+                           tuple(bool(w) for w in self.layer_windowed))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        if self.layer_windowed:
+            assert len(self.layer_windowed) == self.n_layers, self.name
+            assert self.sliding_window > 0, self.name
+        if self.num_experts or self.layer_windowed or self.post_norm:
+            if (self.layer_kinds or self.has_mixer or self.parallel_block
+                    or self.kv_cache_int8
+                    or self.pos_embedding != "rotary"):
+                raise ValueError(
+                    f"{self.name}: windows, experts and branch-output norms "
+                    "run in a sequential rotary block with a float cache")
+        if self.num_experts:
+            first, count = self.experts_held
+            assert 0 <= first and 0 < count and first + count <= \
+                self.num_experts, self.name
+            assert 0 < self.expert_top_k <= self.num_experts, self.name
+            assert 0 <= self.first_dense_layers < self.n_layers, self.name
 
     @property
     def rotary_dim(self) -> int:
@@ -219,6 +264,12 @@ class ModelConfig:
         """A recurrent state rides the cache beside (or instead of) K/V:
         what no mask can rewind (decoder.rewind, decoder.refuse_recurrent)."""
         return self.has_mixer or "lightning" in self.layer_kinds
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers that route to experts (0 without experts)."""
+        return (self.n_layers - self.first_dense_layers
+                if self.num_experts else 0)
 
     def kind_layers(self, kind: str) -> int:
         return sum(k == kind for k in self.layer_kinds)
@@ -423,6 +474,39 @@ def minicpm_sala() -> ModelConfig:
         sparse_init_blocks=1, sparse_window=2048, sparse_dense_len=8192)
 
 
+def k_exaone_236b(n_layers: int = 48, vocab_size: int = 153600,
+                  experts_held: tuple = (0, 128)) -> ModelConfig:
+    """LGAI-EXAONE/K-EXAONE-236B-A23B (config.json, ``model_type``
+    exaone_moe): d 6144, GQA 64 query / 8 key-value heads of 128 with a
+    per-head RMSNorm on q and k; layers in the pattern ``LLLG``, the
+    ``L`` layers attending a 128-token window and rotated (RoPE theta
+    1e6), the ``G`` layers attending every key unrotated; a norm on each
+    branch's output and none on its input; layer 0 a gated SiLU MLP of
+    18432, every later layer a sigmoid router over 128 experts of 2048
+    (top 8 by score + selection bias, the chosen scores normalized and
+    scaled by 2.5) beside one shared expert of 2048; untied head.
+    ``n_layers``, ``vocab_size`` and ``experts_held`` are what a cut may
+    change (benchmarks/configs/k-exaone-236b.json)."""
+    return ModelConfig(
+        name="k-exaone-236b", vocab_size=vocab_size, hidden_size=6144,
+        n_layers=n_layers, n_heads=64, n_kv_heads=8, head_dim=128,
+        intermediate_size=18432, max_seq_len=262144, rope_theta=1e6,
+        norm_eps=1e-5, use_flash_attention=False, qk_norm=True,
+        layer_windowed=tuple(i % 4 != 3 for i in range(n_layers)),
+        sliding_window=128, post_norm=True,
+        num_experts=128, experts_held=experts_held, expert_top_k=8,
+        expert_size=2048, shared_experts=1, router_scale=2.5,
+        first_dense_layers=1)
+
+
+def k_exaone_236b_l12() -> ModelConfig:
+    """One chip of the 32-chip deployment PERF.md §4 sets out: the first
+    pipeline stage's 12 layers (three whole ``LLLG`` periods: the dense
+    layer and 11 expert layers), experts 0-15 of every expert layer, and
+    a 19,200-row slice of the embedding and the head."""
+    return k_exaone_236b(n_layers=12, vocab_size=19200, experts_held=(0, 16))
+
+
 def bloom_7b1() -> ModelConfig:
     return ModelConfig(
         name="bloom-7b1", vocab_size=250880, hidden_size=4096, n_layers=30,
@@ -503,6 +587,17 @@ def tiny(family: str) -> ModelConfig:
             sparse_stride=2, sparse_topk=2, sparse_init_blocks=1,
             sparse_window=32, sparse_dense_len=32,
             **{**base, "n_layers": 4, "max_seq_len": 256})
+    if family == "exaone-moe":
+        # Two LLLG periods; a window shorter than the tests' trunks; 16
+        # experts of which the program holds 4.
+        return ModelConfig(
+            name="tiny-exaone-moe", n_kv_heads=2, head_dim=16,
+            qk_norm=True, post_norm=True, rope_theta=1e6,
+            layer_windowed=tuple(i % 4 != 3 for i in range(8)),
+            sliding_window=8, num_experts=16,
+            experts_held=(4, 4), expert_top_k=4, expert_size=32,
+            shared_experts=1, router_scale=2.5, first_dense_layers=1,
+            **{**base, "n_layers": 8})
     if family == "bloom":
         return ModelConfig(name="tiny-bloom", pos_embedding="alibi", norm="layernorm",
                            activation="gelu_new", gated_mlp=False, embedding_norm=True,
@@ -521,6 +616,7 @@ REGISTRY = {
     "mistral-7b": mistral_7b, "qwen-7b": qwen_7b, "baichuan2-7b": baichuan2_7b,
     "falcon-7b": falcon_7b, "falcon-h1-34b": falcon_h1_34b,
     "falcon-h1-34b-l8": falcon_h1_34b_l8, "minicpm-sala": minicpm_sala,
+    "k-exaone-236b": k_exaone_236b, "k-exaone-236b-l12": k_exaone_236b_l12,
     "bloom-7b1": bloom_7b1, "opt": opt,
     "t5-v1_1": t5_v1_1, "flan-t5": flan_t5, "t0-3b": t0_3b,
 }

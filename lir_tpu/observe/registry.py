@@ -129,6 +129,11 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "dispatches", "state_bytes", "kv_bytes", "forks", "scan_calls",
         "step_calls", "trunk_states_shared",
     ),
+    "ExpertStats": (
+        "picks", "picks_held", "rows_computed", "rows_padding",
+        "max_expert_picks", "dispatches",
+    ),
+    "WindowStats": ("key_slots_read", "key_slots_needed", "calls"),
     "MemStats": (
         "ledger_bytes", "budget_bytes", "pressure", "rung",
         "rung_downs", "rung_ups", "admits", "denials", "oom_events",
@@ -302,6 +307,10 @@ def engine_registry(engine, sink=None,
         reg.register("recurrent", engine.recurrent_stats)
     if getattr(engine, "sparse_stats", None) is not None:
         reg.register("sparse", engine.sparse_stats)
+    if getattr(engine, "expert_stats", None) is not None:
+        reg.register("moe", engine.expert_stats)
+    if getattr(engine, "window_stats", None) is not None:
+        reg.register("window", engine.window_stats)
     if getattr(engine, "governor", None) is not None:
         # HBM-governor gauges (engine/hbm.py): ledger/pressure/rung
         # land in the snapshot next to device_memory_stats(), so budget

@@ -228,9 +228,8 @@ def pick_window_rows(window: int, n_groups: int,
     return max(fit) if fit else min(ok)
 
 
-def _fused_cascade_kernel(slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref,
-                          sk_ref, sv_ref, tk_ref, tv_ref, o_ref, *,
-                          sm_scale: float, alibi: bool):
+def _fused_cascade_kernel(*refs, sm_scale: float, alibi: bool,
+                          windowed: bool = False):
     """One (kv head, batch row, query block) program of the FULLY-FUSED
     cascade: prefix leg + suffix leg + log-sum-exp merge in a single
     kernel, so the partial (o, m, l) triples never round-trip through
@@ -239,7 +238,17 @@ def _fused_cascade_kernel(slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref,
     block is :func:`_suffix_partials`' (per (row, kv head) slice), and
     the merge is :func:`~lir_tpu.ops.lse.merge_partials`' stacked-sum
     order — so the fused output is BITWISE the two-leg path's (pinned
-    across the cascade matrix by tests/test_cascade.py)."""
+    across the cascade matrix by tests/test_cascade.py).
+
+    ``windowed``: a layer that attends a window, whose width rides the
+    first operand: a key counts, in either leg, only where its position
+    lies within it of the query's, so the trunk leg may hold no key for
+    a query (its partial then carries m = -inf and weight 0 in the
+    merge, as a masked split does in the decode kernel)."""
+    if windowed:
+        win_ref, refs = refs[0], refs[1:]
+    (slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref, sk_ref, sv_ref,
+     tk_ref, tv_ref, o_ref) = refs
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # (rG, hd)
     # Per-flattened-row slopes and query positions arrive HOST-built as
     # (rG, 1) columns (like _prefix_partials' flattened slope array):
@@ -250,11 +259,18 @@ def _fused_cascade_kernel(slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref,
     # Prefix leg (== _prefix_kernel, non-int8): no mask, no causality.
     s = jnp.dot(q, tk_ref[0].astype(jnp.float32).T,
                 preferred_element_type=jnp.float32)       # (rG, Tt)
-    if alibi:
+    if alibi or windowed:
         kp_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if alibi:
         s = s + slope * kp_t.astype(jnp.float32)
+    if windowed:
+        lo = qpos_ref[0] - win_ref[...]                   # (rG, 1)
+        valid_t = kp_t > lo
+        s = jnp.where(valid_t, s, -jnp.inf)
     m_t = s.max(axis=-1, keepdims=True)                   # (rG, 1)
     p = jnp.exp(s - m_t)
+    if windowed:
+        p = jnp.where(valid_t, p, 0.0)                    # no trunk key
     o_t = jnp.dot(p, tv_ref[0].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
     l_t = p.sum(axis=-1, keepdims=True)
@@ -267,6 +283,8 @@ def _fused_cascade_kernel(slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref,
     if alibi:
         s2 = s2 + slope * kp.astype(jnp.float32)
     valid = (smask_ref[0] > 0) & (kp <= qpos_ref[0])      # (rG, R)
+    if windowed:
+        valid = valid & (kp > lo)
     s2 = jnp.where(valid, s2, -jnp.inf)
     m_s = s2.max(axis=-1, keepdims=True)
     p2 = jnp.exp(s2 - m_s)
@@ -286,7 +304,7 @@ def _fused_cascade_kernel(slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref,
 
 
 def _cascade_fused(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
-                   q_positions, slopes, interpret: bool):
+                   q_positions, slopes, interpret: bool, window=None):
     """Single-launch cascade attention: grid (K, B, query blocks), each
     program owns a block of one row's R*G flattened queries against the
     whole trunk plus the row's own suffix window, merged in VMEM — one
@@ -313,11 +331,15 @@ def _cascade_fused(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
                       axis=1)[..., None]                  # (B, RG, 1)
     smask = jnp.asarray(suffix_mask, jnp.int32)[:, None, :]
     kernel = functools.partial(_fused_cascade_kernel, sm_scale=sm_scale,
-                               alibi=alibi)
+                               alibi=alibi, windowed=window is not None)
+    lead, win_spec = (), []
+    if window is not None:
+        lead = (jnp.asarray(window, jnp.int32).reshape(1, 1),)
+        win_spec = [pl.BlockSpec((1, 1), lambda h, b, i: (0, 0))]
     out = pl.pallas_call(
         kernel,
         grid=(K, B, RG // rG),
-        in_specs=[
+        in_specs=win_spec + [
             pl.BlockSpec((1, rG, 1), lambda h, b, i: (h, i, 0)),
             pl.BlockSpec((1, rG, 1), lambda h, b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, R), lambda h, b, i: (b, 0, 0)),
@@ -333,7 +355,7 @@ def _cascade_fused(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
         out_shape=jax.ShapeDtypeStruct((B, K, RG, hd), jnp.float32),
         interpret=interpret,
         name="cascade_attention",
-    )(sl, qpos, kpos, smask, qf, skt, svt, trunk_k, trunk_v)
+    )(*lead, sl, qpos, kpos, smask, qf, skt, svt, trunk_k, trunk_v)
     out = out.reshape(B, K, R, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, R, H, hd).astype(q.dtype)
 
@@ -345,7 +367,8 @@ def cascade_attention(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
                       q_positions, alibi_slopes=None, int8_qk: bool = False,
                       block_n: int = DEFAULT_BLOCK_N,
                       interpret: bool = False,
-                      fused_suffix: bool = True) -> jnp.ndarray:
+                      fused_suffix: bool = True,
+                      window=None) -> jnp.ndarray:
     """Shared-trunk cascade attention for one layer's remainder window.
 
     ``q``: (B, R, H, hd) post-RoPE queries at the dispatch's remainder
@@ -363,11 +386,19 @@ def cascade_attention(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
     variant keeps the two-leg split (its prefix leg quantizes in-kernel
     over flattened query blocks; --no-cascade-fused-suffix restores the
     two-leg path for float too).
+
+    ``window`` (a traced scalar, or None): a query attends only keys whose
+    positions lie within ``window`` of its own, in both legs; the fused
+    launch alone takes one.
     """
     if fused_suffix and not int8_qk:
         return _cascade_fused(q, sfx_k, sfx_v, trunk_k, trunk_v,
                               suffix_mask, q_positions, alibi_slopes,
-                              interpret)
+                              interpret, window)
+    if window is not None:
+        raise NotImplementedError(
+            "a window runs in the fused cascade launch only (float QK^T, "
+            "cascade_fused_suffix)")
     B, R, H, hd = q.shape
     o_t, m_t, l_t = _prefix_partials(q, trunk_k, trunk_v, alibi_slopes,
                                      int8_qk, block_n, interpret)

@@ -145,14 +145,19 @@ def decode_extent(need: int, batch: int, n_groups: int,
     return first
 
 
-def _decode_kernel(layer_ref, rowb_ref, laneb_ref, qpos_ref, kpos_ref,
-                   slope_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-                   sm_scale: float, alibi: bool):
+def _decode_kernel(*refs, sm_scale: float, alibi: bool, n_blocks: int = 0):
     """One (kv head, key split, batch block, window query) program.
 
-    ``layer_ref`` is the prefetched layer index: the index maps spend it
-    (they pick the layer's K/V blocks out of the stacked cache), the body
-    does not read it.
+    The first operand is the prefetched layer index: the index maps spend
+    it (they pick the layer's K/V blocks out of the stacked cache), the
+    body does not read it. ``n_blocks`` > 0 (the batch blocks of the
+    call): a layer that attends a window, whose width, live-split table
+    and fetch table ride the prefetch after the layer
+    (:func:`_window_tables`): a key counts only where its position also
+    lies within the window of the query's, and a program whose split no
+    query of its batch block reaches computes nothing and hands the
+    merge an empty partial (its K/V index map names the block the
+    program before it fetched, so no DMA is issued for it either).
 
     The cache block is (split, bb, hd): ``bb`` batch rows ride the
     sublanes under every key slot, which is the cache's own (B, hd)
@@ -166,35 +171,101 @@ def _decode_kernel(layer_ref, rowb_ref, laneb_ref, qpos_ref, kpos_ref,
     decode step has to spare, and buys plain 2-D matmuls and lane
     reductions — nothing the TPU compiler has to shuffle across
     sublanes."""
-    # MXU operands stay in the cache dtype (bf16 on the chip: one MXU
-    # pass, as the dense path's einsums run); accumulation is fp32.
-    del layer_ref
-    kb = k_ref[0, 0]                                      # (bs, bb, hd)
-    bs, bb, hd = kb.shape
-    k = kb.reshape(bs * bb, hd)
-    v = v_ref[0, 0].reshape(bs * bb, hd)
-    q = q_ref[0, 0, 0].astype(k.dtype)                    # (R, hd)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-    kp = kpos_ref[0, 0]                                   # (1, bs*bb)
-    qp = qpos_ref[0, 0]                                   # (R, 1)
-    if alibi:
-        # Per-row slope of this kv head's query group (h = kh*G + g).
-        s = s + slope_ref[0] * kp.astype(jnp.float32)
-    valid = (laneb_ref[...] == rowb_ref[...]) & (kp <= qp)  # (R, bs*bb)
-    s = jnp.where(valid, s, -jnp.inf)
+    win_ref = live_ref = None
+    if n_blocks:
+        _, win_ref, live_ref, _ = refs[:4]
+        refs = refs[4:]
+    else:
+        refs = refs[1:]
+    (rowb_ref, laneb_ref, qpos_ref, kpos_ref, slope_ref, q_ref, k_ref,
+     v_ref, o_ref, m_ref, l_ref) = refs
 
-    m = s.max(axis=-1, keepdims=True)                     # (R, 1)
-    p = jnp.exp(s - m)
-    p = jnp.where(valid, p, 0.0)                          # all-masked split
-    o_ref[0, 0, 0, 0] = jnp.dot(p.astype(v.dtype), v,
-                                preferred_element_type=jnp.float32)
-    m_ref[0, 0, 0, 0] = m
-    l_ref[0, 0, 0, 0] = p.sum(axis=-1, keepdims=True)
+    def attend():
+        # MXU operands stay in the cache dtype (bf16 on the chip: one MXU
+        # pass, as the dense path's einsums run); accumulation is fp32.
+        kb = k_ref[0, 0]                                  # (bs, bb, hd)
+        bs, bb, hd = kb.shape
+        k = kb.reshape(bs * bb, hd)
+        v = v_ref[0, 0].reshape(bs * bb, hd)
+        q = q_ref[0, 0, 0].astype(k.dtype)                # (R, hd)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        kp = kpos_ref[0, 0]                               # (1, bs*bb)
+        qp = qpos_ref[0, 0]                               # (R, 1)
+        if alibi:
+            # Per-row slope of this kv head's query group (h = kh*G + g).
+            s = s + slope_ref[0] * kp.astype(jnp.float32)
+        valid = (laneb_ref[...] == rowb_ref[...]) & (kp <= qp)  # (R, bs*bb)
+        if win_ref is not None:
+            valid = valid & (kp > qp - win_ref[0])
+        s = jnp.where(valid, s, -jnp.inf)
+
+        m = s.max(axis=-1, keepdims=True)                 # (R, 1)
+        p = jnp.exp(s - m)
+        p = jnp.where(valid, p, 0.0)                      # all-masked split
+        o_ref[0, 0, 0, 0] = jnp.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32)
+        m_ref[0, 0, 0, 0] = m
+        l_ref[0, 0, 0, 0] = p.sum(axis=-1, keepdims=True)
+
+    if live_ref is None:
+        attend()
+        return
+    live = live_ref[pl.program_id(1) * n_blocks + pl.program_id(2)] > 0
+    pl.when(live)(attend)
+
+    @pl.when(jnp.logical_not(live))
+    def _empty():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, m_ref.dtype)
+        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+
+
+def _window_tables(kpos, qpos, window, n_blocks: int, n_splits: int):
+    """(live, fetch) of a windowed call, each (n_splits * n_blocks,) int32
+    in grid order (split-major, batch block minor): whether any query of
+    the batch block reaches a key of the split (its position in the
+    query's window and not past the query), and the (split * n_blocks +
+    batch block) whose K/V block the program's index map names: its own
+    where live, else the last live one before it in grid order (the
+    block the pipeline holds already: no DMA), else the first live one.
+    ``kpos`` (nB, n_splits, 1, L) with masked slots at _MASKED_POS,
+    ``qpos`` (nB, S, R, 1)."""
+    q_hi = qpos.max(axis=(1, 2, 3))                       # (nB,)
+    q_lo = qpos.min(axis=(1, 2, 3))
+    kp = kpos[:, :, 0, :]                                 # (nB, n_splits, L)
+    hit = ((kp <= q_hi[:, None, None])
+           & (kp > (q_lo - window)[:, None, None])).any(axis=-1)
+    live = hit.T.reshape(-1)                              # (n_splits * nB,)
+    order = jnp.arange(live.shape[0], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(live, order, -1))
+    first = jnp.argmax(live).astype(jnp.int32)
+    fetch = jnp.where(last < 0, first, last).astype(jnp.int32)
+    return live.astype(jnp.int32), fetch
+
+
+def _window_keys(fetch, kpos, q_positions, window, n_blocks: int, nt: int,
+                 block_slots: int):
+    """(3,) int32 of a windowed call, for one kv head: the (key slot,
+    batch row) pairs its K blocks bring in from HBM, the pairs its queries
+    attend (key not past the query and within its window), and 1 (the
+    call). A block is brought in where the index map names another block
+    than the program before it in grid order (the pipeline keeps the one
+    it holds): a skipped program's costs nothing, a trunk split, named
+    once for every batch block, one block. ``fetch`` as
+    :func:`_window_tables` gives it; ``kpos`` (B, T) with masked slots at
+    _MASKED_POS; ``q_positions`` (B, S)."""
+    jf, i_f = fetch // n_blocks, fetch % n_blocks
+    named = jf * n_blocks + jnp.where(jf < nt, 0, i_f)
+    fetched = 1 + jnp.sum(named[1:] != named[:-1])
+    qp = q_positions.astype(jnp.int32)[:, :, None]
+    kp = kpos[:, None, :]
+    needed = jnp.sum((kp <= qp) & (kp > qp - window))
+    return jnp.stack([fetched * block_slots, needed, 1]).astype(jnp.int32)
 
 
 def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
                  alibi_slopes, trunk_len: int, block_k: int,
-                 interpret: bool, layer=None):
+                 interpret: bool, layer=None, window=None):
     """The one pallas_call behind all four entry points, under the entry
     point's ``name``: the name a profiler trace shows for the kernel
     (``flash_decode_trunk``, ``flash_decode_mq`` ...), pinned here so
@@ -213,7 +284,14 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
     loaded, and for the leading ``trunk_len`` slots (identical in every
     row by the cascade contract) every batch block names block 0: the
     trunk's K/V leaves HBM once per (kv head, split), not once per
-    row."""
+    row.
+
+    ``window`` (a traced scalar, or None): a key counts only where its
+    position also lies within ``window`` of the query's, and the program
+    of a (split, batch block) that no query of the block reaches is
+    skipped, its K/V DMA with it (:func:`_decode_kernel`); the kernel
+    is then named ``flash_decode_windowed``, and the call returns
+    ``(out, keys)`` (:func:`_window_keys`)."""
     B, S, H, hd = q.shape
     if layer is None:
         k, v, layer = k[None], v[None], 0
@@ -234,6 +312,7 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
     nt = max(0, min(int(trunk_len), T - 1)) // split
     L = split * bb
 
+    kpos_bt = kpos
     # Lane order of a split's keys is (slot, batch row); one full-extent
     # (1, L) row per (batch block, split), so any split width lowers.
     kpos = (kpos.reshape(nB, bb, n_splits, split).transpose(0, 2, 3, 1)
@@ -264,32 +343,55 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
 
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                alibi=alibi)
+    prefetch = (layer,)
+    if window is not None:
+        # The window's tables ride as three more prefetched scalar
+        # arrays; every index map takes them after the layer.
+        win = jnp.asarray(window, jnp.int32).reshape(1)
+        live, fetch = _window_tables(kpos, qpos, win[0], nB, n_splits)
+        keys = _window_keys(fetch, kpos_bt, q_positions, win[0], nB, nt, L)
+        prefetch = (layer, win, live, fetch)
+        kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
+                                   alibi=alibi, n_blocks=nB)
+        name = "flash_decode_windowed"
+
+        def kv_index(h, j, i, s, l, w, lv, f):
+            at = f[j * nB + i]
+            jf, i_f = at // nB, at % nB
+            return (l[0], h, jf, jnp.where(jf < nt, 0, i_f), 0)
+
+    def grid_map(fn):
+        """An index map of the grid alone, whatever rides the scalar
+        prefetch after it."""
+        return lambda h, j, i, s, *_: fn(h, j, i, s)
+
     f32 = jnp.float32
     o_p, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(K, n_splits, nB, S),
             in_specs=[
-                pl.BlockSpec((R, 1), lambda h, j, i, s, l: (0, 0)),
-                pl.BlockSpec((1, L), lambda h, j, i, s, l: (0, 0)),
+                pl.BlockSpec((R, 1), grid_map(lambda h, j, i, s: (0, 0))),
+                pl.BlockSpec((1, L), grid_map(lambda h, j, i, s: (0, 0))),
                 pl.BlockSpec((1, 1, R, 1),
-                             lambda h, j, i, s, l: (i, s, 0, 0)),
+                             grid_map(lambda h, j, i, s: (i, s, 0, 0))),
                 pl.BlockSpec((1, 1, 1, L),
-                             lambda h, j, i, s, l: (i, j, 0, 0)),
-                pl.BlockSpec((1, R, 1), lambda h, j, i, s, l: (h, 0, 0)),
+                             grid_map(lambda h, j, i, s: (i, j, 0, 0))),
+                pl.BlockSpec((1, R, 1),
+                             grid_map(lambda h, j, i, s: (h, 0, 0))),
                 pl.BlockSpec((1, 1, 1, R, hd),
-                             lambda h, j, i, s, l: (i, h, s, 0, 0)),
+                             grid_map(lambda h, j, i, s: (i, h, s, 0, 0))),
                 pl.BlockSpec((1, 1, split, bb, hd), kv_index),
                 pl.BlockSpec((1, 1, split, bb, hd), kv_index),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, 1, 1, R, hd),
-                             lambda h, j, i, s, l: (i, h, s, j, 0, 0)),
+                             grid_map(lambda h, j, i, s: (i, h, s, j, 0, 0))),
                 pl.BlockSpec((1, 1, 1, 1, R, 1),
-                             lambda h, j, i, s, l: (i, h, s, j, 0, 0)),
+                             grid_map(lambda h, j, i, s: (i, h, s, j, 0, 0))),
                 pl.BlockSpec((1, 1, 1, 1, R, 1),
-                             lambda h, j, i, s, l: (i, h, s, j, 0, 0)),
+                             grid_map(lambda h, j, i, s: (i, h, s, j, 0, 0))),
             ],
         ),
         out_shape=[
@@ -299,7 +401,7 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
         ],
         interpret=interpret,
         name=name,
-    )(layer, rowb, laneb, qpos, kpos, slopes, qg, k, v)
+    )(*prefetch, rowb, laneb, qpos, kpos, slopes, qg, k, v)
 
     # Log-sum-exp combine across splits (ops/lse.merge_partials, shared
     # with the cascade-prefill merge): renormalize each partial by the
@@ -307,7 +409,8 @@ def _decode_call(name: str, q, k, v, q_positions, key_mask, key_positions,
     # fully-masked split carries m = -inf and weight exactly 0.
     out = merge_partials(o_p, m_p[..., 0], l_p[..., 0], axis=3)
     out = out.reshape(nB, K, S, bb, G, hd).transpose(0, 3, 2, 1, 4, 5)
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    out = out.reshape(B, S, H, hd).astype(q.dtype)
+    return out if window is None else (out, keys)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -320,6 +423,7 @@ def flash_decode(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
     layer: jnp.ndarray | None = None,
+    window: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """One decode step of attention, fused. Returns (B, H, hd) in q's dtype.
 
@@ -328,7 +432,11 @@ def flash_decode(
     or, with ``layer`` (a scalar, traced or not), the stacked cache sides
     (L, K, T, B, hd) of which the kernel reads that layer alone, in place
     (the decode step's layer loop, models/decoder._block). All four entry
-    points take the pair the same way.
+    points take the pair the same way. ``window`` (this entry point and
+    :func:`flash_decode_trunk`): the layer's window, a traced scalar
+    (:func:`_decode_call`); None, no window. With a window the entry
+    point returns ``(out, keys)``, ``keys`` the call's key slots
+    (:func:`_window_keys`).
     ``q_positions``: (B,) mask-aware query positions. ``key_mask``: (B, T)
     {0,1} validity over cache slots (any pattern). ``key_positions``:
     (B, T) mask-aware slot positions (decoder.mask_positions of the cache
@@ -339,9 +447,10 @@ def flash_decode(
     emits a partial (o, m, l); the final output is the log-sum-exp
     combination of the splits — exact attention, any split count.
     """
-    return _decode_call("flash_decode", q[:, None], k, v,
-                        q_positions[:, None], key_mask, key_positions,
-                        alibi_slopes, 0, block_k, interpret, layer)[:, 0]
+    out = _decode_call("flash_decode", q[:, None], k, v,
+                       q_positions[:, None], key_mask, key_positions,
+                       alibi_slopes, 0, block_k, interpret, layer, window)
+    return out[:, 0] if window is None else (out[0][:, 0], out[1])
 
 
 @functools.partial(jax.jit,
@@ -356,6 +465,7 @@ def flash_decode_trunk(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
     layer: jnp.ndarray | None = None,
+    window: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Trunk-aware decode step for shared-prefix (cascade) dispatches.
 
@@ -370,10 +480,11 @@ def flash_decode_trunk(
     and layer it saves ``2 * K * nt*split * hd * itemsize * (B - bb)``
     trunk bytes, nt the trunk split count and bb the batch block.
     """
-    return _decode_call("flash_decode_trunk", q[:, None], k, v,
-                        q_positions[:, None], key_mask, key_positions,
-                        alibi_slopes, trunk_len, block_k, interpret,
-                        layer)[:, 0]
+    out = _decode_call("flash_decode_trunk", q[:, None], k, v,
+                       q_positions[:, None], key_mask, key_positions,
+                       alibi_slopes, trunk_len, block_k, interpret,
+                       layer, window)
+    return out[:, 0] if window is None else (out[0][:, 0], out[1])
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))

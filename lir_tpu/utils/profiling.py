@@ -989,6 +989,111 @@ class SparseStats:
 
 
 @dataclasses.dataclass
+class ExpertStats:
+    """Counters of the expert layers (models/moe.py; metrics source
+    ``moe``). All stay 0 for a model without experts.
+
+    - ``picks``: (token, choice) pairs the routers made: tokens routed x
+      top_k x expert layers, every expert whoever holds it.
+    - ``picks_held``: those that chose an expert this chip holds.
+    - ``rows_computed``: rows the grouped matmul computed for them, the
+      padding of each expert's last row tile included; ``rows_padding``
+      the padding alone (``rows_computed - picks_held``).
+    - ``max_expert_picks``: the most picks one held expert took in one
+      layer of one dispatch.
+    - ``dispatches``: dispatches whose counts were read.
+
+    The device counts them (the dispatch program's int32 array,
+    ``FusedDecodeOut.expert_counts``), and the sweep's drain folds them in
+    as it reads the dispatch's answers (:meth:`fold`)."""
+
+    picks: int = 0
+    picks_held: int = 0
+    rows_computed: int = 0
+    rows_padding: int = 0
+    max_expert_picks: int = 0
+    dispatches: int = 0
+
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+
+    def fold(self, counts, top_k: int) -> None:
+        """Add one dispatch's counts: (expert layers, 2 * held + 1), the
+        held experts' picks, their rows, the tokens routed."""
+        import numpy as np
+
+        c = np.asarray(counts, np.int64)
+        held = (c.shape[1] - 1) // 2
+        with self._lock:
+            self.picks += int(c[:, -1].sum()) * int(top_k)
+            self.picks_held += int(c[:, :held].sum())
+            self.rows_computed += int(c[:, held:2 * held].sum())
+            self.rows_padding += int(c[:, held:2 * held].sum()
+                                     - c[:, :held].sum())
+            self.max_expert_picks = max(self.max_expert_picks,
+                                        int(c[:, :held].max(initial=0)))
+            self.dispatches += 1
+
+    def summary(self) -> Dict[str, object]:
+        with self._lock:
+            return {
+                "picks": self.picks,
+                "picks_held": self.picks_held,
+                "rows_computed": self.rows_computed,
+                "rows_padding": self.rows_padding,
+                "max_expert_picks": self.max_expert_picks,
+                "dispatches": self.dispatches,
+            }
+
+
+@dataclasses.dataclass
+class WindowStats:
+    """Key slots the windowed decode kernel fetched (a model whose layers
+    attend a window, ModelConfig.layer_windowed, runs it on every layer:
+    a global layer's window reaches every key; metrics source
+    ``window``). All stay 0 for a model without windows.
+
+    - ``key_slots_read``: (key slot, batch row) pairs the windowed decode
+      kernel's K blocks brought in from HBM, one kv head's, summed over
+      its calls: whole splits of whole batch blocks, the splits no query
+      of a block reaches skipped.
+    - ``key_slots_needed``: the pairs the same calls' queries attend,
+      the keys within each query's window.
+    - ``calls``: the kernel's calls (layers x decode steps).
+
+    The device counts them (ops/flash_decode._window_keys, on the cache's
+    models/moe.RouteCounts leaf), and the sweep's drain folds them in as
+    it reads the dispatch's answers (:meth:`fold`)."""
+
+    key_slots_read: int = 0
+    key_slots_needed: int = 0
+    calls: int = 0
+
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+
+    def fold(self, keys) -> None:
+        """Add one dispatch's (read, needed, calls)."""
+        read, needed, calls = (int(n) for n in keys)
+        with self._lock:
+            self.key_slots_read += read
+            self.key_slots_needed += needed
+            self.calls += calls
+
+    def summary(self) -> Dict[str, object]:
+        with self._lock:
+            return {
+                "key_slots_read": self.key_slots_read,
+                "key_slots_needed": self.key_slots_needed,
+                "calls": self.calls,
+            }
+
+
+@dataclasses.dataclass
 class RecurrentStats:
     """Counters of the second kind of per-sequence state, the recurrent
     state a state-space mixer keeps beside K/V (models/decoder._mixer;

@@ -821,3 +821,134 @@ def test_gelu_is_a_short_epilogue_of_the_up_projection(one_chip, rows):
     outside = [line.strip()[:120] for name, lines in comps.items()
                if name != holders[0] for line in lines if "f32" + out in line]
     assert outside == []
+
+
+# ---------------------------------------------------------------------------
+# Routed experts and window layers (K-EXAONE) at the published widths
+# ---------------------------------------------------------------------------
+# d 6144, 16 held experts of 2048, GQA 64 / 8 heads of 128, a window of 128:
+# the grouped matmul's two kernels (a prefill chunk's row tile and a decode
+# step's), the windowed decode kernel over the stacked cache, the model's
+# loops over its cache, and the cell's whole dispatch program.
+
+EXP_D, EXP_F, EXP_HELD = 6144, 2048, 16
+
+
+@pytest.mark.parametrize("tokens", [3840, 40])
+@pytest.mark.parametrize("which", ["gate_up", "down"])
+def test_expert_matmul_compiles(one_chip, which, tokens):
+    from lir_tpu.ops import expert_matmul as gmm
+
+    tm = gmm.pick_tm(tokens, 8, 128)
+    rows = -(-(tokens * 8 + EXP_HELD * (tm - 1)) // tm) * tm
+    k_in, n_out = (EXP_D, EXP_F) if which == "gate_up" else (EXP_F, EXP_D)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = quant.QuantTensor(q=sd((EXP_HELD, k_in, n_out), jnp.int8),
+                          scale=sd((EXP_HELD, n_out), jnp.float32))
+
+    def run(x, w, te, n):
+        if which == "gate_up":
+            return gmm.gate_up(x, w, w, te, n, tm)
+        return gmm.down(x, w, te, n, tm)
+
+    text = jax.jit(run).lower(sd((rows, k_in), jnp.bfloat16), w,
+                              sd((rows // tm,), jnp.int32),
+                              sd((), jnp.int32)).compile().as_text()
+    assert f"expert_matmul_{which}" in text
+
+
+@pytest.mark.parametrize("trunk", [0, 64])
+def test_windowed_decode_compiles(one_chip, trunk):
+    """The windowed kernel over a 12-layer stacked cache at the sweep's
+    extent, batch 40: the live-split table and the fetch table ride the
+    scalar prefetch beside the layer."""
+    H, K, hd, T = 64, 8, 128, 504
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(q, k, v, qpos, mask, kpos, layer, window):
+        return flash_decode_trunk(q, k, v, qpos, mask, kpos, layer=layer,
+                                  window=window, trunk_len=trunk)
+
+    text = jax.jit(run).lower(
+        sd((BATCH, H, hd), jnp.bfloat16), sd((12, K, T, BATCH, hd),
+                                             jnp.bfloat16),
+        sd((12, K, T, BATCH, hd), jnp.bfloat16), sd((BATCH,), jnp.int32),
+        sd((BATCH, T), jnp.int32), sd((BATCH, T), jnp.int32),
+        sd((), jnp.int32), sd((), jnp.int32)).compile().as_text()
+    assert "flash_decode_windowed" in text
+
+
+def _exaone_engine(one_chip, **cut):
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+    sys.path.insert(0, str(bench))
+    from harness import builders
+    from references import exaone_moe as ref
+
+    raw = json.loads((bench / "configs" / "k-exaone-236b.json").read_text())
+    spec = dataclasses.replace(ref.spec_from_config("k-exaone-236b", raw),
+                               **cut)
+    cfg = builders.program_config(spec, ref, check=not cut)
+    params = _shaped(jax.eval_shape(lambda: builders.wrap_quantized(
+        ref.weights(spec, ref.seed_key(0)))), one_chip)
+    return builders.build_engine(params, cfg, raw["lir_tpu"]["runtime"])
+
+
+def _exaone_rows():
+    return [tuple(range(64)) + tuple(1000 + 13 * r + i for i in range(380))
+            for r in range(BATCH)]
+
+
+def test_the_expert_models_loops_update_the_stacked_cache_in_place(
+        one_chip, on_tpu):
+    """One LLLG period (the dense layer, three expert layers; the window
+    and the rotation chosen per layer) at the published widths: the
+    40-row cascade dispatch program's loops (the layer scans of both runs,
+    the decode loops) hold no copy and no second buffer of a stacked side
+    and write no whole layer back; the routing counts ride beside."""
+    from lir_tpu.engine import compile_plan
+
+    engine = _exaone_engine(one_chip, layers=4, windows=(128, 128, 128, 0))
+    route = engine.route("shared", 448, BATCH, 0, 32, 32, 4, 8, True,
+                         _exaone_rows(), BATCH)
+    assert route.trunk == 64
+    spec = [s for s in route.planned(False) if s.trunk][0]
+    text = compile_plan._lower(engine, spec).compile().as_text()
+    for kernel in ("expert_matmul_gate_up", "expert_matmul_down",
+                   "flash_decode_windowed", "cascade_attention"):
+        assert kernel in text
+    cache = generate.greedy_decode_dispatch.eval_shape(
+        engine.params, engine.cfg, compile_plan.dispatch_program(engine, spec),
+        compile_plan.dispatch_args(engine, spec))[-1]
+    assert cache[0].shape == (4, 8, EXTENT, BATCH, 128)
+    assert cache[-1].counts.shape == (3, 2 * EXP_HELD + 1)
+    stacked, layers = _cache_shapes(engine.cfg, cache[:2])
+    assert _cache_moves(text, stacked, layers, whole_layer_reads=False) == []
+
+
+@pytest.mark.parametrize("program", ["cascade", "dense"])
+def test_the_expert_cells_dispatch_programs_fit(one_chip, on_tpu, program):
+    """k-exaone-236b.sweep-trunk512's programs, every layer: 9.2 GB of
+    weights as arguments beside the dispatch's temporaries and its cache
+    within 15.5 GB of the chip's 16.9."""
+    from lir_tpu.engine import compile_plan
+
+    engine = _exaone_engine(one_chip)
+    route = engine.route("shared", 448, BATCH, 0, 32, 32, 4, 8, True,
+                         _exaone_rows(), BATCH)
+    (spec,) = [s for s in route.planned(False)
+               if bool(s.trunk) == (program == "cascade")]
+    compiled = compile_plan._lower(engine, spec).compile()
+    mem = compiled.memory_analysis()
+    assert 9.0e9 < mem.argument_size_in_bytes < 9.3e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
+    assert "expert_matmul_gate_up" in compiled.as_text()
