@@ -455,9 +455,9 @@ def _add_cascade_flags(p) -> None:
     p.add_argument("--no-cascade-fused-suffix", action="store_true",
                    help="run the cascade prefill as two kernel launches "
                         "plus an HBM merge round-trip instead of the "
-                        "fused single-kernel path (bitwise-identical "
-                        "results; the two-leg path is the fused "
-                        "kernel's verification baseline)")
+                        "fused single-kernel path (the same scores, "
+                        "summed in another order; the two-leg path is "
+                        "the fused kernel's verification baseline)")
 
 
 def _cascade_rt_kw(args, rt_kw: dict) -> None:

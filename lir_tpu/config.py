@@ -283,13 +283,14 @@ class RuntimeConfig:
     # (the flag mirrors into the static ModelConfig, re-keying every
     # decode executable). Trunk eligibility shares CascadeConfig.
     cascade_decode: bool = True       # cli: --no-cascade-decode
-    # Fused single-kernel cascade prefill (ops/cascade_prefill): prefix
-    # leg + suffix leg + log-sum-exp merge in ONE Pallas launch — no HBM
-    # round-trip for the per-leg partials. BITWISE the two-leg path at
-    # every trunk extent (tests/test_cascade.py); --no-cascade-fused-
-    # suffix restores the two-leg lowering exactly (mirrored into the
-    # static ModelConfig like cascade_decode). float QK^T only — the
-    # int8_qk cascade keeps the two-leg path.
+    # Fused single-kernel cascade prefill (ops/cascade_prefill): trunk
+    # and own keys under one softmax in ONE Pallas launch, only
+    # the key chunks a row block may attend — no HBM round-trip for
+    # per-leg partials. The two-leg path's scores, summed in another
+    # order (tests/test_cascade_decode.py); --no-cascade-fused-suffix
+    # restores the two-leg lowering exactly (mirrored into the static
+    # ModelConfig like cascade_decode). float QK^T only — the int8_qk
+    # cascade keeps the two-leg path.
     cascade_fused_suffix: bool = True  # cli: --no-cascade-fused-suffix
     # Lease time-to-live in WALL-CLOCK seconds (leases compare across
     # hosts, so the shared clock is time.time, not monotonic). A holder

@@ -1008,6 +1008,32 @@ class ScoringEngine:
             held = routes[-1].held_ids
         return routes
 
+    def _note_key_pairs(self, trunk: int, prefix_mask,
+                        int8_qk: bool) -> None:
+        """Fold one cascade dispatch's (query, key) pairs of the fused
+        cascade-prefill kernel into the cascade counters: those its loop
+        visits and those the dense mask admits, every layer's window
+        (ops/cascade_prefill.key_pairs), from the dispatch's remainder
+        mask and its positions as the program derives them
+        (decoder.mask_positions). The two-leg path and a model whose
+        layers differ in kind run no such kernel."""
+        cfg = self.cfg
+        if (getattr(cfg, "layer_kinds", ()) or int8_qk
+                or not cfg.cascade_fused_suffix):
+            return
+        from ..ops.cascade_prefill import key_pairs
+
+        mask = np.asarray(prefix_mask)[:, trunk:]
+        positions = trunk + np.maximum(np.cumsum(mask, axis=-1) - 1, 0)
+        windows = ([cfg.sliding_window if w else None
+                    for w in cfg.layer_windowed] if cfg.layer_windowed
+                   else [None] * cfg.n_layers)
+        computed, attended = key_pairs(positions, mask, trunk,
+                                       cfg.n_heads // cfg.n_kv_heads,
+                                       windows)
+        self.cascade_stats.count("key_pairs_computed", computed)
+        self.cascade_stats.count("key_pairs_attended", attended)
+
     def _note_cascade_decode(self, dtrunk: int, rows: int, cache,
                              new_tokens: int, conf_tokens: int) -> None:
         """Fold one trunk-aware decode dispatch into the cascade
@@ -1547,6 +1573,8 @@ class ScoringEngine:
                     "prefix_flops_saved",
                     int(cascade_prefill_flops_saved(self.cfg, shared_rows + 1,
                                                     route.trunk)))
+                self._note_key_pairs(route.trunk, host["prefix_mask"],
+                                     route.int8)
             # The trunk program counted its own tokens and scans.
             self._note_prefilled(route.trunk, lcp[:rows], sfx_a_ids[:rows],
                                  sfx_b_ids[:rows], cache, new_tokens,

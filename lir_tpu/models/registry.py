@@ -90,10 +90,10 @@ class ModelConfig:
     # kernel exactly (the trunk extent is then pinned to 0).
     cascade_decode: bool = True
 
-    # Fused cascade-prefill suffix leg (ops/cascade_prefill): prefix +
-    # suffix + log-sum-exp merge in ONE Pallas launch, no HBM round-trip
-    # for the partial (o, m, l) triples. Bitwise the two-leg path on the
-    # cascade matrix; RuntimeConfig.cascade_fused_suffix /
+    # Fused cascade-prefill suffix leg (ops/cascade_prefill): trunk and
+    # own keys under one softmax in ONE Pallas launch, no HBM
+    # round-trip for partial (o, m, l) triples; the two-leg path to
+    # float32 rounding; RuntimeConfig.cascade_fused_suffix /
     # --no-cascade-fused-suffix restores the two-leg lowering exactly.
     cascade_fused_suffix: bool = True
 

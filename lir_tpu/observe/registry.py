@@ -115,7 +115,7 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "prefix_flops_saved", "cascade_decode_dispatches",
         "trunk_bytes_deduped", "tokens_prefilled",
         "trunk_tokens_prefilled", "trunk_programs",
-        "trunk_held_dispatches",
+        "trunk_held_dispatches", "key_pairs_computed", "key_pairs_attended",
     ),
     "FillStats": (
         "fill_s", "ahead_s", "windows", "windows_ahead", "wait_s",

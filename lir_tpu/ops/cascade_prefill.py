@@ -38,12 +38,15 @@ test hook, mirroring FUSED_DECODE_INTERPRET_ON_CPU).
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..models.quant import dynamic_quant
 from .lse import merge_partials
@@ -210,154 +213,273 @@ def _suffix_partials(q, sfx_k, sfx_v, suffix_mask, q_positions, slopes):
     return o, m, p.sum(axis=-1)
 
 
-# Flattened (position, group) query rows one fused-cascade program takes:
-# bounds the (rows, trunk) fp32 score tile inside the scoped VMEM limit
-# at the widest group the zoo has (falcon MQA, G = 71).
-FUSED_MAX_ROWS = 512
+# Query rows ((position, group) flattened) of one fused-cascade program,
+# the float32 scores it may hold in VMEM (rows x trunk and window keys),
+# and keys of one of its suffix chunks. A row block skips the key chunks
+# none of its queries may attend, so finer blocks and chunks skip more,
+# but each program pays a fixed cost (its block DMAs and grid step) that
+# outweighs the finer skip at the sweep's shapes: on a v5e the largest
+# blocks tried (1,200 rows over 64 trunk + 384 window keys: falcon's
+# group of 71 at 16 positions) and lane-wide chunks ran fastest at every
+# head layout (PERF.md, PR 40). Longer prefixes take fewer rows.
+FUSED_ROWS = 1200
+FUSED_SCORES = FUSED_ROWS * (64 + 384)
+FUSED_CHUNK = 128
 
 
-def pick_window_rows(window: int, n_groups: int,
-                     max_rows: int = FUSED_MAX_ROWS) -> int:
-    """Window positions per fused-cascade program: the largest divisor
-    ``r`` of the window whose ``r * G`` flattened rows fit ``max_rows``
-    and fill whole sublane groups (or the whole window); when no divisor
-    fits, the smallest sublane-aligned one."""
+def fused_edges(window: int, n_groups: int, trunk_len: int) -> tuple:
+    """(positions a row block takes, keys a suffix chunk takes) of the
+    fused cascade over a remainder window of ``window`` positions behind
+    a ``trunk_len`` trunk, ``n_groups`` query heads a kv head: the
+    largest divisor ``r`` of the window whose ``r * G`` rows fill whole
+    sublane groups (or the whole window), fit :data:`FUSED_ROWS`, and
+    whose scores over trunk and window fit :data:`FUSED_SCORES`, else
+    the smallest sublane-aligned one; the largest divisor of the
+    window up to :data:`FUSED_CHUNK` keys on the bfloat16 sublane grid
+    (16), else the whole window as one chunk."""
     ok = [r for r in range(1, window + 1)
           if window % r == 0 and ((r * n_groups) % 8 == 0 or r == window)]
-    fit = [r for r in ok if r * n_groups <= max_rows]
-    return max(fit) if fit else min(ok)
+    fit = [r for r in ok if r * n_groups <= FUSED_ROWS
+           and r * n_groups * (trunk_len + window) <= FUSED_SCORES]
+    chunks = [c for c in range(16, min(FUSED_CHUNK, window) + 1, 16)
+              if window % c == 0]
+    return (max(fit) if fit else min(ok)), (max(chunks) if chunks
+                                            else window)
 
 
-def _fused_cascade_kernel(*refs, sm_scale: float, alibi: bool,
-                          windowed: bool = False):
-    """One (kv head, batch row, query block) program of the FULLY-FUSED
-    cascade: prefix leg + suffix leg + log-sum-exp merge in a single
-    kernel, so the partial (o, m, l) triples never round-trip through
-    HBM. Every per-element op mirrors the two-leg path exactly — the
-    prefix block is :func:`_prefix_kernel`'s arithmetic, the suffix
-    block is :func:`_suffix_partials`' (per (row, kv head) slice), and
-    the merge is :func:`~lir_tpu.ops.lse.merge_partials`' stacked-sum
-    order — so the fused output is BITWISE the two-leg path's (pinned
-    across the cascade matrix by tests/test_cascade.py).
+def key_chunks(first_pos, last_slot, trunk_len: int, chunk: int,
+               window=None) -> tuple:
+    """The fused cascade kernel's loop bounds for row blocks: ``(lo, hi,
+    trunk)``, the first and last suffix chunk a block visits and whether
+    it visits the trunk (0 / 1), each shaped like ``first_pos``.
+
+    ``first_pos``: the position of each block's first query (positions
+    never fall along a row, so it is the block's least); ``last_slot``:
+    each block's last window slot. A key at slot j of the window sits at
+    position trunk_len + j at most (the canonical layout; pads repeat an
+    earlier position), and a query attends no key past its own slot, so
+    chunks past the one holding ``last_slot`` are never attended, and
+    under a ``window`` neither are those before the one holding slot
+    first_pos - trunk_len - window + 1, nor the trunk once that slot is
+    past it. Takes numpy and jax arrays alike (the kernel's table is
+    built on the device, the counter's on the host)."""
+    hi = last_slot // chunk + 0 * first_pos
+    if window is None:
+        return 0 * first_pos, hi, 1 + 0 * first_pos
+    lo = (first_pos - trunk_len - window + 1).clip(0) // chunk
+    trunk = (first_pos - window + 1 < trunk_len).astype(np.int32)
+    return lo, hi, trunk
+
+
+def key_pairs(q_positions, suffix_mask, trunk_len: int, n_groups: int,
+              windows) -> tuple:
+    """(computed, attended) (query, key) pairs of the fused cascade
+    kernel over one dispatch's layers, a query counted once for its
+    ``n_groups`` heads: the keys of the chunks :func:`key_chunks` hands
+    the kernel (with :func:`fused_edges`' blocks), and those the dense
+    mask admits (trunk keys within the window, own keys valid, not past
+    the query and within the window). Host numpy, no device work.
+    ``q_positions`` / ``suffix_mask``: (B, R) as the kernel gets them;
+    ``windows``: each layer's window, None where it attends every key."""
+    qp = np.asarray(q_positions, np.int64)
+    valid = np.asarray(suffix_mask) > 0
+    B, R = qp.shape
+    r, chunk = fused_edges(R, n_groups, trunk_len)
+    last = np.arange(r - 1, R, r)
+    computed = attended = 0
+    for window, layers in collections.Counter(windows).items():
+        lo, hi, trunk = key_chunks(qp[:, ::r], last, trunk_len, chunk,
+                                   window)
+        keys = (np.minimum((hi + 1) * chunk, R) - lo * chunk
+                + trunk * trunk_len)
+        computed += layers * r * int(keys.sum())
+        w = np.iinfo(np.int64).max // 2 if window is None else window
+        seen = np.clip(trunk_len - (qp - w + 1).clip(0), 0, trunk_len)
+        for b in range(B):
+            kp = qp[b][valid[b]]
+            seen[b] += (np.searchsorted(kp, qp[b], "right")
+                        - np.searchsorted(kp, qp[b] - w, "right"))
+        attended += layers * int(seen.sum())
+    return computed, attended
+
+
+def _fused_cascade_kernel(bounds_ref, *refs, sm_scale: float, alibi: bool,
+                          windowed: bool, chunk: int, n_blocks: int):
+    """One (kv head, batch row, row block) program of the FULLY-FUSED
+    cascade: the block's queries attend the shared trunk and the row's
+    own window keys under ONE softmax, visiting only the key chunks
+    :func:`key_chunks` says some query of the block may attend
+    (``bounds_ref``, prefetched: lo, hi, trunk for each (row, block)).
+    Two passes over the visited chunks: the scores and their maximum
+    (kept per lane, reduced across lanes once), then the exponentials,
+    their sum (per lane too) and P.V; the result is written once, in the
+    output's dtype. Every score is the two-leg path's float32 arithmetic
+    (the prefix block :func:`_prefix_kernel`'s, the suffix chunks
+    :func:`_suffix_partials`' masks); only the order of the softmax sums
+    differs.
 
     ``windowed``: a layer that attends a window, whose width rides the
-    first operand: a key counts, in either leg, only where its position
-    lies within it of the query's, so the trunk leg may hold no key for
-    a query (its partial then carries m = -inf and weight 0 in the
-    merge, as a masked split does in the decode kernel)."""
+    first operand: a key counts, trunk or own, only where its position
+    lies within it of the query's. ``alibi``: the next operand is the
+    rows' slopes."""
     if windowed:
         win_ref, refs = refs[0], refs[1:]
-    (slope_ref, qpos_ref, kpos_ref, smask_ref, q_ref, sk_ref, sv_ref,
-     tk_ref, tv_ref, o_ref) = refs
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # (rG, hd)
+    if alibi:
+        slope_ref, refs = refs[0], refs[1:]
+    (qpos_ref, kpos_ref, smask_ref, q_ref, sk_ref, sv_ref, tk_ref, tv_ref,
+     o_ref, s_sc, t_sc, mx_sc, lx_sc, l_sc, acc_sc) = refs
+    f32 = jnp.float32
+    at = 3 * (pl.program_id(1) * n_blocks + pl.program_id(2))
+    lo, hi, trunk = bounds_ref[at], bounds_ref[at + 1], bounds_ref[at + 2]
+    q = q_ref[0, 0].astype(f32) * sm_scale                # (rG, hd)
     # Per-flattened-row slopes and query positions arrive HOST-built as
-    # (rG, 1) columns (like _prefix_partials' flattened slope array):
-    # building them in-kernel from a (G,) block lets XLA contract the
-    # bias mul+add into an FMA, a 1-ulp drift off the two-leg lowering.
-    slope = slope_ref[0]                                  # (rG, 1)
-
-    # Prefix leg (== _prefix_kernel, non-int8): no mask, no causality.
-    s = jnp.dot(q, tk_ref[0].astype(jnp.float32).T,
-                preferred_element_type=jnp.float32)       # (rG, Tt)
-    if alibi or windowed:
-        kp_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    # (rG, 1) columns (like _prefix_partials' flattened slope array).
+    qpos = qpos_ref[0]                                    # (rG, 1)
     if alibi:
-        s = s + slope * kp_t.astype(jnp.float32)
+        slope = slope_ref[0]                              # (rG, 1)
     if windowed:
-        lo = qpos_ref[0] - win_ref[...]                   # (rG, 1)
-        valid_t = kp_t > lo
-        s = jnp.where(valid_t, s, -jnp.inf)
-    m_t = s.max(axis=-1, keepdims=True)                   # (rG, 1)
-    p = jnp.exp(s - m_t)
-    if windowed:
-        p = jnp.where(valid_t, p, 0.0)                    # no trunk key
-    o_t = jnp.dot(p, tv_ref[0].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    l_t = p.sum(axis=-1, keepdims=True)
+        lo_pos = qpos - win_ref[...]                      # (rG, 1)
+    R = sk_ref.shape[2]
+    chunks = [(c, c * chunk, (c + 1) * chunk) for c in range(R // chunk)]
 
-    # Suffix leg (== _suffix_partials for this (b, kh) slice): causal
-    # within the window, mask-aware, ALiBi on absolute key positions.
-    sk = sk_ref[0, 0].astype(jnp.float32)                 # (R, hd)
-    s2 = jnp.dot(q, sk.T, preferred_element_type=jnp.float32)  # (rG, R)
-    kp = kpos_ref[0]                                      # (1, R)
-    if alibi:
-        s2 = s2 + slope * kp.astype(jnp.float32)
-    valid = (smask_ref[0] > 0) & (kp <= qpos_ref[0])      # (rG, R)
-    if windowed:
-        valid = valid & (kp > lo)
-    s2 = jnp.where(valid, s2, -jnp.inf)
-    m_s = s2.max(axis=-1, keepdims=True)
-    p2 = jnp.exp(s2 - m_s)
-    p2 = jnp.where(valid, p2, 0.0)                        # all-masked row
-    o_s = jnp.dot(p2, sv_ref[0, 0].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    l_s = p2.sum(axis=-1, keepdims=True)
+    # Pass 1: the scores of the visited keys; masked and skipped ones
+    # read -inf.
+    t_sc[...] = jnp.full(t_sc.shape, -jnp.inf, f32)
+    mx_sc[...] = jnp.full(mx_sc.shape, -jnp.inf, f32)
 
-    # In-VMEM merge: merge_partials' exact stacked-reduction order over
-    # the two partials, trunk first.
-    m_p = jnp.stack([m_t, m_s])                           # (2, rG, 1)
-    m = m_p.max(axis=0)
-    w = jnp.where(jnp.isfinite(m_p), jnp.exp(m_p - m[None]), 0.0)
-    l = (w * jnp.stack([l_t, l_s])).sum(axis=0)
-    o = (w * jnp.stack([o_t, o_s])).sum(axis=0)
-    o_ref[0, 0] = o / jnp.maximum(l, 1e-30)
+    @pl.when(trunk > 0)
+    def _trunk_scores():
+        # The prefix leg's keys: no mask, no causality, slot t IS
+        # position t.
+        s = jnp.dot(q, tk_ref[0].astype(f32).T,
+                    preferred_element_type=f32)           # (rG, Tt)
+        if alibi or windowed:
+            kp_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if alibi:
+            s = s + slope * kp_t.astype(f32)
+        if windowed:
+            s = jnp.where(kp_t > lo_pos, s, -jnp.inf)
+        t_sc[...] = s
+
+    for c, a, e in chunks:
+        @pl.when((lo <= c) & (c <= hi))
+        def _chunk_scores(c=c, a=a, e=e):
+            # The suffix leg's keys a:e: causal within the window,
+            # mask-aware, ALiBi on absolute key positions.
+            sk = sk_ref[0, 0, a:e, :].astype(f32)         # (C, hd)
+            s = jnp.dot(q, sk.T, preferred_element_type=f32)  # (rG, C)
+            kp = kpos_ref[0, :, a:e]                      # (1, C)
+            if alibi:
+                s = s + slope * kp.astype(f32)
+            valid = (smask_ref[0, :, a:e] > 0) & (kp <= qpos)
+            if windowed:
+                valid = valid & (kp > lo_pos)
+            s = jnp.where(valid, s, -jnp.inf)
+            s_sc[c] = s
+            mx_sc[...] = jnp.maximum(mx_sc[...], s)
+
+    m = jnp.maximum(t_sc[...].max(axis=-1, keepdims=True),
+                    mx_sc[...].max(axis=-1, keepdims=True))
+    # A row that attends no key at all exponentiates against 0: every
+    # weight is then 0 and so is its result.
+    m = jnp.where(m == -jnp.inf, 0.0, m)
+
+    # Pass 2: exponentials, their sums, and P.V, trunk first.
+    l_sc[...] = jnp.zeros(l_sc.shape, f32)
+    lx_sc[...] = jnp.zeros(lx_sc.shape, f32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, f32)
+
+    @pl.when(trunk > 0)
+    def _trunk_values():
+        p = jnp.exp(t_sc[...] - m)
+        l_sc[...] = p.sum(axis=-1, keepdims=True)
+        acc_sc[...] = jnp.dot(p, tv_ref[0].astype(f32),
+                              preferred_element_type=f32)
+
+    for c, a, e in chunks:
+        @pl.when((lo <= c) & (c <= hi))
+        def _chunk_values(c=c, a=a, e=e):
+            p = jnp.exp(s_sc[c] - m)
+            lx_sc[...] += p
+            acc_sc[...] += jnp.dot(p, sv_ref[0, 0, a:e, :].astype(f32),
+                                   preferred_element_type=f32)
+
+    l = l_sc[...] + lx_sc[...].sum(axis=-1, keepdims=True)
+    o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _cascade_fused(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
                    q_positions, slopes, interpret: bool, window=None):
-    """Single-launch cascade attention: grid (K, B, query blocks), each
+    """Single-launch cascade attention: grid (K, B, row blocks), each
     program owns a block of one row's R*G flattened queries against the
-    whole trunk plus the row's own suffix window, merged in VMEM — one
-    kernel, zero HBM round-trips for the partials."""
+    trunk and the chunks of the row's own window that block may attend
+    (:func:`key_chunks`), under one softmax in VMEM — one kernel, zero
+    HBM round-trips, the result written in q's dtype."""
     B, R, H, hd = q.shape
     K, Tt = trunk_k.shape[0], trunk_k.shape[1]
     G = H // K
     RG = R * G
-    rG = pick_window_rows(R, G) * G
+    r, chunk = fused_edges(R, G, Tt)
+    rG, n_blocks = r * G, R // r
     sm_scale = 1.0 / math.sqrt(hd)
-    alibi = slopes is not None
-    if alibi:
-        sl = jnp.broadcast_to(
-            jnp.asarray(slopes, jnp.float32).reshape(K, 1, G),
-            (K, R, G)).reshape(K, RG, 1)
-    else:
-        sl = jnp.zeros((K, RG, 1), jnp.float32)
     qf = (q.reshape(B, R, K, G, hd).transpose(0, 2, 1, 3, 4)
           .reshape(B, K, RG, hd))
     skt = sfx_k.transpose(0, 2, 1, 3)                     # (B, K, R, hd)
     svt = sfx_v.transpose(0, 2, 1, 3)
-    kpos = jnp.asarray(q_positions, jnp.int32)[:, None, :]  # keys = queries
-    qpos = jnp.repeat(jnp.asarray(q_positions, jnp.int32), G,
-                      axis=1)[..., None]                  # (B, RG, 1)
+    positions = jnp.asarray(q_positions, jnp.int32)
+    kpos = positions[:, None, :]                          # keys = queries
+    qpos = jnp.repeat(positions, G, axis=1)[..., None]    # (B, RG, 1)
     smask = jnp.asarray(suffix_mask, jnp.int32)[:, None, :]
+    win = None if window is None else jnp.asarray(window, jnp.int32)
+    bounds = jnp.stack(key_chunks(positions[:, ::r],
+                                  jnp.arange(r - 1, R, r, dtype=jnp.int32),
+                                  Tt, chunk, win), axis=-1)
+    bounds = bounds.astype(jnp.int32).reshape(-1)         # (B*n_blocks*3,)
+    lead, lead_specs = [], []
+    if win is not None:
+        lead.append(win.reshape(1, 1))
+        lead_specs.append(pl.BlockSpec((1, 1), lambda h, b, i, t: (0, 0)))
+    if slopes is not None:
+        lead.append(jnp.broadcast_to(
+            jnp.asarray(slopes, jnp.float32).reshape(K, 1, G),
+            (K, R, G)).reshape(K, RG, 1))
+        lead_specs.append(pl.BlockSpec((1, rG, 1),
+                                       lambda h, b, i, t: (h, i, 0)))
     kernel = functools.partial(_fused_cascade_kernel, sm_scale=sm_scale,
-                               alibi=alibi, windowed=window is not None)
-    lead, win_spec = (), []
-    if window is not None:
-        lead = (jnp.asarray(window, jnp.int32).reshape(1, 1),)
-        win_spec = [pl.BlockSpec((1, 1), lambda h, b, i: (0, 0))]
+                               alibi=slopes is not None,
+                               windowed=win is not None, chunk=chunk,
+                               n_blocks=n_blocks)
+    f32 = jnp.float32
     out = pl.pallas_call(
         kernel,
-        grid=(K, B, RG // rG),
-        in_specs=win_spec + [
-            pl.BlockSpec((1, rG, 1), lambda h, b, i: (h, i, 0)),
-            pl.BlockSpec((1, rG, 1), lambda h, b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, R), lambda h, b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, R), lambda h, b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, rG, hd), lambda h, b, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, R, hd), lambda h, b, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, hd), lambda h, b, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, Tt, hd), lambda h, b, i: (h, 0, 0)),
-            pl.BlockSpec((1, Tt, hd), lambda h, b, i: (h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rG, hd),
-                               lambda h, b, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, RG, hd), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(K, B, n_blocks),
+            in_specs=lead_specs + [
+                pl.BlockSpec((1, rG, 1), lambda h, b, i, t: (b, i, 0)),
+                pl.BlockSpec((1, 1, R), lambda h, b, i, t: (b, 0, 0)),
+                pl.BlockSpec((1, 1, R), lambda h, b, i, t: (b, 0, 0)),
+                pl.BlockSpec((1, 1, rG, hd), lambda h, b, i, t: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, R, hd), lambda h, b, i, t: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, R, hd), lambda h, b, i, t: (b, h, 0, 0)),
+                pl.BlockSpec((1, Tt, hd), lambda h, b, i, t: (h, 0, 0)),
+                pl.BlockSpec((1, Tt, hd), lambda h, b, i, t: (h, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, rG, hd),
+                                   lambda h, b, i, t: (b, h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((R // chunk, rG, chunk), f32),
+                            pltpu.VMEM((rG, Tt), f32),
+                            pltpu.VMEM((rG, chunk), f32),
+                            pltpu.VMEM((rG, chunk), f32),
+                            pltpu.VMEM((rG, 1), f32),
+                            pltpu.VMEM((rG, hd), f32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, K, RG, hd), q.dtype),
         interpret=interpret,
         name="cascade_attention",
-    )(*lead, sl, qpos, kpos, smask, qf, skt, svt, trunk_k, trunk_v)
+    )(bounds, *lead, qpos, kpos, smask, qf, skt, svt, trunk_k, trunk_v)
     out = out.reshape(B, K, R, G, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, R, H, hd).astype(q.dtype)
+    return out.reshape(B, R, H, hd)
 
 
 @functools.partial(jax.jit,
@@ -381,8 +503,9 @@ def cascade_attention(q, sfx_k, sfx_v, trunk_k, trunk_v, suffix_mask,
     in q's dtype — softmax over trunk + window keys, exact.
 
     ``fused_suffix`` (default ON, RuntimeConfig.cascade_fused_suffix)
-    runs prefix + suffix + merge as ONE Pallas launch with the partials
-    merged in VMEM — bitwise the two-leg path below. The int8-QK^T
+    runs prefix + suffix as ONE Pallas launch under one softmax
+    in VMEM over only the key chunks a row block may attend — the two-leg
+    path's scores, summed in another order. The int8-QK^T
     variant keeps the two-leg split (its prefix leg quantizes in-kernel
     over flattened query blocks; --no-cascade-fused-suffix restores the
     two-leg path for float too).

@@ -799,6 +799,12 @@ class CascadeStats:
       row — profiling.cascade_decode_bytes_saved); bench.py's
       ``cascade_decode`` key divides the flat kernel's decode bytes by
       the deduped total for the headline bytes/row reduction.
+    - ``key_pairs_computed`` / ``key_pairs_attended``: (query, key) pairs
+      of the fused cascade-prefill kernel over a dispatch's layers, a
+      query counted once for its group of heads: those its loop visits
+      and those the dense mask admits (ops/cascade_prefill.key_pairs,
+      from the dispatch's host arrays; the ratio is what the key skip
+      leaves).
     """
 
     cascade_dispatches: int = 0
@@ -815,6 +821,8 @@ class CascadeStats:
     trunk_tokens_prefilled: int = 0
     trunk_programs: int = 0
     trunk_held_dispatches: int = 0
+    key_pairs_computed: int = 0
+    key_pairs_attended: int = 0
 
     def __post_init__(self) -> None:
         import threading

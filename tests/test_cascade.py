@@ -133,12 +133,27 @@ class TestMergePartials:
 # The cascade kernel vs a dense full-softmax reference
 # ---------------------------------------------------------------------------
 
+def _dense_mask(q_pos, sfx_mask, Tt, window=None):
+    """(B, R, Tt + R) keys each query attends: trunk slot t is position t
+    and always valid, window keys carry the row's mask, and the causal
+    key-pos <= query-pos rule (keys ARE the queries' slots); under a
+    ``window`` only keys within it of the query's position."""
+    q_pos, sfx_mask = np.asarray(q_pos), np.asarray(sfx_mask)
+    B = q_pos.shape[0]
+    key_pos = np.concatenate([np.broadcast_to(np.arange(Tt), (B, Tt)),
+                              q_pos], axis=1)
+    key_ok = np.concatenate([np.ones((B, Tt), bool), sfx_mask > 0], axis=1)
+    allowed = key_ok[:, None, :] & (key_pos[:, None, :] <= q_pos[:, :, None])
+    if window is not None:
+        allowed &= key_pos[:, None, :] > q_pos[:, :, None] - window
+    return allowed
+
+
 def _dense_cascade_reference(q, sfx_k, sfx_v, trunk_k, trunk_v, sfx_mask,
-                             q_pos, slopes=None):
-    """Plain softmax over trunk ++ window keys per row: trunk slot t is
-    position t and always valid; window keys carry the row's mask and
-    the causal key-pos <= query-pos rule (keys ARE the queries' slots);
-    ALiBi biases by key position (decoder._causal_bias convention)."""
+                             q_pos, slopes=None, window=None):
+    """Plain softmax over trunk ++ window keys per row under
+    :func:`_dense_mask`; ALiBi biases by key position
+    (decoder._causal_bias convention)."""
     B, R, H, hd = q.shape
     K, Tt = trunk_k.shape[0], trunk_k.shape[1]
     G = H // K
@@ -150,23 +165,22 @@ def _dense_cascade_reference(q, sfx_k, sfx_v, trunk_k, trunk_v, sfx_mask,
     key_pos = jnp.concatenate(
         [jnp.broadcast_to(jnp.arange(Tt, dtype=jnp.float32)[None], (B, Tt)),
          q_pos.astype(jnp.float32)], axis=1)                   # (B, Tt+R)
-    key_ok = jnp.concatenate(
-        [jnp.ones((B, Tt), bool), sfx_mask > 0], axis=1)
     qg = (q.reshape(B, R, K, G, hd).astype(jnp.float32)
           / math.sqrt(hd))
-    s = jnp.einsum("brkgd,bktd->bkrgt", qg, k_all.astype(jnp.float32))
+    s = jnp.einsum("brkgd,bktd->bkrgt", qg, k_all.astype(jnp.float32),
+                   precision="highest")
     if slopes is not None:
         sl = jnp.asarray(slopes, jnp.float32).reshape(K, G)
         s = s + (sl[None, :, None, :, None]
                  * key_pos[:, None, None, None, :])
-    allowed = (key_ok[:, None, :]
-               & (key_pos[:, None, :] <= q_pos.astype(jnp.float32)[:, :, None]))
+    allowed = jnp.asarray(_dense_mask(q_pos, sfx_mask, Tt, window))
     s = jnp.where(allowed[:, None, :, None, :], s, -jnp.inf)
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     p = jnp.where(jnp.isfinite(s), p, 0.0)
     p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bkrgt,bktd->bkrgd", p, v_all.astype(jnp.float32))
+    o = jnp.einsum("bkrgt,bktd->bkrgd", p, v_all.astype(jnp.float32),
+                   precision="highest")
     return o.transpose(0, 2, 1, 3, 4).reshape(B, R, H, hd)
 
 
@@ -276,6 +290,141 @@ class TestCascadeKernel:
         got = cascade_attention(*case, block_n=16, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The fused kernel's key loop: only the chunks a row block may attend
+# ---------------------------------------------------------------------------
+
+# (query heads, kv heads) of the sweep cells' head layouts: mistral-7b
+# GQA 32/8, falcon-7b MQA 71/1, falcon-h1-34b 20/4, k-exaone-236b 64/8.
+CELL_LAYOUTS = {"mistral": (32, 8), "falcon": (71, 1), "falcon-h1": (20, 4),
+                "k-exaone": (64, 8)}
+
+
+def _ragged_case(H, K, Tt, lengths, R=384, seed=0, hd=16,
+                 dtype=jnp.float32):
+    """One row per entry of ``lengths``, right-padded to that many real
+    remainder tokens (0: the whole prefix is the trunk), positions as
+    the program derives them (trunk_len + decoder.mask_positions)."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), dtype)
+
+    mask = (np.arange(R)[None] < np.asarray(lengths)[:, None]).astype(
+        np.int32)
+    q_pos = Tt + np.maximum(np.cumsum(mask, -1) - 1, 0)
+    return (draw(B, R, H, hd), draw(B, R, K, hd), draw(B, R, K, hd),
+            draw(K, Tt, hd), draw(K, Tt, hd), jnp.asarray(mask),
+            jnp.asarray(q_pos))
+
+
+class TestFusedKeySkip:
+    @pytest.mark.parametrize("layout", list(CELL_LAYOUTS))
+    def test_matches_dense_at_the_cells_layouts(self, layout):
+        """Every sweep cell's head layout at the cells' window of 384
+        behind a 64-token trunk: a full row and a ragged one, so row
+        blocks past a row's end (pad queries) run too."""
+        H, K = CELL_LAYOUTS[layout]
+        case = _ragged_case(H, K, 64, [384, 301], seed=len(layout))
+        got = cascade_attention(*case, interpret=True)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(_dense_cascade_reference(*case)),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("window", [128, 40])
+    def test_window_blocks_that_miss_the_trunk(self, window):
+        """k-exaone's window layers: blocks whose window reaches the trunk
+        and blocks whose window does not, ragged and fully-masked rows
+        (a pad query's window starts below its slot's)."""
+        H, K = CELL_LAYOUTS["k-exaone"]
+        case = _ragged_case(H, K, 64, [384, 150, 0], seed=window)
+        got = cascade_attention(*case, window=jnp.int32(window),
+                                interpret=True)
+        exp = _dense_cascade_reference(*case, window=window)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                                   atol=2e-5)
+
+    def test_a_global_layer_through_the_window_operand(self):
+        """A global layer of a windowed model rides the same launch with
+        the window at decoder.NO_WINDOW: every causal key, the trunk in
+        every block."""
+        H, K = CELL_LAYOUTS["k-exaone"]
+        case = _ragged_case(H, K, 64, [384, 222], seed=5)
+        got = cascade_attention(*case, window=jnp.int32(decoder.NO_WINDOW),
+                                interpret=True)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(_dense_cascade_reference(*case)),
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("Tt", [48, 100])
+    def test_non_power_of_two_trunks_and_alibi(self, Tt):
+        """ALiBi slopes on trunk and own keys, trunks off the lane grid,
+        falcon-h1's group of 5."""
+        H, K = CELL_LAYOUTS["falcon-h1"]
+        q, sk, sv, tk, tv, mask, q_pos = _ragged_case(H, K, Tt, [384, 77, 0],
+                                                      seed=Tt)
+        slopes = decoder.alibi_slopes(H)
+        got = cascade_attention(q, sk, sv, tk, tv, mask, q_pos,
+                                alibi_slopes=slopes, interpret=True)
+        exp = _dense_cascade_reference(q, sk, sv, tk, tv, mask, q_pos,
+                                       slopes=slopes)
+        # Biases of ~200 at these positions leave float32 scores ~1e-5
+        # apart in any order of summation.
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                                   rtol=1e-4, atol=1e-4)
+
+    def test_writes_the_query_dtype(self):
+        """The result is written once, in q's dtype: bfloat16 in,
+        bfloat16 out, the two-leg path's float32 result rounded."""
+        H, K = CELL_LAYOUTS["falcon"]
+        case = _ragged_case(H, K, 64, [384, 129], dtype=jnp.bfloat16)
+        got = cascade_attention(*case, interpret=True)
+        two_leg = cascade_attention(*case, fused_suffix=False, interpret=True)
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(two_leg, np.float32),
+                                   atol=1e-2)
+
+    @pytest.mark.parametrize("window", [None, 128])
+    def test_key_pairs_are_the_kernels_own(self, monkeypatch, window):
+        """``key_pairs`` counts the keys of the chunks the kernel's bound
+        table names (read off the ``pallas_call`` operand the launch is
+        handed) and the pairs the dense mask admits."""
+        from lir_tpu.ops import cascade_prefill as cp
+
+        H, K = CELL_LAYOUTS["k-exaone"]
+        Tt, lengths = 64, [384, 150, 0]
+        case = _ragged_case(H, K, Tt, lengths, hd=8)
+        tables = []
+        real = cp.pl.pallas_call
+
+        def spy(kernel, **kw):
+            run = real(kernel, **kw)
+
+            def call(*args):
+                tables.append(np.asarray(args[0]))
+                return run(*args)
+            return call
+
+        monkeypatch.setattr(cp.pl, "pallas_call", spy)
+        win = None if window is None else jnp.int32(window)
+        cp._cascade_fused(*case, None, True, win)
+        R = case[0].shape[1]
+        r, chunk = cp.fused_edges(R, H // K, Tt)
+        lo, hi, trunk = tables[0].reshape(len(lengths), R // r, 3).T
+        keys = (np.minimum((hi + 1) * chunk, R) - lo * chunk
+                + trunk * Tt)
+        computed, attended = cp.key_pairs(case[6], case[5], Tt, H // K,
+                                          [window, window])
+        assert computed == 2 * r * int(keys.sum())
+        assert attended == 2 * int(_dense_mask(case[6], case[5], Tt,
+                                               window).sum())
+        full = 2 * len(lengths) * R * (Tt + R)     # every key, two layers
+        assert attended < computed < full
 
 
 # ---------------------------------------------------------------------------
@@ -472,6 +621,35 @@ class TestEngineRouting:
         assert off.cascade_stats.cascade_dispatches == 0
         for a, b in zip(f_on, f_off):
             _assert_fused_out_close(a, b, atol=5e-5)
+
+    def test_dispatch_counts_the_kernels_key_pairs(self, cascade_interpret):
+        """A cascade dispatch folds the fused kernel's (query, key) pairs
+        into CascadeStats from its own remainder mask: the pairs the dense
+        mask admits, and the keys of the chunks ``key_pairs`` reckons from
+        the kernel's bound function (held to the kernel's own table in
+        TestFusedKeySkip), over every layer; no device work."""
+        from lir_tpu.ops.cascade_prefill import key_pairs
+
+        rows = _trunk_rows()
+        t = np.asarray([5] * 4, np.int32)
+        eng = _fake_engine()
+        eng.decode_fused_shared(
+            [""] * 4, [""] * 4, t, t, new_tokens=3, conf_tokens=4,
+            pretokenized_a=[r + [5, 6] for r in rows],
+            pretokenized_b=[r + [7, 8] for r in rows], bucket=64,
+            sfx_buckets_ab=(8, 8), reuse_cache=True, n_real=4)
+        trunk, R = 32, 64 - 32
+        mask = (np.arange(R)[None] < np.asarray(
+            [len(r) - trunk for r in rows])[:, None]).astype(np.int32)
+        q_pos = trunk + np.maximum(np.cumsum(mask, -1) - 1, 0)
+        cfg = eng.cfg
+        stats = eng.cascade_stats
+        assert (stats.key_pairs_computed, stats.key_pairs_attended) == (
+            key_pairs(q_pos, mask, trunk, cfg.n_heads // cfg.n_kv_heads,
+                      [None] * cfg.n_layers))
+        assert stats.key_pairs_attended == cfg.n_layers * int(
+            _dense_mask(q_pos, mask, trunk).sum())
+        assert stats.key_pairs_computed >= stats.key_pairs_attended
 
     def test_ineligible_dispatch_counts_dense_fallback(self,
                                                        cascade_interpret):

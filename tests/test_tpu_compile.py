@@ -224,6 +224,47 @@ def test_cascade_two_leg_compiles(one_chip, layout, R, Tt, alibi, int8_qk):
              one_chip, fused_suffix=False, int8_qk=int8_qk)
 
 
+# The sweep cells' head layouts (query heads, kv heads, head dim) and the
+# window a layer may attend, at the cells' 384-slot remainder behind the
+# 64-token trunk: mistral-7b, falcon-7b, falcon-h1-34b, and k-exaone-236b
+# (its window a traced scalar, as the layer loop hands it).
+CELL_CASCADES = {"mistral-7b": (32, 8, 128, False),
+                 "falcon-7b": (71, 1, 64, False),
+                 "falcon-h1-34b": (20, 4, 128, False),
+                 "k-exaone-236b": (64, 8, 128, True)}
+
+
+@pytest.mark.parametrize("cell", list(CELL_CASCADES))
+def test_cascade_fused_compiles_at_the_cells(one_chip, cell):
+    """The fused kernel's row blocks, key chunks and scratch fit the
+    scoped VMEM limit at every cell's shapes, and the launch keeps its
+    name (``^cascade_attention``, what the rooflines read)."""
+    H, K, hd, windowed = CELL_CASCADES[cell]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    args = [((BATCH, 384, H, hd), bf16), ((BATCH, 384, K, hd), bf16),
+            ((BATCH, 384, K, hd), bf16), ((K, 64, hd), bf16),
+            ((K, 64, hd), bf16), ((BATCH, 384), i32), ((BATCH, 384), i32)]
+    fn = cascade_attention
+    if windowed:
+        args.append(((), i32))
+
+        def fn(*a):
+            return cascade_attention(*a[:7], window=a[7])
+    text = _compile(fn, args, one_chip).as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1
+    assert re.search(r"%cascade_attention[.\d]* = bf16\[", calls[0])
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_cascade_fused_compiles_at_a_long_window(one_chip, layout):
+    """A 2048 bucket's 1,984-slot remainder behind a 64-token trunk: the
+    row blocks shrink with the window, so the scores a program holds
+    stay inside the scoped VMEM limit."""
+    _compile(cascade_attention, _cascade_args(layout, 1984, 64), one_chip)
+
+
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("masked", [False, True])
 def test_flash_attention_compiles(one_chip, layout, masked):
