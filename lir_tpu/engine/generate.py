@@ -977,12 +977,12 @@ def _start(params, cfg: ModelConfig, program: Program, args: DispatchArgs):
         if k:
             raise ValueError("no speculative tail over per-row stop "
                              "tables (a grouped batch)")
-        if cfg.layer_kinds:
+        if cfg.trunk_apart:
             raise NotImplementedError(
                 f"{cfg.name}: a grouped batch gathers member rows out of "
-                "prefix rows, and a cache of layers that differ in kind is "
-                "not laid out by row (models/mixed.py); the sweep plans "
-                "none for such a model")
+                "prefix rows, and a cache that holds its trunk apart is not "
+                "laid out by row (models/mixed.py, models/mla.py); the sweep "
+                "plans none for such a model")
         from ..models import cache as cache_mod
 
         start = cache_mod.gather_rows(start, a.group_idx)

@@ -31,7 +31,8 @@ from ..observe import tracing
 from ..utils.logging import get_logger
 from ..utils.profiling import (CascadeStats, CheckpointStats, CompileStats,
                                ExpertStats, FaultStats, FillStats,
-                               GuardStats, KernelStats, PrefixCacheStats,
+                               GuardStats, KernelStats, LatentStats,
+                               PrefixCacheStats,
                                RecurrentStats, SparseStats, SpecStats,
                                WindowStats, cascade_decode_bytes_saved,
                                cascade_prefill_flops_saved)
@@ -334,6 +335,10 @@ class ScoringEngine:
         # "window").
         self.expert_stats = ExpertStats()
         self.window_stats = WindowStats()
+        # Latent attention (models/mla.py): key positions expanded
+        # against those needed, queries of each form, read back the same
+        # way (profiling.LatentStats; metrics source "latent").
+        self.latent_stats = LatentStats()
         self._spec_draft = None
         self._spec_pending: List[Any] = []
         self.spec_fault_plan = None
@@ -976,7 +981,7 @@ class ScoringEngine:
             donate_first=bool(self.rt.donate_first),
             held=hold, trunk_run=hold and ids != held,
             held_ids=ids if hold else held,
-            kinds=bool(getattr(self.cfg, "layer_kinds", ())))
+            kinds=bool(getattr(self.cfg, "trunk_apart", False)))
 
     def route_dispatch(self, d, new_tokens: int, conf_tokens: int,
                        stops_armed: bool,
@@ -1015,10 +1020,11 @@ class ScoringEngine:
         visits and those the dense mask admits, every layer's window
         (ops/cascade_prefill.key_pairs), from the dispatch's remainder
         mask and its positions as the program derives them
-        (decoder.mask_positions). The two-leg path and a model whose
-        layers differ in kind run no such kernel."""
+        (decoder.mask_positions). The two-leg path and a model whose cache
+        holds its trunk apart (layers that differ in kind, latent
+        attention) run no such kernel."""
         cfg = self.cfg
-        if (getattr(cfg, "layer_kinds", ()) or int8_qk
+        if (getattr(cfg, "trunk_apart", False) or int8_qk
                 or not cfg.cascade_fused_suffix):
             return
         from ..ops.cascade_prefill import key_pairs
@@ -1042,7 +1048,9 @@ class ScoringEngine:
         row — profiling.cascade_decode_bytes_saved), over both format
         branches' full decode budgets, at the extent of the dispatch's
         own ``cache`` (slots are its leaves' axis 2)."""
-        if not dtrunk or rows <= 1:
+        if not dtrunk or rows <= 1 or getattr(self.cfg, "latent", False):
+            # (Latent attention reads its trunk once a batch block on its
+            # own, ops/latent_attention.decode_main; no K/V split to count.)
             return
         t0 = jax.tree.leaves(cache)[0].shape[2]
         self.cascade_stats.count("cascade_decode_dispatches")
@@ -1661,7 +1669,7 @@ class ScoringEngine:
         try:
             with tracing.span("engine/dispatch_args"):
                 key = compile_plan.handoff_key(
-                    spec, bool(getattr(self.cfg, "layer_kinds", ())))
+                    spec, bool(getattr(self.cfg, "trunk_apart", False)))
                 scratch = self._handoff.take(key) if reuse else None
                 first = scratch is None and reuse and self.rt.donate_first
                 spec = dataclasses.replace(

@@ -603,10 +603,10 @@ def _ragged_planner(engine, new_tokens, conf_tokens):
         edge_grid=edge_grid,
         min_group_prefix=engine.rt.sweep_group_min_prefix,
         min_group_cells=engine.rt.sweep_group_min_cells,
-        # A grouped batch gathers cache rows; a cache of layers that
-        # differ in kind is not laid out by row (models/mixed.py).
+        # A grouped batch gathers cache rows; a cache that holds its
+        # trunk apart is not laid out by row (models/mixed.py, mla.py).
         group_cells=(engine.rt.sweep_group_min_cells > 0
-                     and not getattr(engine.cfg, "layer_kinds", ())),
+                     and not getattr(engine.cfg, "trunk_apart", False)),
         cached_probe=cached_probe,
         fused_decode=engine.rt.fused_decode,
         stats=OccupancyStats(), token_cap=engine.rt.dispatch_tokens)
@@ -943,6 +943,8 @@ def _run_pipelined(engine, model_name, todo, target_ids, results_path,
         if routed is not None:
             engine.expert_stats.fold(routed.counts, engine.cfg.expert_top_k)
             engine.window_stats.fold(routed.keys)
+            if routed.latent is not None:
+                engine.latent_stats.fold(routed.latent)
         if last_readback is not None:
             last_readback[:] = [time.monotonic()]
         if spec_rec is not None:

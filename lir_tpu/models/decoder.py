@@ -67,6 +67,10 @@ SPARSE_INTERPRET_ON_CPU = False
 # with experts (models/moe.py); production CPU runs lax.ragged_dot.
 MOE_INTERPRET_ON_CPU = False
 
+# Same hook for the latent-attention kernels (ops/latent_attention) of a
+# model with latent attention (models/mla.py).
+LATENT_INTERPRET_ON_CPU = False
+
 # The window of a global layer where windows are per layer: no key is
 # that far behind a query.
 NO_WINDOW = 1 << 30
@@ -174,8 +178,47 @@ def _act(x: jax.Array, kind: str) -> jax.Array:
     return jax.nn.relu(x)
 
 
-def _rope_sincos(positions: jax.Array, rotary_dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """sin/cos tables for rotate-half RoPE. positions: (..., S) int."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1`` (1 where
+    the factor does not stretch)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(rotary_dim: int, theta: float, scaling: tuple):
+    """YaRN's frequencies (numpy float32, rotary_dim / 2): the plain ones
+    on the pairs that turn fast over ``original`` positions, those
+    divided by ``factor`` on the slow ones, a linear ramp between the
+    pairs that turn ``beta_fast`` and ``beta_slow`` times (DeepSeek-V3's
+    ``yarn_find_correction_range``). ``scaling`` is ModelConfig's
+    ``rope_scaling``."""
+    import numpy as np
+
+    factor, original, beta_fast, beta_slow = (float(v) for v in scaling[:4])
+    d = rotary_dim
+    extra = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def pair(rotations):
+        return d * math.log(original / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 0.001), 0, 1)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def _rope_sincos(positions: jax.Array, rotary_dim: int, theta: float,
+                 scaling: tuple = ()) -> Tuple[jax.Array, jax.Array]:
+    """sin/cos tables for rotate-half RoPE. positions: (..., S) int.
+    ``scaling``: YaRN's (ModelConfig.rope_scaling), its frequencies
+    (:func:`yarn_inv_freq`) and its factor on sin and cos, ``mscale(
+    factor, mscale) / mscale(factor, mscale_all_dim)``."""
+    if scaling:
+        inv_freq = jnp.asarray(yarn_inv_freq(rotary_dim, theta, scaling))
+        angles = positions.astype(jnp.float32)[..., None] * inv_freq
+        factor, _, _, _, mscale, mscale_all_dim = scaling
+        m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+        return jnp.sin(angles) * m, jnp.cos(angles) * m
     inv_freq = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., S, rd/2)
     return jnp.sin(angles), jnp.cos(angles)
@@ -817,7 +860,10 @@ def refuse_recurrent(cfg, what: str) -> None:
     state at a page's edge), speculative verify (no roll-back to the last
     accepted token), the piggyback chain (one parked cache, two branches),
     tiering and migration (pages again). They refuse; none answers
-    wrongly."""
+    wrongly. A model with latent attention (models/mla.py) holds a latent
+    a token in a cache laid out apart from the rows' own slots, which no
+    page, verify window or parked branch holds yet (ROADMAP M2)."""
+    refuse_latent(cfg, what)
     if getattr(cfg, "carries_state", False):
         raise NotImplementedError(
             f"{cfg.name}: {what} cannot carry a recurrent state (a "
@@ -829,6 +875,20 @@ def refuse_recurrent(cfg, what: str) -> None:
             "a model with experts carries its routing counts beside K/V "
             "(models/moe.RouteCounts), one with window layers a window a "
             "layer (ROADMAP M1, M3)")
+
+
+def refuse_latent(cfg, what: str) -> None:
+    """The one plain error of everything that cannot run latent attention
+    yet (ROADMAP M2): the page pool and radix index, the speculative
+    verify window, the piggyback chain and the server hold or move
+    per-head K/V by row, and a model with latent attention caches one
+    latent a token in a cache that holds its trunk apart from the rows'
+    own slots (models/mla.py)."""
+    if getattr(cfg, "kv_lora_rank", 0):
+        raise NotImplementedError(
+            f"{cfg.name}: {what} holds per-head K/V by row, and a model "
+            "with latent attention caches one latent a token beside its "
+            "rows' own slots (models/mla.py); not yet (ROADMAP M2)")
 
 
 def rewind(cache, snapshot):
@@ -1089,6 +1149,17 @@ def _mixed(cfg: ModelConfig, attn_impl=None):
     return mixed
 
 
+def _latent(cfg: ModelConfig, attn_impl=None):
+    """models/mla.py, which runs a model with latent attention behind
+    every entry point below (``cfg.kv_lora_rank``)."""
+    from . import mla
+
+    if attn_impl is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: sequence-parallel attention over latent attention")
+    return mla
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             attn_mask: Optional[jax.Array] = None,
             attn_impl=None) -> jax.Array:
@@ -1102,6 +1173,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         attn_mask = jnp.ones_like(tokens)
     if cfg.layer_kinds:
         return _mixed(cfg, attn_impl).forward(params, cfg, tokens, attn_mask)
+    if cfg.kv_lora_rank:
+        return _latent(cfg, attn_impl).forward(params, cfg, tokens, attn_mask)
     positions = mask_positions(attn_mask)
     x = _embed(params, cfg, tokens, positions)
     sin = cos = None
@@ -1134,6 +1207,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
     """
     if cfg.layer_kinds:
         return _mixed(cfg).init_cache(cfg, batch, max_len, 0, dtype)
+    if cfg.kv_lora_rank:
+        return _latent(cfg).init_cache(cfg, batch, max_len, 0, dtype)
     shape = (cfg.n_layers, cfg.n_kv_heads, max_len, batch, cfg.head_dim)
     if cfg.kv_cache_int8:
         def side():
@@ -1185,6 +1260,9 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     if cfg.layer_kinds:
         return _mixed(cfg, attn_impl).prefill(params, cfg, tokens, attn_mask,
                                               max_len)
+    if cfg.kv_lora_rank:
+        return _latent(cfg, attn_impl).prefill(params, cfg, tokens,
+                                               attn_mask, max_len)
     B, S = tokens.shape
     positions = mask_positions(attn_mask)
     x = _embed(params, cfg, tokens, positions)
@@ -1254,6 +1332,9 @@ def extend(params: Params, cfg: ModelConfig, cache, suffix_tokens: jax.Array,
     if cfg.layer_kinds:
         return _mixed(cfg).extend(params, cfg, cache, suffix_tokens,
                                   suffix_mask, cache_mask, start_index)
+    if cfg.kv_lora_rank:
+        return _latent(cfg).extend(params, cfg, cache, suffix_tokens,
+                                   suffix_mask, cache_mask, start_index)
     B, S2 = suffix_tokens.shape
     key_positions = mask_positions(cache_mask)
     qpos = lax.dynamic_slice_in_dim(key_positions, start_index, S2, axis=1)
@@ -1302,6 +1383,10 @@ def cascade_extend(params: Params, cfg: ModelConfig, trunk_cache,
         return _mixed(cfg).cascade_extend(params, cfg, trunk_cache,
                                           rem_tokens, rem_mask, trunk_len,
                                           total_len)
+    if cfg.kv_lora_rank:
+        return _latent(cfg).cascade_extend(params, cfg, trunk_cache,
+                                           rem_tokens, rem_mask, trunk_len,
+                                           total_len)
     B, R = rem_tokens.shape
     L, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     qpos = trunk_len + mask_positions(rem_mask)                  # (B, R)
@@ -1436,6 +1521,9 @@ def decode_step(params: Params, cfg: ModelConfig, cache, token: jax.Array,
     if cfg.layer_kinds:
         return _mixed(cfg).decode_step(params, cfg, cache, token, position,
                                        step_index, prompt_mask)
+    if cfg.kv_lora_rank:
+        return _latent(cfg).decode_step(params, cfg, cache, token, position,
+                                        step_index, prompt_mask)
     B = token.shape[0]
     x = _embed(params, cfg, token[:, None], position[:, None])
     sin = cos = None

@@ -26,6 +26,7 @@ routed. Masked (padding) tokens route nowhere.
 from __future__ import annotations
 
 import dataclasses
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -48,10 +49,13 @@ class RouteCounts:
     ``counts``, the routing counts of every expert layer, ``(expert
     layers, 2 * held + 1)`` int32; ``keys``, (3,) int32, what the window
     layers' decode kernel calls fetched (ops/flash_decode._window_keys:
-    key slots brought in, key slots attended, calls)."""
+    key slots brought in, key slots attended, calls); ``latent``, (4,)
+    int32 in a model with latent attention, None otherwise: the latent
+    counters of models/mla.py."""
 
     counts: jax.Array
     keys: jax.Array
+    latent: Any = None
 
 
 def counts_width(cfg) -> int:
@@ -59,8 +63,11 @@ def counts_width(cfg) -> int:
 
 
 def empty_counts(cfg) -> RouteCounts:
+    latent = (jnp.zeros((4,), jnp.int32)
+              if getattr(cfg, "kv_lora_rank", 0) else None)
     return RouteCounts(jnp.zeros((cfg.expert_layers, counts_width(cfg)),
-                                 jnp.int32), jnp.zeros((3,), jnp.int32))
+                                 jnp.int32), jnp.zeros((3,), jnp.int32),
+                       latent)
 
 
 def _kernels_lower(cfg) -> bool:

@@ -200,6 +200,24 @@ class ModelConfig:
     shared_experts: int = 0
     router_scale: float = 1.0
     first_dense_layers: int = 0
+    # Latent attention (MLA: DeepSeek-V3, Kimi-K2): ``kv_lora_rank`` > 0
+    # makes every layer's attention project the normed input down to a
+    # query latent of ``q_lora_rank`` (RMSNorm) and a key-value latent of
+    # ``kv_lora_rank`` (RMSNorm) beside ONE rotated key part of
+    # ``qk_rope_dim`` that every head shares; each of the ``n_heads``
+    # heads expands its query (``qk_nope_dim`` + ``qk_rope_dim``), key
+    # (``qk_nope_dim``, then the shared rotated part) and value
+    # (``v_head_dim``) from them. The cache holds the latent and the
+    # rotated key part alone (models/mla.py). ``rope_scaling`` is YaRN's
+    # ``(factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim)`` or empty (plain rotation). 0: no latent
+    # attention, every model before this one bit for bit.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: tuple = ()
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -250,6 +268,32 @@ class ModelConfig:
                 self.num_experts, self.name
             assert 0 < self.expert_top_k <= self.num_experts, self.name
             assert 0 <= self.first_dense_layers < self.n_layers, self.name
+        object.__setattr__(self, "rope_scaling", tuple(self.rope_scaling))
+        if self.kv_lora_rank:
+            if (self.layer_kinds or self.has_mixer or self.parallel_block
+                    or self.kv_cache_int8 or self.layer_windowed
+                    or self.post_norm or self.pos_embedding != "rotary"):
+                raise ValueError(
+                    f"{self.name}: latent attention runs in a sequential "
+                    "pre-norm rotary block with a float latent cache")
+            assert self.head_dim == self.qk_nope_dim + self.qk_rope_dim, \
+                self.name
+            assert self.rope_scaling == () or len(self.rope_scaling) == 6, \
+                self.name
+
+    @property
+    def latent(self) -> bool:
+        """Latent attention (models/mla.py)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def trunk_apart(self) -> bool:
+        """The cache holds a dispatch's shared trunk ONCE, apart from the
+        rows' own slots, and is not laid out by row: layers that differ
+        in kind (models/mixed.py) and latent attention (models/mla.py).
+        A grouped batch cannot gather such a cache's rows, and one trunk
+        length is one cache shape."""
+        return bool(self.layer_kinds) or self.latent
 
     @property
     def rotary_dim(self) -> int:
@@ -507,6 +551,39 @@ def k_exaone_236b_l12() -> ModelConfig:
     return k_exaone_236b(n_layers=12, vocab_size=19200, experts_held=(0, 16))
 
 
+def kimi_k2_6(n_layers: int = 61, vocab_size: int = 163840,
+              experts_held: tuple = (0, 384)) -> ModelConfig:
+    """moonshotai/Kimi-K2.6 (config.json, ``model_type`` kimi_k2, the
+    DeepSeek-V3 layout; its MoonViT vision tower is not here): d 7168,
+    64 heads of latent attention (query latent 1536, key-value latent
+    512, 128 unrotated + 64 rotated query-key channels a head, values of
+    128), YaRN rotation (factor 64 over 4096 positions, beta 32 / 1,
+    theta 50000); layer 0 a gated SiLU MLP of 18432, every later layer a
+    sigmoid router over 384 experts of 2048 (top 8 by score + selection
+    bias, normalized, scaled by 2.827) beside one shared expert of 2048;
+    untied head. ``n_layers``, ``vocab_size`` and ``experts_held`` are
+    what a cut may change (benchmarks/configs/kimi-k2-6.json)."""
+    return ModelConfig(
+        name="kimi-k2-6", vocab_size=vocab_size, hidden_size=7168,
+        n_layers=n_layers, n_heads=64, n_kv_heads=1, head_dim=192,
+        intermediate_size=18432, max_seq_len=262144, rope_theta=50000.0,
+        norm_eps=1e-5, use_flash_attention=False,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128,
+        rope_scaling=(64.0, 4096, 32.0, 1.0, 1.0, 1.0),
+        num_experts=384, experts_held=experts_held, expert_top_k=8,
+        expert_size=2048, shared_experts=1, router_scale=2.827,
+        first_dense_layers=1)
+
+
+def kimi_k2_6_l13() -> ModelConfig:
+    """One chip of the 160-chip deployment PERF.md §4 sets out: the
+    first pipeline stage's 13 layers (the dense layer and 12 expert
+    layers), experts 0-11 of every expert layer (32-way expert
+    parallel) and a 20,480-row slice of the embedding and the head."""
+    return kimi_k2_6(n_layers=13, vocab_size=20480, experts_held=(0, 12))
+
+
 def bloom_7b1() -> ModelConfig:
     return ModelConfig(
         name="bloom-7b1", vocab_size=250880, hidden_size=4096, n_layers=30,
@@ -617,6 +694,7 @@ REGISTRY = {
     "falcon-7b": falcon_7b, "falcon-h1-34b": falcon_h1_34b,
     "falcon-h1-34b-l8": falcon_h1_34b_l8, "minicpm-sala": minicpm_sala,
     "k-exaone-236b": k_exaone_236b, "k-exaone-236b-l12": k_exaone_236b_l12,
+    "kimi-k2-6": kimi_k2_6, "kimi-k2-6-l13": kimi_k2_6_l13,
     "bloom-7b1": bloom_7b1, "opt": opt,
     "t5-v1_1": t5_v1_1, "flan-t5": flan_t5, "t0-3b": t0_3b,
 }

@@ -134,6 +134,10 @@ STATS_SCHEMA: Dict[str, Tuple[str, ...]] = {
         "max_expert_picks", "dispatches",
     ),
     "WindowStats": ("key_slots_read", "key_slots_needed", "calls"),
+    "LatentStats": (
+        "keys_expanded", "keys_needed", "queries_absorbed",
+        "queries_expanded", "dispatches",
+    ),
     "MemStats": (
         "ledger_bytes", "budget_bytes", "pressure", "rung",
         "rung_downs", "rung_ups", "admits", "denials", "oom_events",
@@ -311,6 +315,8 @@ def engine_registry(engine, sink=None,
         reg.register("moe", engine.expert_stats)
     if getattr(engine, "window_stats", None) is not None:
         reg.register("window", engine.window_stats)
+    if getattr(engine, "latent_stats", None) is not None:
+        reg.register("latent", engine.latent_stats)
     if getattr(engine, "governor", None) is not None:
         # HBM-governor gauges (engine/hbm.py): ledger/pressure/rung
         # land in the snapshot next to device_memory_stats(), so budget

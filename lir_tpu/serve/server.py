@@ -51,6 +51,7 @@ from ..engine import tokens as tok
 from ..faults import (CLOSED, HALF_OPEN, CircuitBreaker, degrade_dispatch,
                       is_program_error)
 from ..guard import numerics
+from ..models import decoder
 from ..observe import registry as metrics_mod
 from ..observe import tracing
 from ..utils.logging import get_logger
@@ -98,6 +99,7 @@ class ScoringServer:
         # the batcher snapshots it; every dispatch then pays prefill
         # only for its rows' unshared suffixes, across requests and
         # batches, with results bitwise-identical to the unpaged path.
+        decoder.refuse_latent(getattr(engine, "cfg", None), "the serve path")
         if self.config.prefix_cache:
             engine.enable_prefix_cache()
         self.batcher = ContinuousBatcher(engine, self.stats,

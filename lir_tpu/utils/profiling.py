@@ -1102,6 +1102,60 @@ class WindowStats:
 
 
 @dataclasses.dataclass
+class LatentStats:
+    """Counters of latent attention (models/mla.py; metrics source
+    ``latent``), summed over layers. All stay 0 for a model without it.
+
+    - ``keys_expanded``: key positions (a shared trunk's and the rows' own)
+      a prefill, window or format-suffix pass turned into per-head keys
+      and values, each pass its own: the pass expands what it reads.
+    - ``keys_needed``: the key positions those passes' queries attend,
+      each counted once a dispatch (a trunk where the dispatch first reads
+      it, every row's own real keys): 100 x expanded / needed is 100 when
+      no key is expanded twice.
+    - ``queries_expanded``: real queries attended in the expanded form;
+      ``queries_absorbed``: decode queries (rows x steps) attended in the
+      absorbed form.
+    - ``dispatches``: dispatches whose counts were read.
+
+    The device counts them (the cache's models/moe.RouteCounts leaf,
+    ``latent``), and the sweep's drain folds them in as it reads the
+    dispatch's answers (:meth:`fold`); a trunk program's own pass is not
+    a dispatch and is not counted."""
+
+    keys_expanded: int = 0
+    keys_needed: int = 0
+    queries_absorbed: int = 0
+    queries_expanded: int = 0
+    dispatches: int = 0
+
+    def __post_init__(self) -> None:
+        import threading
+
+        self._lock = threading.Lock()
+
+    def fold(self, latent) -> None:
+        """Add one dispatch's (expanded, needed, absorbed, queries)."""
+        expanded, needed, absorbed, queries = (int(n) for n in latent)
+        with self._lock:
+            self.keys_expanded += expanded
+            self.keys_needed += needed
+            self.queries_absorbed += absorbed
+            self.queries_expanded += queries
+            self.dispatches += 1
+
+    def summary(self) -> Dict[str, object]:
+        with self._lock:
+            return {
+                "keys_expanded": self.keys_expanded,
+                "keys_needed": self.keys_needed,
+                "queries_absorbed": self.queries_absorbed,
+                "queries_expanded": self.queries_expanded,
+                "dispatches": self.dispatches,
+            }
+
+
+@dataclasses.dataclass
 class RecurrentStats:
     """Counters of the second kind of per-sequence state, the recurrent
     state a state-space mixer keeps beside K/V (models/decoder._mixer;

@@ -993,3 +993,96 @@ def test_the_expert_cells_dispatch_programs_fit(one_chip, on_tpu, program):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
     assert "expert_matmul_gate_up" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (kimi-k2-6.sweep-doc16k): the two kernels at the
+# published widths and the cell's 40-row dispatch behind its held trunk
+# ---------------------------------------------------------------------------
+# 64 heads; expanded keys of 128 + 64, values of 128; the latent 512 + 64.
+
+@pytest.mark.parametrize("case", ["trunk", "window", "suffix"])
+def test_latent_prefill_compiles(one_chip, case):
+    from lir_tpu.ops import latent_attention as la
+
+    H, dq, dv = 64, 192, 128
+    rows, own_len = {"trunk": (0, 0), "window": (40, 128),
+                     "suffix": (40, 144)}[case]
+    queries = {"trunk": DOC_TRUNK, "window": 40 * 128, "suffix": 40 * 16}[case]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    args = [((1, H, queries, dq), bf), ((1, H, DOC_TRUNK, dq), bf),
+            ((1, H, DOC_TRUNK, dv), bf), ((1,), i32), ((1, queries), i32)]
+    span = 0
+    if rows:
+        No = rows * own_len
+        args += [((1, H, No, dq), bf), ((1, H, No, dv), bf), ((1, No), i32),
+                 ((1, No), i32), ((1, queries), i32)]
+        span = (la.QUERY_TILE // (queries // rows) + 2) * own_len
+
+    def fn(q, mk, mv, ml, qp, *own):
+        return la.prefill(q, mk, mv, ml, qp, own or None, sm_scale=0.1,
+                          own_span=span)
+
+    text = _compile(fn, args, one_chip).as_text()
+    assert "latent_prefill" in text
+
+
+@pytest.mark.parametrize("rows", [40, 1])
+def test_latent_decode_compiles(one_chip, rows):
+    from lir_tpu.ops import latent_attention as la
+
+    args = [((rows, 64, 576), jnp.bfloat16),
+            ((13, 1, DOC_TRUNK, 576), jnp.bfloat16), ((1,), jnp.int32),
+            ((), jnp.int32)]
+
+    def fn(q, latent, ml, layer):
+        return la.decode_main(q, latent, ml, value_width=512, sm_scale=0.1,
+                              layer=layer)
+
+    assert "latent_decode" in _compile(fn, args, one_chip).as_text()
+
+
+def test_the_latent_cells_held_dispatch_fits(one_chip, on_tpu):
+    """kimi-k2-6.sweep-doc16k's 40-row dispatch behind the held latent
+    trunk, every layer: 9.0 GB of weights and the 0.24 GB trunk as
+    arguments beside its temporaries within the chip's 16.9 GB; no
+    per-head K/V in what it returns."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from lir_tpu.engine import compile_plan
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+    sys.path.insert(0, str(bench))
+    from harness import builders
+    from references import mla_moe as ref
+
+    raw = json.loads((bench / "configs" / "kimi-k2-6.json").read_text())
+    spec = ref.spec_from_config("kimi-k2-6", raw)
+    params = _shaped(jax.eval_shape(lambda: builders.wrap_quantized(
+        ref.weights(spec, ref.seed_key(0)))), one_chip)
+    engine = builders.build_engine(params, builders.program_config(spec, ref),
+                                   raw["lir_tpu"]["runtime"])
+    doc = tuple(range(DOC_TRUNK))
+    route = engine.route("shared", DOC_EDGE, BATCH, 0, 64, 64, 4, 8, True,
+                         [doc + (20_000 + r,) * 100 for r in range(BATCH)],
+                         BATCH, held=doc)
+    assert route.held and route.trunk == DOC_TRUNK
+    (spec_,) = route.planned(False)
+    compiled = compile_plan._lower(engine, spec_).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert 9.6e9 < mem.argument_size_in_bytes < 9.9e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM
+    for kernel in ("latent_prefill", "latent_decode", "expert_matmul"):
+        assert kernel in text
+    cache = generate.greedy_decode_dispatch.eval_shape(
+        engine.params, engine.cfg, compile_plan.dispatch_program(engine, spec_),
+        compile_plan.dispatch_args(engine, spec_))[-1]
+    assert cache[1].shape == (13, 1, DOC_TRUNK, 576)
+    assert cache[0].shape[:2] == (13, BATCH) and cache[0].shape[3] == 576
+    # The layer and decode loops update the stacked latent where it lies.
+    big = list(cache[:2])
+    assert _cache_moves(text, [_hlo_shape(leaf) for leaf in big],
+                        [_hlo_shape(leaf, 1) for leaf in big],
+                        whole_layer_reads=False) == []
